@@ -9,8 +9,11 @@ hardware: the CPU tests (tests/test_engine_backends.py, tests/
 test_fused.py) compare the same paths where matmuls are f32-exact, so a
 wrong digit decomposition could only be caught here.
 
-Exit code 0 = all paths agree; invoked by tests/test_tpu_equivalence.py
-(skipped off-TPU) and runnable standalone in the bench environment.
+Exit code 0 = all paths agree.  Run it by hand on the chip (one process:
+``python benchmarks/tpu_equivalence.py``); it is not part of the pytest
+suite, whose outcome must not depend on whether a chip is reachable.  The
+full-width served-vs-plain comparison (seg, SALSA, packed wire, explain)
+is chip_smoke.py's equivalence phase.
 """
 
 from __future__ import annotations

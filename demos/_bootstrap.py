@@ -1,4 +1,4 @@
-"""Shared demo setup: repo-root imports + platform selection.
+"""Shared demo setup: repo-root imports + platform default.
 
 QPS-based demos assume entries are much faster than the 1 s statistic
 window; on very slow hosts (cold XLA compiles) a demo may show fewer
@@ -10,11 +10,9 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+# demos are documentation: they default to CPU (demos/README.md); set
+# JAX_PLATFORMS yourself to run one elsewhere
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
 
 def warm(client, resource: str = "__warmup__") -> None:

@@ -7,7 +7,8 @@ model on top: admitted requests enter a FIFO backend that serves at most
 more steps to finish — latency is queue wait plus service.  Offered
 load under capacity rides at base latency; 2× capacity with unbounded
 admission grows the queue linearly and latency collapses (the
-BENCH_r05 req_p99 ≈ 1 s failure mode, reproduced in miniature), while
+round-5 on-chip client run's req_p99 ≈ 1 s failure mode, reproduced in
+miniature), while
 the adaptive gate bounds in-flight work at the BBR product and keeps
 latency flat at ~capacity goodput.  Everything is engine-time
 pure: the same inputs replay the same admissions, ladder transitions and

@@ -63,9 +63,11 @@ class ConstHoistPass(JaxprPass):
                         "jnp.int32(...)) so it inlines as a literal "
                         "(see ops/rowmin.py:36)",
                     )
-                elif (
-                    isinstance(c, np.ndarray) and c.nbytes > _BIG_NP_CONST_BYTES
-                ):
+                    continue
+                # a closed-over numpy array reaches the jaxpr wrapped in
+                # jax's typed-literal holder (its ndarray sits in .val)
+                c = np.asarray(getattr(c, "val", c))
+                if c.nbytes > _BIG_NP_CONST_BYTES:
                     yield self.finding(
                         entry,
                         f"large numpy const {c.dtype}{tuple(c.shape)} "

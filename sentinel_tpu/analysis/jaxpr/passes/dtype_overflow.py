@@ -169,8 +169,8 @@ def _run_body(
     """Propagate factors through a ClosedJaxpr body (consts untainted).
 
     ``in_mags``: known constant magnitudes of the call's operands — a
-    literal divisor crossing a pjit boundary (``t // 500`` traces to
-    ``pjit[floor_divide] t 500``) must stay a known constant inside the
+    literal divisor crossing a jit boundary (``t // 500`` traces to
+    ``jit[name=floor_divide] t 500``) must stay a known constant inside the
     body or the division never shrinks the scale factor."""
     jx = closed.jaxpr if hasattr(closed, "jaxpr") else closed
     consts = list(getattr(closed, "consts", ()))
@@ -218,7 +218,7 @@ def _scan_eqns(ctx: _Ctx, jx, env: Dict[Any, float], const_env: Dict[Any, Any]) 
 
         # -- control flow: recurse with positional mapping ------------------
         mags = [_literal_mag(v, const_env) for v in eqn.invars]
-        if name in ("pjit", "closed_call", "core_call", "xla_call", "custom_jvp_call", "custom_vjp_call", "remat", "checkpoint"):
+        if name in ("jit", "closed_call", "core_call", "xla_call", "custom_jvp_call", "custom_vjp_call", "remat", "checkpoint"):
             closed = _sub_closed(eqn.params, "jaxpr") or _sub_closed(
                 eqn.params, "call_jaxpr"
             )

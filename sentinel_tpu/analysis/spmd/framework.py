@@ -47,6 +47,19 @@ DTYPE_BYTES = {
     "c128": 16,
 }
 
+
+def hlo_dtype(name: str) -> str:
+    """HLO spelling of a numpy dtype name ("int32" -> "s32")."""
+    import numpy as np
+
+    if name == "bfloat16":
+        return "bf16"
+    dt = np.dtype(name)
+    if dt.kind == "b":
+        return "pred"
+    return {"i": "s", "u": "u", "f": "f"}[dt.kind] + str(dt.itemsize * 8)
+
+
 #: collective ops the ledger tracks (async "-start" forms fold into the
 #: base kind; "-done" carries no new transfer)
 COLLECTIVE_KINDS = (
@@ -187,12 +200,63 @@ class SpmdPass:
 # -- HLO parsing -------------------------------------------------------------
 
 # `  %all-gather.12 = s32[2,512]{1,0} all-gather(...), ..., metadata={...
-# source_file="/abs/sentinel_tpu/ops/tables.py" source_line=246 ...}`
+# stack_frame_id=72}` — the frame id indexes the module's stack-frame
+# tables, printed once in the HLO text header:
+#   FileNames        `3 "/abs/sentinel_tpu/ops/tables.py"`
+#   FileLocations    `9 {file_name_id=3 function_name_id=5 line=255 ...}`
+#   StackFrames      `72 {file_location_id=9 parent_frame_id=70}`
+# A frame's own location is the innermost user line of the op.
 _INSTR_RE = re.compile(
     r"=\s+(?P<dtype>\w+)\[(?P<shape>[\d,]*)\]\S*\s+"
     r"(?P<kind>" + "|".join(COLLECTIVE_KINDS) + r")(?:-start)?\("
 )
-_SRC_RE = re.compile(r'source_file="([^"]+)"\s+source_line=(\d+)')
+# `%all-reduce.20 = (f32[1]{0}, f32[63]{0}) all-reduce(...)`: XLA's
+# combiner merges same-kind collectives into ONE tuple-shaped op — each
+# element is still its own transfer
+_TUPLE_INSTR_RE = re.compile(
+    r"=\s+\((?P<elems>[^()]*)\)\s+"
+    r"(?P<kind>" + "|".join(COLLECTIVE_KINDS) + r")\("
+)
+_ELEM_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+_FRAME_RE = re.compile(r"stack_frame_id=(\d+)")
+_TABLE_ROW_RE = re.compile(r"\s*(\d+)\s+(.*)")
+_LOC_RE = re.compile(r"file_name_id=(\d+)\b.*?\bline=(\d+)")
+_FRAME_LOC_RE = re.compile(r"file_location_id=(\d+)")
+
+
+def _frame_sources(lines: List[str]) -> Dict[int, Tuple[str, int]]:
+    """stack_frame_id -> (absolute file, line) from the header tables."""
+    files: Dict[int, str] = {}
+    locs: Dict[int, Tuple[int, int]] = {}
+    frames: Dict[int, int] = {}
+    section = None
+    for ln in lines:
+        head = ln.strip()
+        if head in ("FileNames", "FunctionNames", "FileLocations", "StackFrames"):
+            section = head
+            continue
+        if section is None:
+            continue
+        row = _TABLE_ROW_RE.fullmatch(ln)
+        if row is None:
+            section = None  # blank line / first computation ends a table
+            continue
+        idx, rest = int(row.group(1)), row.group(2)
+        if section == "FileNames":
+            files[idx] = rest.strip('"')
+        elif section == "FileLocations":
+            m = _LOC_RE.search(rest)
+            if m:
+                locs[idx] = (int(m.group(1)), int(m.group(2)))
+        elif section == "StackFrames":
+            m = _FRAME_LOC_RE.search(rest)
+            if m:
+                frames[idx] = int(m.group(1))
+    out: Dict[int, Tuple[str, int]] = {}
+    for fid, loc in frames.items():
+        if loc in locs and locs[loc][0] in files:
+            out[fid] = (files[locs[loc][0]], locs[loc][1])
+    return out
 
 
 def parse_hlo_collectives(
@@ -200,22 +264,29 @@ def parse_hlo_collectives(
 ) -> List[Collective]:
     """Every collective instruction in an optimized-HLO dump.
 
-    Shapes are the per-device result buffers the partitioner printed;
-    tuple-shaped results (async forms) are skipped at the "-done" side so
-    each transfer counts once.
+    Shapes are the per-device result buffers the partitioner printed.
+    A combined (tuple-shaped) collective counts once per element; the
+    "-done" side of an async pair is skipped so each transfer counts once.
     """
+    lines = hlo_text.splitlines()
+    sources = _frame_sources(lines)
     out: List[Collective] = []
-    for ln in hlo_text.splitlines():
+    for ln in lines:
         m = _INSTR_RE.search(ln)
-        if not m:
-            continue
-        shape = tuple(int(d) for d in m.group("shape").split(",") if d)
+        if m:
+            kind = m.group("kind")
+            elems = [(m.group("dtype"), m.group("shape"))]
+        else:
+            m = _TUPLE_INSTR_RE.search(ln)
+            if not m:
+                continue
+            kind = m.group("kind")
+            elems = _ELEM_RE.findall(m.group("elems"))
         src: Optional[str] = None
         line = 0
-        sm = _SRC_RE.search(ln)
-        if sm:
-            fn = sm.group(1)
-            line = int(sm.group(2))
+        fm = _FRAME_RE.search(ln)
+        if fm and int(fm.group(1)) in sources:
+            fn, line = sources[int(fm.group(1))]
             if repo_root:
                 try:
                     rel = os.path.relpath(fn, repo_root).replace(os.sep, "/")
@@ -224,15 +295,16 @@ def parse_hlo_collectives(
                 src = None if rel.startswith("..") else rel
             else:
                 src = fn
-        out.append(
-            Collective(
-                kind=m.group("kind"),
-                dtype=m.group("dtype"),
-                shape=shape,
-                source=src,
-                line=line,
+        for dtype, dims in elems:
+            out.append(
+                Collective(
+                    kind=kind,
+                    dtype=dtype,
+                    shape=tuple(int(d) for d in dims.split(",") if d),
+                    source=src,
+                    line=line,
+                )
             )
-        )
     return out
 
 

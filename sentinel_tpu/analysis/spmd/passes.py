@@ -14,6 +14,7 @@ from sentinel_tpu.analysis.spmd.framework import (
     SpmdPass,
     SpmdProgram,
     group_collectives,
+    hlo_dtype,
     ledger_bytes,
 )
 
@@ -165,7 +166,14 @@ class ImplicitReshardPass(SpmdPass):
                 by_global.setdefault(p.global_bytes, []).append(p.name)
                 for i, axis in enumerate(p.spec):
                     if axis is not None:
-                        dim_owners.setdefault(p.shape[i], set()).add(p.name)
+                        # keyed by dtype too: a gathered slice OF the
+                        # leaf carries the leaf's dtype, so an unrelated
+                        # gather that merely shares the dim size (the
+                        # f32 timeline rows vs the s32 salsa width) is
+                        # not attributed to it
+                        dim_owners.setdefault(
+                            (hlo_dtype(p.dtype), p.shape[i]), set()
+                        ).add(p.name)
             for c in e.collectives:
                 if c.kind != "all-gather":
                     continue
@@ -178,7 +186,7 @@ class ImplicitReshardPass(SpmdPass):
                 matches = by_global.get(c.nbytes, [])
                 slice_of = sorted(
                     set().union(
-                        *(dim_owners.get(d, set()) for d in c.shape)
+                        *(dim_owners.get((c.dtype, d), set()) for d in c.shape)
                     )
                 )
                 if matches:

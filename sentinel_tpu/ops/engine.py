@@ -2285,7 +2285,11 @@ def _check_tail_flow(
         return ruled & (est + rank + cnt > thr)
 
     # runtime skip when no tail rules exist at all (the table scan is
-    # trivial against the per-item gathers + sort it gates)
+    # trivial against the per-item gathers + sort it gates).  Under the
+    # SPMD mesh the partitioner hoists _run's salsa read reshard (the
+    # flatten in tables.depth_gather_1col, pinned in the ledger) out of
+    # the branch, so its all-gather is attributed to this line.
+    # stlint: disable-next-line=implicit-reshard — known salsa read reshard, hoisted to the cond boundary
     return jax.lax.cond(
         jnp.any(thr_tab < RT.TAIL_UNRULED / 2) & jnp.any(elig),
         _run,

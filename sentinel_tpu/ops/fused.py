@@ -1,7 +1,7 @@
 """Fused Pallas effects-phase megakernels.
 
-Why this exists (measured on v5e, see benchmarks/probe_fused_hist.py and
-BENCH_r02): the XLA one-hot-matmul table path (ops/mxu_table.py) pays
+Why this exists (measured on v5e in round 2, see
+benchmarks/probe_fused_hist.py): the XLA one-hot-matmul table path (ops/mxu_table.py) pays
 ~0.3-0.9 ms PER OP at B=128K regardless of FLOPs — every scatter/gather
 materializes [B, n_lo] one-hot tensors in HBM and takes its own fusion,
 and the tick makes ~25 such calls (19 ms total).  The fused formulation
@@ -49,74 +49,26 @@ TILE_GATHER = 2048
 N_LO = 128
 
 
-def tpu_compiler_params(**kw):
-    """Mosaic compiler params across jax versions: the class was renamed
-    TPUCompilerParams -> CompilerParams (jax 0.5); accept either."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kw)
-
-
-@functools.cache
-def _patch_eager_interpret_impl() -> bool:
-    """jax<0.5 only: make EAGER interpret-mode pallas calls work.
-
-    That jax's ``_pallas_call_impl`` re-binds the primitive inside a
-    FRESH ``jax.jit`` closure per invocation, which (a) infinitely
-    recurses under ``jax.disable_jit()`` (the test suite's eager-heavy
-    fixture) and (b) even with jit enabled re-traces and re-compiles the
-    kernel on EVERY eager call (the closure is new each time, so the jit
-    cache never hits).  Interpret mode needs neither: its evaluator is
-    plain jnp ops (a scan over the grid), exactly what eager execution
-    wants.  Route the eager impl straight there; jitted lowering and the
-    Mosaic TPU path are untouched.  jax>=0.5 fixed both and keeps the
-    CompilerParams name, which is the version gate."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    if hasattr(pltpu, "CompilerParams"):
-        return False  # jax>=0.5: eager pallas is healthy
-    try:
-        from jax._src.pallas import pallas_call as _pc
-
-        orig = _pc._pallas_call_impl
-        interp = _pc._pallas_call_impl_interpret
-
-        def impl(*args, **params):
-            if params.get("interpret"):
-                p = {k: v for k, v in params.items() if k not in ("interpret", "backend")}
-                return interp(*args, **p)
-            return orig(*args, **params)
-
-        _pc.pallas_call_p.def_impl(impl)
-        return True
-    except (ImportError, AttributeError):  # pragma: no cover - future jax layouts
-        return False
-
-
 #: jitted pallas wrappers for EAGER callers, keyed by the call site's
-#: static plan (kernel structure + shapes).  Eager pallas on this jax
-#: either recurses (disable_jit) or re-compiles per call (fresh impl
-#: closure defeats the jit cache); wrapping the built pallas_call in a
-#: key-cached jit pays one small compile per distinct kernel and runs
-#: compiled thereafter — the behavior the suite's eager-heavy fixture
-#: (tests/conftest.py) was measured against.
+#: static plan (kernel structure + shapes).  Under ``jax.disable_jit()``
+#: (the test suite's eager-heavy fixture, tests/conftest.py) every eager
+#: pallas call re-traces its interpreted kernel; wrapping the built
+#: pallas_call in a key-cached jit pays one small compile per distinct
+#: kernel and runs compiled thereafter.  The eager modules need it:
+#: tests/test_fused.py takes 97 s with the cache and 120 s without
+#: (CPU, measured), and tier-1 has no such slack.
 _EAGER_PALLAS_CACHE: dict = {}
 _EAGER_PALLAS_LOCK = threading.Lock()
 
 
 def run_pallas(call, *args, key=None):
-    """Invoke a built pallas_call so it works EAGERLY on every jax this
-    repo meets; inside a jit trace this is a plain call (the lowering
-    path is healthy everywhere).
+    """Invoke a built pallas_call; inside a jit trace (and eagerly with
+    jit enabled) this is a plain call.
 
     ``key``: hashable static plan of the call site (kernel structure,
     shapes, tiling).  Two calls with equal keys MUST be equivalent
     pallas programs up to traced inputs — the first caller's kernel is
     the one that stays cached."""
-    _patch_eager_interpret_impl()
     if key is None or not jax.config.jax_disable_jit:
         return call(*args)
     with _EAGER_PALLAS_LOCK:
@@ -128,12 +80,13 @@ def run_pallas(call, *args, key=None):
         return fn(*args)
 
 
+@functools.cache
 def interpret_mode() -> bool:
-    """True when running without a Mosaic backend (tests on CPU)."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # stlint: disable=fail-open — backend probe failure selects INTERPRET mode (exact, slow); verdicts unaffected
-        return True
+    """True when the kernels run interpreted because the backend has no
+    Mosaic (tests on CPU).  Decided once per process from the live
+    backend; a backend that fails to initialise raises here instead of
+    silently selecting the interpreter."""
+    return jax.default_backend() != "tpu"
 
 
 @functools.cache
@@ -328,7 +281,7 @@ def scatter_many(jobs: Sequence[Job], tb: int = TILE, interpret: Optional[bool] 
         # ~28-unit job mixes (observed 16.24 MB on a 27-val-row mix at
         # B=4096 after the 2-D block-spec change); v5e has 128 MB VMEM
         # per core, so double the scope rather than split finer
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=32 * 1024 * 1024
         ),
     ), *ins,
@@ -467,7 +420,7 @@ def gather_many(
         out_shape=out_shapes,
         interpret=interpret,
         # same scoped-vmem headroom as scatter_many (see comment there)
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=32 * 1024 * 1024
         ),
     ), *ins,

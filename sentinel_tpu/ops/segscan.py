@@ -113,7 +113,7 @@ def seg_excl_cumsum_pl(head: jax.Array, values: jax.Array) -> jax.Array:
         ),
         out_shape=jax.ShapeDtypeStruct((V, Np), jnp.int32),
         scratch_shapes=[pltpu.VMEM((8, 128), jnp.int32)],
-        compiler_params=FU.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=FU.interpret_mode(),
@@ -201,7 +201,7 @@ def seg_incl_min_pl(head: jax.Array, values: jax.Array, fill: float) -> jax.Arra
         ),
         out_shape=jax.ShapeDtypeStruct((1, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
-        compiler_params=FU.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=FU.interpret_mode(),

@@ -245,6 +245,29 @@ def test_platform_engine_config_detects_backend(monkeypatch):
     assert not (cfg2.use_mxu_tables or cfg2.fused_effects or cfg2.seg_effects)
 
 
+def test_dev_col_never_aliases_the_staging_slot(vt):
+    """jnp.asarray is zero-copy for a 64-byte-aligned host buffer on the
+    CPU backend, so a column uploaded straight from a staging slot would
+    change under a queued (pipelined) tick when the slot is rewritten —
+    chip_smoke's equivalence phase caught exactly that.  Both _dev_col
+    paths must hand the tick a buffer nothing rewrites."""
+    c = _mk(vt)
+    n = c.cfg.batch_size
+    raw = np.empty(n + 16, np.int32)
+    off = (-raw.ctypes.data % 64) // 4
+    slot = raw[off:off + n]  # what a lucky np.empty hands _sbuf
+    assert slot.ctypes.data % 64 == 0
+
+    slot[:] = np.arange(n)
+    varying = c._dev_col("t.varying", slot, -1)
+    slot[:] = 5
+    const = c._dev_col("t.const", slot, 5)
+    slot[:] = 9  # the slot's next use
+    assert np.array_equal(np.asarray(varying), np.arange(n))
+    assert (np.asarray(const) == 5).all()
+    assert (np.asarray(c._dev_col("t.const", np.full(n, 5, np.int32), 5)) == 5).all()
+
+
 @pytest.mark.jitted  # the POINT: no disable_jit — pin jit-only buffer behavior
 def test_jitted_const_column_cache_and_empty_batches(vt):
     """ADVICE r5 low #4: the jit-only buffer-dedup failure class (per-leaf

@@ -2,8 +2,8 @@
 engine-path equivalence.
 
 On CPU the kernels run in Pallas interpret mode — semantics only; the
-device-speed path is exercised by bench.py and the on-TPU equivalence
-test (test_tpu_equivalence.py)."""
+device path is exercised on the chip by chip_smoke.py (full-width
+served-vs-plain verdict equivalence) and bench.py."""
 
 from __future__ import annotations
 

@@ -102,11 +102,33 @@ def _run(p, program):
 
 _HLO = textwrap.dedent(
     """\
-    %all-gather.1 = s32[2,512]{1,0} all-gather(s32[2,64]{1,0} %p), dimensions={1}, metadata={op_name="x" source_file="@ROOT@/sentinel_tpu/ops/tables.py" source_line=255}
+    HloModule jit_tick, is_scheduled=true
+
+    FileNames
+    1 "@ROOT@/sentinel_tpu/ops/tables.py"
+    2 "/somewhere/else/x.py"
+
+    FunctionNames
+    1 "depth_gather_1col"
+    2 "elsewhere"
+
+    FileLocations
+    1 {file_name_id=1 function_name_id=1 line=255 end_line=255 column=15 end_column=44}
+    2 {file_name_id=2 function_name_id=2 line=3 end_line=3 column=1 end_column=9}
+
+    StackFrames
+    1 {file_location_id=2 parent_frame_id=1}
+    2 {file_location_id=1 parent_frame_id=2}
+
+    ENTRY %main {
+    %all-gather.1 = s32[2,512]{1,0} all-gather(s32[2,64]{1,0} %p), dimensions={1}, metadata={op_name="x" stack_frame_id=2}
     %ar = f32[63]{0} all-reduce(f32[63]{0} %q), to_apply=%add
     %ag2 = s32[2,512]{1,0} all-gather-start(s32[2,64]{1,0} %r), dimensions={1}
     %cp = s32[7,5]{1,0} collective-permute(s32[7,5]{1,0} %s), source_target_pairs={{0,1}}
-    %elsewhere = f32[8]{0} all-reduce(f32[8]{0} %t), metadata={source_file="/somewhere/else/x.py" source_line=3}
+    %elsewhere = f32[8]{0} all-reduce(f32[8]{0} %t), metadata={op_name="y" stack_frame_id=1}
+    %combined = (f32[1]{0}, s32[63,5]{1,0}) all-reduce(f32[1]{0} %u, s32[63,5]{1,0} %v), to_apply=%add
+    %gte = f32[1]{0} get-tuple-element((f32[1]{0}, s32[63,5]{1,0}) %combined), index=0
+    }
     """
 ).replace("@ROOT@", REPO_ROOT)
 
@@ -119,6 +141,9 @@ def test_parse_hlo_collectives_kinds_shapes_and_sources():
         ("all-gather", "s32", (2, 512)),  # -start folds into the base kind
         ("collective-permute", "s32", (7, 5)),
         ("all-reduce", "f32", (8,)),
+        # a combiner-merged tuple op counts once per element
+        ("all-reduce", "f32", (1,)),
+        ("all-reduce", "s32", (63, 5)),
     ]
     # in-repo source metadata is relativized; out-of-repo dropped
     assert colls[0].source == "sentinel_tpu/ops/tables.py"
