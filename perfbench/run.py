@@ -1,0 +1,344 @@
+"""Run one cell of the benchmark.
+
+    python3 perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+The cell is looked up in ``BENCHMARK.json`` and its configuration, traffic
+mix and metrics in the files those names lead to (``perfbench/manifest.py``).
+The last line of standard output is the result object; everything else a
+reader may want (sample counts, slices, each number compared beside its
+limit) goes on earlier lines.  Without a TPU, or with fewer chips than the
+cell asks for, the command prints a reason to standard error, no result, and
+exits with code 2.
+"""
+
+from __future__ import annotations
+
+import time
+
+_T_PROCESS = time.monotonic()  # as early as this process can read a clock
+
+import argparse  # noqa: E402
+import gc  # noqa: E402
+import importlib  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import sys  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import numpy as np  # noqa: E402
+
+from perfbench import manifest as M  # noqa: E402
+from perfbench.generators import Hooks  # noqa: E402
+
+#: scratch of a traced run, inside the checkout and listed in .gitignore
+TRACE_DIR = os.path.join(M.ROOT, ".perfbench_trace")
+SLICE_S = 5.0
+
+
+class NoChip(RuntimeError):
+    pass
+
+
+def _say(**kw) -> None:
+    print(json.dumps(kw), flush=True)
+
+
+def device_info(chips: int, require_tpu: bool) -> dict:
+    import jax
+
+    devs = jax.devices()
+    info = {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)}
+    if require_tpu:
+        if info["platform"] != "tpu":
+            raise NoChip(f"needs a TPU, JAX found platform {info['platform']!r}")
+        if info["count"] < chips:
+            raise NoChip(f"the cell needs {chips} chips, JAX found {info['count']}")
+        with open(os.path.join(M.ROOT, M.HERE, "peaks.json")) as f:
+            if info["kind"] not in json.load(f):
+                raise NoChip(f"device kind {info['kind']!r} is not in perfbench/peaks.json")
+    return info
+
+
+def memory_peak_bytes() -> int:
+    import jax
+
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in jax.devices()]
+    return int(max(peaks))
+
+
+class SetupClock:
+    """Where set-up went: stage times by the host's clock, and JAX's own
+    compile events (seconds in the backend compiler or loading from the
+    cache, seconds tracing and lowering, cache hits)."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    TRACE = (
+        "/jax/core/compile/jaxpr_trace_duration",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    )
+    HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        import jax.monitoring as mon
+
+        self.compile_s = self.trace_s = 0.0
+        self.cache_hits = self.compiles = 0
+        self.stages = {}
+        self._last = _T_PROCESS
+        mon.register_event_duration_secs_listener(self._duration)
+        mon.register_event_listener(self._event)
+
+    def _duration(self, event, duration, **kw):
+        if event == self.COMPILE:
+            self.compile_s += duration
+            self.compiles += 1
+        elif event in self.TRACE:
+            self.trace_s += duration
+
+    def _event(self, event, **kw):
+        if event == self.HIT:
+            self.cache_hits += 1
+
+    def stage(self, name: str) -> None:
+        now = time.monotonic()
+        self.stages[name] = round(now - self._last, 3)
+        self._last = now
+
+    def line(self) -> dict:
+        return dict(stages_s=self.stages, compile_s=round(self.compile_s, 3),
+                    trace_lower_s=round(self.trace_s, 3), compiles=self.compiles,
+                    cache_hits=self.cache_hits)
+
+
+class _Hooks(Hooks):
+    """Stamps the set-up time when the window opens; in a traced run also
+    switches the program's spans on for exactly the window and marks the
+    window in the profiler's trace."""
+
+    def __init__(self, trace: bool):
+        self.trace = trace
+        self.setup_s = None
+        self._mark = None
+
+    def opened(self) -> None:
+        self.setup_s = time.monotonic() - _T_PROCESS
+        if self.trace:
+            import jax
+            from sentinel_tpu import obs
+            from perfbench.xplane import WINDOW_MARK
+
+            self._mark = jax.profiler.TraceAnnotation(WINDOW_MARK)
+            self._mark.__enter__()
+            obs.enable()
+
+    def closed(self) -> None:
+        if self.trace:
+            from sentinel_tpu import obs
+
+            obs.disable()
+            self._mark.__exit__(None, None, None)
+
+
+def _percentiles(v) -> dict:
+    if not len(v):
+        return {}
+    qs = (50, 90, 95, 99, 100)
+    return {f"p{q}": round(float(x), 3) for q, x in zip(qs, np.percentile(v, qs))}
+
+
+def slow_episodes(win, factor: float = 1.5, apart_s: float = 0.5, limit: int = 6) -> list:
+    """Where in the window the slow requests sit: runs of samples slower
+    than ``factor`` times the median, as ``[start s, end s, samples, worst
+    ms, second of the wall-clock minute]``.  A tail that one stall makes
+    shows here as one episode."""
+    lat = win.latency_ms
+    if not len(lat):
+        return []
+    at = (win.due_ns - win.open_ns) / 1e9
+    order = np.argsort(at)
+    at, lat = at[order], lat[order]
+    slow = np.flatnonzero(lat > factor * np.median(lat))
+    if not len(slow):
+        return []
+    wall0 = time.time() - (time.monotonic_ns() - win.open_ns) / 1e9
+    groups = np.split(slow, np.flatnonzero(np.diff(at[slow]) > apart_s) + 1)
+    groups.sort(key=len, reverse=True)
+    return [
+        [round(float(at[g[0]]), 2), round(float(at[g[-1]]), 2), int(len(g)),
+         round(float(lat[g].max()), 1), round((wall0 + float(at[g[0]])) % 60, 1)]
+        for g in groups[:limit]
+    ]
+
+
+def slices(win) -> list:
+    """Median latency per ``SLICE_S`` of the window, by due time."""
+    if not len(win.latency_ms):
+        return []
+    idx = ((win.due_ns - win.open_ns) / 1e9 // SLICE_S).astype(int)
+    return [
+        round(float(np.median(win.latency_ms[idx == i])), 4)
+        for i in range(int(idx.max()) + 1)
+        if (idx == i).any()
+    ]
+
+
+def run_cell(
+    workload: str,
+    seed: int,
+    seconds: float,
+    trace: bool,
+    *,
+    sizes=None,
+    require_tpu: bool = True,
+    params_override=None,
+    on_profile=None,
+    root: str = M.ROOT,
+) -> dict:
+    """One run of one cell; returns the result object.  ``sizes`` and
+    ``require_tpu`` exist for the CPU rehearsal in the tests (no compile
+    cache, no device check), ``params_override`` and ``on_profile`` (called with the
+    loaded trace, the window and its spans) for the noise study and a first
+    look at a trace (``perfbench/study.py``); the command passes none of
+    them."""
+    manifest = M.load(root)
+    bad = M.problems(manifest, root)
+    if bad:
+        raise ValueError("BENCHMARK.json: " + "; ".join(bad))
+    cell = M.cell(manifest, workload)
+    cfg = M.config(cell["config"], root)
+    params = M.traffic(cell, root)
+    params.update(params_override or {})
+    generator = importlib.import_module(f"perfbench.generators.{params['generator']}")
+    if trace:
+        # the program's span ring (read at import): room for a whole window
+        os.environ.setdefault("SENTINEL_TRACE_CAPACITY", str(1 << 18))
+        seconds = min(seconds, params["trace_seconds"])
+
+    from sentinel_tpu.utils.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache() if require_tpu else None
+    import jax
+
+    # A Mosaic kernel's payload carries its debug locations into the compile
+    # cache's key, and with full tracebacks those name every caller's line:
+    # the same tick would compile again under another entry point.
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    clock = SetupClock()
+    device = device_info(cell["chips"], require_tpu)
+    clock.stage("import_and_device")
+
+    from perfbench import check, deployment
+    from perfbench.readers import Context
+
+    dep = deployment.build(cfg, seed, sizes)
+    clock.stage("deployment")
+    dep.client.start()  # rules are loaded: starting first would compile twice
+    clock.stage("client_start")
+    # The client's own warm-up runs empty ticks.  The first ticks that carry
+    # items compile some thirty small programs more (wire unpack, telemetry
+    # folds), seconds of stall that belong to set-up: so the cell's own
+    # traffic runs for a moment here, until the client is idle again.
+    generator.run(dep, dict(params, preroll_s=0.0, postroll_s=0.0), seed,
+                  params["prime_seconds"], Hooks())
+    clock.stage("prime")
+    gc.collect()
+    gc.freeze()
+    at_setup = clock.line()
+    _say(phase="setup", cache_dir=cache_dir, **at_setup)
+
+    hooks = _Hooks(trace)
+    if trace:
+        import jax
+        from sentinel_tpu import obs
+
+        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        obs.TRACER.reset()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(TRACE_DIR, profiler_options=opts)
+    try:
+        win = generator.run(dep, params, seed, seconds, hooks)
+    finally:
+        if trace:
+            jax.profiler.stop_trace()
+    device["memory_peak_bytes"] = memory_peak_bytes()
+    since_setup = clock.line()
+    _say(
+        phase="window", compiles_since_setup=since_setup["compiles"] - at_setup["compiles"],
+        compile_s_since_setup=round(since_setup["compile_s"] - at_setup["compile_s"], 3), samples=int(len(win.latency_ms)), attempted=win.attempted,
+        failed=win.failed, codes=win.codes, span_s=round(win.span_s, 3),
+        p50_ms_per_slice=slices(win), latency_ms=_percentiles(win.latency_ms),
+        slow_episodes=slow_episodes(win),
+        **win.extra,
+    )
+
+    ctx = Context(window=win, setup_s=hooks.setup_s, batch=dep.batch)
+    result = {}
+    if trace:
+        from perfbench import xplane
+
+        ctx.spans = [
+            s for s in obs.TRACER.snapshot()
+            if win.open_ns <= s["t0_ns"] < win.close_ns
+        ]
+        profile = xplane.load(xplane.find(TRACE_DIR))
+        if on_profile:
+            on_profile(profile, win, ctx.spans)
+        summary = xplane.summarize(profile, win.open_ns, ctx.spans)
+        ctx.trace = summary
+        device["busy_s"] = summary.busy_s
+        device["window_s"] = summary.window_s
+        result["breakdown"] = {
+            "device_ops": [list(kv) for kv in summary.device_ops],
+            "idle_gaps": [list(kv) for kv in summary.idle_gaps],
+        }
+        starts = np.sort([s["t0_ns"] for s in ctx.spans if s["name"] == "tick.assemble"])
+        _say(phase="trace", spans=len(ctx.spans), ticks=int(len(summary.tick_busy_ms)),
+             longest_tick_gap_ms=float(np.diff(starts).max() / 1e6) if len(starts) > 1 else None,
+             span_summary=obs.summarize(ctx.spans))
+        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+
+    metrics = {}
+    for m in M.metrics_of(manifest, workload, "per_layer" if trace else "end_to_end"):
+        spec = M.metric(m["name"], root)
+        reader = importlib.import_module(f"perfbench.readers.{spec['reader']}")
+        value = reader.read(ctx, **spec["args"])
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+
+    correct, numbers, replayed = check.decide(dep, generator, params, seed, win)
+    for n in numbers:
+        _say(compared=n.name, value=n.value, limit=n.limit,
+             rule="at least" if n.at_least else "at most", ok=n.ok)
+    _say(phase="replay", **replayed)
+    result = {
+        "correct": bool(correct),
+        "attempted": int(win.attempted),
+        "failed": int(win.failed),
+        "metrics": metrics,
+        "device": device,
+        **result,
+    }
+    return result
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+    try:
+        result = run_cell(args.workload, args.seed, args.seconds, bool(args.trace))
+    except NoChip as e:
+        print(f"perfbench: {e}; nothing was run", file=sys.stderr)
+        return 2
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

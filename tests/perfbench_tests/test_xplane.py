@@ -1,0 +1,159 @@
+"""The reduction from a profiler trace to device numbers: on hand-made
+traces whose answers are known, and on a slice recorded on the chip
+(``data/trace_slice.json``, the first 80 ms of a traced zipf-1m.paced
+window on a TPU v5e) against a plain loop over the same events."""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+from perfbench import xplane
+
+US = 1_000
+
+
+def trace(ops, modules, mark=(0, 1000 * US)):
+    """A one-chip trace from ``(name, start_us, dur_us)`` tuples."""
+    def events(rows):
+        return [{"name": n, "start_ns": s * US, "duration_ns": d * US} for n, s, d in rows]
+
+    return xplane.from_json({"planes": [
+        {"name": "/host:CPU", "lines": [{"name": "python3", "events": [
+            {"name": xplane.WINDOW_MARK, "start_ns": mark[0], "duration_ns": mark[1] - mark[0]}]}]},
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": events(ops)},
+            {"name": "XLA Modules", "events": events(modules)},
+        ]},
+    ]})
+
+
+KERNEL = '%branch_1_fun.3 = f32[2,128]{1,0} custom-call(s32[4]{0} %p), custom_call_target="tpu_custom_call"'
+OPS = [
+    ("%fusion.1 = s32[8]{0} fusion(s32[8]{0} %a), kind=kLoop", 100, 50),
+    (KERNEL, 150, 20),
+    ("%sort.2 = s32[8]{0} sort(s32[8]{0} %b)", 200, 20),
+    # a container: its time is its children's, and the 10 us between them
+    ("%conditional.4 = (s32[8]{0}) conditional(s32[] %i, (s32[8]{0}) %t, (s32[8]{0}) %f)", 600, 100),
+    ("%fusion.1 = s32[8]{0} fusion(s32[8]{0} %a), kind=kLoop", 600, 50),
+    (KERNEL, 660, 40),
+    ("%and.9 = s32[2]{0} and(s32[2]{0} %x, s32[2]{0} %y)", 900, 1),
+]
+MODULES = [("jit_tick(111)", 90, 140), ("jit_tick(222)", 590, 120), ("jit_and(5)", 899, 3)]
+
+
+def test_names_are_shortened_and_kernels_marked():
+    assert xplane.short(OPS[0][0]) == "fusion.1"
+    assert xplane.short(KERNEL) == "branch_1_fun.3__mosaic"
+    assert xplane.program("jit_tick(111)") == "jit_tick"
+
+
+def test_union_and_overlap():
+    s, e = xplane.union(np.array([0., 5., 20., 21.]), np.array([10., 8., 30., 25.]))
+    assert s.tolist() == [0., 20.] and e.tolist() == [10., 30.]
+    got = xplane._overlap(np.array([5., 0., 12.]), np.array([25., 40., 18.]), s, e)
+    assert got.tolist() == [10., 20., 0.]
+
+
+def test_hand_made_trace():
+    spans = [
+        {"name": "tick.presort", "t0_ns": 7_000_000 + 300 * US, "dur_ns": 200 * US},
+        {"name": "tick.assemble", "t0_ns": 7_000_000 + 250 * US, "dur_ns": 300 * US},
+    ]
+    s = xplane.summarize(trace(OPS, MODULES), 7_000_000, spans)
+    assert s.window_s == pytest.approx(1e-3)
+    assert s.clock_offset_ns == 7_000_000
+    # 100-170, 200-220, 600-700, 900-901
+    assert s.busy_s == pytest.approx((70 + 20 + 100 + 1) * 1e-6)
+    # the tick program is the one that holds the device longest
+    assert s.tick_busy_ms.tolist() == pytest.approx([0.090, 0.100])
+    assert s.tick_kernels_ms.tolist() == pytest.approx([0.020, 0.040])
+    assert dict(s.device_ops)["fusion.1"] == pytest.approx(100e-6)
+    assert dict(s.device_ops)["branch_1_fun.3__mosaic"] == pytest.approx(60e-6)
+    assert "conditional.4" not in dict(s.device_ops)
+    gaps = dict(s.idle_gaps)
+    # inside the two tick programs: 90-100, 170-200, 220-230; 590-600, 700-710
+    assert gaps["in_program"] == pytest.approx(70e-6)
+    # 230-590 is idle between programs: presort covers 300-500, assemble the
+    # rest of 250-550, nothing 0-90, 230-250, 550-590, 710-900 and 901-1000
+    assert gaps["tick.presort"] == pytest.approx(200e-6)
+    assert gaps["tick.assemble"] == pytest.approx(100e-6)
+    assert gaps["host_other"] == pytest.approx((90 + 20 + 40 + 190 + 99) * 1e-6)
+    assert sum(gaps.values()) + s.busy_s == pytest.approx(s.window_s, rel=1e-3)
+
+
+def test_events_are_clipped_to_the_marked_window():
+    s = xplane.summarize(trace(OPS, MODULES, mark=(150 * US, 620 * US)), 0, [])
+    assert s.busy_s == pytest.approx((20 + 20 + 20) * 1e-6)
+    assert len(s.tick_busy_ms) == 0  # no tick program lies whole in the window
+
+
+def test_a_trace_without_the_mark_or_a_device_is_an_error():
+    no_mark = trace(OPS, MODULES)
+    no_mark.planes[0].lines[0].events[0].name = "something else"
+    with pytest.raises(ValueError, match="annotation"):
+        xplane.summarize(no_mark, 0, [])
+    no_device = trace(OPS, MODULES)
+    no_device.planes[1].name = "/device:GPU:0"
+    with pytest.raises(ValueError, match="TPU"):
+        xplane.summarize(no_device, 0, [])
+
+
+# -- the recorded slice -------------------------------------------------------
+
+SLICE = os.path.join(os.path.dirname(__file__), "data", "trace_slice.json")
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    with open(SLICE) as f:
+        return json.load(f)
+
+
+def _loop_busy(events, w0, w1):
+    """Union of intervals the slow way: sort, then walk."""
+    busy, reach = 0.0, w0
+    for s, e in sorted((max(ev["start_ns"], w0), min(ev["start_ns"] + ev["duration_ns"], w1))
+                       for ev in events):
+        if e <= reach:
+            continue
+        busy += e - max(s, reach)
+        reach = e
+    return busy
+
+
+def test_recorded_slice_against_a_plain_loop(recorded):
+    data = recorded["trace"]
+    mark = data["planes"][0]["lines"][0]["events"][0]
+    w0, w1 = mark["start_ns"], mark["start_ns"] + mark["duration_ns"]
+    device = [p for p in data["planes"] if p["name"].startswith("/device:TPU")]
+    assert len(device) == 1
+    lines = {ln["name"]: ln["events"] for ln in device[0]["lines"]}
+    ops = [e for e in lines["XLA Ops"] if e["start_ns"] + e["duration_ns"] > w0 and e["start_ns"] < w1]
+    assert len(ops) > 1000
+
+    s = xplane.summarize(xplane.from_json(data), recorded["open_ns"], recorded["spans"])
+    assert s.window_s == pytest.approx(0.08)
+    assert s.busy_s * 1e9 == pytest.approx(_loop_busy(ops, w0, w1), rel=1e-9)
+    assert 0 < s.busy_s < s.window_s
+
+    # per tick, by the loop: the program that holds the device longest
+    held = {}
+    for m in lines["XLA Modules"]:
+        if m["start_ns"] >= w0 and m["start_ns"] + m["duration_ns"] <= w1:
+            held.setdefault(m["name"].split("(")[0], []).append(m)
+    ticks = max(held.values(), key=lambda ms: sum(m["duration_ns"] for m in ms))
+    assert len(ticks) == len(s.tick_busy_ms) >= 2
+    for m, busy_ms, kern_ms in zip(sorted(ticks, key=lambda m: m["start_ns"]),
+                                   s.tick_busy_ms, s.tick_kernels_ms):
+        m0, m1 = m["start_ns"], m["start_ns"] + m["duration_ns"]
+        inside = [e for e in ops if e["start_ns"] + e["duration_ns"] > m0 and e["start_ns"] < m1]
+        assert busy_ms * 1e6 == pytest.approx(_loop_busy(inside, m0, m1), rel=1e-6)
+        kern = sum(min(e["start_ns"] + e["duration_ns"], w1) - max(e["start_ns"], w0) for e in ops
+                   if "tpu_custom_call" in e["name"] and m0 <= e["start_ns"] < m1)
+        assert kern_ms * 1e6 == pytest.approx(kern, rel=1e-6)
+        assert 0 < kern_ms <= busy_ms <= m["duration_ns"] / 1e6 + 1e-9
+    # what is busy and what is idle make up the window
+    assert s.busy_s + sum(v for _n, v in s.idle_gaps) == pytest.approx(s.window_s, rel=0.01)
+    assert all("__mosaic" in n or "tpu_custom_call" not in n for n, _v in s.device_ops)
