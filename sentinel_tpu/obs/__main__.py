@@ -57,7 +57,10 @@ from typing import List, Optional
 
 from sentinel_tpu.obs import trace as OT
 
-#: the six pipelined tick stages every capture should surface
+#: the six pipelined tick stages every capture should surface.  tick.device
+#: is NOT device time: it runs from dispatch end to verdicts host-visible,
+#: exactly tick.resident + tick.wait (pipeline residency and the resolver
+#: pool's queue included); device time comes from a profiler trace only.
 TICK_STAGES = (
     "tick.assemble",
     "tick.presort",

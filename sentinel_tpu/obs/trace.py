@@ -179,6 +179,9 @@ class _NoopSpan:
 
 
 _NOOP = _NoopSpan()
+#: the shared do-nothing context, for a call site that enters a span or an
+#: annotation only while tracing (no allocation when it is off)
+NOOP = _NOOP
 
 
 class SpanTracer:

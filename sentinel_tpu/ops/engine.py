@@ -1670,6 +1670,7 @@ def _acquire_effects_fused(
     return state
 
 
+@jax.named_scope("stage.authority")
 def _check_authority(cfg: EngineConfig, rules: RuleSet, acq: AcquireBatch):
     """AuthoritySlot: origin allow/deny (AuthorityRuleChecker.java:28-54)."""
     res_l = jnp.minimum(acq.res, cfg.max_resources)
@@ -1684,6 +1685,7 @@ def _check_authority(cfg: EngineConfig, rules: RuleSet, acq: AcquireBatch):
     return white_block | black_block
 
 
+@jax.named_scope("stage.system")
 def _check_system(
     cfg: EngineConfig,
     state: EngineState,
@@ -1744,6 +1746,7 @@ def _check_system(
     return blk & inbound
 
 
+@jax.named_scope("stage.param")
 def _check_param(
     cfg: EngineConfig,
     state: EngineState,
@@ -1926,6 +1929,7 @@ def _sync_warmup(
     )
 
 
+@jax.named_scope("stage.flow")
 def _check_flow(
     cfg: EngineConfig,
     state: EngineState,
@@ -2241,6 +2245,7 @@ def _apply_latest(latest_passed_ms, T_s, n_s, now_ms):
     return jnp.where(n_s > 0, cand, latest_passed_ms)
 
 
+@jax.named_scope("stage.tail_flow")
 def _check_tail_flow(
     cfg: EngineConfig,
     state: EngineState,
@@ -2297,6 +2302,7 @@ def _check_tail_flow(
     )
 
 
+@jax.named_scope("stage.degrade")
 def _check_degrade(
     cfg: EngineConfig,
     state: EngineState,
@@ -2500,6 +2506,35 @@ def _run_checks_plain(
     )
 
 
+def _telemetry_and_output(
+    cfg, state, rules, acq, verdict, wait_ms, valid, forced, fslots, now_ms,
+    seg_dropped, n_seg,
+) -> TickOutput:
+    """The tick's last two stages, shared by the fused and the plain path:
+    the device telemetry reads, then the output (packed wire or columns)."""
+    with jax.named_scope("stage.telemetry"):
+        stats = None
+        res_stats = None
+        if cfg.device_telemetry:
+            stats = _device_stats(
+                cfg, state, rules, acq, verdict, valid, now_ms, seg_dropped, n_seg
+            )
+            if timeline_k(cfg) > 0:
+                res_stats = _device_res_stats(cfg, state, now_ms)
+        hot = None
+        if hotset_k(cfg) > 0:
+            hot = _device_hot_candidates(cfg, state, acq, valid, now_ms)
+        expl = None
+        if explain_k(cfg) > 0:
+            expl = _device_explain(
+                cfg, state, rules, acq, verdict, valid, forced, fslots, now_ms
+            )
+    with jax.named_scope("stage.pack"):
+        return _tick_output(
+            cfg, verdict, wait_ms, seg_dropped, stats, res_stats, hot, expl
+        )
+
+
 def tick(
     state: EngineState,
     rules: RuleSet,
@@ -2518,8 +2553,9 @@ def tick(
         # narrow uploads (ops/wire.py) widen here, before anything else
         # touches the batch — every stage below sees the classic int32
         # columns, so the packed and classic ticks share one code path
-        acq = WIRE.widen_acquire(acq)
-        comp = WIRE.widen_complete(comp)
+        with jax.named_scope("stage.widen"):
+            acq = WIRE.widen_acquire(acq)
+            comp = WIRE.widen_complete(comp)
     zero_block = jnp.zeros((b,), bool)
 
     # segment-compacted effects (ops/engine_seg.py): build the key-run
@@ -2530,39 +2566,42 @@ def tick(
         # binds ES for every use_seg-guarded block below (checks, effects)
         from sentinel_tpu.ops import engine_seg as ES
 
-        ctx_c, carry_c = ES.prepare_completions(cfg, comp, features)
-        ctx_a, carry_a = ES.prepare_acquire(cfg, acq)
+        with jax.named_scope("stage.seg_prepare"):
+            ctx_c, carry_c = ES.prepare_completions(cfg, comp, features)
+            ctx_a, carry_a = ES.prepare_acquire(cfg, acq)
 
     # 1. exits first: they release concurrency and update breakers
     seg_dropped = jnp.int32(0)
-    if use_seg:
-        if cfg.seg_fallback:
-            state = jax.lax.cond(
-                ctx_c.ok,
-                lambda: ES.process_completions_seg(
+    with jax.named_scope("stage.exits"):
+        if use_seg:
+            if cfg.seg_fallback:
+                state = jax.lax.cond(
+                    ctx_c.ok,
+                    lambda: ES.process_completions_seg(
+                        cfg, state, rules, comp, now_ms, features, ctx_c, carry_c
+                    ),
+                    lambda: _process_completions_fused(
+                        cfg, state, rules, comp, now_ms, features
+                    ),
+                )
+            else:
+                state = ES.process_completions_seg(
                     cfg, state, rules, comp, now_ms, features, ctx_c, carry_c
-                ),
-                lambda: _process_completions_fused(
-                    cfg, state, rules, comp, now_ms, features
-                ),
-            )
+                )
+                seg_dropped = seg_dropped + ES.dropped_items(
+                    ctx_c, comp.res != cfg.trash_row
+                )
+        elif _use_fused(cfg):
+            state = _process_completions_fused(cfg, state, rules, comp, now_ms, features)
         else:
-            state = ES.process_completions_seg(
-                cfg, state, rules, comp, now_ms, features, ctx_c, carry_c
-            )
-            seg_dropped = seg_dropped + ES.dropped_items(
-                ctx_c, comp.res != cfg.trash_row
-            )
-    elif _use_fused(cfg):
-        state = _process_completions_fused(cfg, state, rules, comp, now_ms, features)
-    else:
-        state = _process_completions(cfg, state, rules, comp, now_ms, features)
+            state = _process_completions(cfg, state, rules, comp, now_ms, features)
 
     # 2. warm-up token sync (per second, vectorized over rules)
-    if "warmup" in features:
-        state = _sync_warmup(cfg, state, rules, now_ms)
-    if "occupy" in features and "flow" in features:
-        state = _fold_occupied(cfg, state, now_ms)
+    with jax.named_scope("stage.warmup"):
+        if "warmup" in features:
+            state = _sync_warmup(cfg, state, rules, now_ms)
+        if "occupy" in features and "flow" in features:
+            state = _fold_occupied(cfg, state, now_ms)
 
     valid = acq.res != cfg.trash_row
     forced = valid & (acq.pre_verdict > 0)
@@ -2578,33 +2617,34 @@ def tick(
         and cfg.degrade_rules_per_resource == 1
         and cfg.param_rules_per_resource == 1
     )
-    if seg_checks and not cfg.seg_fallback:
-        # presorting callers (seg_fallback=False): run the segment check
-        # phase UNCONDITIONALLY — the lax.cond boundary alone cost ~1.4 ms
-        # at B=128K (operand/result copies) plus the whole plain branch's
-        # compile.  Items in segments past seg_u FAIL CLOSED (sys_block
-        # inside run_checks_seg) and are already counted in seg_dropped.
-        checks = ES.run_checks_seg(
-            cfg, state, rules, acq, now_ms, sys_load, sys_cpu,
-            valid, forced, ctx_a, carry_a, features,
-        )
-    elif seg_checks:
-        checks = jax.lax.cond(
-            ctx_a.ok,
-            lambda: ES.run_checks_seg(
+    with jax.named_scope("stage.checks"):
+        if seg_checks and not cfg.seg_fallback:
+            # presorting callers (seg_fallback=False): run the segment check
+            # phase UNCONDITIONALLY — the lax.cond boundary alone cost ~1.4 ms
+            # at B=128K (operand/result copies) plus the whole plain branch's
+            # compile.  Items in segments past seg_u FAIL CLOSED (sys_block
+            # inside run_checks_seg) and are already counted in seg_dropped.
+            checks = ES.run_checks_seg(
                 cfg, state, rules, acq, now_ms, sys_load, sys_cpu,
                 valid, forced, ctx_a, carry_a, features,
-            ),
-            lambda: _run_checks_plain(
+            )
+        elif seg_checks:
+            checks = jax.lax.cond(
+                ctx_a.ok,
+                lambda: ES.run_checks_seg(
+                    cfg, state, rules, acq, now_ms, sys_load, sys_cpu,
+                    valid, forced, ctx_a, carry_a, features,
+                ),
+                lambda: _run_checks_plain(
+                    cfg, state, rules, acq, now_ms, sys_load, sys_cpu,
+                    valid, forced, features,
+                ),
+            )
+        else:
+            checks = _run_checks_plain(
                 cfg, state, rules, acq, now_ms, sys_load, sys_cpu,
                 valid, forced, features,
-            ),
-        )
-    else:
-        checks = _run_checks_plain(
-            cfg, state, rules, acq, now_ms, sys_load, sys_cpu,
-            valid, forced, features,
-        )
+            )
     (
         auth_block,
         sys_block,
@@ -2626,240 +2666,212 @@ def tick(
     if "param" in features:
         (pcms, pcms_epochs, pcms_idx, prows, p_qps_add, p_thread_add) = param_state
 
-    passed = valid & ~forced & ~(
-        auth_block | sys_block | param_block | flow_block | degrade_block
-    )
-    # occupy grants only COMMIT for items that finally pass — a grant
-    # revoked by a later slot (e.g. an open circuit breaker) books nothing
-    occupying = occupying & passed
-    fused = _use_fused(cfg)
-    if occ_grant is not None and not fused:
-        grant_lane, onodes, ocnt = occ_grant
-        b_k = grant_lane.shape[0] // b
-        commit = grant_lane & _fan(occupying, b_k)
-        # node-keyed booking (FutureBucket lives on the node): one
-        # histogram over the node table
-        add = T.histogram(
-            cfg,
-            jnp.where(commit, onodes, jnp.int32(-1)),
-            jnp.where(commit, jnp.round(ocnt).astype(jnp.int32), 0),
-            cfg.node_rows,
-        ).astype(jnp.float32)
-        cur_wid = W.wid_of(now_ms, cfg.second_window_ms)
-        pool_vec = jnp.where(state.occ_epoch == cur_wid + 1, state.occ_tokens, 0.0)
-        state = state._replace(
-            occ_tokens=pool_vec + add,
-            occ_epoch=jnp.where(add > 0, cur_wid + 1, state.occ_epoch),
+    with jax.named_scope("stage.verdict"):
+        passed = valid & ~forced & ~(
+            auth_block | sys_block | param_block | flow_block | degrade_block
         )
+        # occupy grants only COMMIT for items that finally pass — a grant
+        # revoked by a later slot (e.g. an open circuit breaker) books nothing
+        occupying = occupying & passed
+        fused = _use_fused(cfg)
+        if occ_grant is not None and not fused:
+            grant_lane, onodes, ocnt = occ_grant
+            b_k = grant_lane.shape[0] // b
+            commit = grant_lane & _fan(occupying, b_k)
+            # node-keyed booking (FutureBucket lives on the node): one
+            # histogram over the node table
+            add = T.histogram(
+                cfg,
+                jnp.where(commit, onodes, jnp.int32(-1)),
+                jnp.where(commit, jnp.round(ocnt).astype(jnp.int32), 0),
+                cfg.node_rows,
+            ).astype(jnp.float32)
+            cur_wid = W.wid_of(now_ms, cfg.second_window_ms)
+            pool_vec = jnp.where(state.occ_epoch == cur_wid + 1, state.occ_tokens, 0.0)
+            state = state._replace(
+                occ_tokens=pool_vec + add,
+                occ_epoch=jnp.where(add > 0, cur_wid + 1, state.occ_epoch),
+            )
 
-    verdict = jnp.full((b,), PASS, dtype=jnp.int8)
-    verdict = jnp.where(forced, acq.pre_verdict.astype(jnp.int8), verdict)
-    verdict = jnp.where(auth_block, jnp.int8(BLOCK_AUTHORITY), verdict)
-    verdict = jnp.where(sys_block, jnp.int8(BLOCK_SYSTEM), verdict)
-    verdict = jnp.where(param_block, jnp.int8(BLOCK_PARAM), verdict)
-    verdict = jnp.where(flow_block, jnp.int8(BLOCK_FLOW), verdict)
-    verdict = jnp.where(degrade_block, jnp.int8(BLOCK_DEGRADE), verdict)
-    verdict = jnp.where(passed & (wait_ms > 0), jnp.int8(PASS_WAIT), verdict)
-    wait_ms = jnp.where(passed, wait_ms, 0)
+        verdict = jnp.full((b,), PASS, dtype=jnp.int8)
+        verdict = jnp.where(forced, acq.pre_verdict.astype(jnp.int8), verdict)
+        verdict = jnp.where(auth_block, jnp.int8(BLOCK_AUTHORITY), verdict)
+        verdict = jnp.where(sys_block, jnp.int8(BLOCK_SYSTEM), verdict)
+        verdict = jnp.where(param_block, jnp.int8(BLOCK_PARAM), verdict)
+        verdict = jnp.where(flow_block, jnp.int8(BLOCK_FLOW), verdict)
+        verdict = jnp.where(degrade_block, jnp.int8(BLOCK_DEGRADE), verdict)
+        verdict = jnp.where(passed & (wait_ms > 0), jnp.int8(PASS_WAIT), verdict)
+        wait_ms = jnp.where(passed, wait_ms, 0)
 
     # 4. effects: pass/block statistics (StatisticSlot.java:54-123).
     # Occupying entries count OCCUPIED now; their PASS lands when the
     # borrowed bucket becomes current (_fold_occupied), so the next
     # window's budget is reduced by exactly the borrowed amount.
     if fused:
-        param_ctx = None
-        if "param" in features:
-            param_ctx = (pcms, pcms_epochs, pcms_idx, prows, p_qps_add, p_thread_add)
-        if use_seg:
-            if cfg.seg_fallback:
-                state = jax.lax.cond(
-                    ctx_a.ok,
-                    lambda: ES.acquire_effects_seg(
+        with jax.named_scope("stage.effects"):
+            param_ctx = None
+            if "param" in features:
+                param_ctx = (pcms, pcms_epochs, pcms_idx, prows, p_qps_add, p_thread_add)
+            if use_seg:
+                if cfg.seg_fallback:
+                    state = jax.lax.cond(
+                        ctx_a.ok,
+                        lambda: ES.acquire_effects_seg(
+                            cfg, state, rules, acq, now_ms, features, passed,
+                            occupying, valid, fslots, occ_grant, rl_info,
+                            param_ctx, ctx_a, carry_a,
+                        ),
+                        lambda: _acquire_effects_fused(
+                            cfg, state, rules, acq, now_ms, features, passed,
+                            occupying, valid, fslots, occ_grant, rl_info,
+                            param_ctx,
+                        ),
+                    )
+                else:
+                    state = ES.acquire_effects_seg(
                         cfg, state, rules, acq, now_ms, features, passed,
                         occupying, valid, fslots, occ_grant, rl_info,
                         param_ctx, ctx_a, carry_a,
-                    ),
-                    lambda: _acquire_effects_fused(
-                        cfg, state, rules, acq, now_ms, features, passed,
-                        occupying, valid, fslots, occ_grant, rl_info,
-                        param_ctx,
-                    ),
-                )
+                    )
+                    seg_dropped = seg_dropped + ES.dropped_items(ctx_a, valid)
             else:
-                state = ES.acquire_effects_seg(
-                    cfg, state, rules, acq, now_ms, features, passed,
-                    occupying, valid, fslots, occ_grant, rl_info,
-                    param_ctx, ctx_a, carry_a,
+                state = _acquire_effects_fused(
+                    cfg,
+                    state,
+                    rules,
+                    acq,
+                    now_ms,
+                    features,
+                    passed,
+                    occupying,
+                    valid,
+                    fslots,
+                    occ_grant,
+                    rl_info,
+                    param_ctx,
                 )
-                seg_dropped = seg_dropped + ES.dropped_items(ctx_a, valid)
-        else:
-            state = _acquire_effects_fused(
+        return state, _telemetry_and_output(
+            cfg, state, rules, acq, verdict, wait_ms, valid, forced, fslots,
+            now_ms, seg_dropped, ctx_a.n_seg if use_seg else 0,
+        )
+
+    with jax.named_scope("stage.effects"):
+        with_nodes = "nodes" in features
+        rows = _stat_rows(cfg, acq.res, acq.ctx_node, acq.origin_node, with_nodes)
+        # planes (PASS, BLOCK, OCCUPIED) only — the entry path writes no others
+        pass_c, block_c, occ_c, entry_deltas = _acquire_entry_stats(
+            cfg, acq, valid, passed, occupying
+        )
+        deltas1 = jnp.stack([pass_c, block_c, occ_c], axis=1)
+
+        def _land_acq(fanned: bool):
+            rws = _stat_rows(cfg, acq.res, acq.ctx_node, acq.origin_node, fanned)
+            f = 3 if fanned else 1
+            return _stat_update(
                 cfg,
                 state,
-                rules,
-                acq,
                 now_ms,
-                features,
-                passed,
-                occupying,
-                valid,
-                fslots,
-                occ_grant,
-                rl_info,
-                param_ctx,
+                rws,
+                jnp.tile(deltas1, (f, 1)) if fanned else deltas1,
+                None,
+                entry_deltas,
+                None,
+                None,
+                plane_idx=(W.EV_PASS, W.EV_BLOCK, W.EV_OCCUPIED),
             )
-        stats = None
-        res_stats = None
-        if cfg.device_telemetry:
-            stats = _device_stats(
-                cfg, state, rules, acq, verdict, valid, now_ms,
-                seg_dropped, ctx_a.n_seg if use_seg else 0,
+
+        if with_nodes:
+            any_fan = jnp.any(
+                valid
+                & ((acq.ctx_node != cfg.trash_row) | (acq.origin_node != cfg.trash_row))
             )
-            if timeline_k(cfg) > 0:
-                res_stats = _device_res_stats(cfg, state, now_ms)
-        hot = None
-        if hotset_k(cfg) > 0:
-            hot = _device_hot_candidates(cfg, state, acq, valid, now_ms)
-        expl = None
-        if explain_k(cfg) > 0:
-            expl = _device_explain(
-                cfg, state, rules, acq, verdict, valid, forced, fslots, now_ms
+            state, hist = jax.lax.cond(
+                any_fan, lambda: _land_acq(True), lambda: _land_acq(False)
             )
-        return state, _tick_output(
-            cfg, verdict, wait_ms, seg_dropped, stats, res_stats, hot, expl
-        )
-
-    with_nodes = "nodes" in features
-    rows = _stat_rows(cfg, acq.res, acq.ctx_node, acq.origin_node, with_nodes)
-    # planes (PASS, BLOCK, OCCUPIED) only — the entry path writes no others
-    pass_c, block_c, occ_c, entry_deltas = _acquire_entry_stats(
-        cfg, acq, valid, passed, occupying
-    )
-    deltas1 = jnp.stack([pass_c, block_c, occ_c], axis=1)
-
-    def _land_acq(fanned: bool):
-        rws = _stat_rows(cfg, acq.res, acq.ctx_node, acq.origin_node, fanned)
-        f = 3 if fanned else 1
-        return _stat_update(
-            cfg,
-            state,
-            now_ms,
-            rws,
-            jnp.tile(deltas1, (f, 1)) if fanned else deltas1,
-            None,
-            entry_deltas,
-            None,
-            None,
-            plane_idx=(W.EV_PASS, W.EV_BLOCK, W.EV_OCCUPIED),
-        )
-
-    if with_nodes:
-        any_fan = jnp.any(
-            valid
-            & ((acq.ctx_node != cfg.trash_row) | (acq.origin_node != cfg.trash_row))
-        )
-        state, hist = jax.lax.cond(
-            any_fan, lambda: _land_acq(True), lambda: _land_acq(False)
-        )
-    else:
-        state, hist = _land_acq(False)
-    if cfg.sketch_stats:
-        gvals = jnp.stack(
-            [
-                jnp.where(passed, acq.count, 0),
-                jnp.where(valid & ~passed, acq.count, 0),
-            ],
-            axis=1,
-        )
-        # completion phase already refreshed this now_ms's bucket — skip
-        # the second masked-multiply copy of the whole counts tensor
-        state = state._replace(
-            gs=_sketch(cfg).add(
-                state.gs,
-                now_ms,
-                acq.res,
-                gvals,
-                (W.EV_PASS, W.EV_BLOCK),
-                valid,
-                sketch_config(cfg),
-                pre_refreshed=True,
-                ecfg=cfg,
+        else:
+            state, hist = _land_acq(False)
+        if cfg.sketch_stats:
+            gvals = jnp.stack(
+                [
+                    jnp.where(passed, acq.count, 0),
+                    jnp.where(valid & ~passed, acq.count, 0),
+                ],
+                axis=1,
             )
-        )
+            # completion phase already refreshed this now_ms's bucket — skip
+            # the second masked-multiply copy of the whole counts tensor
+            state = state._replace(
+                gs=_sketch(cfg).add(
+                    state.gs,
+                    now_ms,
+                    acq.res,
+                    gvals,
+                    (W.EV_PASS, W.EV_BLOCK),
+                    valid,
+                    sketch_config(cfg),
+                    pre_refreshed=True,
+                    ecfg=cfg,
+                )
+            )
 
-    if hist is not None:  # MXU: concurrency rides the pass+occupied histogram
-        # (the histogram already carries the ENTRY-row reduction; occupying
-        # entries hold a concurrency slot even though their PASS lands later)
-        concurrency = state.concurrency + hist[:, W.EV_PASS] + hist[:, W.EV_OCCUPIED]
-    else:
-        fan = 3 if with_nodes else 1
-        rows = _stat_rows(cfg, acq.res, acq.ctx_node, acq.origin_node, with_nodes)
-        inc = jnp.tile(jnp.where(passed, acq.count, 0), (fan,))
-        concurrency = state.concurrency.at[rows].add(inc, mode="drop")
-        concurrency = concurrency.at[cfg.entry_node_row].add(
-            entry_deltas[W.EV_PASS] + entry_deltas[W.EV_OCCUPIED]
-        )
-    state = state._replace(concurrency=concurrency)
+        if hist is not None:  # MXU: concurrency rides the pass+occupied histogram
+            # (the histogram already carries the ENTRY-row reduction; occupying
+            # entries hold a concurrency slot even though their PASS lands later)
+            concurrency = state.concurrency + hist[:, W.EV_PASS] + hist[:, W.EV_OCCUPIED]
+        else:
+            fan = 3 if with_nodes else 1
+            rows = _stat_rows(cfg, acq.res, acq.ctx_node, acq.origin_node, with_nodes)
+            inc = jnp.tile(jnp.where(passed, acq.count, 0), (fan,))
+            concurrency = state.concurrency.at[rows].add(inc, mode="drop")
+            concurrency = concurrency.at[cfg.entry_node_row].add(
+                entry_deltas[W.EV_PASS] + entry_deltas[W.EV_OCCUPIED]
+            )
+        state = state._replace(concurrency=concurrency)
 
-    # warm-up drain accounting: exact per-slot admitted counts this second
-    # (pad-slot lanes drop — row F is never read, and dropping keeps this
-    # bit-identical with the fused path's row masking)
-    if "warmup" in features and fslots is not None:
-        K = cfg.flow_rules_per_resource
-        adm = _fan(passed, K) & (fslots < cfg.max_flow_rules)
-        acc_add = T.small_scatter_add(
-            cfg,
-            jnp.zeros((cfg.max_flow_rules + 1,), jnp.float32),
-            jnp.where(adm, fslots, jnp.int32(-1)),
-            jnp.where(adm, _fan(acq.count, K).astype(jnp.float32), 0.0),
-        )
-        state = state._replace(warm_acc=state.warm_acc + acc_add)
-
-    # param pass counting + THREAD concurrency (only admitted traffic
-    # consumes the per-value budget, like the token bucket decrement in
-    # ParamFlowChecker.passDefaultLocalCheck; ParamFlowSlot entry thread++)
-    if "param" in features:
-        KP = cfg.param_rules_per_resource
-        adm = _fan(passed, KP)
-        pcms = P.add(
-            pcms,
-            pcms_idx,
-            jnp.where((p_qps_add & adm)[:, None], prows, -1),
-            _fan(acq.count, KP),
-            cfg,
-        )
-        thread_mask = p_thread_add & adm
-        pconc = jax.lax.cond(
-            jnp.any(thread_mask),
-            lambda: P.conc_add(
+        # warm-up drain accounting: exact per-slot admitted counts this second
+        # (pad-slot lanes drop — row F is never read, and dropping keeps this
+        # bit-identical with the fused path's row masking)
+        if "warmup" in features and fslots is not None:
+            K = cfg.flow_rules_per_resource
+            adm = _fan(passed, K) & (fslots < cfg.max_flow_rules)
+            acc_add = T.small_scatter_add(
                 cfg,
-                state.pconc,
-                jnp.where(thread_mask[:, None], prows, -1),
-                _fan(acq.count, KP),
-                jnp.zeros_like(_fan(acq.count, KP)),
-            ),
-            lambda: state.pconc,
-        )
-        state = state._replace(pcms=pcms, pcms_epochs=pcms_epochs, pconc=pconc)
+                jnp.zeros((cfg.max_flow_rules + 1,), jnp.float32),
+                jnp.where(adm, fslots, jnp.int32(-1)),
+                jnp.where(adm, _fan(acq.count, K).astype(jnp.float32), 0.0),
+            )
+            state = state._replace(warm_acc=state.warm_acc + acc_add)
 
-    stats = None
-    res_stats = None
-    if cfg.device_telemetry:
-        stats = _device_stats(
-            cfg, state, rules, acq, verdict, valid, now_ms, 0, 0
-        )
-        if timeline_k(cfg) > 0:
-            res_stats = _device_res_stats(cfg, state, now_ms)
-    hot = None
-    if hotset_k(cfg) > 0:
-        hot = _device_hot_candidates(cfg, state, acq, valid, now_ms)
-    expl = None
-    if explain_k(cfg) > 0:
-        expl = _device_explain(
-            cfg, state, rules, acq, verdict, valid, forced, fslots, now_ms
-        )
-    return state, _tick_output(
-        cfg, verdict, wait_ms, 0, stats, res_stats, hot, expl
+        # param pass counting + THREAD concurrency (only admitted traffic
+        # consumes the per-value budget, like the token bucket decrement in
+        # ParamFlowChecker.passDefaultLocalCheck; ParamFlowSlot entry thread++)
+        if "param" in features:
+            KP = cfg.param_rules_per_resource
+            adm = _fan(passed, KP)
+            pcms = P.add(
+                pcms,
+                pcms_idx,
+                jnp.where((p_qps_add & adm)[:, None], prows, -1),
+                _fan(acq.count, KP),
+                cfg,
+            )
+            thread_mask = p_thread_add & adm
+            pconc = jax.lax.cond(
+                jnp.any(thread_mask),
+                lambda: P.conc_add(
+                    cfg,
+                    state.pconc,
+                    jnp.where(thread_mask[:, None], prows, -1),
+                    _fan(acq.count, KP),
+                    jnp.zeros_like(_fan(acq.count, KP)),
+                ),
+                lambda: state.pconc,
+            )
+            state = state._replace(pcms=pcms, pcms_epochs=pcms_epochs, pconc=pconc)
+
+    return state, _telemetry_and_output(
+        cfg, state, rules, acq, verdict, wait_ms, valid, forced, fslots,
+        now_ms, 0, 0,
     )
 
 
@@ -3103,6 +3115,8 @@ def migrate_state(
 
 _TICK_CACHE: dict = {}
 _TICK_CACHE_LOCK = threading.Lock()
+#: the jitted tick's program name (``jit_sentinel_tick`` in a trace)
+TICK_PROGRAM = "sentinel_tick"
 
 #: distinct compiled-tick builds this process created (each is a future
 #: XLA compile; a climbing count in steady state means config churn)
@@ -3139,6 +3153,9 @@ def make_tick(
         if fn is None:
             fn = functools.partial(tick, cfg=cfg, features=features)
             if jit:
+                # a bare partial compiles as ``jit__unknown``: the name is
+                # what a profiler trace lists the tick program under
+                fn.__name__ = TICK_PROGRAM
                 fn = jax.jit(fn, donate_argnums=(0,) if donate else ())
             _TICK_CACHE[key] = fn
             # a fresh tick build is a hot-swap/recompile PRECURSOR worth

@@ -318,233 +318,234 @@ def run_checks_seg(
     exp = _Expander(ctx)
 
     # ================= segment-level phase =================
-    with_auth = "authority" in features
-    with_param = "param" in features
-    with_flow = "flow" in features
-    with_degrade = "degrade" in features
+    with jax.named_scope("stage.segment_reads"):
+        with_auth = "authority" in features
+        with_param = "param" in features
+        with_flow = "flow" in features
+        with_degrade = "degrade" in features
 
-    # all four per-resource slot tables are read at the SAME index — one
-    # shared 8-lane row gather serves them (tables.lane_gather_multi; a
-    # separate lane gather each cost ~0.1 ms apiece at U~16K).  Keyed by
-    # NAME so the gather list and the consumers can never fall out of
-    # order.
-    n_res1 = cfg.max_resources + 1
-    slot_tabs = []
-    if with_auth:
-        slot_tabs.append(("auth", jnp.asarray(rules.auth.mode)))
-    if with_param:
-        slot_tabs.append(("param", jnp.asarray(rules.param.res_params)[:, 0]))
-    if with_flow:
-        slot_tabs.append(("flow", jnp.asarray(rules.flow.res_rules)[:, 0]))
-    if with_degrade:
-        slot_tabs.append(("degrade", jnp.asarray(rules.degrade.res_cbs)[:, 0]))
-    slot_vals = {
-        name: g.astype(jnp.int32)
-        for (name, _t), g in zip(
-            slot_tabs,
-            T.lane_gather_multi(cfg, [t for _n, t in slot_tabs], res_l, n_res1)
-            if slot_tabs
-            else [],
-        )
-    }
-
-    if with_auth:
-        n = n_res1
-        mode = slot_vals["auth"]
-        origins = T.big_gather(cfg, rules.auth.origins, res_l, n)
-        listed = (
-            (origins == carry.origin_id[:, None]) & (origins != RT.AUTH_EMPTY)
-        ).any(axis=1)
-        auth_u = ((mode == 1) & ~listed) | ((mode == 2) & listed)
-
-    if with_param:
-        # KP == 1 statically (the seg_checks gate) -> shared slot gather
-        pslot_u = slot_vals["param"]
-        pcms, pcms_epochs, pcms_idx = P.refresh(
-            state.pcms, state.pcms_epochs, now_ms, cfg
-        )
-        pgu = T.small_gather_fields(
-            cfg,
-            T.pack_fields(
-                [
-                    rules.param.enabled,
-                    rules.param.threshold,
-                    rules.param.grade,
-                    rules.param.cls,
-                    rules.param.lane,
-                ]
-            ),
-            pslot_u,
-        )
-        ih_u = T.small_gather_int(cfg, rules.param.item_hash, pslot_u)  # [U, KI]
-        it_u = T.small_gather_fields(
-            cfg, jnp.asarray(rules.param.item_threshold, jnp.float32), pslot_u
-        )
-        KI = ih_u.shape[1]
-        p_en_u = (pgu[:, 0] > 0) & live
-        p_thread_u = pgu[:, 2].astype(jnp.int32) == GRADE_THREAD
-        i_pflags = exp.add(
-            p_en_u.astype(jnp.int32) | (p_thread_u.astype(jnp.int32) << 1)
-        )
-        i_plane = exp.add(jnp.clip(pgu[:, 4].astype(jnp.int32), -1, cfg.param_dims - 1))
-        i_pslot = exp.add(jnp.where(live, pslot_u, cfg.max_param_rules))
-        i_pcls = exp.add(
-            jnp.clip(pgu[:, 3].astype(jnp.int32), 0, max(cfg.param_classes - 1, 0))
-        )
-        i_pthr = exp.add_f(pgu[:, 1])
-        i_ih = [exp.add(ih_u[:, k]) for k in range(KI)]
-        i_it = [exp.add_f(it_u[:, k]) for k in range(KI)]
-
-    if with_flow:
-        f = rules.flow
-        sec_cfg = W.WindowConfig(cfg.second_sample_count, cfg.second_window_ms)
-        slot_u = slot_vals["flow"]
-        fg = T.small_gather_fields(
-            cfg,
-            T.pack_fields(
-                [
-                    f.enabled, f.limit_app, f.strategy, f.ref_node, f.ref_ctx,
-                    f.grade, f.count, f.behavior, f.max_queue_ms,
-                    f.warning_token, f.slope, state.warmup_tokens,
-                ]
-            ),
-            slot_u,
-        )
-        latest_u = T.small_gather_int(
-            cfg, jnp.round(state.latest_passed_ms).astype(jnp.int32), slot_u
-        ).astype(jnp.float32)
-        enabled = fg[:, 0] > 0
-        la = fg[:, 1].astype(jnp.int32)
-        named = (la >= 0) & (la == carry.origin_id)
-        match = (
-            (la == RT.LIMIT_ANY)
-            | ((la >= 0) & (la == carry.origin_id))
-            | ((la == RT.LIMIT_OTHER) & (carry.origin_id >= 0) & ~named)
-        )
-        applicable_u = enabled & match & live
-        strategy = fg[:, 2].astype(jnp.int32)
-        ref_node = fg[:, 3].astype(jnp.int32)
-        ref_ctx = fg[:, 4].astype(jnp.int32)
-        direct_node = jnp.where(la == RT.LIMIT_ANY, carry.res, carry.origin_node)
-        chain_ok = (ref_ctx >= 0) & (ref_ctx == carry.ctx_name)
-        node = jnp.where(
-            strategy == STRATEGY_DIRECT,
-            direct_node,
-            jnp.where(
-                strategy == STRATEGY_RELATE,
-                ref_node,
-                jnp.where(chain_ok, carry.ctx_node, -1),
-            ),
-        )
-        node_ok = (node >= 0) & (node != cfg.trash_row)
-        applicable_u = applicable_u & node_ok
-        node_safe_u = jnp.where(node_ok & (node < cfg.node_rows), node, cfg.trash_row)
-        grade = fg[:, 5].astype(jnp.int32)
-        rcount = fg[:, 6]
-        behavior = jnp.where(
-            grade == GRADE_QPS, fg[:, 7].astype(jnp.int32), CONTROL_DEFAULT
-        )
-        rest = fg[:, 11]
-        warning = fg[:, 9]
-        above = jnp.maximum(rest - warning, 0.0)
-        warm_qps = jnp.floor(
-            1.0 / (above * fg[:, 10] + 1.0 / jnp.maximum(rcount, 1e-9)) + 0.5
-        )
-        warm_qps = jnp.where(rest >= warning, warm_qps, rcount)
-        is_warm = (behavior == CONTROL_WARM_UP) | (
-            behavior == CONTROL_WARM_UP_RATE_LIMITER
-        )
-        is_rl = (behavior == CONTROL_RATE_LIMITER) | (
-            behavior == CONTROL_WARM_UP_RATE_LIMITER
-        )
-        pace_qps = jnp.where(
-            behavior == CONTROL_WARM_UP_RATE_LIMITER,
-            warm_qps,
-            jnp.maximum(rcount, 1e-9),
-        )
-        thr_eff = jnp.where(is_warm, warm_qps, rcount)
-        cur_wid = W.wid_of(now_ms, cfg.second_window_ms)
-        pool_dense = jnp.where(
-            state.occ_epoch == cur_wid + 1, state.occ_tokens, 0.0
-        )
-        # running sums are exact here: completions refreshed this now_ms
-        # before checks (ops/window.py Option-B read contract)
-        wsum = W.window_event_run(state.win_sec, W.EV_PASS)
-        tab = jnp.stack(
-            [wsum, state.concurrency, jnp.round(pool_dense).astype(jnp.int32)],
-            axis=1,
-        )
-        g = tab[node_safe_u]
-        wp = g[:, 0].astype(jnp.float32)
-        conc = g[:, 1].astype(jnp.float32)
-        pool = g[:, 2].astype(jnp.float32)
-        i_fflags = exp.add(
-            applicable_u.astype(jnp.int32)
-            | (is_rl.astype(jnp.int32) << 1)
-            | ((behavior == CONTROL_WARM_UP_RATE_LIMITER).astype(jnp.int32) << 2)
-            | ((grade == GRADE_QPS).astype(jnp.int32) << 3)
-            | ((behavior == CONTROL_DEFAULT).astype(jnp.int32) << 4)
-        )
-        i_node = exp.add(node_safe_u)
-        i_fslot = exp.add(jnp.where(live, slot_u, cfg.max_flow_rules))
-        i_mq = exp.add_f(thr_eff - wp)
-        i_mt = exp.add_f(rcount - conc)
-        i_mrl = exp.add_f(latest_u - now_f)
-        i_maxq = exp.add_f(fg[:, 8])
-        i_pace = exp.add_f(pace_qps)
-        i_mo = exp.add_f(rcount - pool)
-
-    with_tail = "tail_flow" in features and cfg.sketch_stats
-    if with_tail:
-        # UNCONDITIONAL under the feature: "tail_flow" is only compiled in
-        # when sketch-id flow rules exist (client._select_features), so a
-        # lax.cond on any_tail_rules would buy nothing on real workloads
-        # while its boundary copies cost ~0.3-1.4 ms at B=128K (STATUS
-        # cond-boundary measurements).  With no rules loaded the gathers
-        # read UNRULED thresholds and nothing blocks — semantics identical.
-        thr_tab = jnp.asarray(rules.tail.thr)
-        tres_u = jnp.where(live, carry.res, -1)
-        tail_u = live & (tres_u >= cfg.node_rows)
-        tcols = P.cms_cell(tres_u, cfg.sketch_depth, cfg.sketch_width)
-        # ONE flat gather across all depths (tables.depth_gather_1col)
-        t = T.depth_gather_1col(cfg, thr_tab, tcols, cfg.sketch_width)
-        thr_u = jnp.max(
-            jnp.where(tail_u[None, :], t, RT.TAIL_UNRULED), axis=0
-        )
-        est_u = _sketch(cfg).estimate_plane_mxu(
-            cfg, state.gs, now_ms, tres_u, W.EV_PASS, E.sketch_config(cfg)
-        )
-        i_tthr = exp.add_f(thr_u)
-        i_test = exp.add_f(est_u)
-
-    if with_degrade:
-        dslot_u = slot_vals["degrade"]
-        dgu = T.small_gather_fields(
-            cfg, T.pack_fields([rules.degrade.enabled, state.cb_state]), dslot_u
-        )
-        d_en = (dgu[:, 0] > 0) & live
-        st_u = dgu[:, 1].astype(jnp.int32)
-        retry_due = now_ms >= T.small_gather_int(cfg, state.cb_retry_ms, dslot_u)
-        open_wait = (st_u == D.CB_OPEN) & ~retry_due
-        open_due = (st_u == D.CB_OPEN) & retry_due
-        half = st_u == D.CB_HALF_OPEN
-        i_dflags = exp.add(
-            d_en.astype(jnp.int32)
-            | (open_wait.astype(jnp.int32) << 1)
-            | (open_due.astype(jnp.int32) << 2)
-            | (half.astype(jnp.int32) << 3)
-        )
-        i_dslot = exp.add(
-            jnp.minimum(
-                jnp.where(live, dslot_u, cfg.max_degrade_rules),
-                cfg.max_degrade_rules,
+        # all four per-resource slot tables are read at the SAME index — one
+        # shared 8-lane row gather serves them (tables.lane_gather_multi; a
+        # separate lane gather each cost ~0.1 ms apiece at U~16K).  Keyed by
+        # NAME so the gather list and the consumers can never fall out of
+        # order.
+        n_res1 = cfg.max_resources + 1
+        slot_tabs = []
+        if with_auth:
+            slot_tabs.append(("auth", jnp.asarray(rules.auth.mode)))
+        if with_param:
+            slot_tabs.append(("param", jnp.asarray(rules.param.res_params)[:, 0]))
+        if with_flow:
+            slot_tabs.append(("flow", jnp.asarray(rules.flow.res_rules)[:, 0]))
+        if with_degrade:
+            slot_tabs.append(("degrade", jnp.asarray(rules.degrade.res_cbs)[:, 0]))
+        slot_vals = {
+            name: g.astype(jnp.int32)
+            for (name, _t), g in zip(
+                slot_tabs,
+                T.lane_gather_multi(cfg, [t for _n, t in slot_tabs], res_l, n_res1)
+                if slot_tabs
+                else [],
             )
-        )
+        }
 
-    if with_auth:
-        i_auth = exp.add(auth_u.astype(jnp.int32))
+        if with_auth:
+            n = n_res1
+            mode = slot_vals["auth"]
+            origins = T.big_gather(cfg, rules.auth.origins, res_l, n)
+            listed = (
+                (origins == carry.origin_id[:, None]) & (origins != RT.AUTH_EMPTY)
+            ).any(axis=1)
+            auth_u = ((mode == 1) & ~listed) | ((mode == 2) & listed)
 
-    exp.run()
+        if with_param:
+            # KP == 1 statically (the seg_checks gate) -> shared slot gather
+            pslot_u = slot_vals["param"]
+            pcms, pcms_epochs, pcms_idx = P.refresh(
+                state.pcms, state.pcms_epochs, now_ms, cfg
+            )
+            pgu = T.small_gather_fields(
+                cfg,
+                T.pack_fields(
+                    [
+                        rules.param.enabled,
+                        rules.param.threshold,
+                        rules.param.grade,
+                        rules.param.cls,
+                        rules.param.lane,
+                    ]
+                ),
+                pslot_u,
+            )
+            ih_u = T.small_gather_int(cfg, rules.param.item_hash, pslot_u)  # [U, KI]
+            it_u = T.small_gather_fields(
+                cfg, jnp.asarray(rules.param.item_threshold, jnp.float32), pslot_u
+            )
+            KI = ih_u.shape[1]
+            p_en_u = (pgu[:, 0] > 0) & live
+            p_thread_u = pgu[:, 2].astype(jnp.int32) == GRADE_THREAD
+            i_pflags = exp.add(
+                p_en_u.astype(jnp.int32) | (p_thread_u.astype(jnp.int32) << 1)
+            )
+            i_plane = exp.add(jnp.clip(pgu[:, 4].astype(jnp.int32), -1, cfg.param_dims - 1))
+            i_pslot = exp.add(jnp.where(live, pslot_u, cfg.max_param_rules))
+            i_pcls = exp.add(
+                jnp.clip(pgu[:, 3].astype(jnp.int32), 0, max(cfg.param_classes - 1, 0))
+            )
+            i_pthr = exp.add_f(pgu[:, 1])
+            i_ih = [exp.add(ih_u[:, k]) for k in range(KI)]
+            i_it = [exp.add_f(it_u[:, k]) for k in range(KI)]
+
+        if with_flow:
+            f = rules.flow
+            sec_cfg = W.WindowConfig(cfg.second_sample_count, cfg.second_window_ms)
+            slot_u = slot_vals["flow"]
+            fg = T.small_gather_fields(
+                cfg,
+                T.pack_fields(
+                    [
+                        f.enabled, f.limit_app, f.strategy, f.ref_node, f.ref_ctx,
+                        f.grade, f.count, f.behavior, f.max_queue_ms,
+                        f.warning_token, f.slope, state.warmup_tokens,
+                    ]
+                ),
+                slot_u,
+            )
+            latest_u = T.small_gather_int(
+                cfg, jnp.round(state.latest_passed_ms).astype(jnp.int32), slot_u
+            ).astype(jnp.float32)
+            enabled = fg[:, 0] > 0
+            la = fg[:, 1].astype(jnp.int32)
+            named = (la >= 0) & (la == carry.origin_id)
+            match = (
+                (la == RT.LIMIT_ANY)
+                | ((la >= 0) & (la == carry.origin_id))
+                | ((la == RT.LIMIT_OTHER) & (carry.origin_id >= 0) & ~named)
+            )
+            applicable_u = enabled & match & live
+            strategy = fg[:, 2].astype(jnp.int32)
+            ref_node = fg[:, 3].astype(jnp.int32)
+            ref_ctx = fg[:, 4].astype(jnp.int32)
+            direct_node = jnp.where(la == RT.LIMIT_ANY, carry.res, carry.origin_node)
+            chain_ok = (ref_ctx >= 0) & (ref_ctx == carry.ctx_name)
+            node = jnp.where(
+                strategy == STRATEGY_DIRECT,
+                direct_node,
+                jnp.where(
+                    strategy == STRATEGY_RELATE,
+                    ref_node,
+                    jnp.where(chain_ok, carry.ctx_node, -1),
+                ),
+            )
+            node_ok = (node >= 0) & (node != cfg.trash_row)
+            applicable_u = applicable_u & node_ok
+            node_safe_u = jnp.where(node_ok & (node < cfg.node_rows), node, cfg.trash_row)
+            grade = fg[:, 5].astype(jnp.int32)
+            rcount = fg[:, 6]
+            behavior = jnp.where(
+                grade == GRADE_QPS, fg[:, 7].astype(jnp.int32), CONTROL_DEFAULT
+            )
+            rest = fg[:, 11]
+            warning = fg[:, 9]
+            above = jnp.maximum(rest - warning, 0.0)
+            warm_qps = jnp.floor(
+                1.0 / (above * fg[:, 10] + 1.0 / jnp.maximum(rcount, 1e-9)) + 0.5
+            )
+            warm_qps = jnp.where(rest >= warning, warm_qps, rcount)
+            is_warm = (behavior == CONTROL_WARM_UP) | (
+                behavior == CONTROL_WARM_UP_RATE_LIMITER
+            )
+            is_rl = (behavior == CONTROL_RATE_LIMITER) | (
+                behavior == CONTROL_WARM_UP_RATE_LIMITER
+            )
+            pace_qps = jnp.where(
+                behavior == CONTROL_WARM_UP_RATE_LIMITER,
+                warm_qps,
+                jnp.maximum(rcount, 1e-9),
+            )
+            thr_eff = jnp.where(is_warm, warm_qps, rcount)
+            cur_wid = W.wid_of(now_ms, cfg.second_window_ms)
+            pool_dense = jnp.where(
+                state.occ_epoch == cur_wid + 1, state.occ_tokens, 0.0
+            )
+            # running sums are exact here: completions refreshed this now_ms
+            # before checks (ops/window.py Option-B read contract)
+            wsum = W.window_event_run(state.win_sec, W.EV_PASS)
+            tab = jnp.stack(
+                [wsum, state.concurrency, jnp.round(pool_dense).astype(jnp.int32)],
+                axis=1,
+            )
+            g = tab[node_safe_u]
+            wp = g[:, 0].astype(jnp.float32)
+            conc = g[:, 1].astype(jnp.float32)
+            pool = g[:, 2].astype(jnp.float32)
+            i_fflags = exp.add(
+                applicable_u.astype(jnp.int32)
+                | (is_rl.astype(jnp.int32) << 1)
+                | ((behavior == CONTROL_WARM_UP_RATE_LIMITER).astype(jnp.int32) << 2)
+                | ((grade == GRADE_QPS).astype(jnp.int32) << 3)
+                | ((behavior == CONTROL_DEFAULT).astype(jnp.int32) << 4)
+            )
+            i_node = exp.add(node_safe_u)
+            i_fslot = exp.add(jnp.where(live, slot_u, cfg.max_flow_rules))
+            i_mq = exp.add_f(thr_eff - wp)
+            i_mt = exp.add_f(rcount - conc)
+            i_mrl = exp.add_f(latest_u - now_f)
+            i_maxq = exp.add_f(fg[:, 8])
+            i_pace = exp.add_f(pace_qps)
+            i_mo = exp.add_f(rcount - pool)
+
+        with_tail = "tail_flow" in features and cfg.sketch_stats
+        if with_tail:
+            # UNCONDITIONAL under the feature: "tail_flow" is only compiled in
+            # when sketch-id flow rules exist (client._select_features), so a
+            # lax.cond on any_tail_rules would buy nothing on real workloads
+            # while its boundary copies cost ~0.3-1.4 ms at B=128K (STATUS
+            # cond-boundary measurements).  With no rules loaded the gathers
+            # read UNRULED thresholds and nothing blocks — semantics identical.
+            thr_tab = jnp.asarray(rules.tail.thr)
+            tres_u = jnp.where(live, carry.res, -1)
+            tail_u = live & (tres_u >= cfg.node_rows)
+            tcols = P.cms_cell(tres_u, cfg.sketch_depth, cfg.sketch_width)
+            # ONE flat gather across all depths (tables.depth_gather_1col)
+            t = T.depth_gather_1col(cfg, thr_tab, tcols, cfg.sketch_width)
+            thr_u = jnp.max(
+                jnp.where(tail_u[None, :], t, RT.TAIL_UNRULED), axis=0
+            )
+            est_u = _sketch(cfg).estimate_plane_mxu(
+                cfg, state.gs, now_ms, tres_u, W.EV_PASS, E.sketch_config(cfg)
+            )
+            i_tthr = exp.add_f(thr_u)
+            i_test = exp.add_f(est_u)
+
+        if with_degrade:
+            dslot_u = slot_vals["degrade"]
+            dgu = T.small_gather_fields(
+                cfg, T.pack_fields([rules.degrade.enabled, state.cb_state]), dslot_u
+            )
+            d_en = (dgu[:, 0] > 0) & live
+            st_u = dgu[:, 1].astype(jnp.int32)
+            retry_due = now_ms >= T.small_gather_int(cfg, state.cb_retry_ms, dslot_u)
+            open_wait = (st_u == D.CB_OPEN) & ~retry_due
+            open_due = (st_u == D.CB_OPEN) & retry_due
+            half = st_u == D.CB_HALF_OPEN
+            i_dflags = exp.add(
+                d_en.astype(jnp.int32)
+                | (open_wait.astype(jnp.int32) << 1)
+                | (open_due.astype(jnp.int32) << 2)
+                | (half.astype(jnp.int32) << 3)
+            )
+            i_dslot = exp.add(
+                jnp.minimum(
+                    jnp.where(live, dslot_u, cfg.max_degrade_rules),
+                    cfg.max_degrade_rules,
+                )
+            )
+
+        if with_auth:
+            i_auth = exp.add(auth_u.astype(jnp.int32))
+
+        exp.run()
 
     # ================= item-level phase (slot order) =================
     # Items in segments past the compacted capacity have no segment-level
@@ -555,12 +556,13 @@ def run_checks_seg(
     # block as system rejections rather than pass unchecked.
     overflow = valid & (ctx.sid >= ctx.U)
 
-    if with_auth:
-        # ~overflow: garbage expansions must not mislabel the fail-closed
-        # block as BLOCK_AUTHORITY (it lands as a system rejection below)
-        auth_block = (exp.get(i_auth) > 0) & valid & ~forced & ~overflow
-    else:
-        auth_block = zero_block
+    with jax.named_scope("stage.authority"):
+        if with_auth:
+            # ~overflow: garbage expansions must not mislabel the fail-closed
+            # block as BLOCK_AUTHORITY (it lands as a system rejection below)
+            auth_block = (exp.get(i_auth) > 0) & valid & ~forced & ~overflow
+        else:
+            auth_block = zero_block
     eligible = valid & ~auth_block & ~forced & ~overflow
 
     if "system" in features:
@@ -572,296 +574,300 @@ def run_checks_seg(
         sys_block = zero_block | overflow
     eligible = eligible & ~sys_block
 
-    if with_param:
-        fl = exp.get(i_pflags)
-        p_en_i = (fl & 1) > 0
-        p_thread_i = (fl & 2) > 0
-        lane_i = exp.get(i_plane)
-        pslot_i = exp.get(i_pslot)
-        cls_i = exp.get(i_pcls)
-        pthr_i = exp.get_f(i_pthr)
-        lane_oh = jnp.clip(lane_i, 0, cfg.param_dims - 1)[
-            :, None
-        ] == jax.lax.broadcasted_iota(jnp.int32, (1, cfg.param_dims), 1)
-        ph = jnp.sum(jnp.where(lane_oh, acq.param_hash, 0), axis=1)
-        ph = jnp.where(lane_i >= 0, ph, 0)
-        p_app = p_en_i & (ph != 0)
-        prows = P.pair_rows(pslot_i, ph, cfg.param_depth, cfg.param_width)
-        wtab = P.class_tables(
-            pcms, pcms_epochs, jnp.asarray(rules.param.class_k), now_ms, cfg
-        )
-        est = P.estimate_fused(cfg, wtab, prows, cls_i)
-        any_thread = jnp.any(
-            jnp.asarray(rules.param.enabled)
-            & (jnp.asarray(rules.param.grade) == GRADE_THREAD)
-        )
-        conc_est = jax.lax.cond(
-            any_thread,
-            lambda: P.conc_estimate(cfg, state.pconc, prows),
-            lambda: jnp.zeros((prows.shape[0],), jnp.float32),
-        )
-        is_item = jnp.zeros((b,), bool)
-        item_thr = jnp.zeros((b,), jnp.float32)
-        for k in range(KI):
-            ihk = exp.get(i_ih[k])
-            itk = exp.get_f(i_it[k])
-            hit = (ihk == ph) & (ihk != 0)
-            item_thr = jnp.where(hit, jnp.maximum(item_thr, itk), item_thr)
-            is_item = is_item | hit
-        pthr = jnp.where(is_item, item_thr, pthr_i)
-        elig_p = eligible & p_app
-        key = ph * jnp.int32(2) + pslot_i  # KP == 1
-        (p_rank,) = grouped_exclusive_cumsum(key, [cnt], elig_p)
-        over = jnp.where(p_thread_i, conc_est, est) + p_rank + cnt > pthr
-        param_block = p_app & over & elig_p & eligible
-        param_state = (
-            pcms, pcms_epochs, pcms_idx, prows,
-            p_app & ~p_thread_i, p_app & p_thread_i,
-        )
-    else:
-        param_block = zero_block
-        param_state = None
+    with jax.named_scope("stage.param"):
+        if with_param:
+            fl = exp.get(i_pflags)
+            p_en_i = (fl & 1) > 0
+            p_thread_i = (fl & 2) > 0
+            lane_i = exp.get(i_plane)
+            pslot_i = exp.get(i_pslot)
+            cls_i = exp.get(i_pcls)
+            pthr_i = exp.get_f(i_pthr)
+            lane_oh = jnp.clip(lane_i, 0, cfg.param_dims - 1)[
+                :, None
+            ] == jax.lax.broadcasted_iota(jnp.int32, (1, cfg.param_dims), 1)
+            ph = jnp.sum(jnp.where(lane_oh, acq.param_hash, 0), axis=1)
+            ph = jnp.where(lane_i >= 0, ph, 0)
+            p_app = p_en_i & (ph != 0)
+            prows = P.pair_rows(pslot_i, ph, cfg.param_depth, cfg.param_width)
+            wtab = P.class_tables(
+                pcms, pcms_epochs, jnp.asarray(rules.param.class_k), now_ms, cfg
+            )
+            est = P.estimate_fused(cfg, wtab, prows, cls_i)
+            any_thread = jnp.any(
+                jnp.asarray(rules.param.enabled)
+                & (jnp.asarray(rules.param.grade) == GRADE_THREAD)
+            )
+            conc_est = jax.lax.cond(
+                any_thread,
+                lambda: P.conc_estimate(cfg, state.pconc, prows),
+                lambda: jnp.zeros((prows.shape[0],), jnp.float32),
+            )
+            is_item = jnp.zeros((b,), bool)
+            item_thr = jnp.zeros((b,), jnp.float32)
+            for k in range(KI):
+                ihk = exp.get(i_ih[k])
+                itk = exp.get_f(i_it[k])
+                hit = (ihk == ph) & (ihk != 0)
+                item_thr = jnp.where(hit, jnp.maximum(item_thr, itk), item_thr)
+                is_item = is_item | hit
+            pthr = jnp.where(is_item, item_thr, pthr_i)
+            elig_p = eligible & p_app
+            key = ph * jnp.int32(2) + pslot_i  # KP == 1
+            (p_rank,) = grouped_exclusive_cumsum(key, [cnt], elig_p)
+            over = jnp.where(p_thread_i, conc_est, est) + p_rank + cnt > pthr
+            param_block = p_app & over & elig_p & eligible
+            param_state = (
+                pcms, pcms_epochs, pcms_idx, prows,
+                p_app & ~p_thread_i, p_app & p_thread_i,
+            )
+        else:
+            param_block = zero_block
+            param_state = None
     eligible = eligible & ~param_block
 
-    occupy = "occupy" in features
-    if with_flow:
-        fl = exp.get(i_fflags)
-        app_i = (fl & 1) > 0
-        rl_i = (fl & 2) > 0
-        wurl_i = (fl & 4) > 0
-        qps_i = (fl & 8) > 0
-        def_i = (fl & 16) > 0
-        node_i = exp.get(i_node)
-        slot_i = exp.get(i_fslot)
-        margin_q = exp.get_f(i_mq)
-        margin_t = exp.get_f(i_mt)
-        m_rl = exp.get_f(i_mrl)
-        mq_i = exp.get_f(i_maxq)
-        pace_i = exp.get_f(i_pace)
-        margin_o = exp.get_f(i_mo)
-        # same 3-digit pacing-cost clamp as _check_flow (int32 rank safety)
-        cost = jnp.where(
-            rl_i,
-            jnp.minimum(
-                jnp.floor(1000.0 * cnt / pace_i + 0.5), float((1 << 24) - 1)
-            ),
-            0.0,
-        )
-        elig_f = eligible & app_i
-        rank_key = jnp.where(rl_i, jnp.int32(cfg.node_rows) + slot_i, node_i)
-        direct_any = ~jnp.any(
-            jnp.asarray(f.enabled)
-            & (
-                (jnp.asarray(f.strategy) != STRATEGY_DIRECT)
-                | (jnp.asarray(f.limit_app) != RT.LIMIT_ANY)
-            )
-        )
-        seg_rank_ok = carry.res_sorted & direct_any
-
-        def _ranks_seg():
-            head_k = jnp.concatenate(
-                [jnp.ones((1,), bool), rank_key[1:] != rank_key[:-1]]
-            )
-            r = SC.seg_excl_cumsum_pl(
-                head_k,
-                jnp.stack(
-                    [jnp.where(elig_f, acq.count, 0), elig_f.astype(jnp.int32)]
+    with jax.named_scope("stage.flow"):
+        occupy = "occupy" in features
+        if with_flow:
+            fl = exp.get(i_fflags)
+            app_i = (fl & 1) > 0
+            rl_i = (fl & 2) > 0
+            wurl_i = (fl & 4) > 0
+            qps_i = (fl & 8) > 0
+            def_i = (fl & 16) > 0
+            node_i = exp.get(i_node)
+            slot_i = exp.get(i_fslot)
+            margin_q = exp.get_f(i_mq)
+            margin_t = exp.get_f(i_mt)
+            m_rl = exp.get_f(i_mrl)
+            mq_i = exp.get_f(i_maxq)
+            pace_i = exp.get_f(i_pace)
+            margin_o = exp.get_f(i_mo)
+            # same 3-digit pacing-cost clamp as _check_flow (int32 rank safety)
+            cost = jnp.where(
+                rl_i,
+                jnp.minimum(
+                    jnp.floor(1000.0 * cnt / pace_i + 0.5), float((1 << 24) - 1)
                 ),
+                0.0,
             )
-            rc = SC.seg_excl_cumsum_wide_pl(
-                head_k, jnp.where(elig_f, cost, 0.0).astype(jnp.int32)
-            )
-            return r[0].astype(jnp.float32), r[1].astype(jnp.float32), rc
-
-        def _ranks_sort():
-            return E._rank(
-                cfg,
-                rank_key,
-                [cnt, jnp.ones_like(cnt), cost],
-                elig_f,
-                cfg.node_rows + cfg.max_flow_rules + 1,
-            )
-
-        if cfg.seg_static_ranks:
-            # scans only (cfg contract: sorted + DIRECT/ANY rules); if the
-            # contract breaks at runtime, ranks are garbage — fail closed
-            # below by blocking every applicable item rather than
-            # misranking silently
-            rank_tok, rank_thr, rank_cost = _ranks_seg()
-            rank_guard = ~seg_rank_ok
-        else:
-            rank_tok, rank_thr, rank_cost = jax.lax.cond(
-                seg_rank_ok, _ranks_seg, _ranks_sort
-            )
-            rank_guard = jnp.zeros((), bool)
-        qps_block = rank_tok + cnt > margin_q
-        thread_block = rank_thr + cnt > margin_t
-        basic_block = jnp.where(qps_i, qps_block, thread_block)
-        csum_incl = rank_cost + cost
-        rl_wait = jnp.maximum(m_rl + csum_incl, csum_incl - cost)
-        rl_block = rl_wait > mq_i
-        entry_block = jnp.where(rl_i, rl_block, basic_block) & app_i
-        entry_block = entry_block | (wurl_i & app_i & qps_block)
-        entry_block = entry_block | (rank_guard & app_i)
-        flow_block = entry_block & elig_f
-
-        occupying = jnp.zeros((b,), bool)
-        occ_wait = jnp.zeros((b,), jnp.float32)
-        occ_grant = None
-        if occupy:
-            cand = (acq.prio > 0) & def_i & qps_i & app_i & elig_f & qps_block
-            if cfg.seg_static_ranks:
-                # under a broken static-rank contract nothing may occupy
-                # ahead (a garbage grant would bypass the fail-closed
-                # entry_block above)
-                cand = cand & ~rank_guard
-
-            def _occ_rank(cand):
-                def _seg():
-                    head_n = jnp.concatenate(
-                        [jnp.ones((1,), bool), node_i[1:] != node_i[:-1]]
-                    )
-                    (r,) = SC.seg_excl_cumsum_pl(
-                        head_n, jnp.where(cand, acq.count, 0)[None, :]
-                    )
-                    return r.astype(jnp.float32)
-
-                def _sort():
-                    (r,) = E._rank(cfg, node_i, [cnt], cand, cfg.node_rows)
-                    return r
-
-                if cfg.seg_static_ranks:
-                    # contract break -> rank_guard already blocks the
-                    # entry, so a garbage occupy rank cannot grant
-                    rank_occ = _seg()
-                else:
-                    rank_occ = jax.lax.cond(seg_rank_ok, _seg, _sort)
-                return cand & (rank_occ + cnt <= margin_o)
-
-            granted = jax.lax.cond(
-                jnp.any(cand), _occ_rank, lambda c: jnp.zeros_like(c), cand
-            )
-            still_blocked = entry_block & ~granted & elig_f
-            occupying = granted & elig_f & ~still_blocked
-            flow_block = still_blocked
-            occ_wait_v = (
-                cfg.second_window_ms - (now_ms % cfg.second_window_ms)
-            ).astype(jnp.float32)
-            occ_wait = jnp.where(occupying, occ_wait_v, 0.0)
-            occ_grant = (granted & elig_f, node_i, cnt)
-
-        rl_ok = rl_i & app_i & ~entry_block & elig_f & ~flow_block
-        wait_ms_entry = jnp.where(rl_ok, jnp.maximum(rl_wait, 0.0), 0.0)
-        wait_ms = jnp.maximum(wait_ms_entry, occ_wait).astype(jnp.int32)
-        fslots = slot_i
-        rl_info = (rl_ok, cost)
-    else:
-        flow_block = zero_block
-        occupying = zero_block
-        occ_grant = None
-        fslots = None
-        rl_info = None
-        wait_ms = jnp.zeros((b,), jnp.int32)
-
-    if with_tail:
-        # unconditional (see the segment-level tail phase above): the rank
-        # scan + compare interior is cheap next to the cond boundary it
-        # replaced, and with no ruled tail items `ruled` is all-False
-        thr = jnp.where(
-            eligible & (acq.res >= cfg.node_rows),
-            exp.get_f(i_tthr),
-            RT.TAIL_UNRULED,
-        )
-        est_t = exp.get_f(i_test)
-        ruled = thr < RT.TAIL_UNRULED / 2
-
-        def _tail_seg():
-            head_r = jnp.concatenate(
-                [jnp.ones((1,), bool), acq.res[1:] != acq.res[:-1]]
-            )
-            (r,) = SC.seg_excl_cumsum_pl(
-                head_r, jnp.where(ruled, acq.count, 0)[None, :]
-            )
-            return r.astype(jnp.float32)
-
-        def _tail_sort():
-            (r,) = grouped_exclusive_cumsum(acq.res, [cnt], ruled)
-            return r
-
-        if cfg.seg_static_ranks:
-            # unsorted batch under the static contract: block ruled
-            # tail items outright (fail closed, loud) — t_rank would
-            # be garbage
-            t_rank = _tail_seg()
-            tail_block = ruled & (
-                (est_t + t_rank + cnt > thr) | ~carry.res_sorted
-            )
-        else:
-            t_rank = jax.lax.cond(carry.res_sorted, _tail_seg, _tail_sort)
-            tail_block = ruled & (est_t + t_rank + cnt > thr)
-        flow_block = flow_block | (tail_block & eligible)
-    eligible = eligible & ~flow_block
-
-    if with_degrade:
-        fl = exp.get(i_dflags)
-        en_i = (fl & 1) > 0
-        ow_i = (fl & 2) > 0
-        od_i = (fl & 4) > 0
-        hf_i = (fl & 8) > 0
-        dslot_i = exp.get(i_dslot)
-        probe_cand = od_i & en_i & eligible
-
-        def _probe_rank(cand):
-            def _seg():
-                head_s = jnp.concatenate(
-                    [jnp.ones((1,), bool), dslot_i[1:] != dslot_i[:-1]]
+            elig_f = eligible & app_i
+            rank_key = jnp.where(rl_i, jnp.int32(cfg.node_rows) + slot_i, node_i)
+            direct_any = ~jnp.any(
+                jnp.asarray(f.enabled)
+                & (
+                    (jnp.asarray(f.strategy) != STRATEGY_DIRECT)
+                    | (jnp.asarray(f.limit_app) != RT.LIMIT_ANY)
                 )
-                (r,) = SC.seg_excl_cumsum_pl(head_s, cand.astype(jnp.int32)[None, :])
+            )
+            seg_rank_ok = carry.res_sorted & direct_any
+
+            def _ranks_seg():
+                head_k = jnp.concatenate(
+                    [jnp.ones((1,), bool), rank_key[1:] != rank_key[:-1]]
+                )
+                r = SC.seg_excl_cumsum_pl(
+                    head_k,
+                    jnp.stack(
+                        [jnp.where(elig_f, acq.count, 0), elig_f.astype(jnp.int32)]
+                    ),
+                )
+                rc = SC.seg_excl_cumsum_wide_pl(
+                    head_k, jnp.where(elig_f, cost, 0.0).astype(jnp.int32)
+                )
+                return r[0].astype(jnp.float32), r[1].astype(jnp.float32), rc
+
+            def _ranks_sort():
+                return E._rank(
+                    cfg,
+                    rank_key,
+                    [cnt, jnp.ones_like(cnt), cost],
+                    elig_f,
+                    cfg.node_rows + cfg.max_flow_rules + 1,
+                )
+
+            if cfg.seg_static_ranks:
+                # scans only (cfg contract: sorted + DIRECT/ANY rules); if the
+                # contract breaks at runtime, ranks are garbage — fail closed
+                # below by blocking every applicable item rather than
+                # misranking silently
+                rank_tok, rank_thr, rank_cost = _ranks_seg()
+                rank_guard = ~seg_rank_ok
+            else:
+                rank_tok, rank_thr, rank_cost = jax.lax.cond(
+                    seg_rank_ok, _ranks_seg, _ranks_sort
+                )
+                rank_guard = jnp.zeros((), bool)
+            qps_block = rank_tok + cnt > margin_q
+            thread_block = rank_thr + cnt > margin_t
+            basic_block = jnp.where(qps_i, qps_block, thread_block)
+            csum_incl = rank_cost + cost
+            rl_wait = jnp.maximum(m_rl + csum_incl, csum_incl - cost)
+            rl_block = rl_wait > mq_i
+            entry_block = jnp.where(rl_i, rl_block, basic_block) & app_i
+            entry_block = entry_block | (wurl_i & app_i & qps_block)
+            entry_block = entry_block | (rank_guard & app_i)
+            flow_block = entry_block & elig_f
+
+            occupying = jnp.zeros((b,), bool)
+            occ_wait = jnp.zeros((b,), jnp.float32)
+            occ_grant = None
+            if occupy:
+                cand = (acq.prio > 0) & def_i & qps_i & app_i & elig_f & qps_block
+                if cfg.seg_static_ranks:
+                    # under a broken static-rank contract nothing may occupy
+                    # ahead (a garbage grant would bypass the fail-closed
+                    # entry_block above)
+                    cand = cand & ~rank_guard
+
+                def _occ_rank(cand):
+                    def _seg():
+                        head_n = jnp.concatenate(
+                            [jnp.ones((1,), bool), node_i[1:] != node_i[:-1]]
+                        )
+                        (r,) = SC.seg_excl_cumsum_pl(
+                            head_n, jnp.where(cand, acq.count, 0)[None, :]
+                        )
+                        return r.astype(jnp.float32)
+
+                    def _sort():
+                        (r,) = E._rank(cfg, node_i, [cnt], cand, cfg.node_rows)
+                        return r
+
+                    if cfg.seg_static_ranks:
+                        # contract break -> rank_guard already blocks the
+                        # entry, so a garbage occupy rank cannot grant
+                        rank_occ = _seg()
+                    else:
+                        rank_occ = jax.lax.cond(seg_rank_ok, _seg, _sort)
+                    return cand & (rank_occ + cnt <= margin_o)
+
+                granted = jax.lax.cond(
+                    jnp.any(cand), _occ_rank, lambda c: jnp.zeros_like(c), cand
+                )
+                still_blocked = entry_block & ~granted & elig_f
+                occupying = granted & elig_f & ~still_blocked
+                flow_block = still_blocked
+                occ_wait_v = (
+                    cfg.second_window_ms - (now_ms % cfg.second_window_ms)
+                ).astype(jnp.float32)
+                occ_wait = jnp.where(occupying, occ_wait_v, 0.0)
+                occ_grant = (granted & elig_f, node_i, cnt)
+
+            rl_ok = rl_i & app_i & ~entry_block & elig_f & ~flow_block
+            wait_ms_entry = jnp.where(rl_ok, jnp.maximum(rl_wait, 0.0), 0.0)
+            wait_ms = jnp.maximum(wait_ms_entry, occ_wait).astype(jnp.int32)
+            fslots = slot_i
+            rl_info = (rl_ok, cost)
+        else:
+            flow_block = zero_block
+            occupying = zero_block
+            occ_grant = None
+            fslots = None
+            rl_info = None
+            wait_ms = jnp.zeros((b,), jnp.int32)
+
+    with jax.named_scope("stage.tail_flow"):
+        if with_tail:
+            # unconditional (see the segment-level tail phase above): the rank
+            # scan + compare interior is cheap next to the cond boundary it
+            # replaced, and with no ruled tail items `ruled` is all-False
+            thr = jnp.where(
+                eligible & (acq.res >= cfg.node_rows),
+                exp.get_f(i_tthr),
+                RT.TAIL_UNRULED,
+            )
+            est_t = exp.get_f(i_test)
+            ruled = thr < RT.TAIL_UNRULED / 2
+
+            def _tail_seg():
+                head_r = jnp.concatenate(
+                    [jnp.ones((1,), bool), acq.res[1:] != acq.res[:-1]]
+                )
+                (r,) = SC.seg_excl_cumsum_pl(
+                    head_r, jnp.where(ruled, acq.count, 0)[None, :]
+                )
                 return r.astype(jnp.float32)
 
-            def _sort():
-                (r,) = E._rank(
-                    cfg,
-                    dslot_i,
-                    [jnp.ones_like(dslot_i, dtype=jnp.float32)],
-                    cand,
-                    cfg.max_degrade_rules + 1,
-                )
+            def _tail_sort():
+                (r,) = grouped_exclusive_cumsum(acq.res, [cnt], ruled)
                 return r
 
             if cfg.seg_static_ranks:
-                # unsorted under the static contract: elect NO probes
-                # (conservative — the breaker simply stays open a tick)
-                p_rank = _seg()
-                return cand & (p_rank < 0.5) & carry.res_sorted
-            p_rank = jax.lax.cond(carry.res_sorted, _seg, _sort)
-            return cand & (p_rank < 0.5)
+                # unsorted batch under the static contract: block ruled
+                # tail items outright (fail closed, loud) — t_rank would
+                # be garbage
+                t_rank = _tail_seg()
+                tail_block = ruled & (
+                    (est_t + t_rank + cnt > thr) | ~carry.res_sorted
+                )
+            else:
+                t_rank = jax.lax.cond(carry.res_sorted, _tail_seg, _tail_sort)
+                tail_block = ruled & (est_t + t_rank + cnt > thr)
+            flow_block = flow_block | (tail_block & eligible)
+    eligible = eligible & ~flow_block
 
-        probe = jax.lax.cond(
-            jnp.any(probe_cand),
-            _probe_rank,
-            lambda c: jnp.zeros_like(c),
-            probe_cand,
-        )
-        entry_blk_d = en_i & (ow_i | (od_i & ~probe) | hf_i)
-        degrade_block = entry_blk_d & eligible
-        probe_ok = probe & ~degrade_block
-        Dn1 = cfg.max_degrade_rules + 1
-        flip = jax.lax.cond(
-            jnp.any(probe_ok),
-            lambda: T.small_scatter_or(
-                cfg, jnp.zeros((Dn1,), jnp.int32), dslot_i, probe_ok
-            ),
-            lambda: jnp.zeros((Dn1,), jnp.int32),
-        )
-        cb_state = jnp.where(
-            (flip > 0) & (state.cb_state == D.CB_OPEN),
-            D.CB_HALF_OPEN,
-            state.cb_state,
-        )
-    else:
-        degrade_block = zero_block
-        cb_state = state.cb_state
+    with jax.named_scope("stage.degrade"):
+        if with_degrade:
+            fl = exp.get(i_dflags)
+            en_i = (fl & 1) > 0
+            ow_i = (fl & 2) > 0
+            od_i = (fl & 4) > 0
+            hf_i = (fl & 8) > 0
+            dslot_i = exp.get(i_dslot)
+            probe_cand = od_i & en_i & eligible
+
+            def _probe_rank(cand):
+                def _seg():
+                    head_s = jnp.concatenate(
+                        [jnp.ones((1,), bool), dslot_i[1:] != dslot_i[:-1]]
+                    )
+                    (r,) = SC.seg_excl_cumsum_pl(head_s, cand.astype(jnp.int32)[None, :])
+                    return r.astype(jnp.float32)
+
+                def _sort():
+                    (r,) = E._rank(
+                        cfg,
+                        dslot_i,
+                        [jnp.ones_like(dslot_i, dtype=jnp.float32)],
+                        cand,
+                        cfg.max_degrade_rules + 1,
+                    )
+                    return r
+
+                if cfg.seg_static_ranks:
+                    # unsorted under the static contract: elect NO probes
+                    # (conservative — the breaker simply stays open a tick)
+                    p_rank = _seg()
+                    return cand & (p_rank < 0.5) & carry.res_sorted
+                p_rank = jax.lax.cond(carry.res_sorted, _seg, _sort)
+                return cand & (p_rank < 0.5)
+
+            probe = jax.lax.cond(
+                jnp.any(probe_cand),
+                _probe_rank,
+                lambda c: jnp.zeros_like(c),
+                probe_cand,
+            )
+            entry_blk_d = en_i & (ow_i | (od_i & ~probe) | hf_i)
+            degrade_block = entry_blk_d & eligible
+            probe_ok = probe & ~degrade_block
+            Dn1 = cfg.max_degrade_rules + 1
+            flip = jax.lax.cond(
+                jnp.any(probe_ok),
+                lambda: T.small_scatter_or(
+                    cfg, jnp.zeros((Dn1,), jnp.int32), dslot_i, probe_ok
+                ),
+                lambda: jnp.zeros((Dn1,), jnp.int32),
+            )
+            cb_state = jnp.where(
+                (flip > 0) & (state.cb_state == D.CB_OPEN),
+                D.CB_HALF_OPEN,
+                state.cb_state,
+            )
+        else:
+            degrade_block = zero_block
+            cb_state = state.cb_state
 
     return (
         auth_block,

@@ -277,6 +277,7 @@ def scatter_many(jobs: Sequence[Job], tb: int = TILE, interpret: Optional[bool] 
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
+        name="scatter_many",
         # Mosaic's default 16 MB scoped-vmem stack is marginal for the
         # ~28-unit job mixes (observed 16.24 MB on a 27-val-row mix at
         # B=4096 after the 2-D block-spec change); v5e has 128 MB VMEM
@@ -419,6 +420,7 @@ def gather_many(
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
+        name="gather_many",
         # same scoped-vmem headroom as scatter_many (see comment there)
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=32 * 1024 * 1024

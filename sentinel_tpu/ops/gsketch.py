@@ -122,6 +122,7 @@ def refresh(state: SketchState, now_ms, cfg: SketchConfig) -> SketchState:
     )
 
 
+@jax.named_scope("stage.sketch")
 def add(
     state: SketchState,
     now_ms,
@@ -163,6 +164,7 @@ def add(
     return state._replace(counts=state.counts.at[idx].set(new_col))
 
 
+@jax.named_scope("stage.sketch")
 def add_dense(
     state: SketchState,
     now_ms,
@@ -181,6 +183,7 @@ def add_dense(
     return state._replace(counts=state.counts.at[idx].set(new_col))
 
 
+@jax.named_scope("stage.sketch")
 def estimate_plane_mxu(
     ecfg,  # EngineConfig — tables.py dispatch
     state: SketchState,

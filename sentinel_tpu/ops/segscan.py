@@ -117,6 +117,7 @@ def seg_excl_cumsum_pl(head: jax.Array, values: jax.Array) -> jax.Array:
             dimension_semantics=("arbitrary",),
         ),
         interpret=FU.interpret_mode(),
+        name="seg_excl_cumsum",
     ), head.astype(jnp.int32)[None, :], v,
         key=("seg_excl_cumsum", V, Np))
 
@@ -205,6 +206,7 @@ def seg_incl_min_pl(head: jax.Array, values: jax.Array, fill: float) -> jax.Arra
             dimension_semantics=("arbitrary",),
         ),
         interpret=FU.interpret_mode(),
+        name="seg_incl_min",
     ), head.astype(jnp.int32)[None, :], v,
         key=("seg_incl_min", Np))
     # sentinel BIG never leaks: every segment has >= 1 item, and heads

@@ -96,6 +96,9 @@ _H_READBACK = OBS.histogram(
 _H_RESOLVE = OBS.histogram(
     "sentinel_tick_resolve_ms", "verdict fan-out (futures, blocks, front doors) per tick"
 )
+# tick.idle attrs: shared, so an idle span allocates nothing
+_IDLE_INTERVAL = {"why": "interval"}
+_IDLE_RESOLVERS = {"why": "resolvers"}
 _G_OCCUPANCY = OBS.gauge(
     "sentinel_pipeline_occupancy", "dispatched-but-unresolved engine ticks"
 )
@@ -303,6 +306,9 @@ class AcquireRequest:
     #: caller (0 = none); expired entries shed CLOSED before dispatch
     deadline_ms: int = 0
     future: Optional[Future] = None
+    submitted_ns: int = 0  # obs: enqueue stamp for req.queue (0 = tracing off)
+    resolved_ns: int = 0  # obs: when the resolver set the future (req.wake)
+    tick_id: int = 0  # obs: the tick that took this request
 
 
 @dataclass
@@ -341,6 +347,7 @@ class ArrayBlock:
     #: remainder of an expired block sheds CLOSED at the tick builder
     deadline_ms: int = 0
     future: Optional[Future] = None
+    submitted_ns: int = 0  # obs: enqueue stamp for req.queue (0 = tracing off)
     # internal progress
     taken: int = 0  # items already placed into ticks
     unresolved: int = 0  # items whose verdicts are still pending
@@ -370,6 +377,8 @@ class _PendingTick:
     wire_lo: Any = None
     tick_id: int = 0  # obs trace correlation id (0 = tracing disabled)
     dispatched_ns: int = 0  # obs: dispatch-complete stamp for the device span
+    handed_ns: int = 0  # obs: when the tick thread handed this tick to a resolver
+    resolving_ns: int = 0  # obs: when _resolve_tick started on its thread
     now_ms: int = 0  # engine timestamp the tick ran at (timeline fold key)
     # fan-out progress (count of blocks/fronts fully resolved): a failed
     # resolve must fail CLOSED only the consumers the normal path hadn't
@@ -679,6 +688,9 @@ class SentinelClient:
         # latency pipelines)
         self._pipeline_depth = max(0, int(pipeline_depth))
         self._pending_ticks: List[_PendingTick] = []
+        # obs: top of the first drain that found nothing (0 = the tick thread
+        # is not idle): the drain that next finds work records one tick.idle
+        self._idle_since = 0
         self._resolver_pool = None  # created lazily (see _pool)
         self._resolve_futs: List[Future] = []
         # serializes whole tick iterations: sync-mode clients call
@@ -1732,6 +1744,7 @@ class SentinelClient:
             if _push_ctx:
                 CTX.push_entry(e)
             return e
+        _t_in = OT.t0()
         ctx_name, ctx_origin = _ctx if _ctx is not None else CTX.current()
         origin = origin if origin is not None else ctx_origin
         # custom-slot hooks: a raised BlockException is carried as a
@@ -1856,6 +1869,7 @@ class SentinelClient:
             pre_verdict=pre_verdict,
             deadline_ms=int(deadline_ms),
             future=Future(),
+            submitted_ns=OT.t0(),
         )
         with self._lock:
             if deadline_ms:
@@ -1865,6 +1879,17 @@ class SentinelClient:
         if self.mode == "sync":
             self.tick_once()
         verdict, wait_ms = req.future.result(timeout=self.entry_timeout_s)
+        if req.resolved_ns:
+            # the caller's own share of an entry(): req.admit is this call
+            # up to the enqueue, req.wake the future being set to this
+            # thread running again; both carry the serving tick's id
+            if _t_in and req.submitted_ns:
+                OT.TRACER.record(
+                    "req.admit", _t_in, req.submitted_ns - _t_in, req.tick_id
+                )
+            OT.TRACER.record(
+                "req.wake", req.resolved_ns, OT.now_ns() - req.resolved_ns, req.tick_id
+            )
 
         if verdict not in (ERR.PASS, ERR.PASS_WAIT):
             # the engine already counted the block; here only the
@@ -2081,6 +2106,7 @@ class SentinelClient:
             pre_verdict=0,
             deadline_ms=int(deadline_ms),
             future=Future(),
+            submitted_ns=OT.t0(),
         )
         with self._lock:
             if deadline_ms:
@@ -2172,6 +2198,7 @@ class SentinelClient:
                     pre_verdict=pre_verdicts[i],
                     deadline_ms=int(deadline_ms),
                     future=Future(),
+                    submitted_ns=OT.t0(),
                 )
                 self._acquires.append(req)
                 futures.append(req.future)
@@ -2269,6 +2296,7 @@ class SentinelClient:
             unresolved=n,
             verdicts=np.zeros(n, np.int8),
             waits=np.zeros(n, np.int32),
+            submitted_ns=OT.t0(),
         )
         with self._lock:
             if deadline_ms:
@@ -2391,18 +2419,27 @@ class SentinelClient:
         ticks behind dispatch (see _PendingTick); the loop always resolves
         everything before returning idle.  Whole iterations serialize on
         _tick_mutex — sync-mode clients call this from request threads."""
+        _t_lock = OT.t0()
         with self._tick_mutex:
-            self._tick_once_locked(now_ms)  # stlint: disable=blocking-under-lock — the tick IS the device dispatch: _tick_mutex exists to serialize exactly this work; readbacks ride the resolver pool, not this lock
+            self._tick_once_locked(now_ms, _t_lock)  # stlint: disable=blocking-under-lock — the tick IS the device dispatch: _tick_mutex exists to serialize exactly this work; readbacks ride the resolver pool, not this lock
         # hot-set promote/demote loop: one cheap cadence check per
         # iteration, outside the tick mutex (the manager takes its own
         # locks; a promotion-triggered rule recompile must not hold up
         # the serving path's mutex holders)
         hs = self.hotset
         if hs is not None:
-            hs.maybe_evaluate()
+            _t_hs = OT.t0()
+            if hs.maybe_evaluate() and _t_hs:
+                # tick.hotset: a promote/demote pass that ran on this thread
+                # (the cadence check alone gets no span)
+                OT.TRACER.record("tick.hotset", _t_hs, OT.now_ns() - _t_hs)
 
-    def _tick_once_locked(self, now_ms: Optional[int]) -> None:
+    def _tick_once_locked(self, now_ms: Optional[int], _t_lock: int = 0) -> None:
         while True:
+            # tick.drain: this iteration's top to the call of _run_tick.  An
+            # iteration that finds nothing opens an idle stretch instead.
+            _t_drain = OT.t0()
+            tick_id = 0
             if self._deadlines_live:
                 # deadline-aware backpressure: work that has already
                 # expired is worthless — shed it CLOSED here, BEFORE it
@@ -2425,6 +2462,32 @@ class SentinelClient:
                     room_blk -= take
                     if blk.taken >= len(blk.res):
                         self._acq_blocks.pop(0)
+                if _t_drain:
+                    # the backlog, counted where it is known
+                    left_blocks = len(self._acq_blocks)
+                    left_items = len(self._acquires) + sum(
+                        len(b.res) - b.taken for b in self._acq_blocks
+                    )
+            if _t_drain and (acq or blocks):
+                # req.queue: enqueue -> taken by this tick, one span per
+                # object request and per piece of a block.  The tick id is
+                # drawn here, before _run_tick, so that these spans and the
+                # drain carry the id of the tick that serves them.
+                tick_id = OT.TRACER.next_trace_id()
+                _taken = OT.now_ns()
+                for r in acq:
+                    if r.submitted_ns:
+                        r.tick_id = tick_id
+                        OT.TRACER.record(
+                            "req.queue", r.submitted_ns, _taken - r.submitted_ns,
+                            tick_id, {"n": 1, "kind": "entry"},
+                        )
+                for blk, _off, take in blocks:
+                    if blk.submitted_ns:
+                        OT.TRACER.record(
+                            "req.queue", blk.submitted_ns, _taken - blk.submitted_ns,
+                            tick_id, {"n": take, "kind": "block"},
+                        )
             # Overflow entries spilled when the ring was FULL, so they
             # postdate everything that was in the ring at spill time; the
             # ring must drain first.  Consuming spill only when the ring
@@ -2505,7 +2568,35 @@ class SentinelClient:
                     )
                     fronts.append((door, cols))
                     room -= len(cols[0])
+            if _t_drain and (acq or n_comp or fronts or blocks or now_ms is not None):
+                # a tick will run: close the idle stretch it ends, name the
+                # wait for the tick mutex (this call's first iteration), and
+                # record the drain
+                tick_id = tick_id or OT.TRACER.next_trace_id()
+                if self._idle_since:
+                    OT.TRACER.record(
+                        "tick.idle", self._idle_since, _t_drain - self._idle_since,
+                        0, _IDLE_INTERVAL,
+                    )
+                if _t_lock:
+                    OT.TRACER.record("tick.lock", _t_lock, _t_drain - _t_lock, tick_id)
+                OT.TRACER.record(
+                    "tick.drain", _t_drain, OT.now_ns() - _t_drain, tick_id,
+                    {
+                        "n_obj": len(acq), "n_blk": sum(t for _b, _o, t in blocks),
+                        "n_comp": n_comp, "blocks": len(blocks),
+                        "left_blocks": left_blocks, "left_items": left_items,
+                    },
+                )
+            _t_lock = 0
             if not acq and not n_comp and not fronts and not blocks and now_ms is None:
+                if _t_drain and not self._idle_since and self.mode == "threaded":
+                    # tick.idle (why="interval") is one span per idle stretch:
+                    # from here, through every poll of the queues that finds
+                    # nothing and every sleep of _tick_loop between them, to
+                    # the drain that finds work.  An idle server records
+                    # nothing; a sync-mode caller's absence is not idleness.
+                    self._idle_since = _t_drain
                 ad = self._adaptive
                 if ad is not None and (
                     ad.ladder.level > DG.NORMAL or ad.ceiling != float("inf")
@@ -2519,10 +2610,19 @@ class SentinelClient:
                 # idle: flush any deferred readbacks before returning
                 self._drain_resolves()
                 return
-            pending = self._run_tick(
-                acq, comp if n_comp else None, now_ms, fronts=fronts,
-                blocks=blocks,
-            )
+            self._idle_since = 0
+            # while tracing: one host event per tick on the profiler's own
+            # clock beside the spans on monotonic_ns, a tie point per tick and
+            # the step number that joins a device execution to its tick id
+            with (
+                jax.profiler.StepTraceAnnotation("sentinel.tick", step_num=tick_id)
+                if _t_drain
+                else OT.NOOP
+            ):
+                pending = self._run_tick(
+                    acq, comp if n_comp else None, now_ms, fronts=fronts,
+                    blocks=blocks, tick_id=tick_id,
+                )
             self._pending_ticks.append(pending)
             # unconditional: the gauges are on the always-on /metrics
             # surface (one float store each — cheaper than the flag test
@@ -2541,6 +2641,7 @@ class SentinelClient:
             depth = self._pipeline_depth if more else 0
             while len(self._pending_ticks) > depth:
                 p = self._pending_ticks.pop(0)
+                p.handed_ns = OT.t0()
                 if self._pipeline_depth > 0:
                     self._resolve_futs.append(
                         self._pool().submit(self._resolve_tick, p)
@@ -2564,6 +2665,17 @@ class SentinelClient:
                         )
                 self._resolve_futs = alive
             _G_RESOLVER_Q.set(len(self._resolve_futs))
+            if pending.dispatched_ns:
+                # tick.handoff: dispatch end -> the hand-over loop and the
+                # sweep of finished resolutions are done
+                OT.TRACER.record(
+                    "tick.handoff", pending.dispatched_ns,
+                    OT.now_ns() - pending.dispatched_ns, pending.tick_id,
+                    {
+                        "pending": len(self._pending_ticks),
+                        "resolvers": len(self._resolve_futs),
+                    },
+                )
             if not more:
                 # wait out in-flight resolutions; their callbacks may
                 # enqueue new work (closed-loop callers) — re-check
@@ -3108,6 +3220,7 @@ class SentinelClient:
         now_ms: Optional[int],
         fronts=(),  # [(door, (row, count, prio, corr, a0, a1)), ...]
         blocks=(),  # [(ArrayBlock, src_off, take), ...]
+        tick_id: int = 0,  # drawn by the drain while tracing is on
     ) -> _PendingTick:
         cfg = self.cfg
         M = cfg.param_dims
@@ -3120,7 +3233,7 @@ class SentinelClient:
         # process-unique trace id correlating this tick's spans across the
         # submitting thread and the resolver pool (per-client counters
         # would collide in multi-client processes sharing the ring)
-        tick_id = OT.TRACER.next_trace_id()
+        tick_id = tick_id or OT.TRACER.next_trace_id()
         # stage brackets (obs/trace.py): _t_asm truthiness is the single
         # flag check; presort time is accumulated separately so the
         # assemble span reports pure column work
@@ -3421,6 +3534,7 @@ class SentinelClient:
         self._build_ms_sum += (_time.perf_counter() - t_build0) * 1000.0
         self._build_ticks += 1
         with self._engine_lock:
+            _t_call = OT.now_ns() if _t_disp else 0
             self._state, out = self._tick(
                 self._state,
                 self._rules_dev,
@@ -3430,12 +3544,15 @@ class SentinelClient:
                 jnp.float32(load),
                 jnp.float32(cpu),
             )
+            _call_ns = OT.now_ns() - _t_call if _t_disp else 0
         _disp_done = 0
         if _t_disp:
             _disp_done = OT.now_ns()
+            # the span also covers the system sample, the rotation count, the
+            # audit and the adaptive step above; call_ns is the jit call alone
             OT.stage_ns(
                 "tick.dispatch", _t_disp, _disp_done - _t_disp, _H_DISPATCH,
-                trace=tick_id,
+                trace=tick_id, attrs={"call_ns": _call_ns},
             )
         p = _PendingTick(
             acq=acq,
@@ -3501,6 +3618,7 @@ class SentinelClient:
         mid-drain and strand later ticks."""
         while self._pending_ticks:
             p = self._pending_ticks.pop(0)
+            p.handed_ns = OT.t0()
             if self._pipeline_depth > 0:
                 self._resolve_futs.append(self._pool().submit(self._resolve_tick, p))
             else:
@@ -3516,11 +3634,14 @@ class SentinelClient:
         # N timeouts for one wedged device.
         deadline = mono_s() + max(2.0 * self.entry_timeout_s, 5.0)
         abandoned = 0
+        _t_idle = OT.t0() if futs else 0
         for f in futs:
             try:
                 f.result(timeout=max(0.0, deadline - mono_s()))  # stlint: disable=blocking-under-lock — the deadline above bounds the whole drain; see the wedge rationale
             except _FutTimeout:
                 abandoned += 1  # still running; its watchdog fails it over
+        if _t_idle:
+            OT.TRACER.record("tick.idle", _t_idle, OT.now_ns() - _t_idle, 0, _IDLE_RESOLVERS)
         if abandoned:
             from sentinel_tpu.utils.record_log import record_log
 
@@ -3540,6 +3661,15 @@ class SentinelClient:
         futures: every waiting caller gets a system-block verdict
         immediately rather than an entry_timeout_s hang.  The same
         degrade-never-break contract the seg-overflow path follows."""
+        if p.dispatched_ns:
+            # tick.resident: dispatch end -> a resolver starts on the tick.
+            # It holds the residency rule's wait (up to handed_ns) and the
+            # resolver pool's queue (from handed_ns on).
+            p.resolving_ns = OT.now_ns()
+            OT.TRACER.record(
+                "tick.resident", p.dispatched_ns, p.resolving_ns - p.dispatched_ns,
+                p.tick_id, {"handed_ns": p.handed_ns},
+            )
         try:
             self._resolve_tick_inner(p)
         except Exception as exc:  # stlint: disable=fail-open — items fail CLOSED (BLOCK_SYSTEM) below; nothing is admitted or stranded
@@ -3658,13 +3788,20 @@ class SentinelClient:
             verdict = np.asarray(out.verdict)
             _C_WIRE["rx"].inc(verdict.nbytes)
         if p.dispatched_ns and OT.TRACER.enabled:
-            # dispatch → verdicts host-visible: device compute + transfer,
-            # plus queue wait when pipelined (spans may overlap in time —
-            # that overlap IS the pipelining being measured)
+            # tick.device is NOT device time: dispatch -> verdicts
+            # host-visible = tick.resident (residency rule + resolver queue)
+            # + tick.wait (this thread blocked on the readback above).  Its
+            # name and edges stay: the histogram, the adaptive controller and
+            # the req_p99 SLO read it.
+            _seen = OT.now_ns()
+            if p.resolving_ns:
+                OT.TRACER.record(
+                    "tick.wait", p.resolving_ns, _seen - p.resolving_ns, p.tick_id
+                )
             OT.stage_ns(
                 "tick.device",
                 p.dispatched_ns,
-                OT.now_ns() - p.dispatched_ns,
+                _seen - p.dispatched_ns,
                 _H_DEVICE,
                 trace=p.tick_id,
             )
@@ -3779,6 +3916,8 @@ class SentinelClient:
                     self._adaptive.signals.note_resolved(passed, n_real - passed)
         for i, r in enumerate(p.acq):
             if r.future is not None:
+                if r.tick_id:
+                    r.resolved_ns = OT.now_ns()
                 r.future.set_result((int(verdict[i]), int(wait[i])))
         o = p.n_obj
         for blk, off, take in p.blocks:

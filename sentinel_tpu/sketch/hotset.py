@@ -166,16 +166,18 @@ class HotSetManager:
 
     # -- evaluation loop -----------------------------------------------------
 
-    def maybe_evaluate(self) -> None:
+    def maybe_evaluate(self) -> bool:
+        """True when the cadence was due and a pass ran."""
         # check-and-stamp under the lock: sync-mode clients call tick_once
         # (and so this) from many request threads, and two winners would
         # run concurrent promote/demote passes
         now = mono_s()
         with self._lock:
             if now - self._last_eval < self._c.cfg.hotset_eval_s:
-                return
+                return False
             self._last_eval = now
         self.evaluate_now()
+        return True
 
     def evaluate_now(self) -> None:
         """One promote/demote pass (tests call this directly — the cadence

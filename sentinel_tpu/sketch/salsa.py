@@ -345,6 +345,7 @@ def sweep_expired(state: SalsaState, now_ms, cfg: SketchConfig) -> SalsaState:
 # -- writes ------------------------------------------------------------------
 
 
+@jax.named_scope("stage.sketch")
 def add_dense(
     state: SalsaState,
     now_ms,
@@ -376,6 +377,7 @@ def add_dense(
     )
 
 
+@jax.named_scope("stage.sketch")
 def add(
     state: SalsaState,
     now_ms,
@@ -407,6 +409,7 @@ def add(
 # -- reads -------------------------------------------------------------------
 
 
+@jax.named_scope("stage.sketch")
 def estimate_plane_mxu(
     ecfg,  # EngineConfig — tables.py dispatch
     state: SalsaState,

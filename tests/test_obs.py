@@ -692,3 +692,254 @@ def test_chrome_roundtrip_through_summarize(tmp_path):
     bad.write_text(json.dumps({"nope": 1}))
     with pytest.raises(ValueError):
         obs.load_spans(str(bad))
+
+
+# ---------------------------------------------------------------------------
+# one timeline from submit to verdict (PR 24)
+# ---------------------------------------------------------------------------
+
+_TICK_THREAD = ("tick.drain", "tick.assemble", "tick.dispatch", "tick.handoff")
+_PER_TICK = ("tick.resident", "tick.wait", "tick.device", "tick.readback", "tick.resolve")
+
+
+def _load(c):
+    import sentinel_tpu as st
+
+    c.flow_rules.load([st.FlowRule(resource="tl-res", count=100)])
+    return c
+
+
+def _serve(c, n_entries=3, block=48):
+    for _ in range(n_entries):
+        with c.entry("tl-res"):
+            pass
+    rid = c.registry.resource_id("tl-res")
+    return c.submit_block(np.full(block, rid, np.int32)).result(timeout=30)
+
+
+def test_tracing_off_a_served_tick_records_nothing_and_reads_no_clock(
+    client_factory, monkeypatch
+):
+    """The contract of obs/trace.py at every site PR 24 added: with the
+    tracer off a served tick costs flag checks only.  The tracer's one clock
+    read raises here, so a site that read it would fail the serving path."""
+    from sentinel_tpu.obs import trace as OT
+    from sentinel_tpu.runtime import client as RC
+
+    def no_clock():
+        raise AssertionError("a tracing site read the clock with tracing off")
+
+    c = _load(client_factory(pipeline_depth=2))  # the flight journal stamps a rule load
+    obs.TRACER.reset()
+    assert not OT.TRACER.enabled
+    monkeypatch.setattr(OT, "now_ns", no_clock)
+    seen = []
+    real_block = RC.ArrayBlock
+
+    def spy_block(*a, **kw):
+        blk = real_block(*a, **kw)
+        seen.append(blk)
+        return blk
+
+    monkeypatch.setattr(RC, "ArrayBlock", spy_block)
+    verdicts, _waits = _serve(c)
+    assert len(verdicts) == 48
+    assert obs.TRACER.snapshot() == []
+    assert [b.submitted_ns for b in seen] == [0]
+    assert c._idle_since == 0
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_traced_ticks_carry_one_id_from_queue_to_resolve(client_factory, depth):
+    """req.queue, the tick thread's spans and the resolver's spans of one
+    tick share its id; tick.resident + tick.wait is tick.device exactly."""
+    c = _load(client_factory(pipeline_depth=depth))
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        _serve(c)
+    finally:
+        obs.disable()
+    spans = obs.TRACER.snapshot()
+    by_tick = {}
+    for s in spans:
+        by_tick.setdefault(s["trace"], {}).setdefault(s["name"], []).append(s)
+    dispatched = {t for t, found in by_tick.items() if t and "tick.dispatch" in found}
+    assert len(dispatched) >= 4
+    queued = [s for s in spans if s["name"] == "req.queue"]
+    assert len(queued) == 4  # three entries and one block
+    assert {s["trace"] for s in queued} <= dispatched
+    assert sorted(s["attrs"]["kind"] for s in queued) == ["block", "entry", "entry", "entry"]
+    assert [s["attrs"]["n"] for s in queued if s["attrs"]["kind"] == "block"] == [48]
+    assert all(s["dur_ns"] >= 0 for s in queued)
+    for t in dispatched:
+        found = by_tick[t]
+        for name in _TICK_THREAD + _PER_TICK:
+            assert len(found.get(name, [])) == 1, (t, name, sorted(found))
+        res, wait, dev = (found[n][0] for n in ("tick.resident", "tick.wait", "tick.device"))
+        assert res["t0_ns"] == dev["t0_ns"] == (
+            found["tick.dispatch"][0]["t0_ns"] + found["tick.dispatch"][0]["dur_ns"])
+        assert wait["t0_ns"] == res["t0_ns"] + res["dur_ns"]
+        assert res["dur_ns"] + wait["dur_ns"] == dev["dur_ns"]
+        assert 0 <= found["tick.dispatch"][0]["attrs"]["call_ns"] <= found["tick.dispatch"][0]["dur_ns"]
+        drain = found["tick.drain"][0]["attrs"]
+        assert {"n_obj", "n_blk", "n_comp", "blocks", "left_blocks", "left_items"} <= set(drain)
+        assert res["attrs"]["handed_ns"] >= res["t0_ns"]
+    # the caller's own share of an entry() carries the serving tick's id too
+    for name in ("req.admit", "req.wake"):
+        own = [s for s in spans if s["name"] == name]
+        assert len(own) == 3 and {s["trace"] for s in own} <= dispatched
+
+
+def test_a_block_that_spans_ticks_records_one_queue_span_per_piece(client_factory):
+    c = _load(client_factory())
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        n = c.cfg.batch_size + 40
+        verdicts, _w = _serve(c, n_entries=0, block=n)
+    finally:
+        obs.disable()
+    assert len(verdicts) == n
+    pieces = [s for s in obs.TRACER.snapshot() if s["name"] == "req.queue"]
+    assert sorted(s["attrs"]["n"] for s in pieces) == [40, c.cfg.batch_size]
+    assert len({s["trace"] for s in pieces}) == 2 and len({s["t0_ns"] for s in pieces}) == 1
+
+
+def test_the_lowered_tick_names_its_program_kernels_and_stages():
+    """Names on the device side are metadata a profiler trace is read by:
+    the program, each Pallas kernel and each stage of ops/engine.py:tick."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from sentinel_tpu.core.config import EngineConfig
+    from sentinel_tpu.ops import engine as E
+
+    # the configuration the chip serves (MXU tables, fused kernels, segment
+    # effects behind the client's presort, packed wire, sketch tier) at the
+    # size of the perfbench rehearsal; the kernels lower interpreted here
+    cfg = EngineConfig(
+        max_resources=112, max_nodes=120, max_flow_rules=112, max_degrade_rules=112,
+        max_param_rules=8, batch_size=512, complete_batch_size=512, sketch_stats=True,
+        flow_rules_per_resource=1, degrade_rules_per_resource=1, param_rules_per_resource=1,
+        use_mxu_tables=True, fused_effects=True, seg_effects=True, seg_fallback=False,
+        packed_wire=True)
+    tick = E.make_tick(cfg, donate=False)
+    args = (E.init_state(cfg), E.compile_ruleset(cfg, _Reg()), E.empty_acquire(cfg),
+            E.empty_complete(cfg), jnp.int32(1000), jnp.float32(0.0), jnp.float32(0.0))
+    lowered = tick.lower(*args)
+    text = lowered.as_text(debug_info=True)
+    assert f"module @jit_{E.TICK_PROGRAM} " in text
+    scopes = set(re.findall(r"stage\.[a-z_]+", text))
+    assert cfg.packed_wire and cfg.seg_effects and cfg.sketch_stats
+    assert scopes == {
+        "stage.widen", "stage.seg_prepare", "stage.exits", "stage.warmup", "stage.checks",
+        "stage.segment_reads", "stage.authority", "stage.system", "stage.param", "stage.flow",
+        "stage.tail_flow", "stage.degrade", "stage.verdict", "stage.effects", "stage.sketch",
+        "stage.telemetry", "stage.pack"}, sorted(scopes)
+
+    def pallas_names(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.append(eqn.params["name"])
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    inner = getattr(sub, "jaxpr", None)
+                    if inner is not None:
+                        pallas_names(getattr(inner, "jaxpr", inner), out)
+        return out
+
+    names = pallas_names(jax.make_jaxpr(tick)(*args).jaxpr, [])
+    assert set(names) == {"seg_excl_cumsum", "seg_incl_min", "scatter_many"}
+    # the fourth kernel serves the per-item flow check, which this tick skips
+    from sentinel_tpu.ops import fused as FU
+
+    job = FU.GatherJob("t", jnp.zeros(256, jnp.int32), jnp.zeros((64, 1), jnp.int32), (1,))
+    assert pallas_names(jax.make_jaxpr(lambda: FU.gather_many([job]))().jaxpr, []) == [
+        "gather_many"]
+
+
+class _Reg:
+    """The registry face compile_ruleset needs for an empty rule set."""
+
+    def resource_id(self, name):
+        return None
+
+
+def test_an_idle_tick_thread_records_nothing_and_one_idle_span_closes_the_stretch():
+    """With tracing on, an idle server must not wrap the ring: the polls
+    that find nothing record no span, and the whole stretch becomes one
+    tick.idle when work next arrives."""
+    import time
+
+    from sentinel_tpu.core.config import small_engine_config
+    from sentinel_tpu.runtime.client import SentinelClient
+
+    c = SentinelClient(cfg=small_engine_config(), mode="threaded", tick_interval_ms=1.0)
+    c.start()
+    try:
+        _load(c)
+        with c.entry("tl-res"):  # first traffic compiles; the client is idle again after it
+            pass
+        time.sleep(0.05)
+        obs.TRACER.reset()
+        obs.enable()
+        time.sleep(0.15)  # some hundred empty iterations of the 1 ms loop
+        quiet = obs.TRACER.snapshot()
+        with c.entry("tl-res"):
+            pass
+    finally:
+        obs.disable()
+        c.stop()
+    assert quiet == []
+    spans = obs.TRACER.snapshot()
+    drains = [s for s in spans if s["name"] == "tick.drain" and s["attrs"]["n_obj"]]
+    assert len(drains) == 1
+    # the stretch ran from a poll that found nothing to the drain that found the
+    # entry (its exit may open and close a second, short one before the tracer is off)
+    idle = [s for s in spans if s["name"] == "tick.idle" and s["attrs"]["why"] == "interval"
+            and s["t0_ns"] + s["dur_ns"] == drains[0]["t0_ns"]]
+    assert len(idle) == 1 and idle[0]["trace"] == 0 and idle[0]["dur_ns"] > 100e6
+    # the wait for the tick mutex is named, with the id of the tick it preceded
+    locks = [s for s in spans if s["name"] == "tick.lock" and s["trace"] == drains[0]["trace"]]
+    assert len(locks) == 1 and locks[0]["t0_ns"] + locks[0]["dur_ns"] == drains[0]["t0_ns"]
+    assert all(s["trace"] for s in spans if s["name"] == "tick.drain")
+
+
+def test_a_sync_callers_absence_is_not_recorded_as_idleness(client_factory):
+    c = _load(client_factory())
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        c.tick_once()  # nothing queued: an iteration that finds nothing
+        _serve(c)
+    finally:
+        obs.disable()
+    assert c._idle_since == 0
+    names = {s["name"] for s in obs.TRACER.snapshot()}
+    assert "tick.drain" in names and "tick.lock" in names
+    assert not [s for s in obs.TRACER.snapshot()
+                if s["name"] == "tick.idle" and s["attrs"]["why"] == "interval"]
+
+
+@pytest.mark.parametrize("due", [True, False])
+def test_a_hot_set_pass_on_the_tick_thread_gets_a_span_and_the_cadence_check_none(
+    client_factory, due
+):
+    from sentinel_tpu.core.config import small_engine_config
+
+    c = _load(client_factory(cfg=small_engine_config(sketch_stats=True)))
+    assert c.hotset is not None
+    _serve(c, n_entries=1, block=8)
+    c.hotset._last_eval = -1e18 if due else 1e18
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        c.tick_once()
+    finally:
+        obs.disable()
+    passes = [s for s in obs.TRACER.snapshot() if s["name"] == "tick.hotset"]
+    assert len(passes) == (1 if due else 0)
+    assert c.hotset.maybe_evaluate() is False  # stamped, or not yet due
