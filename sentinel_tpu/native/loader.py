@@ -136,11 +136,10 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.sx_front_respond.argtypes = [p, i64] + [p] * 3
     lib.sx_front_respond_ex.restype = i32
     lib.sx_front_respond_ex.argtypes = [p, i64] + [p] * 5
-    # batch-build presort (stable multi-key argsort + inverse permutation)
-    lib.sx_batch_sort5.restype = i64
-    lib.sx_batch_sort5.argtypes = [i64] + [p] * 7
-    lib.sx_batch_sort3.restype = i64
-    lib.sx_batch_sort3.argtypes = [i64] + [p] * 5
+    # batch-build presort (stable multi-key argsort of the live rows, the
+    # inverse permutation and every column's gather in one call)
+    lib.sx_presort.restype = i32
+    lib.sx_presort.argtypes = [i64, i64, i32, p, p, p, p, i32, p, p, i32, p, p]
     # protocol v2 BATCH framing (big-endian column entries <-> int columns)
     lib.sx_frame_pack_entries.restype = i64
     lib.sx_frame_pack_entries.argtypes = [i64] + [p] * 5

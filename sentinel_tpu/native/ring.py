@@ -176,30 +176,137 @@ def _as_i32(a) -> np.ndarray:
     return np.ascontiguousarray(a, dtype=np.int32)
 
 
+# what sx_presort returns -> the name `tick.presort` carries as `path`
+_PRESORT_PATHS = {1: "small", 2: "radix"}
+
+
+def _ptr_table(arrs):
+    return (ctypes.c_void_p * len(arrs))(*[a.ctypes.data for a in arrs])
+
+
+def _check_cols(arrs, rows: int, exact: bool) -> None:
+    for a in arrs:
+        ok = a.dtype.itemsize == 4 and a.flags.c_contiguous and (
+            a.shape[0] == rows if exact else a.shape[0] >= rows
+        )
+        if not ok:
+            raise ValueError(
+                f"presort column {a.dtype}{a.shape}: want 4-byte C-contiguous "
+                f"with {'' if exact else '>= '}{rows} rows"
+            )
+
+
+def presort(keys, n_live, order, inv, scratch, src=(), dst=(), wide=None,
+            wide_dst=None) -> str:
+    """The tick builder's segment-key presort, one native call a side.
+
+    ``keys`` are int32 columns (``keys[0]`` most significant) of at least
+    ``B = len(order)`` rows, of which the first ``n_live`` are live and the
+    rest padding.  The padding rows must be one equal-key run (the client
+    fills them with one value a column); only row ``n_live`` of it is read.
+
+    Writes into the caller's buffers, allocating nothing:
+
+    - ``order`` (int32, B): exactly ``np.lexsort(keys[::-1])`` over the B
+      rows, though only the live rows are sorted: being equal and last in
+      arrival order, the padding run lands after the live rows whose key
+      is <= its own, found by one search.
+    - ``inv`` (int32, B, or None): the inverse, ``inv[order] == arange(B)``.
+    - ``dst[i][:] = src[i][order]`` for every 4-byte column pair, and
+      ``wide_dst[:] = wide[order]`` for one ``(B, w)`` int32 column.
+
+    ``scratch`` is uint64 of at least ``2 * n_live``.  Returns the path
+    taken: ``radix`` / ``small`` natively (chosen on ``n_live``), ``numpy``
+    for the fallback, which is bit-identical so that peers without a
+    toolchain agree.
+    """
+    B = order.shape[0]
+    n = int(n_live)
+    if not 0 <= n <= B:
+        raise ValueError(f"presort: {n} live rows of {B}")
+    if len(src) != len(dst) or (wide is None) != (wide_dst is None):
+        raise ValueError("presort: every source column needs a destination")
+    for k in keys:
+        if k.dtype != np.int32:
+            raise ValueError(f"presort key {k.dtype}: want int32")
+    if len(keys) > 8:
+        raise ValueError("presort: at most 8 keys")
+    _check_cols(keys, B, exact=False)
+    _check_cols(src, B, exact=False)
+    _check_cols(dst, B, exact=True)
+    _check_cols((order,) if inv is None else (order, inv), B, exact=True)
+    w = 0
+    if wide is not None:
+        w = wide.shape[1]
+        if wide.dtype != np.int32 or wide_dst.shape != (B, w):
+            raise ValueError("presort: wide column must be (B, w) int32")
+        _check_cols((wide, wide_dst), B, exact=False)
+    lib = load_native()
+    if lib is None:
+        _presort_numpy(keys, n, order, inv, src, dst, wide, wide_dst)
+        return "numpy"
+    if scratch.dtype != np.uint64 or scratch.shape[0] < 2 * n:
+        raise ValueError("presort: scratch must hold 2 * n_live uint64")
+    path = lib.sx_presort(
+        n, B, len(keys), _ptr_table(keys), order.ctypes.data,
+        None if inv is None else inv.ctypes.data, scratch.ctypes.data,
+        len(src), _ptr_table(src), _ptr_table(dst), w,
+        None if wide is None else wide.ctypes.data,
+        None if wide is None else wide_dst.ctypes.data,
+    )
+    return _PRESORT_PATHS[path]
+
+
+def _presort_numpy(keys, n, order, inv, src, dst, wide, wide_dst) -> None:
+    """:func:`presort` without the native library: np.lexsort over the live
+    rows, the padding run spliced in, np.take for the columns."""
+    B = order.shape[0]
+    live = np.lexsort(tuple(k[:n] for k in reversed(keys)))
+    if n < B:
+        # live rows whose key tuple is <= the padding run's sort before it
+        lt = np.zeros(n, bool)
+        eq = np.ones(n, bool)
+        for k in keys:
+            lt |= eq & (k[:n] < k[n])
+            eq &= k[:n] == k[n]
+        p = int(np.count_nonzero(lt | eq))
+        order[:p] = live[:p]
+        order[p : p + B - n] = np.arange(n, B, dtype=np.int32)
+        order[p + B - n :] = live[p:]
+    else:
+        order[:] = live
+    if inv is not None:
+        inv[order] = np.arange(B, dtype=np.int32)
+    for s, d in zip(src, dst):
+        np.take(s, order, out=d)
+    if wide is not None:
+        np.take(wide, order, axis=0, out=wide_dst)
+
+
+def _batch_sort(keys, want_inv: bool):
+    keys = tuple(map(_as_i32, keys))
+    n = keys[0].shape[0]
+    order = np.empty(n, np.int32)
+    inv = np.empty(n, np.int32) if want_inv else None
+    presort(keys, n, order, inv, np.empty(2 * n, np.uint64))
+    return order, inv
+
+
 def batch_sort5(k0, k1, k2, k3, k4, want_inv: bool = True):
     """Stable argsort by (k0, k1, k2, k3, k4), k0 most significant.
 
     Equivalent to ``np.lexsort((k4, k3, k2, k1, k0))`` — both the native
     and the fallback path are stable sorts, so tie order is identical.
     Returns ``(order, inv)`` int32 arrays (``inv`` None when not wanted);
-    ``inv[order] == arange(n)``.
+    ``inv[order] == arange(n)``.  :func:`presort` with every row live and
+    buffers of its own.
     """
-    k0, k1, k2, k3, k4 = map(_as_i32, (k0, k1, k2, k3, k4))
-    n = k0.shape[0]
-    lib = load_native()
-    if lib is not None:
-        order = np.empty(n, np.int32)
-        inv = np.empty(n, np.int32) if want_inv else None
-        cp = lambda a: a.ctypes.data_as(ctypes.c_void_p) if a is not None else None
-        lib.sx_batch_sort5(n, cp(k0), cp(k1), cp(k2), cp(k3), cp(k4),
-                           cp(order), cp(inv))
-        return order, inv
-    order = np.lexsort((k4, k3, k2, k1, k0)).astype(np.int32)
-    inv = None
-    if want_inv:
-        inv = np.empty(n, np.int32)
-        inv[order] = np.arange(n, dtype=np.int32)
-    return order, inv
+    return _batch_sort((k0, k1, k2, k3, k4), want_inv)
+
+
+def batch_sort3(k0, k1, k2, want_inv: bool = False):
+    """Stable argsort by (k0, k1, k2); see :func:`batch_sort5`."""
+    return _batch_sort((k0, k1, k2), want_inv)
 
 
 # ---------------------------------------------------------------------------
@@ -310,22 +417,3 @@ def unpack_batch_results(buf: bytes) -> Tuple[np.ndarray, ...]:
         rec["wait"].astype(np.int32),
         rec["token"].astype(np.int64),
     )
-
-
-def batch_sort3(k0, k1, k2, want_inv: bool = False):
-    """Stable argsort by (k0, k1, k2); see :func:`batch_sort5`."""
-    k0, k1, k2 = map(_as_i32, (k0, k1, k2))
-    n = k0.shape[0]
-    lib = load_native()
-    if lib is not None:
-        order = np.empty(n, np.int32)
-        inv = np.empty(n, np.int32) if want_inv else None
-        cp = lambda a: a.ctypes.data_as(ctypes.c_void_p) if a is not None else None
-        lib.sx_batch_sort3(n, cp(k0), cp(k1), cp(k2), cp(order), cp(inv))
-        return order, inv
-    order = np.lexsort((k2, k1, k0)).astype(np.int32)
-    inv = None
-    if want_inv:
-        inv = np.empty(n, np.int32)
-        inv[order] = np.arange(n, dtype=np.int32)
-    return order, inv

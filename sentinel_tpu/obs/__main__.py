@@ -392,7 +392,8 @@ def _print_summary(spans: List[dict], out=None) -> None:
     for name, s in summ.items():
         print(
             f"{name.ljust(w)}{s['count']:>8}{s['p50_ms']:>12.3f}"
-            f"{s['p99_ms']:>12.3f}{s['mean_ms']:>12.3f}{s['total_ms']:>12.3f}",
+            f"{s['p99_ms']:>12.3f}{s['mean_ms']:>12.3f}{s['total_ms']:>12.3f}"
+            + "".join(f"  {k}={v}" for k, v in sorted(s.get("path", {}).items())),
             file=out,
         )
     missing = [n for n in TICK_STAGES if n not in summ]

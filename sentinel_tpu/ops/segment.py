@@ -308,7 +308,7 @@ def expand(ctx: SegCtx, seg_vals: jax.Array) -> jax.Array:
 def sort_batch(key_cols: Sequence[jax.Array], payloads: Sequence[jax.Array]):
     """Device-side stable sort fallback for callers without a presorted
     batch: returns (perm, sorted_payloads).  The runtime client presorts
-    on the host instead (np.lexsort over the segment keys in
+    on the host instead (native/ring.presort over the segment keys in
     runtime/client._run_tick, verdicts mapped back through the inverse
     permutation) and never calls this."""
     n = key_cols[0].shape[0]

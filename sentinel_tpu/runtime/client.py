@@ -80,7 +80,7 @@ _H_ASSEMBLE = OBS.histogram(
     "sentinel_tick_assemble_ms", "host batch assembly (columns + uploads) per tick"
 )
 _H_PRESORT = OBS.histogram(
-    "sentinel_tick_presort_ms", "host segment-key presort (np.lexsort + permute) per tick"
+    "sentinel_tick_presort_ms", "host segment-key presort (sort of the live rows + column gather, one native call a side) per tick"
 )
 _H_DISPATCH = OBS.histogram(
     "sentinel_tick_dispatch_ms", "engine tick dispatch (async jit call) per tick"
@@ -726,6 +726,12 @@ class SentinelClient:
         # private copy
         self._stage: Dict[tuple, list] = {}
         self._stage_parity = 0
+        # the presort's inverse permutation outlives its tick (the
+        # _PendingTick holds it until its verdicts are unsorted, and the
+        # number of unresolved ticks has no bound a ring could be sized
+        # from), so it is lent from a free list keyed by batch shape: the
+        # resolver returns it, and steady serving allocates none
+        self._inv_free: Dict[int, List[np.ndarray]] = {}
         # packed-wire offset tables keyed by (cfg, batch shape)
         self._wire_layouts: Dict[tuple, Any] = {}
         # completions are fire-and-forget (no futures), so they ride the
@@ -3240,6 +3246,8 @@ class SentinelClient:
         _t_asm = OT.t0()
         _tp0 = 0
         _ns_presort = 0
+        _n_a = _n_c = 0  # live rows presorted a side; with _path, span attrs
+        _path = ""
         # concatenate every attached door's drained engine items; responses
         # route back per door by slice
         if fronts:
@@ -3276,9 +3284,11 @@ class SentinelClient:
         # batches by its segment keys (stably — arrival order within equal
         # keys is preserved, so every rank/verdict is bit-identical; see
         # ops/segment.py module docstring) and map verdicts back through
-        # the inverse permutation.  np.lexsort at the client's batch sizes
-        # is tens of microseconds — host work that overlaps the previous
-        # device tick anyway.
+        # the inverse permutation.  Nothing overlaps this: the device
+        # idles under it (6.8 ms of a full 131,072-row tick and 2.5 ms of
+        # a third-full one on the v5e's host; PERF.md section 5), so
+        # native/ring.presort sorts the live rows only, in linear time,
+        # and gathers every column in the same call.
         presort = cfg.seg_effects and clamp
 
         inv_a = None
@@ -3358,24 +3368,29 @@ class SentinelClient:
                 # key order matches engine_seg.prepare_acquire's segment
                 # keys, res-major (seg ranks also need res nondecreasing);
                 # trash-row padding sorts wherever its id lands — padding
-                # items are engine no-ops at any position.  Native stable
-                # argsort (native/ring.batch_sort5) with a bit-identical
-                # np.lexsort fallback; inverse permutation comes from the
-                # same call.
-                order, inv_a = RING.batch_sort5(
-                    res_np, cnode_np, onode_np, oid_np, cname_np
+                # items are engine no-ops at any position.  One native
+                # call (native/ring.presort, with a bit-identical numpy
+                # fallback) sorts the live rows, splices the padding run,
+                # writes the inverse permutation and gathers the columns
+                # into the s.* staging slots.
+                _n_a = n + n_blk + n_front
+                src = (res_np, cnt_np, prio_np, oid_np, onode_np, cnode_np,
+                       cname_np, inb_np, pre_np)
+                dst = tuple(
+                    self._sbuf(f"s.{i}", B, np.int32) for i in range(len(src))
                 )
-                cols = [res_np, cnt_np, prio_np, oid_np, onode_np,
-                        cnode_np, cname_np, inb_np, pre_np]
-                for i, x in enumerate(cols):
-                    dst = self._sbuf(f"s.{i}", B, x.dtype)
-                    np.take(x, order, out=dst)
-                    cols[i] = dst
+                ph_dst = self._sbuf("s.ph", (B, M), np.int32)
+                free = self._inv_free.setdefault(B, [])
+                inv_a = free.pop() if free else np.empty(B, np.int32)
+                _path = RING.presort(
+                    (res_np, cnode_np, onode_np, oid_np, cname_np), _n_a,
+                    self._sbuf("s.order", B, np.int32), inv_a,
+                    self._sbuf("s.scratch", 2 * max(B, B2), np.uint64),
+                    src, dst, ph_np, ph_dst,
+                )
                 (res_np, cnt_np, prio_np, oid_np, onode_np, cnode_np,
-                 cname_np, inb_np, pre_np) = cols
-                dst = self._sbuf("s.ph", (B, M), np.int32)
-                np.take(ph_np, order, axis=0, out=dst)
-                ph_np = dst
+                 cname_np, inb_np, pre_np) = dst
+                ph_np = ph_dst
                 if _tp:
                     _tp0 = _tp0 or _tp
                     _ns_presort += OT.now_ns() - _tp
@@ -3428,14 +3443,32 @@ class SentinelClient:
                 self._adaptive.signals.note_completions(n, float(rt_a.min()))
             if presort and n > 1:
                 _tp = OT.t0()
-                # completions carry no futures — sort in place, no unsort
-                # (all completion effects are order-independent sums/minima)
-                order, _ = RING.batch_sort3(res_a, ctx_a, org_a)
-                res_a, cnt_a, org_a, ctx_a, flags_a, rt_a, err_a = (
-                    x[order]
-                    for x in (res_a, cnt_a, org_a, ctx_a, flags_a, rt_a, err_a)
+                # completions carry no futures — sorted for good, no unsort
+                # (all completion effects are order-independent sums/minima);
+                # only the aux lanes that become param_hash are carried
+                _n_c = n
+                # (a producer may hand a wider integer column, as
+                # submit_completion_block's flags are: narrowed here, as
+                # pad() below would on the way to the wire anyway)
+                src = tuple(
+                    np.ascontiguousarray(
+                        x, np.float32 if x is rt_a else np.int32
+                    )
+                    for x in (res_a, cnt_a, org_a, ctx_a, flags_a, rt_a,
+                              err_a, *aux_a[:M])
                 )
-                aux_a = [x[order] for x in aux_a]
+                dst = tuple(
+                    self._sbuf(f"sc.{i}", B2, x.dtype)[:n]
+                    for i, x in enumerate(src)
+                )
+                _path_c = RING.presort(
+                    (src[0], src[3], src[2]), n,
+                    self._sbuf("sc.order", B2, np.int32)[:n], None,
+                    self._sbuf("s.scratch", 2 * max(B, B2), np.uint64), src, dst,
+                )
+                _path = _path or _path_c
+                res_a, cnt_a, org_a, ctx_a, flags_a, rt_a, err_a = dst[:7]
+                aux_a = list(dst[7:])
                 if _tp:
                     _tp0 = _tp0 or _tp
                     _ns_presort += OT.now_ns() - _tp
@@ -3504,8 +3537,12 @@ class SentinelClient:
                 attrs={"b": B, "b2": B2},
             )
             if _ns_presort:
+                # path is the acquire side's when it sorted, else the
+                # completion side's: radix / small (chosen on the live row
+                # count) or numpy (no native library)
                 OT.stage_ns(
-                    "tick.presort", _tp0, _ns_presort, _H_PRESORT, trace=tick_id
+                    "tick.presort", _tp0, _ns_presort, _H_PRESORT, trace=tick_id,
+                    attrs={"n_a": _n_a, "n_c": _n_c, "path": _path},
                 )
         load, cpu = self._sys.sample()
         t = now_ms if now_ms is not None else self.time.now_ms()
@@ -3896,6 +3933,10 @@ class SentinelClient:
             # map sorted-batch verdicts back to submission order
             verdict = verdict[p.inv_a]
             wait = wait[p.inv_a]
+            # the tick is claimed and its permutation spent: lend the
+            # buffer to a later tick (see _inv_free)
+            inv, p.inv_a = p.inv_a, None
+            self._inv_free[inv.shape[0]].append(inv)
         if self._adaptive is not None:
             if stats is not None:
                 # device accounting: valid items ARE the real items (all

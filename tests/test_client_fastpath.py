@@ -1,7 +1,8 @@
 """Production client on the fast engine path (VERDICT r4 #1).
 
-The client presorts batches by the segment keys host-side (np.lexsort in
-_run_tick) and maps verdicts back through the inverse permutation; seg_u
+The client presorts batches by the segment keys host-side (one call of
+native/ring.presort in _run_tick, bit-identical to np.lexsort + np.take) and
+maps verdicts back through the inverse permutation; seg_u
 grows automatically when traffic overflows the compacted capacity; fail-
 closed overflow drops are surfaced loudly.  On CPU the fused kernels run
 in Pallas interpret mode — semantics only (device speed is bench.py's
@@ -313,3 +314,180 @@ def test_jitted_const_column_cache_and_empty_batches(vt):
     e = c.entry(names[3])
     e.exit()
     c.tick_once()
+
+
+# -- the presort of _run_tick against the parent's (PR 25) -------------------
+#
+# The parent sorted all B padded rows with a comparison sort and permuted each
+# column with np.take (completions: x[order] over the n drained rows).  Its few
+# lines are the reference here; the one-call presort must upload the same bytes.
+
+
+def _parent_presort_acquire(keys, cols, ph):
+    order = np.lexsort(tuple(reversed(keys))).astype(np.int32)
+    inv = np.empty_like(order)
+    inv[order] = np.arange(order.shape[0], dtype=np.int32)
+    return inv, [np.take(x, order) for x in cols], np.take(ph, order, axis=0)
+
+
+def _mixed_tick(cfg, rng, n_obj, n_blk, n_comp):
+    """Object requests + two array blocks + completions, seeded: ids out of
+    order, origins, contexts, counts over the clamp, hot-param lanes, a few
+    live rows on the trash row."""
+    from sentinel_tpu.runtime.client import AcquireRequest, ArrayBlock
+
+    trash, M = cfg.trash_row, cfg.param_dims
+
+    def ids(n):
+        x = rng.integers(1, 48, n).astype(np.int32)
+        x[rng.random(n) < 0.05] = trash
+        return x
+
+    acq = [
+        AcquireRequest(
+            res=int(r), count=int(rng.integers(1, 4)), prio=int(rng.integers(0, 2)),
+            origin_id=int(rng.integers(-1, 3)), origin_node=int(rng.integers(50, 54)),
+            ctx_node=int(rng.integers(54, 58)), ctx_name=int(rng.integers(-1, 2)),
+            inbound=int(rng.integers(0, 2)),
+            param_hash=tuple(int(v) for v in rng.integers(0, 9, M)),
+        )
+        for r in ids(n_obj)
+    ]
+    half = n_blk // 2
+    blocks = []
+    for take in (half, n_blk - half):
+        blk = ArrayBlock(
+            res=ids(take + 3),
+            count=rng.integers(1, 70000, take + 3).astype(np.int32),
+            origin_id=rng.integers(-1, 3, take + 3).astype(np.int32),
+            origin_node=rng.integers(50, 54, take + 3).astype(np.int32),
+            inbound=rng.integers(0, 2, take + 3).astype(np.int32),
+            param_hash=rng.integers(0, 9, (take + 3, M)).astype(np.int32),
+            unresolved=take, verdicts=np.zeros(take + 3, np.int8),
+            waits=np.zeros(take + 3, np.int32),
+        )
+        blocks.append((blk, 3, take))  # a piece: offset 3 into the block
+    i32 = lambda lo, hi: rng.integers(lo, hi, n_comp).astype(np.int32)
+    comp = (
+        ids(n_comp), i32(1, 70000), i32(50, 54), i32(54, 58), i32(4, 6),
+        rng.random(n_comp).astype(np.float32), i32(0, 3), np.zeros(n_comp, np.int32),
+        i32(0, 9), i32(0, 9), i32(0, 9), i32(0, 9),
+    )
+    return acq, blocks, comp
+
+
+@pytest.mark.parametrize("fill", ["third", "full"])
+def test_run_tick_uploads_the_parents_presorted_columns(vt, fill, monkeypatch):
+    """A seeded mixed tick, a third full (the comparison path on both sides)
+    and full (radix): every uploaded acquire and completion column and inv_a
+    are byte-equal to what the parent's presort gives for the same inputs."""
+    import sentinel_tpu.native.ring as RM
+    from sentinel_tpu.native.ring import FLAG_INBOUND
+
+    B = 3072
+    c = _mk(vt, batch_size=B, complete_batch_size=B)  # never started: no compile
+    cfg, trash, M = c.cfg, c.cfg.trash_row, c.cfg.param_dims
+    n_blk = (B // 3 if fill == "third" else B) - 40
+    acq, blocks, comp = _mixed_tick(cfg, np.random.default_rng(25), 40, n_blk, n_blk)
+
+    seen = []  # what each side handed the presort, copied before it ran
+    real = RM.presort
+
+    def spy(keys, n_live, order, inv, scratch, src=(), dst=(), wide=None, wide_dst=None):
+        seen.append(([k.copy() for k in keys], [x.copy() for x in src],
+                     None if wide is None else wide.copy()))
+        return real(keys, n_live, order, inv, scratch, src, dst, wide, wide_dst)
+
+    uploaded = {}
+
+    def fake_tick(state, rules, a, cb, *_rest):
+        uploaded["a"], uploaded["c"] = a, cb
+        return state, None  # the device never runs; nothing is resolved
+
+    monkeypatch.setattr(RM, "presort", spy)
+    monkeypatch.setattr(c, "_tick", fake_tick)
+    p = c._run_tick(acq, comp, 1_000, blocks=blocks)
+
+    (keys_a, src_a, ph_a), (keys_c, src_c, _none) = seen
+    assert len(keys_a[0]) == B and len(keys_c[0]) == n_blk
+    inv, cols, ph = _parent_presort_acquire(keys_a, src_a, ph_a)
+    assert p.inv_a.tobytes() == inv.tobytes()
+    a = uploaded["a"]
+    got = (a.res, a.count, a.prio, a.origin_id, a.origin_node, a.ctx_node,
+           a.ctx_name, a.inbound, a.pre_verdict)
+    for g, want in zip(got, cols):
+        g = np.asarray(g)
+        assert g.tobytes() == want.astype(g.dtype).tobytes()
+    assert np.asarray(a.param_hash).tobytes() == ph.tobytes()
+    assert int(np.asarray(a.count).max()) == cfg.max_batch_count  # clamped first
+
+    # completions, the parent's lines: sort the n drained rows, then pad
+    res_a, cnt_a, org_a, ctx_a, flags_a, rt_a, err_a = src_c[:7]
+    order = np.lexsort((org_a, ctx_a, res_a))
+    res_a, cnt_a, org_a, ctx_a, flags_a, rt_a, err_a = (
+        x[order] for x in (res_a, cnt_a, org_a, ctx_a, flags_a, rt_a, err_a)
+    )
+    aux = [x[order] for x in comp[8:]]
+
+    def pad(x, fill_v, like):
+        out = np.full(B, fill_v, like.dtype)
+        out[:n_blk] = x
+        return out
+
+    cb = {k: np.asarray(v) for k, v in uploaded["c"]._asdict().items()}
+    want_ph = np.zeros((B, M), np.int32)
+    for k in range(M):
+        want_ph[:n_blk, k] = aux[k]
+    want = dict(
+        res=pad(res_a, trash, cb["res"]),
+        origin_node=pad(org_a, trash, cb["origin_node"]),
+        ctx_node=pad(ctx_a, trash, cb["ctx_node"]),
+        inbound=pad(flags_a & FLAG_INBOUND, 0, cb["inbound"]),
+        rt=pad(rt_a, 0.0, cb["rt"]),
+        success=pad(np.minimum(cnt_a, cfg.max_batch_count), 0, cb["success"]),
+        error=pad(np.minimum(err_a, cfg.max_batch_count), 0, cb["error"]),
+        param_hash=want_ph,
+    )
+    for name, w in want.items():
+        assert cb[name].tobytes() == w.tobytes(), name
+
+
+def test_pipelined_ticks_do_not_share_presort_storage(vt, monkeypatch):
+    """order and inv of a tick stay its own while it is unresolved: the next
+    tick gets other storage; a resolved tick's inv is lent again, so steady
+    serving allocates none."""
+    import sentinel_tpu.native.ring as RM
+
+    c = _mk(vt)
+    c.start()
+    try:
+        orders = []
+        real = RM.presort
+
+        def spy(keys, n_live, order, inv, *rest, **kw):
+            if inv is not None:  # the acquire side
+                orders.append(order)
+            return real(keys, n_live, order, inv, *rest, **kw)
+
+        monkeypatch.setattr(RM, "presort", spy)
+        rng = np.random.default_rng(3)
+        ticks = []
+        for _ in range(2):
+            acq, blocks, _comp = _mixed_tick(c.cfg, rng, 4, 30, 0)
+            ticks.append(c._run_tick(acq, None, None, blocks=blocks))
+        p1, p2 = ticks
+        inv1 = p1.inv_a.copy()
+        assert not np.shares_memory(p1.inv_a, p2.inv_a)
+        assert not np.shares_memory(orders[0], orders[1])
+        assert sorted(inv1) == list(range(c.cfg.batch_size))
+        lent = p1.inv_a
+        c._resolve_tick(p1)
+        assert p1.inv_a is None and c._inv_free[c.cfg.batch_size] == [lent]
+        acq, blocks, _comp = _mixed_tick(c.cfg, rng, 4, 30, 0)
+        p3 = c._run_tick(acq, None, None, blocks=blocks)
+        assert p3.inv_a is lent and p2.inv_a is not lent
+        c._resolve_tick(p2)
+        c._resolve_tick(p3)
+        assert len(c._inv_free[c.cfg.batch_size]) == 2
+    finally:
+        c.stop()

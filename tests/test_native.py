@@ -162,6 +162,155 @@ def test_batch_sort_native_matches_numpy_fallback(monkeypatch):
             assert np.array_equal(i5n[o5n], np.arange(n))
 
 
+# -- the tick builder's presort (native/ring.presort) ------------------------
+#
+# The reference is the parent's presort, kept here in its few lines: a stable
+# lexsort of ALL B rows, the inverse by scatter, one np.take a column.  The
+# new routine sorts the live rows only and must give the same bits.
+
+_NODE_ROWS = 16368  # the served deployments' tables; trash row = the last
+_TRASH = _NODE_ROWS - 1
+_SMALL_N = 1024  # SX_PRESORT_SMALL_N: std::sort at or under it, radix over
+
+
+def _reference_presort(keys, cols, wide):
+    order = np.lexsort(tuple(reversed(keys))).astype(np.int32)
+    inv = np.empty_like(order)
+    inv[order] = np.arange(order.shape[0], dtype=np.int32)
+    return order, inv, [np.take(x, order) for x in cols], np.take(wide, order, axis=0)
+
+
+def _presort_keys(kind, rng, B, n):
+    """Five B-row key columns, the first n live, the rest one padding run."""
+    if kind == "served":
+        # res-major keys as _run_tick builds them: ruled ids, sketch-tier
+        # ids beyond 2**20 + node_rows (they sort AFTER the padding run),
+        # live rows on the trash row (the negative-id sanitiser) and live
+        # rows whose whole key equals the padding key
+        pad = (_TRASH, _TRASH, _TRASH, -1, -1)
+        z = rng.zipf(1.3, n) % (1 << 20)
+        res = np.where(z <= 10_000, z, _NODE_ROWS + z).astype(np.int32)
+        res[rng.random(n) < 0.02] = _TRASH
+        res[rng.random(n) < 0.01] = (1 << 20) + _NODE_ROWS
+        with_origin = rng.random(n) < 0.125
+        live = [
+            res,
+            np.where(rng.random(n) < 0.5, 7, _TRASH),
+            np.where(with_origin, 9, _TRASH),
+            np.where(with_origin, 3, -1),
+            np.where(rng.random(n) < 0.1, rng.integers(0, 40, n), -1),
+        ]
+    elif kind == "ties":
+        pad = (0, 1, -1, 2, -2)
+        live = [rng.integers(-2, 3, n) for _ in range(5)]
+    else:  # "wide": five full-range keys, 160 bits: several packed rounds
+        pad = (-5, 2**31 - 1, -(2**31), 0, 17)
+        live = [rng.integers(-(2**31), 2**31, n) for _ in range(5)]
+        if n:
+            live[0][rng.random(n) < 0.5] = pad[0]  # deeper keys decide
+    keys = []
+    for fill, k in zip(pad, live):
+        col = np.full(B, fill, np.int32)
+        col[:n] = k
+        keys.append(col)
+    return keys
+
+
+_PRESORT_SHAPES = [(256, n) for n in (0, 1, 2, 255, 256)] + [
+    (131072, n)
+    for n in (0, 1, 2, 255, 256, 257, _SMALL_N, _SMALL_N + 1, 4096, 43000, 131072)
+]
+
+
+@pytest.mark.parametrize("force_fallback", [False, True], ids=["native", "numpy"])
+@pytest.mark.parametrize("kind,m", [("served", 2), ("ties", 1), ("wide", 3)])
+@pytest.mark.parametrize("B,n", _PRESORT_SHAPES)
+def test_presort_matches_lexsort_and_take(B, n, kind, m, force_fallback, monkeypatch):
+    """order, inv and every permuted column equal np.lexsort + np.take over
+    the B-row columns bit for bit, native and fallback, on both sides of the
+    small-n threshold, with the padding run in the middle of the order."""
+    import sentinel_tpu.native.ring as RM
+
+    assert native_available()
+    rng = np.random.default_rng(B + 7 * n + len(kind))
+    keys = _presort_keys(kind, rng, B, n)
+    cols = keys + [rng.integers(-9, 9, B).astype(np.int32) for _ in range(3)]
+    cols.append(rng.random(B).astype(np.float32))  # a 4-byte column that is no int
+    wide = rng.integers(0, 1 << 20, (B, m)).astype(np.int32)
+    for x in cols[5:] + [wide]:
+        x[n:] = 0  # padding rows hold one fill value a column
+    want_order, want_inv, want_cols, want_wide = _reference_presort(keys, cols, wide)
+
+    order, inv = np.empty(B, np.int32), np.empty(B, np.int32)
+    dst = [np.empty_like(x) for x in cols]
+    wide_dst = np.empty_like(wide)
+    if force_fallback:
+        monkeypatch.setattr(RM, "load_native", lambda: None)
+    path = RM.presort(
+        keys, n, order, inv, np.empty(2 * B, np.uint64), cols, dst, wide, wide_dst
+    )
+    assert path == ("numpy" if force_fallback else "small" if n <= _SMALL_N else "radix")
+    assert order.tobytes() == want_order.tobytes()
+    assert inv.tobytes() == want_inv.tobytes()
+    for got, want in zip(dst, want_cols):
+        assert got.tobytes() == want.tobytes()
+    assert wide_dst.tobytes() == want_wide.tobytes()
+    if kind == "served" and 255 <= n < B:
+        # the run of padding rows sits inside the order, not at its end
+        at = int(inv[n])
+        assert list(order[at : at + B - n]) == list(range(n, B))
+        assert 0 < at < n
+
+
+@pytest.mark.parametrize("force_fallback", [False, True], ids=["native", "numpy"])
+@pytest.mark.parametrize("n", [2, 255, _SMALL_N, _SMALL_N + 1, 43000])
+def test_presort_three_key_completion_form(n, force_fallback, monkeypatch):
+    """The completion side: three keys, every row live (the columns are not
+    padded before the sort), no inverse wanted, float and aux columns."""
+    import sentinel_tpu.native.ring as RM
+
+    rng = np.random.default_rng(n)
+    res = (rng.zipf(1.3, n) % 10_000).astype(np.int32)
+    ctx = np.where(rng.random(n) < 0.5, 7, _TRASH).astype(np.int32)
+    org = np.where(rng.random(n) < 0.125, 9, _TRASH).astype(np.int32)
+    cols = [res, ctx, org, rng.random(n).astype(np.float32),
+            rng.integers(0, 1 << 20, n).astype(np.int32)]
+    want = np.lexsort((org, ctx, res))
+    order = np.empty(n, np.int32)
+    dst = [np.empty_like(x) for x in cols]
+    if force_fallback:
+        monkeypatch.setattr(RM, "load_native", lambda: None)
+    RM.presort((res, ctx, org), n, order, None, np.empty(2 * n, np.uint64), cols, dst)
+    assert np.array_equal(order, want)
+    for got, x in zip(dst, cols):
+        assert got.tobytes() == x[want].tobytes()
+
+
+def test_presort_refuses_columns_it_cannot_permute():
+    """Pointers go to native code only after sizes, dtypes and contiguity
+    were checked here."""
+    import sentinel_tpu.native.ring as RM
+
+    k = np.zeros(8, np.int32)
+    order, scratch = np.empty(8, np.int32), np.empty(16, np.uint64)
+    ok = dict(order=order, inv=None, scratch=scratch)
+    with pytest.raises(ValueError):
+        RM.presort((k.astype(np.int64),), 8, **ok)  # keys are int32
+    with pytest.raises(ValueError):
+        RM.presort((k,), 9, **ok)  # more live rows than rows
+    with pytest.raises(ValueError):
+        RM.presort((k[:4],), 8, **ok)  # a key column shorter than B
+    with pytest.raises(ValueError):
+        RM.presort((k,), 8, order, None, scratch[:8])  # scratch holds 2 n
+    with pytest.raises(ValueError):
+        RM.presort((k,), 8, src=(k.astype(np.int64),), dst=(k.copy(),), **ok)
+    with pytest.raises(ValueError):
+        RM.presort((k,), 8, src=(np.zeros(16, np.int32)[::2],), dst=(k.copy(),), **ok)
+    with pytest.raises(ValueError):
+        RM.presort((k,), 8, src=(k,), dst=(), **ok)
+    assert RM.presort((k,), 8, src=(k,), dst=(k.copy(),), **ok) == "small"
+
+
 def test_batch_framing_native_matches_numpy_fallback(monkeypatch):
     """Protocol-v2 frame pack/unpack (sx_frame_pack_entries & co) must be
     BYTE-identical to the numpy big-endian structured fallback — the two

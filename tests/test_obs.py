@@ -749,6 +749,70 @@ def test_tracing_off_a_served_tick_records_nothing_and_reads_no_clock(
     assert c._idle_since == 0
 
 
+def _seg_client(client_factory):
+    """A client whose ticks take the host presort (segment effects behind
+    the fused path), as the served deployments do."""
+    from sentinel_tpu.core.config import small_engine_config
+
+    cfg = small_engine_config(
+        use_mxu_tables=True, fused_effects=True, seg_effects=True,
+        flow_rules_per_resource=1, degrade_rules_per_resource=1,
+        param_rules_per_resource=1)
+    return _load(client_factory(cfg=cfg, pipeline_depth=2))
+
+
+def test_tracing_off_the_presort_reads_no_clock_and_builds_no_attrs(
+    client_factory, monkeypatch
+):
+    """PR 25's site under the same contract: off, the presort runs and the
+    span machinery is never entered (so its attrs dict is never built)."""
+    import sentinel_tpu.native.ring as RM
+    from sentinel_tpu.obs import trace as OT
+
+    def never(*_a, **_kw):
+        raise AssertionError("the presort site entered the tracer with tracing off")
+
+    c = _seg_client(client_factory)
+    obs.TRACER.reset()
+    assert not OT.TRACER.enabled
+    monkeypatch.setattr(OT, "now_ns", never)
+    monkeypatch.setattr(OT, "stage_ns", never)
+    sorted_rows = []
+    real = RM.presort
+    monkeypatch.setattr(
+        RM, "presort", lambda keys, n, *a, **kw: sorted_rows.append(n) or real(keys, n, *a, **kw)
+    )
+    verdicts, _waits = _serve(c)
+    assert len(verdicts) == 48 and 48 in sorted_rows
+    assert obs.TRACER.snapshot() == []
+
+
+@pytest.mark.parametrize("path", ["small", "numpy"])
+def test_traced_presort_says_how_many_rows_it_sorted_and_by_which_path(
+    client_factory, monkeypatch, path
+):
+    """tick.presort carries n_a / n_c (live rows sorted a side) and path;
+    obs.summarize counts the paths, which --summary and the benchmark's
+    span_summary print."""
+    import sentinel_tpu.native.ring as RM
+
+    c = _seg_client(client_factory)
+    if path == "numpy":
+        monkeypatch.setattr(RM, "load_native", lambda: None)
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        _serve(c)
+    finally:
+        obs.disable()
+    spans = [s for s in obs.TRACER.snapshot() if s["name"] == "tick.presort"]
+    assert spans and all(set(s["attrs"]) == {"n_a", "n_c", "path"} for s in spans)
+    assert {s["attrs"]["path"] for s in spans} == {path}
+    assert 48 in [s["attrs"]["n_a"] for s in spans]  # the block's tick
+    assert all(0 <= s["attrs"]["n_c"] <= c.cfg.complete_batch_size for s in spans)
+    assert obs.summarize(spans)["tick.presort"]["path"] == {path: len(spans)}
+
+
 @pytest.mark.parametrize("depth", [0, 2])
 def test_traced_ticks_carry_one_id_from_queue_to_resolve(client_factory, depth):
     """req.queue, the tick thread's spans and the resolver's spans of one
