@@ -1,4 +1,5 @@
-"""What decides ``correct``.
+"""The check of a ``single_client`` deployment under FlowRule traffic, held
+to the plain reference ``perfbench/reference/leap.py``.
 
 Inside the window: every request resolved, none failed or answered
 BLOCK_SYSTEM, only the verdict codes this traffic can produce, and no ruled
@@ -22,29 +23,17 @@ never blocked.
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Dict, List, Tuple
 
 import numpy as np
 
+from perfbench.checks import Compared
 from perfbench.generators import BLOCK_FLOW, PASS, Window
 from perfbench.reference.leap import FlowReference, LeapWindows
 
 #: blocked share of the unruled sketch-tier items of a replay
 SKETCH_FALSE_BLOCK_LIMIT = 7.5e-4
 Tick = Tuple[int, np.ndarray, np.ndarray]  # now_ms, ids, verdicts
-
-
-@dataclasses.dataclass
-class Compared:
-    name: str
-    value: float
-    limit: float
-    at_least: bool = False  # the value must reach the limit, not stay under it
-
-    @property
-    def ok(self) -> bool:
-        return self.value >= self.limit if self.at_least else self.value <= self.limit
 
 
 def flow_rules(dep) -> Tuple[np.ndarray, np.ndarray]:
@@ -121,7 +110,7 @@ def decide(dep, generator, params: dict, seed: int, win: Window) -> Tuple[bool, 
     """Stop the client's tick thread, let the cell's generator replay a
     sample at virtual times, compare.  Returns ``(correct, every number
     compared, the replayed ticks' summary)``."""
-    dep.client.stop()
+    dep.stop()
     ticks = generator.replay(dep, params, seed)
     numbers = in_window(dep, win) + compare_replay(dep, ticks)
     items = sum(len(t[1]) for t in ticks)

@@ -1,4 +1,5 @@
-"""Build a configuration's deployment through the public client surface.
+"""One ``SentinelClient`` on the first chip, built through the public client
+surface: ``res-<i>`` names with a rule set each and a pool of Zipf traffic.
 
 A copy of ``bench.served_scenario`` (sound; see PERF.md section 6) that reads
 its sizes from ``perfbench/configs/<name>.json`` and also serves a
@@ -8,10 +9,14 @@ here from the seed; nothing is loaded from disk.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
 from typing import List, Optional, Tuple
 
 import numpy as np
+
+from perfbench.deployments import with_sizes
 
 #: one batch of the pool: ids, origin_node, origin_id, param_hash, inbound, rt
 Columns = Tuple[np.ndarray, ...]
@@ -26,6 +31,17 @@ class Deployment:
     tail_ids: np.ndarray  # current engine id of every tail-ruled resource
     batch: int
     sketch_base: int  # engine ids from here on live in the sketch tier
+    _serving: bool = False
+
+    def start(self) -> None:
+        self.client.start()  # rules are loaded: starting first would compile twice
+        self._serving = True
+
+    def stop(self) -> None:
+        """Stops the tick thread; the client still answers ``tick_once``."""
+        if self._serving:
+            self._serving = False
+            self.client.stop()
 
 
 def _rules(c, cfg: dict, ruled: List[str], tail_names: List[str]) -> None:
@@ -67,6 +83,28 @@ def _rules(c, cfg: dict, ruled: List[str], tail_names: List[str]) -> None:
         c.system_rules.load([SystemRule(qps=r["system_qps"])])
 
 
+@contextlib.contextmanager
+def control():
+    """The control of this kind's cells (``study.py control``): while this
+    holds, ``build`` loads every FlowRule one per cent above what its
+    configuration states (rounded up), so the guarantee "over-limit blocked"
+    is broken and a run has to come out as not correct.  (On the chip one
+    more than 1000 was not enough: all twelve such runs of PR 23 came out
+    correct, see PERF.md.)"""
+    global _rules
+    real = _rules
+
+    def one_more(c, cfg, ruled, tail_names):
+        rules = dict(cfg["rules"], flow_qps=math.ceil(cfg["rules"]["flow_qps"] * 1.01))
+        real(c, dict(cfg, rules=rules), ruled, tail_names)
+
+    _rules = one_more
+    try:
+        yield
+    finally:
+        _rules = real
+
+
 def make_pool(cfg: dict, seed: int, batch: int, tail_ids, node_rows, trash_row,
               origin_row, origin_id, param_dims) -> List[Columns]:
     """``pool_batches`` full batches of seeded Zipf traffic as column tuples."""
@@ -103,19 +141,11 @@ def make_pool(cfg: dict, seed: int, batch: int, tail_ids, node_rows, trash_row,
 
 
 def build(cfg: dict, seed: int, sizes: Optional[dict] = None) -> Deployment:
-    """The configuration's client (not started) and its traffic pool.
-
-    ``sizes`` replaces keys of the configuration's groups for a CPU rehearsal
-    at a tiny size (``{"engine": {...}, "resources": {...}, ...}``); a
-    measurement never passes it."""
+    """The configuration's client (not started) and its traffic pool."""
     from sentinel_tpu.core.config import platform_engine_config
     from sentinel_tpu.runtime.client import SentinelClient
 
-    if sizes:
-        cfg = {
-            k: ({**v, **sizes[k]} if k in sizes and isinstance(v, dict) else v)
-            for k, v in cfg.items()
-        }
+    cfg = with_sizes(cfg, sizes)
     res = cfg["resources"]
     ecfg = platform_engine_config(**cfg["engine"])
     c = SentinelClient(cfg=ecfg, **cfg["client"])

@@ -3,8 +3,13 @@
 A cell names a configuration and a traffic mix; each is a file found by that
 name, and so is every metric.  Adding one is adding files and entries:
 
-- ``configs/<config>.json``: the deployment's sizes, rules and guarantees
-- ``traffic/<traffic>.json``: the mix's generator and its parameters
+- ``configs/<config>.json``: the deployment's sizes, rules and guarantees,
+  and the three modules it is built and judged by: ``deployment`` (a module
+  of ``perfbench.deployments``), ``check`` (``perfbench.checks``) and the
+  plain ``reference`` that check imports (``perfbench.reference``)
+- ``traffic/<traffic>.json``: the mix's generator (a module of
+  ``perfbench.generators``), its parameters, and under ``drives`` the
+  deployment kinds that generator can drive
 - ``cells/<cell>.json`` (optional): parameters of the mix that belong to
   this cell alone, such as the offered rate found by the sweep
 - ``metrics/<metric>.json``: the metric's reader (a module of
@@ -13,6 +18,7 @@ name, and so is every metric.  Adding one is adding files and entries:
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import re
@@ -23,11 +29,18 @@ HERE = "perfbench"
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+#: what a configuration file names, and the package each is a module of
+CONFIG_MODULES = (("deployment", "deployments"), ("check", "checks"), ("reference", "reference"))
 
 
 def _read(root: str, *parts: str) -> dict:
     with open(os.path.join(root, HERE, *parts)) as f:
         return json.load(f)
+
+
+def module(group: str, name: str):
+    """The code a data file names: ``perfbench/<group>/<name>.py``."""
+    return importlib.import_module(f"{HERE}.{group}.{name}")
 
 
 def load(root: str = ROOT) -> dict:
@@ -85,8 +98,19 @@ def problems(manifest: dict, root: str = ROOT) -> List[str]:
             out.append(f"{'/'.join(parts)}: {e}")
             return None
 
+    def code(group: str, name) -> Optional[str]:
+        """The text of ``<group>/<name>.py``, if the name can be one."""
+        if not (isinstance(name, str) and NAME.match(name)):
+            return None
+        try:
+            with open(os.path.join(root, HERE, group, f"{name}.py")) as f:
+                return f.read()
+        except OSError:
+            return None
+
     configs = {c["name"]: c for c in manifest["configs"]}
     cells = {w["name"]: w for w in manifest["workloads"]}
+    kinds = {}  # configuration -> the deployment kind its file names
     for n, c in configs.items():
         name_ok("config", n)
         if c["file"] != f"{HERE}/configs/{n}.json":
@@ -99,6 +123,19 @@ def problems(manifest: dict, root: str = ROOT) -> List[str]:
                 out.append(f"config {n}: reduced differs from its file's")
             if not body.get("guarantees"):
                 out.append(f"config {n}: states no guarantees")
+            kinds[n] = body.get("deployment")
+            text = {}
+            for key, group in CONFIG_MODULES:
+                if key not in body:
+                    out.append(f"config {n}: names no {key}")
+                    continue
+                text[key] = code(group, body[key])
+                if text[key] is None:
+                    out.append(f"config {n}: no {key} {body[key]!r} ({HERE}/{group}/{body[key]}.py)")
+            if text.get("check") and text.get("reference") is not None:
+                wanted = f"{HERE}.reference.{body['reference']}"
+                if wanted not in text["check"]:
+                    out.append(f"config {n}: check {body['check']!r} does not import {wanted}")
         if not any(w["config"] == n for w in cells.values()):
             out.append(f"config {n} has no cell")
     pairs = set()
@@ -119,6 +156,10 @@ def problems(manifest: dict, root: str = ROOT) -> List[str]:
             gen = os.path.join(root, HERE, "generators", f"{mix.get('generator')}.py")
             if not os.path.exists(gen):
                 out.append(f"traffic {w['traffic']}: no generator {mix.get('generator')!r}")
+            kind = kinds.get(w["config"])
+            if kind is not None and kind not in mix.get("drives", []):
+                out.append(f"workload {n}: traffic {w['traffic']} drives {mix.get('drives', [])}, "
+                           f"not config {w['config']}'s deployment kind {kind!r}")
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
     if "setup_s" not in e2e:
         out.append("no end-to-end metric setup_s")

@@ -18,8 +18,8 @@ import time
 _T_PROCESS = time.monotonic()  # as early as this process can read a clock
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -61,11 +61,12 @@ def device_info(chips: int, require_tpu: bool) -> dict:
     return info
 
 
-def memory_peak_bytes() -> int:
+def memory_peaks() -> list:
+    """Peak bytes in use on every chip, in ``jax.devices()`` order; the
+    result's ``memory_peak_bytes`` is the fullest."""
     import jax
 
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in jax.devices()]
-    return int(max(peaks))
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in jax.devices()]
 
 
 class SetupClock:
@@ -184,6 +185,88 @@ def slices(win) -> list:
     ]
 
 
+@dataclasses.dataclass
+class Cell:
+    """A cell set up: looked up, its deployment built, started and primed."""
+
+    manifest: dict
+    entry: dict  # the cell's entry of ``workloads``
+    params: dict  # the traffic mix's parameters, then the cell's own
+    generator: object  # module of perfbench.generators
+    check: object  # module of perfbench.checks
+    dep: object  # what the configuration's deployment kind built
+    device: dict
+    clock: SetupClock
+    at_setup: dict  # the clock's line when set-up ended
+    root: str  # where BENCHMARK.json and the data files were read
+
+
+def set_up(
+    workload: str,
+    seed: int,
+    *,
+    sizes=None,
+    require_tpu: bool = True,
+    params_override=None,
+    whole_locations: bool = False,
+    root: str = M.ROOT,
+) -> Cell:
+    """The one set-up: the cell looked up in ``BENCHMARK.json`` and the data
+    files, its deployment built from the seed, started and primed with a
+    moment of the cell's own traffic.  ``sizes`` and ``require_tpu`` exist
+    for the CPU rehearsal in the tests (no compile cache, no device check);
+    ``whole_locations`` is ``timeline.py trace``'s, which needs the stage
+    scopes in its operations' names."""
+    manifest = M.load(root)
+    bad = M.problems(manifest, root)
+    if bad:
+        raise ValueError("BENCHMARK.json: " + "; ".join(bad))
+    entry = M.cell(manifest, workload)
+    cfg = M.config(entry["config"], root)
+    params = M.traffic(entry, root)
+    params.update(params_override or {})
+    generator = M.module("generators", params["generator"])
+
+    cache_dir = None
+    if require_tpu:
+        from sentinel_tpu.utils.compile_cache import enable_compile_cache
+
+        cache_dir = enable_compile_cache()
+    import jax
+
+    # A Mosaic kernel's payload carries its debug locations into the compile
+    # cache's key, and with full tracebacks those name every caller's line:
+    # the same tick would compile again under another entry point.  Whole,
+    # they put a ``jax.named_scope`` into an operation's ``op_name``; cut to
+    # one frame, the path of every operation outside a nested ``jit`` goes.
+    jax.config.update("jax_include_full_tracebacks_in_locations", bool(whole_locations))
+    clock = SetupClock()
+    device = device_info(entry["chips"], require_tpu)
+    clock.stage("import_and_device")
+
+    dep = M.module("deployments", cfg["deployment"]).build(cfg, seed, sizes)
+    clock.stage("deployment")
+    try:
+        dep.start()
+        clock.stage("start")
+        # The deployment's own warm-up runs empty ticks.  The first ticks that
+        # carry items compile some thirty small programs more (wire unpack,
+        # telemetry folds), seconds of stall that belong to set-up: so the
+        # cell's own traffic runs for a moment here, until it is idle again.
+        generator.run(dep, dict(params, preroll_s=0.0, postroll_s=0.0), seed,
+                      params["prime_seconds"], Hooks())
+    except BaseException:
+        dep.stop()  # whoever called has no deployment to stop
+        raise
+    clock.stage("prime")
+    gc.collect()
+    gc.freeze()
+    at_setup = clock.line()
+    _say(phase="setup", cache_dir=cache_dir, **at_setup)
+    return Cell(manifest, entry, params, generator, M.module("checks", cfg["check"]),
+                dep, device, clock, at_setup, root)
+
+
 def run_cell(
     workload: str,
     seed: int,
@@ -197,62 +280,32 @@ def run_cell(
     root: str = M.ROOT,
 ) -> dict:
     """One run of one cell; returns the result object.  ``sizes`` and
-    ``require_tpu`` exist for the CPU rehearsal in the tests (no compile
-    cache, no device check), ``params_override`` and ``on_profile`` (called with the
-    loaded trace, the window and its spans) for the noise study and a first
-    look at a trace (``perfbench/study.py``); the command passes none of
-    them."""
-    manifest = M.load(root)
-    bad = M.problems(manifest, root)
-    if bad:
-        raise ValueError("BENCHMARK.json: " + "; ".join(bad))
-    cell = M.cell(manifest, workload)
-    cfg = M.config(cell["config"], root)
-    params = M.traffic(cell, root)
-    params.update(params_override or {})
-    generator = importlib.import_module(f"perfbench.generators.{params['generator']}")
+    ``require_tpu`` are ``set_up``'s, ``params_override`` and ``on_profile``
+    (called with the loaded trace, the window and its spans) for the noise
+    study and a first look at a trace (``perfbench/study.py``); the command
+    passes none of them."""
     if trace:
         # the program's span ring (read at import): room for a whole window
         os.environ.setdefault("SENTINEL_TRACE_CAPACITY", str(1 << 18))
-        seconds = min(seconds, params["trace_seconds"])
+    cell = set_up(workload, seed, sizes=sizes, require_tpu=require_tpu,
+                  params_override=params_override, root=root)
+    try:
+        return _measure(cell, seed, seconds, trace, on_profile)
+    finally:
+        cell.dep.stop()
 
-    from sentinel_tpu.utils.compile_cache import enable_compile_cache
 
-    cache_dir = enable_compile_cache() if require_tpu else None
-    import jax
-
-    # A Mosaic kernel's payload carries its debug locations into the compile
-    # cache's key, and with full tracebacks those name every caller's line:
-    # the same tick would compile again under another entry point.
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
-    clock = SetupClock()
-    device = device_info(cell["chips"], require_tpu)
-    clock.stage("import_and_device")
-
-    from perfbench import check, deployment
+def _measure(cell: Cell, seed: int, seconds: float, trace: bool, on_profile) -> dict:
+    """The window, its metrics and the check, on a cell that is set up."""
     from perfbench.readers import Context
 
-    dep = deployment.build(cfg, seed, sizes)
-    clock.stage("deployment")
-    dep.client.start()  # rules are loaded: starting first would compile twice
-    clock.stage("client_start")
-    # The client's own warm-up runs empty ticks.  The first ticks that carry
-    # items compile some thirty small programs more (wire unpack, telemetry
-    # folds), seconds of stall that belong to set-up: so the cell's own
-    # traffic runs for a moment here, until the client is idle again.
-    generator.run(dep, dict(params, preroll_s=0.0, postroll_s=0.0), seed,
-                  params["prime_seconds"], Hooks())
-    clock.stage("prime")
-    gc.collect()
-    gc.freeze()
-    at_setup = clock.line()
-    _say(phase="setup", cache_dir=cache_dir, **at_setup)
-
+    params, generator, dep, device = cell.params, cell.generator, cell.dep, cell.device
     hooks = _Hooks(trace)
     if trace:
         import jax
         from sentinel_tpu import obs
 
+        seconds = min(seconds, params["trace_seconds"])
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         obs.TRACER.reset()
         opts = jax.profiler.ProfileOptions()
@@ -263,14 +316,15 @@ def run_cell(
     finally:
         if trace:
             jax.profiler.stop_trace()
-    device["memory_peak_bytes"] = memory_peak_bytes()
-    since_setup = clock.line()
+    peaks = memory_peaks()
+    device["memory_peak_bytes"] = max(peaks)
+    at_setup, since_setup = cell.at_setup, cell.clock.line()
     _say(
         phase="window", compiles_since_setup=since_setup["compiles"] - at_setup["compiles"],
         compile_s_since_setup=round(since_setup["compile_s"] - at_setup["compile_s"], 3), samples=int(len(win.latency_ms)), attempted=win.attempted,
         failed=win.failed, codes=win.codes, span_s=round(win.span_s, 3),
         p50_ms_per_slice=slices(win), latency_ms=_percentiles(win.latency_ms),
-        slow_episodes=slow_episodes(win),
+        slow_episodes=slow_episodes(win), memory_peak_bytes_per_chip=peaks,
         **win.extra,
     )
 
@@ -297,23 +351,25 @@ def run_cell(
         starts = np.sort([s["t0_ns"] for s in ctx.spans if s["name"] == "tick.assemble"])
         _say(phase="trace", spans=len(ctx.spans), ticks=int(len(summary.tick_busy_ms)),
              longest_tick_gap_ms=float(np.diff(starts).max() / 1e6) if len(starts) > 1 else None,
+             busy_s_per_chip=summary.chip_busy_s,
+             chips_without_a_device_plane=device["count"] - len(summary.chip_busy_s),
              span_summary=obs.summarize(ctx.spans))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
 
     metrics = {}
-    for m in M.metrics_of(manifest, workload, "per_layer" if trace else "end_to_end"):
-        spec = M.metric(m["name"], root)
-        reader = importlib.import_module(f"perfbench.readers.{spec['reader']}")
-        value = reader.read(ctx, **spec["args"])
+    group = "per_layer" if trace else "end_to_end"
+    for m in M.metrics_of(cell.manifest, cell.entry["name"], group):
+        spec = M.metric(m["name"], cell.root)
+        value = M.module("readers", spec["reader"]).read(ctx, **spec["args"])
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
-    correct, numbers, replayed = check.decide(dep, generator, params, seed, win)
+    correct, numbers, replayed = cell.check.decide(dep, generator, params, seed, win)
     for n in numbers:
         _say(compared=n.name, value=n.value, limit=n.limit,
              rule="at least" if n.at_least else "at most", ok=n.ok)
     _say(phase="replay", **replayed)
-    result = {
+    return {
         "correct": bool(correct),
         "attempted": int(win.attempted),
         "failed": int(win.failed),
@@ -321,7 +377,6 @@ def run_cell(
         "device": device,
         **result,
     }
-    return result
 
 
 def main(argv=None) -> int:

@@ -10,9 +10,10 @@
 backlog does not grow (``pending_end`` no higher than ``pending_mid``) with
 ``failed`` 0.  ``run`` is one whole run of the command with the rate replaced,
 for the noise study.  ``describe`` is a traced run that also writes the
-trace's planes and lines to a file.  ``control`` is a run whose deployment
-breaks the guarantee "over-limit blocked" (every FlowRule loaded one per cent
-above what the configuration states): it has to come out as not correct.  None of
+trace's planes and lines to a file.  ``control`` is a run under the
+``control()`` of the cell's deployment kind, which breaks one guarantee the
+configuration states (``single_client``: "over-limit blocked", every FlowRule
+loaded one per cent high): it has to come out as not correct.  None of
 this runs in a check: the rate a cell offers is the literal in
 ``perfbench/cells/<cell>.json``.
 """
@@ -20,11 +21,7 @@ this runs in a check: the rate a cell offers is the literal in
 from __future__ import annotations
 
 import argparse
-import contextlib
-import gc
-import importlib
 import json
-import math
 import os
 import sys
 
@@ -38,57 +35,24 @@ from perfbench.generators import Hooks  # noqa: E402
 
 
 def sweep(workload: str, rates, step_seconds: float, seed: int) -> None:
-    from sentinel_tpu.utils.compile_cache import enable_compile_cache
-
-    enable_compile_cache()
-    manifest = M.load()
-    cell = M.cell(manifest, workload)
-    R.device_info(cell["chips"], True)
-    from perfbench import deployment
-
-    dep = deployment.build(M.config(cell["config"]), seed)
-    dep.client.start()
-    gc.collect()
-    gc.freeze()
-    params = M.traffic(cell)
-    params.update(preroll_s=2.0, postroll_s=0.0)
-    generator = importlib.import_module(f"perfbench.generators.{params['generator']}")
-    for i, rate in enumerate(rates):
-        params["rate_items_per_s"] = rate
-        win = generator.run(dep, params, seed + i, step_seconds, Hooks())
-        lat = win.latency_ms
-        print(json.dumps({
-            "rate_items_per_s": rate,
-            "visible_items_per_s": win.visible_items / win.seconds,
-            "p50_ms": float(np.median(lat)) if len(lat) else None,
-            "p99_ms": float(np.percentile(lat, 99)) if len(lat) else None,
-            "late_p99_ms": float(np.percentile(win.late_ms, 99)),
-            "attempted": win.attempted, "failed": win.failed,
-            "unresolved": win.unresolved, **win.extra,
-        }), flush=True)
-    dep.client.stop()
-
-
-@contextlib.contextmanager
-def over_admitting_deployment():
-    """The control: while this holds, a deployment loads every FlowRule one
-    per cent above what its configuration states (rounded up), so the
-    guarantee "over-limit blocked" is broken and a run has to come out as not
-    correct.  (On the chip one more than 1000 was not enough: all twelve
-    such runs of PR 23 came out correct, see PERF.md.)"""
-    from perfbench import deployment
-
-    real = deployment._rules
-
-    def one_more(c, cfg, ruled, tail_names):
-        rules = dict(cfg["rules"], flow_qps=math.ceil(cfg["rules"]["flow_qps"] * 1.01))
-        real(c, dict(cfg, rules=rules), ruled, tail_names)
-
-    deployment._rules = one_more
+    cell = R.set_up(workload, seed)
+    params = dict(cell.params, preroll_s=2.0, postroll_s=0.0)
     try:
-        yield
+        for i, rate in enumerate(rates):
+            params["rate_items_per_s"] = rate
+            win = cell.generator.run(cell.dep, params, seed + i, step_seconds, Hooks())
+            lat = win.latency_ms
+            print(json.dumps({
+                "rate_items_per_s": rate,
+                "visible_items_per_s": win.visible_items / win.seconds,
+                "p50_ms": float(np.median(lat)) if len(lat) else None,
+                "p99_ms": float(np.percentile(lat, 99)) if len(lat) else None,
+                "late_p99_ms": float(np.percentile(win.late_ms, 99)),
+                "attempted": win.attempted, "failed": win.failed,
+                "unresolved": win.unresolved, **win.extra,
+            }), flush=True)
     finally:
-        deployment._rules = real
+        cell.dep.stop()
 
 
 def write_profile(describe_to: str, slice_to, slice_s: float = 0.08):
@@ -138,7 +102,8 @@ def main(argv=None) -> int:
         over = {k: json.loads(v) for k, v in (kv.split("=", 1) for kv in a.set)}
         print(json.dumps(R.run_cell(a.workload, a.seed, a.seconds, False, params_override=over)))
     elif a.cmd == "control":
-        with over_admitting_deployment():
+        kind = M.config(M.cell(M.load(), a.workload)["config"])["deployment"]
+        with M.module("deployments", kind).control():
             print(json.dumps(R.run_cell(a.workload, a.seed, a.seconds, False)))
     else:
         os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)

@@ -17,12 +17,13 @@ it, that prints one ``phase="timeline"`` object: how tightly the two clocks
 are tied, every execution of the tick program joined to the tick id that
 dispatched it, how long finished verdicts lay unread, device seconds per
 stage and per kernel, the tick thread's unnamed share and the closure of the
-request path.  It sets the cell up itself, because stage scopes reach a
-device operation's ``op_name`` only while JAX's locations are whole
-and ``run.py`` cuts them to one frame to keep its compile-cache key still
-(the traced program is the same; the Mosaic kernels compile to code that
-reads 1.7 % faster with whole locations, PERF.md section 7).  The fork goes
-when a ``benchmark`` PR folds it into ``run.py`` (ROADMAP D13).
+request path.  It asks ``run.set_up`` for whole locations, because stage
+scopes reach a device operation's ``op_name`` only while JAX's locations are
+whole and ``run.py`` cuts them to one frame to keep its compile-cache key
+still (the traced program is the same; the Mosaic kernels compile to code
+that reads 1.7 % faster with whole locations, PERF.md section 7).  The window
+and the reductions are still this module's own, beside ``run.py``'s
+``phase="trace"`` line (ROADMAP D13).
 ``spans`` is one set-up and two windows of the same seed, the
 first with everything off and the second with the program's spans on and the
 profiler off: what tracing costs, and which spans cover each slow episode.
@@ -32,8 +33,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import gc
-import importlib
 import json
 import os
 import re
@@ -592,41 +591,6 @@ def _stat(v) -> Optional[dict]:
 # -- the two commands ----------------------------------------------------------
 
 
-def _set_up(workload: str, seed: int, *, sizes=None, require_tpu: bool = True,
-            params_override=None, scopes: bool = False):
-    """The cell's deployment started and primed, as ``run.run_cell`` does it.
-    ``scopes`` leaves JAX's locations whole (its default), which is what puts
-    a ``jax.named_scope`` into an operation's ``op_name``: ``run_cell`` cuts
-    them to one frame so that the compile cache's key does not move with the
-    caller (PERF.md, finding 2 of PR 23), and with them goes the path of every
-    operation that is not inside a nested ``jit``."""
-    from perfbench import manifest as M
-    from perfbench import run as R
-
-    cell = M.cell(M.load(), workload)
-    params = M.traffic(cell)
-    params.update(params_override or {})
-    generator = importlib.import_module(f"perfbench.generators.{params['generator']}")
-    if require_tpu:
-        from sentinel_tpu.utils.compile_cache import enable_compile_cache
-
-        enable_compile_cache()
-    import jax
-
-    jax.config.update("jax_include_full_tracebacks_in_locations", bool(scopes))
-    device = R.device_info(cell["chips"], require_tpu)
-    from perfbench import deployment
-    from perfbench.generators import Hooks
-
-    dep = deployment.build(M.config(cell["config"]), seed, sizes)
-    dep.client.start()
-    generator.run(dep, dict(params, preroll_s=0.0, postroll_s=0.0), seed,
-                  params["prime_seconds"], Hooks())
-    gc.collect()
-    gc.freeze()
-    return dep, generator, params, device
-
-
 def traced(workload: str, seed: int, slice_to: Optional[str] = None,
            slice_s: float = 0.08) -> dict:
     """A traced window of the cell, as ``run.py --trace 1`` takes it (the
@@ -639,7 +603,9 @@ def traced(workload: str, seed: int, slice_to: Optional[str] = None,
     from perfbench import run as R
     from sentinel_tpu import obs
 
-    dep, generator, params, device = _set_up(workload, seed, scopes=True)
+    # whole locations: stage scopes reach an operation's name only with them
+    cell = R.set_up(workload, seed, whole_locations=True)
+    dep, generator, params, device = cell.dep, cell.generator, cell.params, cell.device
     shutil.rmtree(R.TRACE_DIR, ignore_errors=True)
     obs.TRACER.reset()
     opts = jax.profiler.ProfileOptions()
@@ -649,7 +615,7 @@ def traced(workload: str, seed: int, slice_to: Optional[str] = None,
         win = generator.run(dep, params, seed, params["trace_seconds"], R._Hooks(True))
     finally:
         jax.profiler.stop_trace()
-    dep.client.stop()
+        dep.stop()
     spans = [s for s in obs.TRACER.snapshot() if win.open_ns <= s["t0_ns"] < win.close_ns]
     path = xplane.find(R.TRACE_DIR)
     profile = xplane.load(path)
@@ -758,9 +724,10 @@ def spans_run(workload: str, seed: int, seconds: float, *, sizes=None, require_t
     from perfbench import run as R
     from sentinel_tpu import obs
 
-    dep, generator, params, device = _set_up(
-        workload, seed, sizes=sizes, require_tpu=require_tpu, params_override=params_override)
-    out = {"workload": workload, "seed": seed, "seconds": seconds, "device": device}
+    cell = R.set_up(workload, seed, sizes=sizes, require_tpu=require_tpu,
+                    params_override=params_override)
+    dep, generator, params = cell.dep, cell.generator, cell.params
+    out = {"workload": workload, "seed": seed, "seconds": seconds, "device": cell.device}
     for on in ((False, True) if untraced_first else (True,)):
         obs.TRACER.reset()
         obs.TRACER.record(RING_MARK, 0, 0)  # the ring's first span: gone if the ring wrapped
@@ -789,7 +756,7 @@ def spans_run(workload: str, seed: int, seconds: float, *, sizes=None, require_t
             ]
             out["spans"] = spans
         out["spans_on" if on else "spans_off"] = side
-    dep.client.stop()
+    dep.stop()
     return out
 
 

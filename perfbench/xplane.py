@@ -31,12 +31,16 @@ HOST_SPANS = ("tick.presort", "tick.assemble", "tick.dispatch", "tick.readback",
 @dataclasses.dataclass
 class Summary:
     window_s: float
-    busy_s: float  # union of device-op intervals in the window, mean over chips
+    busy_s: float  # union of device-op intervals in the window, mean over the chips with a plane
     tick_busy_ms: np.ndarray  # per execution of the tick program
     tick_kernels_ms: np.ndarray
     device_ops: List[Tuple[str, float]]  # top operations by seconds in the window
     idle_gaps: List[Tuple[str, float]]  # idle seconds by what the host was doing
     clock_offset_ns: int  # monotonic_ns = file time + offset
+    #: device plane -> its own busy seconds; ``busy_s`` is their mean.  A chip
+    #: on which nothing ran has no device plane at all (a four-chip host with
+    #: one client on chip 0 gave ``/device:TPU:0`` alone; PERF.md, PR 26)
+    chip_busy_s: Dict[str, float]
 
 
 def find(trace_dir: str) -> str:
@@ -160,7 +164,7 @@ def summarize(pd, open_mono_ns: int, spans: List[dict]) -> Summary:
     planes = [p for p in pd.planes if DEVICE_PLANE.match(p.name)]
     if not planes:
         raise ValueError("the trace has no /device:TPU plane")
-    busy_s = []
+    busy_s: Dict[str, float] = {}
     totals: Dict[str, float] = {}
     tick_busy, tick_kern = [], []
     gap_by: Dict[str, float] = {}
@@ -173,7 +177,7 @@ def summarize(pd, open_mono_ns: int, spans: List[dict]) -> Summary:
         keep = (e > w0) & (s < w1)
         names, s, e = names[keep], np.maximum(s[keep], w0), np.minimum(e[keep], w1)
         u0, u1 = union(s, e)
-        busy_s.append(float((u1 - u0).sum()) / 1e9)
+        busy_s[plane.name] = float((u1 - u0).sum()) / 1e9
         # an operation that the next one starts inside is a container
         # (a conditional, a loop): its time is its children's, listed anyway
         leaf = np.concatenate([s[1:] >= e[:-1], [True]]) if len(s) else np.zeros(0, bool)
@@ -222,12 +226,13 @@ def summarize(pd, open_mono_ns: int, spans: List[dict]) -> Summary:
     gaps = sorted(((n, v / k) for n, v in gap_by.items() if v > 0), key=lambda kv: -kv[1])[:10]
     return Summary(
         window_s=(w1 - w0) / 1e9,
-        busy_s=float(np.mean(busy_s)),
+        busy_s=float(np.mean(list(busy_s.values()))),
         tick_busy_ms=np.asarray(tick_busy),
         tick_kernels_ms=np.asarray(tick_kern),
         device_ops=[(n, v / k) for n, v in top],
         idle_gaps=gaps,
         clock_offset_ns=offset,
+        chip_busy_s=busy_s,
     )
 
 

@@ -39,9 +39,13 @@ class FakeDeployment:
         rng = np.random.default_rng(seed)
         self.client = FakeClient(**client_kw)
         self.batch = batch
+        self.stops = 0
         self.pool = []
         for _ in range(batches):
             ids = rng.integers(1, 40, batch).astype(np.int32)
             z = np.zeros(batch, np.int32)
             self.pool.append((ids, z, z, np.zeros((batch, 2), np.int32), z,
                               np.ones(batch, np.float32)))
+
+    def stop(self) -> None:
+        self.stops += 1

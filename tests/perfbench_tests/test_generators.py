@@ -136,3 +136,29 @@ def test_pass_counter_adds_up_per_engine_id():
     pc.add(0, 0, np.array([0, 0, 0, 0], np.int8))
     assert pc.passes().tolist() == [0, 0, 0, 3, 0, 2, 0, 0, 0, 4]
     assert pc.code_counts() == {0: 9, 1: 2, 4: 1}
+
+
+def test_the_sweep_takes_the_one_set_up_and_stops_what_it_started(monkeypatch, capsys):
+    """``study.sweep`` builds nothing itself: it asks ``run.set_up`` for the
+    cell, steps the rate on that deployment, and stops it."""
+    import json
+
+    from perfbench import run, study
+
+    dep = FakeDeployment(delay_s=0.001)
+    asked = []
+
+    def set_up(workload, seed, **kw):
+        asked.append((workload, seed, kw))
+        return run.Cell(manifest={}, entry={}, params=dict(PACED, rate_items_per_s=1.0),
+                        generator=open_loop_blocks, check=None, dep=dep, device={},
+                        clock=None, at_setup={}, root="")
+
+    monkeypatch.setattr(run, "set_up", set_up)
+    study.sweep("zipf-1m.paced", [6400.0, 12800.0], 0.3, seed=4)
+    rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    assert asked == [("zipf-1m.paced", 4, {})] and dep.stops == 1
+    assert [r["rate_items_per_s"] for r in rows] == [6400.0, 12800.0]
+    assert all(r["failed"] == 0 and r["attempted"] > 0 for r in rows)
+    # 2 s of pre-roll and 0.3 s of window at each step's own rate
+    assert dep.client.blocks == int(2.3 * 100) + int(2.3 * 200)

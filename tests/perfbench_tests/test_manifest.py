@@ -5,6 +5,7 @@ metric can each be added as new files and entries only."""
 import copy
 import json
 import os
+import re
 import shutil
 
 import pytest
@@ -85,6 +86,52 @@ def _broken(edit):
 ])
 def test_faults_are_named(edit, word):
     assert any(word in p for p in _broken(edit)), _broken(edit)
+
+
+def _edit_file(root, rel, edit):
+    path = os.path.join(root, "perfbench", rel)
+    with open(path) as f:
+        body = json.load(f)
+    edit(body)
+    with open(path, "w") as f:
+        json.dump(body, f)
+
+
+@pytest.mark.parametrize("rel, edit, lines", [
+    ("configs/zipf-1m.json", lambda c: c.pop("deployment"),
+     ["config zipf-1m: names no deployment"]),
+    ("configs/zipf-1m.json", lambda c: c.update(deployment="token_mesh"),
+     ["config zipf-1m: no deployment 'token_mesh' (perfbench/deployments/token_mesh.py)",
+      "workload zipf-1m.flood: traffic flood-128k drives ['single_client'], not config zipf-1m's deployment kind 'token_mesh'",
+      "workload zipf-1m.paced: traffic paced-4k drives ['single_client'], not config zipf-1m's deployment kind 'token_mesh'"]),
+    ("configs/zipf-10k.json", lambda c: c.update(check="no_such_check"),
+     ["config zipf-10k: no check 'no_such_check' (perfbench/checks/no_such_check.py)"]),
+    ("configs/zipf-10k.json", lambda c: c.update(reference="../check"),
+     ["config zipf-10k: no reference '../check' (perfbench/reference/../check.py)"]),
+    ("configs/zipf-10k.json", lambda c: c.update(reference="plain"),
+     ["config zipf-10k: check 'flow_replay' does not import perfbench.reference.plain"]),
+    ("traffic/entry-8t.json", lambda t: t.update(drives=["token_mesh"]),
+     ["workload zipf-10k.entry: traffic entry-8t drives ['token_mesh'], not config zipf-10k's deployment kind 'single_client'"]),
+    ("traffic/entry-8t.json", lambda t: t.pop("drives"),
+     ["workload zipf-10k.entry: traffic entry-8t drives [], not config zipf-10k's deployment kind 'single_client'"]),
+])
+def test_a_configurations_modules_and_a_mixs_kinds_are_each_named_in_one_line(tmp_path, rel, edit, lines):
+    """What a configuration names (deployment, check, reference) and what a
+    mix says it drives are held against the files before anything is built."""
+    from perfbench import run
+
+    root = str(tmp_path)
+    shutil.copytree(os.path.join(M.ROOT, "perfbench"), os.path.join(root, "perfbench"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(M.ROOT, "BENCHMARK.json"), root)
+    with open(os.path.join(root, "perfbench", "reference", "plain.py"), "w") as f:
+        f.write("# a reference that no check imports\n")
+    assert M.problems(M.load(root), root) == []
+    _edit_file(root, rel, edit)
+    assert M.problems(M.load(root), root) == lines
+    # and a run stops there, in one line, before it builds a thing
+    with pytest.raises(ValueError, match="^BENCHMARK.json: " + re.escape(lines[0])):
+        run.run_cell("zipf-10k.entry", 1, 1.0, False, require_tpu=False, root=root)
 
 
 def test_one_of_each_can_be_added_without_editing_a_file(tmp_path):

@@ -12,8 +12,9 @@ from concurrent.futures import Future
 import numpy as np
 import pytest
 
-from perfbench import deployment, run, study
 from perfbench import manifest as M
+from perfbench import run
+from perfbench.deployments import single_client as deployment
 
 pytestmark = pytest.mark.jitted
 
@@ -51,15 +52,19 @@ def rehearse(cell, seed=2**31 + 17, seconds=1.5):
                         require_tpu=False, params_override=PARAMS[entry["traffic"]])
 
 
-def compared(capsys):
-    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines() if l.startswith("{")]
-    return {l["compared"]: l for l in lines if "compared" in l}
+def printed(capsys):
+    return [json.loads(l) for l in capsys.readouterr().out.splitlines() if l.startswith("{")]
+
+
+def compared(capsys, lines=None):
+    return {l["compared"]: l for l in (lines or printed(capsys)) if "compared" in l}
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_rehearses_correct_with_the_result_lines_shape(cell, capsys):
     result = rehearse(cell)
-    numbers = compared(capsys)
+    lines = printed(capsys)
+    numbers = compared(capsys, lines)
     assert result["correct"] is True, numbers
     assert sorted(result) == ["attempted", "correct", "device", "failed", "metrics"]
     assert result["attempted"] > 0 and result["failed"] == 0
@@ -69,6 +74,11 @@ def test_cell_rehearses_correct_with_the_result_lines_shape(cell, capsys):
     assert {k: v["unit"] for k, v in result["metrics"].items()} == want
     assert all(isinstance(v["value"], float) and v["value"] > 0 for v in result["metrics"].values())
     json.dumps(result)
+    # beside the fullest chip's peak, every chip's own
+    window = next(l for l in lines if l.get("phase") == "window")
+    assert len(window["memory_peak_bytes_per_chip"]) == result["device"]["count"]
+    assert max(window["memory_peak_bytes_per_chip"]) == result["device"]["memory_peak_bytes"]
+    assert [l["phase"] for l in lines if "phase" in l] == ["setup", "window", "replay"]
     # every number compared is printed beside its limit, and the replay bit
     assert numbers["replay_pass_count_mismatches"]["limit"] == 0
     assert numbers["replay_blocked_items"]["value"] >= 1
@@ -81,7 +91,7 @@ def test_the_control_thresholds_one_per_cent_too_high_is_not_correct(cell, capsy
     """The guarantee 'over-limit blocked', broken in the deployment: every
     FlowRule admits one per cent more than the configuration states."""
     real = deployment._rules
-    with study.over_admitting_deployment():
+    with deployment.control():
         result = rehearse(cell)
     assert deployment._rules is real
     numbers = compared(capsys)

@@ -83,6 +83,25 @@ def test_hand_made_trace():
     assert sum(gaps.values()) + s.busy_s == pytest.approx(s.window_s, rel=1e-3)
 
 
+def test_each_chips_own_busy_seconds_stand_beside_their_mean():
+    """The result's ``busy_s`` stays the mean over the device planes; beside
+    it the harness prints each plane's own.  (A chip on which nothing ran has
+    no plane: the harness prints how many of JAX's devices have none.)"""
+    one = xplane.summarize(trace(OPS, MODULES), 0, [])
+    assert one.chip_busy_s == {"/device:TPU:0": pytest.approx(191e-6)}
+    assert one.busy_s == one.chip_busy_s["/device:TPU:0"]
+    two = trace(OPS, MODULES)
+    two.planes.append(trace(OPS[:3], MODULES[:1]).planes[1])
+    two.planes[-1].name = "/device:TPU:1"
+    s = xplane.summarize(two, 0, [])
+    assert s.chip_busy_s == {"/device:TPU:0": pytest.approx(191e-6),
+                             "/device:TPU:1": pytest.approx(90e-6)}
+    assert s.busy_s == pytest.approx((191e-6 + 90e-6) / 2)
+    assert len(s.tick_busy_ms) == 3
+    assert dict(s.device_ops)["fusion.1"] == pytest.approx((100e-6 + 50e-6) / 2)
+    assert sum(dict(s.idle_gaps).values()) + s.busy_s == pytest.approx(s.window_s, rel=1e-3)
+
+
 def test_events_are_clipped_to_the_marked_window():
     s = xplane.summarize(trace(OPS, MODULES, mark=(150 * US, 620 * US)), 0, [])
     assert s.busy_s == pytest.approx((20 + 20 + 20) * 1e-6)
@@ -98,6 +117,10 @@ def test_a_trace_without_the_mark_or_a_device_is_an_error():
     no_device.planes[1].name = "/device:GPU:0"
     with pytest.raises(ValueError, match="TPU"):
         xplane.summarize(no_device, 0, [])
+    no_ops = trace(OPS, MODULES)
+    no_ops.planes[1].lines[0].name = "Ops"
+    with pytest.raises(ValueError, match="/device:TPU:0 has no 'XLA Ops' line"):
+        xplane.summarize(no_ops, 0, [])
 
 
 # -- the recorded slice -------------------------------------------------------
