@@ -1,9 +1,14 @@
 """The check of a ``single_client`` deployment under FlowRule traffic, held
 to the plain reference ``perfbench/reference/leap.py``.
 
-Inside the window: every request resolved, none failed or answered
+Inside the window: every request resolved, none errored or answered
 BLOCK_SYSTEM, only the verdict codes this traffic can produce, and no ruled
-resource admitted more than its windows allow over the run.
+resource admitted more than its windows allow over the run.  A block that
+was answered in full but later than the client's own timeout (``Window.late``)
+is late and not wrong: it stays in the result's ``failed``, is lost to the
+rate and the latency samples, is printed beside the window, and fails no
+comparison here (PERF.md, PR 27: one standstill of 12 s in a run of the
+driver's check made eight such blocks, and the run not correct).
 
 After the window, on the same client and the same compiled programs: the
 tick thread is stopped and a seeded sample of the cell's traffic is driven
@@ -54,7 +59,7 @@ def in_window(dep, win: Window) -> List[Compared]:
     other = sum(v for k, v in win.codes.items() if k not in (PASS, BLOCK_FLOW))
     return [
         Compared("window_requests", win.attempted, 1, at_least=True),
-        Compared("window_failed", win.failed, 0),
+        Compared("window_failed", win.failed - win.late, 0),
         Compared("window_unresolved", win.unresolved, 0),
         Compared("window_other_codes", other, 0),
         Compared("window_over_admitted_resources", over, 0),

@@ -16,10 +16,39 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from perfbench.deployments import with_sizes
+from perfbench.deployments import intervals, with_sizes
 
 #: one batch of the pool: ids, origin_node, origin_id, param_hash, inbound, rt
 Columns = Tuple[np.ndarray, ...]
+#: the span ``runtime/client.py`` opens once a tick, on the tick thread
+TICK_SPAN = "tick.assemble"
+#: its host spans that can explain an idle device, most specific first: the
+#: five of a tick's path, then those that tile the rest of the tick thread.
+#: ``tick.device``, ``tick.resident`` and ``tick.wait`` are waits for the
+#: device itself and explain nothing
+HOST_SPANS = ("tick.presort", TICK_SPAN, "tick.dispatch", "tick.readback", "tick.resolve",
+              "tick.drain", "tick.handoff", "tick.hotset", "tick.lock", "tick.idle")
+
+
+def host_intervals(spans: List[dict]) -> list:
+    """``HOST_SPANS`` as the intervals they cover.  ``tick.assemble`` is
+    recorded with its own duration, the presort inside it apart: the two
+    together run from assemble's start to its tick's dispatch, so assemble's
+    interval is lengthened by its tick's presort (what then lies under both
+    goes to the presort, which is asked first)."""
+    presort = {s["trace"]: s["dur_ns"] for s in spans if s["name"] == "tick.presort"}
+    return [intervals(spans, n, presort if n == TICK_SPAN else None) for n in HOST_SPANS]
+
+
+def journal(t0_ns: int, t1_ns: int) -> list:
+    """The program's flight journal (``obs.FLIGHT``: rare state changes such
+    as a rule recompile, a hot-set promotion, a capacity resize, a watchdog
+    that fired, a tick failed closed) between two instants of
+    ``monotonic_ns``, as ``(t_ns, kind, fields)``; its newest 64 at most."""
+    from sentinel_tpu import obs
+
+    return [(e["t_ns"], e["kind"], e["fields"]) for e in obs.FLIGHT.events(last=64)
+            if t0_ns <= e["t_ns"] < t1_ns]
 
 
 @dataclasses.dataclass

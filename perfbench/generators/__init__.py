@@ -22,7 +22,11 @@ BLOCK_SYSTEM = 4
 class Hooks:
     """What the harness wants to know while a generator runs.  The generator
     calls ``opened()`` at the instant the measured window opens and
-    ``closed()`` when it closes."""
+    ``closed()`` when it closes.  A generator may set ``progress`` to a
+    function that returns a count of requests sent or answered so far: the
+    harness's witness asks it ten times a second to see a window stand still."""
+
+    progress = None
 
     def opened(self) -> None:
         pass
@@ -39,7 +43,7 @@ class Window:
     open_ns: int  # time.monotonic_ns() at window open
     close_ns: int
     attempted: int  # requests due in the window
-    failed: int  # of those: errored, timed out or answered BLOCK_SYSTEM
+    failed: int  # of those: errored, never answered, answered BLOCK_SYSTEM, or answered late
     latency_ms: np.ndarray  # due -> resolved, per request due in the window
     due_ns: np.ndarray  # due time of each of those requests (for slices)
     visible_items: int  # items whose verdict became visible inside the window
@@ -49,6 +53,12 @@ class Window:
     unresolved: int  # requests of the whole run that never resolved
     span_s: float  # first submit -> last resolve, whole run
     extra: Dict[str, float] = dataclasses.field(default_factory=dict)
+    #: of ``failed``: blocks answered in full and without BLOCK_SYSTEM, but
+    #: later than the client's own ``entry_timeout_s`` after they were due (a
+    #: caller of the blocking form would have given up).  Late, not wrong:
+    #: they are lost to the rate and to the latency samples, and a check
+    #: holds them against no answer's limit
+    late: int = 0
 
 
 def now_ns() -> int:

@@ -47,6 +47,7 @@ def run(dep, params: dict, seed: int, seconds: float, hooks: Hooks) -> Window:
 
         fut.add_done_callback(on_done)
 
+    hooks.progress = lambda: state["next"]
     t0 = now_ns()
     open_ns = t0 + int(params["preroll_s"] * 1e9)
     close_ns = open_ns + int(seconds * 1e9)
@@ -82,6 +83,10 @@ def run(dep, params: dict, seed: int, seconds: float, hooks: Hooks) -> Window:
     failed = in_win & ~good
     # blocks that never resolved count against the window they were sent in
     lost = (done == 0) & (sent >= open_ns) & (sent < close_ns)
+    # the longest the window went without a reply, and when: a stall shows
+    # here whatever it did to the rate
+    seen = np.sort(np.concatenate([[open_ns], done[in_win], [close_ns]]))
+    gap = int(np.argmax(np.diff(seen)))
     return Window(
         seconds=seconds,
         open_ns=open_ns,
@@ -96,4 +101,12 @@ def run(dep, params: dict, seed: int, seconds: float, hooks: Hooks) -> Window:
         codes=counter.code_counts(),
         unresolved=int(n - np.count_nonzero(done)),
         span_s=float((done.max() - sent[0]) / 1e9),
+        late=int((in_win & ok & ~good).sum()),
+        extra={
+            "longest_reply_gap_s": float((seen[gap + 1] - seen[gap]) / 1e9),
+            "longest_reply_gap_at_s": float((seen[gap] - open_ns) / 1e9),
+            "failed_block_system_or_error": int((in_win & ~ok).sum()),
+            "failed_lost": int(lost.sum()),
+            "worst_latency_ms": float(lat_ms[in_win].max()) if in_win.any() else None,
+        },
     )

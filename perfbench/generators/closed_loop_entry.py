@@ -56,6 +56,7 @@ def run(dep, params: dict, seed: int, seconds: float, hooks: Hooks) -> Window:
         threading.Thread(target=caller, args=(i,), name=f"perfbench-caller-{i}")
         for i in range(n_threads)
     ]
+    hooks.progress = lambda: sum(int(np.count_nonzero(r[2])) for r in list(rows))
     t0 = now_ns()
     open_ns = t0 + int(params["preroll_s"] * 1e9)
     close_ns = open_ns + int(seconds * 1e9)

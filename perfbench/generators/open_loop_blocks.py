@@ -64,6 +64,7 @@ def run(dep, params: dict, seed: int, seconds: float, hooks: Hooks) -> Window:
     k_mid = int(np.searchsorted(due, (open_rel + close_rel) // 2))
     k_end = int(np.searchsorted(due, close_rel))
     pending = {}
+    hooks.progress = lambda: int(np.count_nonzero(done))
 
     t0 = now_ns() + 2_000_000
     opened = closed = False
@@ -119,11 +120,14 @@ def run(dep, params: dict, seed: int, seconds: float, hooks: Hooks) -> Window:
         codes=counter.code_counts(),
         unresolved=int(n - np.count_nonzero(done)),
         span_s=float((done.max() - sent[0]) / 1e9),
+        late=int((in_win & ok & ~good).sum()),
         extra={
             "pending_mid": pending.get(k_mid, 0),
             "pending_end": pending.get(k_end, 0),
             "offered_items_per_s": n * params["block_items"]
             / (params["preroll_s"] + seconds + params["postroll_s"]),
+            "failed_block_system_or_error": int((in_win & ~ok).sum()),
+            "worst_latency_ms": float(lat_ms[in_win & (done > 0)].max(initial=0.0)),
         },
     )
 

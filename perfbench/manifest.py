@@ -160,6 +160,13 @@ def problems(manifest: dict, root: str = ROOT) -> List[str]:
             if kind is not None and kind not in mix.get("drives", []):
                 out.append(f"workload {n}: traffic {w['traffic']} drives {mix.get('drives', [])}, "
                            f"not config {w['config']}'s deployment kind {kind!r}")
+    # the driver's rule, met here first: a four-chip cell costs four times
+    # the chip time in every later check, so at most half the cells, rounded
+    # down, may ask for four chips, and one always may
+    four = [n for n, w in cells.items() if w["chips"] == 4]
+    if len(four) > max(1, len(cells) // 2):
+        out.append(f"{len(four)} of {len(cells)} cells ask for four chips ({', '.join(four)}); "
+                   f"at most {max(1, len(cells) // 2)} may")
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
     if "setup_s" not in e2e:
         out.append("no end-to-end metric setup_s")

@@ -4,9 +4,13 @@
 
 The cell is looked up in ``BENCHMARK.json`` and its configuration, traffic
 mix and metrics in the files those names lead to (``perfbench/manifest.py``).
-The last line of standard output is the result object; everything else a
-reader may want (sample counts, slices, each number compared beside its
-limit) goes on earlier lines.  Without a TPU, or with fewer chips than the
+The last line of standard output is the result object: the keys the driver
+reads, then ``beside`` (what stood beside a window that lost requests: the
+longest gap between replies, the witness's late wake-ups and stalls, the
+program's journal; empty in a sound run) and, last, ``compared``: every
+number compared beside its limit, which are also the last lines of standard
+error.  Everything else a reader may want (sample counts, slices) goes on
+earlier lines.  Without a TPU, or with fewer chips than the
 cell asks for, the command prints a reason to standard error, no result, and
 exits with code 2.
 """
@@ -24,6 +28,8 @@ import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+import threading  # noqa: E402
+import traceback  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -31,6 +37,7 @@ import numpy as np  # noqa: E402
 
 from perfbench import manifest as M  # noqa: E402
 from perfbench.generators import Hooks  # noqa: E402
+from perfbench.xplane import WINDOW_MARK  # noqa: E402
 
 #: scratch of a traced run, inside the checkout and listed in .gitignore
 TRACE_DIR = os.path.join(M.ROOT, ".perfbench_trace")
@@ -116,30 +123,137 @@ class SetupClock:
 class _Hooks(Hooks):
     """Stamps the set-up time when the window opens; in a traced run also
     switches the program's spans on for exactly the window and marks the
-    window in the profiler's trace."""
+    window in the profiler's trace.  ``mark_ns`` is ``monotonic_ns`` at the
+    instant the mark opens, read on both sides of it: what ties the file's
+    clock to the spans'.  (The window's own ``open_ns`` is when it was due to
+    open; the mark opens a wake-up and this method's first lines later,
+    0.2 to 2 ms, which is a whole stage of a light tick.)"""
 
     def __init__(self, trace: bool):
         self.trace = trace
         self.setup_s = None
+        self.mark_ns = None
+        self.opened_ns = self.closed_ns = None  # as the generator called; the witness reads them
         self._mark = None
 
     def opened(self) -> None:
         self.setup_s = time.monotonic() - _T_PROCESS
+        self.opened_ns = time.monotonic_ns()
         if self.trace:
             import jax
             from sentinel_tpu import obs
-            from perfbench.xplane import WINDOW_MARK
 
+            before = time.monotonic_ns()
             self._mark = jax.profiler.TraceAnnotation(WINDOW_MARK)
             self._mark.__enter__()
+            self.mark_ns = (before + time.monotonic_ns()) // 2
             obs.enable()
 
     def closed(self) -> None:
+        self.closed_ns = time.monotonic_ns()
         if self.trace:
             from sentinel_tpu import obs
 
             obs.disable()
             self._mark.__exit__(None, None, None)
+
+
+class Witness(threading.Thread):
+    """What a stalled window looked like from beside it, so that a run that
+    comes out not correct says why (PERF.md, PR 27: one run of the driver's
+    check lost 12 s of a flood window and every block in flight).  A thread
+    that sleeps ``PERIOD_S`` at a time and notes how late it wakes, and the
+    CPU seconds all threads of the process used meanwhile.  Late by seconds,
+    the whole process stood still: with next to no CPU used, the host had the
+    cores; with as much as the wall time, code of this process held the
+    interpreter lock.  On time while the generator's ``hooks.progress()``
+    does not move for ``STALL_S`` of the window, the serving path alone stood
+    still, and the other threads' innermost frames are kept, once.  It costs
+    ten wake-ups a second and reads no clock of the program."""
+
+    PERIOD_S = 0.1
+    STALL_S = 2.0
+    LATE_S = 0.03  # a wake-up later than this is kept
+
+    def __init__(self, hooks):
+        super().__init__(name="perfbench-witness", daemon=True)
+        self.hooks = hooks
+        self.late = []  # [monotonic_ns woken, seconds late, process CPU seconds meanwhile]
+        self.stalls = []  # [monotonic_ns when seen, seconds without progress so far]
+        self.stacks = None
+        self._stop_evt = threading.Event()
+        self._proc0 = time.process_time()
+
+    def run(self):
+        last, moved_ns, seen = None, time.monotonic_ns(), False
+        while True:
+            due = time.monotonic_ns() + int(self.PERIOD_S * 1e9)
+            cpu = time.process_time()
+            if self._stop_evt.wait(self.PERIOD_S):
+                return
+            now = time.monotonic_ns()
+            if now - due > self.LATE_S * 1e9:
+                self.late.append([now, (now - due) / 1e9, time.process_time() - cpu])
+                if len(self.late) > 64:
+                    self.late.remove(min(self.late, key=lambda x: x[1]))
+            probe = self.hooks.progress
+            if probe is None or self.hooks.opened_ns is None or self.hooks.closed_ns is not None:
+                continue  # requests stop by design outside the window
+            at = probe()
+            if at != last:
+                last, moved_ns, seen = at, now, False
+            elif (now - moved_ns) / 1e9 >= self.STALL_S:
+                if not seen:
+                    seen = True
+                    self.stalls.append([now, 0.0])
+                    if self.stacks is None:
+                        self.stacks = self._frames()
+                self.stalls[-1][1] = round((now - moved_ns) / 1e9, 3)
+
+    @staticmethod
+    def _frames(limit: int = 4000) -> list:
+        """Every other thread's innermost five frames, shortest first, cut to
+        ``limit`` characters in all."""
+        names = {t.ident: t.name for t in threading.enumerate()}
+        me = threading.get_ident()
+        out = []
+        for ident, frame in sys._current_frames().items():
+            if ident == me:
+                continue
+            where = [f"{os.path.basename(f.filename)}:{f.lineno}:{f.name}"
+                     for f in traceback.extract_stack(frame)[-5:]]
+            out.append(f"{names.get(ident, ident)}: " + " < ".join(reversed(where)))
+        out.sort(key=len)
+        kept, used = [], 0
+        for line in out:
+            if used + len(line) > limit:
+                break
+            kept.append(line)
+            used += len(line)
+        return kept
+
+    def close(self, win) -> dict:
+        """Stop, and say what was seen: the four latest wake-ups inside the
+        window as ``[seconds into the window, seconds late, process CPU
+        seconds meanwhile]``, the stalls as ``[seconds into the window when
+        seen, seconds without progress]``."""
+        self._stop_evt.set()
+        self.join(timeout=2.0)
+
+        def at(ns):
+            return round((ns - win.open_ns) / 1e9, 3)
+
+        inside = [x for x in self.late if win.open_ns <= x[0] < win.close_ns + int(1e9)]
+        inside.sort(key=lambda x: -x[1])
+        line = {
+            "witness_late": [[at(ns), round(late, 4), round(cpu, 4)] for ns, late, cpu in inside[:4]],
+            "witness_late_wakeups": len(inside),
+            "process_cpu_s": round(time.process_time() - self._proc0, 3),
+            "stalls": [[at(ns), s] for ns, s in self.stalls],
+        }
+        if self.stacks:
+            line["stalled_threads"] = self.stacks
+        return line
 
 
 def _percentiles(v) -> dict:
@@ -194,7 +308,8 @@ class Cell:
     params: dict  # the traffic mix's parameters, then the cell's own
     generator: object  # module of perfbench.generators
     check: object  # module of perfbench.checks
-    dep: object  # what the configuration's deployment kind built
+    kind: object  # module of perfbench.deployments: the configuration's deployment kind
+    dep: object  # what that kind built
     device: dict
     clock: SetupClock
     at_setup: dict  # the clock's line when set-up ended
@@ -244,7 +359,8 @@ def set_up(
     device = device_info(entry["chips"], require_tpu)
     clock.stage("import_and_device")
 
-    dep = M.module("deployments", cfg["deployment"]).build(cfg, seed, sizes)
+    kind = M.module("deployments", cfg["deployment"])
+    dep = kind.build(cfg, seed, sizes)
     clock.stage("deployment")
     try:
         dep.start()
@@ -264,7 +380,7 @@ def set_up(
     at_setup = clock.line()
     _say(phase="setup", cache_dir=cache_dir, **at_setup)
     return Cell(manifest, entry, params, generator, M.module("checks", cfg["check"]),
-                dep, device, clock, at_setup, root)
+                kind, dep, device, clock, at_setup, root)
 
 
 def run_cell(
@@ -281,7 +397,8 @@ def run_cell(
 ) -> dict:
     """One run of one cell; returns the result object.  ``sizes`` and
     ``require_tpu`` are ``set_up``'s, ``params_override`` and ``on_profile``
-    (called with the loaded trace, the window and its spans) for the noise
+    (called with the loaded trace, the window, its spans and the host's
+    clock at the window mark) for the noise
     study and a first look at a trace (``perfbench/study.py``); the command
     passes none of them."""
     if trace:
@@ -311,11 +428,24 @@ def _measure(cell: Cell, seed: int, seconds: float, trace: bool, on_profile) -> 
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(TRACE_DIR, profiler_options=opts)
+    witness = Witness(hooks)
+    witness.start()
     try:
         win = generator.run(dep, params, seed, seconds, hooks)
     finally:
+        witness._stop_evt.set()
         if trace:
             jax.profiler.stop_trace()
+    # what stood beside the window: the generator's own account, the
+    # witness's, and the kind's journal of rare state changes (a rule
+    # recompile, a capacity resize, a tick failed closed) where it keeps one
+    beside = dict(answered_late=win.late, **win.extra, **witness.close(win))
+    journal = getattr(cell.kind, "journal", None)
+    if journal:
+        beside["journal"] = [
+            [round((t_ns - win.open_ns) / 1e9, 3), what, json.loads(json.dumps(fields, default=str))]
+            for t_ns, what, fields in journal(win.open_ns - 5_000_000_000, win.close_ns + 1_000_000_000)
+        ]
     peaks = memory_peaks()
     device["memory_peak_bytes"] = max(peaks)
     at_setup, since_setup = cell.at_setup, cell.clock.line()
@@ -325,13 +455,13 @@ def _measure(cell: Cell, seed: int, seconds: float, trace: bool, on_profile) -> 
         failed=win.failed, codes=win.codes, span_s=round(win.span_s, 3),
         p50_ms_per_slice=slices(win), latency_ms=_percentiles(win.latency_ms),
         slow_episodes=slow_episodes(win), memory_peak_bytes_per_chip=peaks,
-        **win.extra,
+        **beside,
     )
 
     ctx = Context(window=win, setup_s=hooks.setup_s, batch=dep.batch)
     result = {}
     if trace:
-        from perfbench import xplane
+        from perfbench import deployments, xplane
 
         ctx.spans = [
             s for s in obs.TRACER.snapshot()
@@ -339,8 +469,10 @@ def _measure(cell: Cell, seed: int, seconds: float, trace: bool, on_profile) -> 
         ]
         profile = xplane.load(xplane.find(TRACE_DIR))
         if on_profile:
-            on_profile(profile, win, ctx.spans)
-        summary = xplane.summarize(profile, win.open_ns, ctx.spans)
+            on_profile(profile, win, ctx.spans, hooks.mark_ns)
+        summary = xplane.summarize(profile, hooks.mark_ns,
+                                   deployments.host_intervals(cell.kind, ctx.spans),
+                                   cell.entry["chips"])
         ctx.trace = summary
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
@@ -348,9 +480,13 @@ def _measure(cell: Cell, seed: int, seconds: float, trace: bool, on_profile) -> 
             "device_ops": [list(kv) for kv in summary.device_ops],
             "idle_gaps": [list(kv) for kv in summary.idle_gaps],
         }
-        starts = np.sort([s["t0_ns"] for s in ctx.spans if s["name"] == "tick.assemble"])
+        # the span that opens once a tick is the kind's to name; a kind that
+        # names none still has its ticks counted, from the trace alone
+        tick_span = getattr(cell.kind, "TICK_SPAN", None)
+        starts = np.sort([s["t0_ns"] for s in ctx.spans if s["name"] == tick_span])
         _say(phase="trace", spans=len(ctx.spans), ticks=int(len(summary.tick_busy_ms)),
              longest_tick_gap_ms=float(np.diff(starts).max() / 1e6) if len(starts) > 1 else None,
+             mark_late_ms=(hooks.mark_ns - win.open_ns) / 1e6,  # the mark after the due opening
              busy_s_per_chip=summary.chip_busy_s,
              chips_without_a_device_plane=device["count"] - len(summary.chip_busy_s),
              span_summary=obs.summarize(ctx.spans))
@@ -369,6 +505,15 @@ def _measure(cell: Cell, seed: int, seconds: float, trace: bool, on_profile) -> 
         _say(compared=n.name, value=n.value, limit=n.limit,
              rule="at least" if n.at_least else "at most", ok=n.ok)
     _say(phase="replay", **replayed)
+    result.update(
+        # what stood beside the window, in a run that lost requests only: a
+        # sound run's line stays short
+        beside=beside if win.failed or win.unresolved or not correct else {},
+        # every number compared beside its limit; last, so that the end of
+        # the line always holds it
+        compared={n.name: {"value": n.value, "at least" if n.at_least else "at most": n.limit,
+                           "ok": n.ok} for n in numbers},
+    )
     return {
         "correct": bool(correct),
         "attempted": int(win.attempted),
@@ -392,6 +537,12 @@ def main(argv=None) -> int:
         print(f"perfbench: {e}; nothing was run", file=sys.stderr)
         return 2
     print(json.dumps(result), flush=True)
+    # the same numbers as the last lines of standard error
+    if result["beside"]:
+        print("beside the window: " + json.dumps(result["beside"]), file=sys.stderr)
+    for name, n in result["compared"].items():
+        print(f"compared {name}: " + json.dumps(n), file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

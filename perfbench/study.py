@@ -8,8 +8,10 @@
 ``sweep`` steps the offered rate of an open-loop cell upward in one process
 (one set-up) and prints a row per step; the knee is the highest step whose
 backlog does not grow (``pending_end`` no higher than ``pending_mid``) with
-``failed`` 0.  ``run`` is one whole run of the command with the rate replaced,
-for the noise study.  ``describe`` is a traced run that also writes the
+``failed`` 0.  The parameter it steps is the one the cell's traffic file names
+under ``rate_key`` (``rate_items_per_s`` where it names none), so another
+kind's knee is found with the same command.  ``run`` is one whole run of the
+command with a parameter replaced, for the noise study.  ``describe`` is a traced run that also writes the
 trace's planes and lines to a file.  ``control`` is a run under the
 ``control()`` of the cell's deployment kind, which breaks one guarantee the
 configuration states (``single_client``: "over-limit blocked", every FlowRule
@@ -37,13 +39,14 @@ from perfbench.generators import Hooks  # noqa: E402
 def sweep(workload: str, rates, step_seconds: float, seed: int) -> None:
     cell = R.set_up(workload, seed)
     params = dict(cell.params, preroll_s=2.0, postroll_s=0.0)
+    rate_key = params.get("rate_key", "rate_items_per_s")
     try:
         for i, rate in enumerate(rates):
-            params["rate_items_per_s"] = rate
+            params[rate_key] = rate
             win = cell.generator.run(cell.dep, params, seed + i, step_seconds, Hooks())
             lat = win.latency_ms
             print(json.dumps({
-                "rate_items_per_s": rate,
+                rate_key: rate,
                 "visible_items_per_s": win.visible_items / win.seconds,
                 "p50_ms": float(np.median(lat)) if len(lat) else None,
                 "p99_ms": float(np.percentile(lat, 99)) if len(lat) else None,
@@ -60,13 +63,13 @@ def write_profile(describe_to: str, slice_to, slice_s: float = 0.08):
     text and, if asked, the window's first ``slice_s`` seconds as JSON."""
     from perfbench import xplane
 
-    def on_profile(profile, win, spans):
+    def on_profile(profile, win, spans, mark_ns):
         with open(describe_to, "w") as f:
             f.write(xplane.describe(profile))
         if slice_to:
             cut = win.open_ns + int(slice_s * 1e9)
             with open(slice_to, "w") as f:
-                json.dump({"trace": xplane.to_json(profile, slice_s), "open_ns": win.open_ns,
+                json.dump({"trace": xplane.to_json(profile, slice_s), "open_ns": mark_ns,
                            "spans": [s for s in spans if s["t0_ns"] < cut]}, f)
 
     return on_profile

@@ -1,8 +1,18 @@
 """One timeline from submit to verdict, read from the program's spans and the
-profiler's trace.
+profiler's trace: a study tool for the cells of one deployment kind,
+``single_client``.
 
     python3 perfbench/timeline.py trace --workload zipf-1m.paced --seed 5
     python3 perfbench/timeline.py spans --workload zipf-1m.paced --seed 5 --seconds 30
+
+Everything here knows ``SentinelClient``'s spans and its tick program by
+name, which the harness proper (``run.py``, ``xplane.py``) does not: both
+commands refuse a cell of another kind in one line, and a kind that wants
+such a study brings a tool of its own.  What the two share is shared as code:
+the set-up (``run.set_up``) and the attribution of the device's idle seconds
+(``xplane.idle_by`` over the kind's ``host_intervals``), so ``idle_by_span_s``
+here and the result's ``breakdown.idle_gaps`` differ only in how the two
+clocks are tied.
 
 The reductions here read what ``sentinel_tpu`` records while its tracer is on
 (``runtime/client.py``): ``req.queue`` per request, ``tick.drain``,
@@ -22,7 +32,7 @@ scopes reach a device operation's ``op_name`` only while JAX's locations are
 whole and ``run.py`` cuts them to one frame to keep its compile-cache key
 still (the traced program is the same; the Mosaic kernels compile to code
 that reads 1.7 % faster with whole locations, PERF.md section 7).  The window
-and the reductions are still this module's own, beside ``run.py``'s
+and the other reductions are still this module's own, beside ``run.py``'s
 ``phase="trace"`` line (ROADMAP D13).
 ``spans`` is one set-up and two windows of the same seed, the
 first with everything off and the second with the program's spans on and the
@@ -43,8 +53,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
 
+from perfbench import manifest as M  # noqa: E402
 from perfbench import xplane  # noqa: E402
+from perfbench.deployments import single_client  # noqa: E402
 from perfbench.readers import by_tick  # noqa: E402
+
+KIND = "single_client"  # the one deployment kind whose spans this module knows
 
 STEP_MARK = "sentinel.tick"  # the program's StepTraceAnnotation, one per tick
 RING_MARK = "perfbench.ring"  # recorded first after a reset: still there = nothing was lost
@@ -229,7 +243,7 @@ def step_marks(pd) -> Dict[int, float]:
     return out
 
 
-def clock_tie(pd, spans: List[dict], open_mono_ns: int) -> Tie:
+def clock_tie(pd, spans: List[dict], mark_mono_ns: int) -> Tie:
     """The file's clock against ``monotonic_ns``, from one pair per tick: the
     step event opens between the end of the tick's ``tick.drain`` and the start
     of its ``tick.assemble``, two reads of ``monotonic_ns`` a few microseconds
@@ -248,7 +262,7 @@ def clock_tie(pd, spans: List[dict], open_mono_ns: int) -> Tie:
             brackets.append(hi - lo)
     if not offsets:
         w0, _w1 = xplane.window_mark(pd)
-        return Tie(open_mono_ns - int(w0), 0, None, None, None)
+        return Tie(mark_mono_ns - int(w0), 0, None, None, None)
     offsets = np.asarray(offsets)
     mid = float(np.median(offsets))
     away = np.abs(offsets - mid) / 1e3
@@ -261,26 +275,12 @@ def executions(pd) -> Tuple[np.ndarray, np.ndarray]:
     program that lies wholly inside the marked window, over all chips, in
     order: the executions ``xplane.summarize`` counts."""
     w0, w1 = xplane.window_mark(pd)
-    starts, ends = [], []
-    for plane in pd.planes:
-        if not xplane.DEVICE_PLANE.match(plane.name):
-            continue
-        lines = {ln.name: ln for ln in plane.lines}
-        if xplane.MODULES_LINE not in lines:
-            continue
-        names, s, d = xplane._line_arrays(lines[xplane.MODULES_LINE])
-        whole = (s >= w0) & (s + d <= w1)
-        names = np.array([xplane.program(n) for n in names], object)
-        held: Dict[str, float] = {}
-        for n, dur in zip(names[whole], d[whole]):
-            held[n] = held.get(n, 0.0) + dur
-        if not held:
-            continue
-        pick = whole & (names == max(held, key=held.get))
-        starts.extend(s[pick])
-        ends.extend((s + d)[pick])
+    found = [xplane.tick_executions(plane, w0, w1) for plane in pd.planes
+             if xplane.DEVICE_PLANE.match(plane.name)]
+    starts = np.concatenate([s for s, _e in found] + [np.zeros(0)])
+    ends = np.concatenate([e for _s, e in found] + [np.zeros(0)])
     order = np.argsort(starts, kind="stable")
-    return np.asarray(starts, np.float64)[order], np.asarray(ends, np.float64)[order]
+    return starts[order], ends[order]
 
 
 @dataclasses.dataclass
@@ -493,54 +493,14 @@ def device_stages(pd, meta=None) -> dict:
     }
 
 
-def idle_by_span(pd, tie: Tie, spans: List[dict]) -> Dict[str, float]:
-    """The device's idle seconds in the window by what covers them, as
-    ``xplane.summarize`` attributes them but with the per-tick clock tie and
-    the spans that tile the tick thread appended after its five: what was
-    ``host_other`` splits into the part of ``tick.assemble`` that follows the
-    presort (which ``xplane.summarize`` cannot see: the span is recorded with
-    assemble's own duration), ``tick.drain``, ``tick.handoff``, ``tick.hotset``,
-    ``tick.lock``, ``tick.idle`` and a remainder."""
-    names = xplane.HOST_SPANS + ("tick.drain", "tick.handoff", "tick.hotset", "tick.lock",
-                                 "tick.idle")
-    presort = {s["trace"]: s["dur_ns"] for s in spans if s["name"] == "tick.presort"}
-    w0, w1 = xplane.window_mark(pd)
-    e0, e1 = executions(pd)
-    out: Dict[str, float] = {}
-    planes = [p for p in pd.planes if xplane.DEVICE_PLANE.match(p.name)]
-    for plane in planes:
-        lines = {ln.name: ln for ln in plane.lines}
-        _n, s, d = xplane._line_arrays(lines[xplane.OPS_LINE])
-        e = s + d
-        keep = (e > w0) & (s < w1)
-        u0, u1 = xplane.union(np.maximum(s[keep], w0), np.minimum(e[keep], w1))
-        g0, g1 = np.concatenate([[w0], u1]), np.concatenate([u0, [w1]])
-        layers = [("in_program",) + xplane.union(e0, e1)]
-        for name in names:
-            # assemble's interval reaches its tick's dispatch once the presort
-            # inside it is added back (see _tick_thread_cover)
-            more = presort if name == "tick.assemble" else {}
-            iv = [(sp["t0_ns"] - tie.offset_ns,
-                   sp["t0_ns"] + sp["dur_ns"] + more.get(sp["trace"], 0) - tie.offset_ns)
-                  for sp in spans if sp["name"] == name]
-            layers.append((name, np.array([a for a, _ in iv], np.float64),
-                           np.array([b for _, b in iv], np.float64)))
-        c0 = c1 = np.zeros(0)
-        covered = 0.0
-        for name, h0, h1 in layers:
-            c0, c1 = xplane.union(*xplane._sorted(np.concatenate([c0, h0]),
-                                                  np.concatenate([c1, h1])))
-            now = float(xplane._overlap(g0, g1, c0, c1).sum())
-            out[name] = out.get(name, 0.0) + (now - covered) / 1e9
-            covered = now
-        out["host_other"] = out.get("host_other", 0.0) + (float((g1 - g0).sum()) - covered) / 1e9
-    return {n: v / max(len(planes), 1) for n, v in sorted(out.items(), key=lambda kv: -kv[1])}
-
-
-def reduce(pd, win, spans: List[dict], meta=None) -> dict:
+def reduce(pd, win, spans: List[dict], meta=None, chips: int = 1, mark_ns=None) -> dict:
     """Everything above for one traced window, as one printable object.
-    ``meta`` is ``event_metadata()`` of the trace's file."""
-    tie = clock_tie(pd, spans, win.open_ns)
+    ``meta`` is ``event_metadata()`` of the trace's file, ``chips`` the
+    number the cell asks for, ``mark_ns`` the host's clock when the window
+    mark opened (``run._Hooks.mark_ns``; a recorded slice keeps it as
+    ``open_ns``)."""
+    mark_ns = win.open_ns if mark_ns is None else mark_ns
+    tie = clock_tie(pd, spans, mark_ns)
     e0, e1 = executions(pd)
     joined = join(e0, e1, tie.offset_ns, spans)
     unread = ready_unread_ms(joined, spans)
@@ -555,7 +515,7 @@ def reduce(pd, win, spans: List[dict], meta=None) -> dict:
         "clock_tie_points": tie.points,
         "clock_spread_us": {"p50": tie.spread_p50_us, "max": tie.spread_max_us},
         "clock_bracket_p50_us": tie.bracket_p50_us,
-        "clock_offset_minus_window_mark_us": (tie.offset_ns - (win.open_ns - int(w0))) / 1e3,
+        "clock_offset_minus_window_mark_us": (tie.offset_ns - (mark_ns - int(w0))) / 1e3,
         "ticks_dispatched": sum(s["name"] == "tick.dispatch" for s in spans),
         "executions": int(len(e0)),
         "joined": int(len(joined.tick_id)),
@@ -569,7 +529,9 @@ def reduce(pd, win, spans: List[dict], meta=None) -> dict:
         "resolver_came_first_share": float(np.mean(unread == 0.0)) if unread is not None else None,
         "tick_program_seen": TICK_PROGRAM in programs,
         "device_stages": device_stages(pd, meta),
-        "idle_by_span_s": idle_by_span(pd, tie, spans),
+        # the harness's one attribution, under the per-tick tie of the clocks
+        "idle_by_span_s": xplane.idle_by(pd, tie.offset_ns,
+                                         single_client.host_intervals(spans), chips),
         "tick_unnamed_pct": unnamed_share(spans),
         "tick_unnamed_between": unnamed_between(spans),
         "closure": closure(spans, win.latency_ms, win.late_ms),
@@ -591,6 +553,18 @@ def _stat(v) -> Optional[dict]:
 # -- the two commands ----------------------------------------------------------
 
 
+class OtherKind(ValueError):
+    pass
+
+
+def its_cell(workload: str) -> None:
+    """Refuse a cell whose deployment kind records other spans than these."""
+    kind = M.config(M.cell(M.load(), workload)["config"]).get("deployment")
+    if kind != KIND:
+        raise OtherKind(f"timeline.py studies cells of kind {KIND!r}; {workload} is of kind "
+                        f"{kind!r}, whose spans it does not know")
+
+
 def traced(workload: str, seed: int, slice_to: Optional[str] = None,
            slice_s: float = 0.08) -> dict:
     """A traced window of the cell, as ``run.py --trace 1`` takes it (the
@@ -603,6 +577,7 @@ def traced(workload: str, seed: int, slice_to: Optional[str] = None,
     from perfbench import run as R
     from sentinel_tpu import obs
 
+    its_cell(workload)
     # whole locations: stage scopes reach an operation's name only with them
     cell = R.set_up(workload, seed, whole_locations=True)
     dep, generator, params, device = cell.dep, cell.generator, cell.params, cell.device
@@ -612,7 +587,8 @@ def traced(workload: str, seed: int, slice_to: Optional[str] = None,
     opts.python_tracer_level = 0
     jax.profiler.start_trace(R.TRACE_DIR, profiler_options=opts)
     try:
-        win = generator.run(dep, params, seed, params["trace_seconds"], R._Hooks(True))
+        hooks = R._Hooks(True)
+        win = generator.run(dep, params, seed, params["trace_seconds"], hooks)
     finally:
         jax.profiler.stop_trace()
         dep.stop()
@@ -624,9 +600,10 @@ def traced(workload: str, seed: int, slice_to: Optional[str] = None,
         os.makedirs(os.path.dirname(slice_to) or ".", exist_ok=True)
         cut = win.open_ns + int(slice_s * 1e9)
         with open(slice_to, "w") as f:
-            json.dump({"trace": to_json(profile, slice_s, meta), "open_ns": win.open_ns,
+            json.dump({"trace": to_json(profile, slice_s, meta), "open_ns": hooks.mark_ns,
                        "spans": [s for s in spans if s["t0_ns"] < cut]}, f)
-    summary = xplane.summarize(profile, win.open_ns, spans)
+    chips = cell.entry["chips"]
+    summary = xplane.summarize(profile, hooks.mark_ns, single_client.host_intervals(spans), chips)
     shutil.rmtree(R.TRACE_DIR, ignore_errors=True)
     return {
         "phase": "timeline", "workload": workload, "seed": seed,
@@ -635,7 +612,9 @@ def traced(workload: str, seed: int, slice_to: Optional[str] = None,
         "window_s": summary.window_s, "busy_s": summary.busy_s,
         "device_tick_ms": _stat(summary.tick_busy_ms), "kernel_ms": _stat(summary.tick_kernels_ms),
         "spans": len(spans), "span_summary": obs.summarize(spans),
-        **reduce(profile, win, spans, meta),
+        # what run.py prints as breakdown.idle_gaps: the window mark's one tie
+        "idle_gaps": [list(kv) for kv in summary.idle_gaps],
+        **reduce(profile, win, spans, meta, chips, hooks.mark_ns),
     }
 
 
@@ -724,6 +703,7 @@ def spans_run(workload: str, seed: int, seconds: float, *, sizes=None, require_t
     from perfbench import run as R
     from sentinel_tpu import obs
 
+    its_cell(workload)
     cell = R.set_up(workload, seed, sizes=sizes, require_tpu=require_tpu,
                     params_override=params_override)
     dep, generator, params = cell.dep, cell.generator, cell.params
@@ -776,12 +756,16 @@ def main(argv=None) -> int:
     a = ap.parse_args(argv)
     # the program's span ring is sized when sentinel_tpu is first imported
     os.environ.setdefault("SENTINEL_TRACE_CAPACITY", str(1 << 18))
-    if a.cmd == "trace":
-        print(json.dumps(traced(a.workload, a.seed, a.slice_out, a.slice_s)))
-    else:
-        out = spans_run(a.workload, a.seed, a.seconds, untraced_first=not a.spans_only)
-        out.pop("spans", None)
-        print(json.dumps(out))
+    try:
+        if a.cmd == "trace":
+            print(json.dumps(traced(a.workload, a.seed, a.slice_out, a.slice_s)))
+        else:
+            out = spans_run(a.workload, a.seed, a.seconds, untraced_first=not a.spans_only)
+            out.pop("spans", None)
+            print(json.dumps(out))
+    except OtherKind as e:
+        print(f"perfbench: {e}; nothing was run", file=sys.stderr)
+        return 2
     return 0
 
 

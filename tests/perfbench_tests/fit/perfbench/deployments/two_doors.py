@@ -15,9 +15,14 @@ from typing import List, Optional
 
 import numpy as np
 
-from perfbench.deployments import with_sizes
+from perfbench.deployments import intervals, with_sizes
 
 GRANTED, SPENT = 0, 9  # 9: a verdict code that no FlowRule traffic produces
+#: how a traced run of this kind's cells would be read (perfbench/deployments/
+#: __init__.py): a door's answer is its tick, and the host spans that can
+#: explain an idle device are its own two, neither of them a ``tick.*``
+TICK_SPAN = "door.answer"
+HOST_SPANS = ("door.answer", "door.queue")
 _extra_grants = 0  # what ``control()`` loads the doors with beyond the configuration
 
 
@@ -62,6 +67,10 @@ class Deployment:
             if d.is_alive():
                 d.requests.put((None, None))
                 d.join(timeout=5.0)
+
+
+def host_intervals(spans: List[dict]) -> list:
+    return [intervals(spans, n) for n in HOST_SPANS]
 
 
 @contextlib.contextmanager

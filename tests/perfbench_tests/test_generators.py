@@ -112,6 +112,7 @@ def test_block_system_counts_as_failed():
     dep.client.submit_block = broken
     win = open_loop_blocks.run(dep, dict(PACED), 3, 0.5, Hooks())
     assert win.failed == win.attempted > 0 and win.codes == {4: win.codes[4]}
+    assert win.late == 0  # failed, and not merely late
 
 
 def test_closed_loop_keeps_its_blocks_in_flight():
@@ -125,6 +126,52 @@ def test_closed_loop_keeps_its_blocks_in_flight():
     assert 100 < win.attempted < 3 * 0.5 / 0.004 + 10
     assert win.visible_items == win.attempted * dep.batch
     assert dep.client.blocks == dep.client.completions
+
+
+def test_a_block_answered_after_the_clients_timeout_is_late_and_says_so():
+    """One standstill longer than the client's timeout: every block in flight
+    is answered in full, late.  They count as failed and as late, are lost to
+    the rate, and the window says how long it stood and when."""
+    dep = FakeDeployment(delay_s=0.002, entry_timeout_s=0.1)
+    real = dep.client.submit_block
+    state = {"n": 0}
+
+    def standing_still(res, **cols):
+        state["n"] += 1
+        if state["n"] == 60:
+            time.sleep(0.25)  # on the thread that resolves: nothing else is answered meanwhile
+        return real(res, **cols)
+
+    dep.client.submit_block = standing_still
+    params = {"inflight": 1, "max_blocks_per_s": 2000, "preroll_s": 0.05, "postroll_s": 0.05}
+    win = closed_loop_blocks.run(dep, params, 2, 0.6, Hooks())
+    assert win.failed == win.late == 1 and win.unresolved == 0
+    assert win.visible_items == (win.attempted - 1) * dep.batch
+    assert win.extra["longest_reply_gap_s"] > 0.25
+    assert win.extra["worst_latency_ms"] > 250.0
+    assert win.extra["failed_block_system_or_error"] == win.extra["failed_lost"] == 0
+
+
+def test_the_check_holds_a_late_block_against_no_answers_limit_and_a_failed_one_still():
+    import dataclasses
+    import types
+
+    from perfbench.checks import flow_replay
+
+    dep = types.SimpleNamespace(
+        config={"rules": {"flow_qps": 10.0, "tail_qps": 2.0}}, ruled_names=["a", "b"],
+        tail_ids=np.array([5], np.int64))
+    win = closed_loop_blocks.run(
+        FakeDeployment(delay_s=0.002),
+        {"inflight": 2, "max_blocks_per_s": 2000, "preroll_s": 0.05, "postroll_s": 0.05},
+        2, 0.2, Hooks())
+    win = dataclasses.replace(win, passes=np.zeros(8, np.int64), codes={0: 1, 1: 1})
+    late = dataclasses.replace(win, failed=8, late=8)
+    numbers = {n.name: n for n in flow_replay.in_window(dep, late)}
+    assert numbers["window_failed"].value == 0 and all(n.ok for n in numbers.values())
+    one_wrong = dataclasses.replace(win, failed=8, late=7)
+    numbers = {n.name: n for n in flow_replay.in_window(dep, one_wrong)}
+    assert numbers["window_failed"].value == 1 and not numbers["window_failed"].ok
 
 
 def test_pass_counter_adds_up_per_engine_id():
@@ -151,7 +198,7 @@ def test_the_sweep_takes_the_one_set_up_and_stops_what_it_started(monkeypatch, c
     def set_up(workload, seed, **kw):
         asked.append((workload, seed, kw))
         return run.Cell(manifest={}, entry={}, params=dict(PACED, rate_items_per_s=1.0),
-                        generator=open_loop_blocks, check=None, dep=dep, device={},
+                        generator=open_loop_blocks, check=None, kind=None, dep=dep, device={},
                         clock=None, at_setup={}, root="")
 
     monkeypatch.setattr(run, "set_up", set_up)
@@ -162,3 +209,33 @@ def test_the_sweep_takes_the_one_set_up_and_stops_what_it_started(monkeypatch, c
     assert all(r["failed"] == 0 and r["attempted"] > 0 for r in rows)
     # 2 s of pre-roll and 0.3 s of window at each step's own rate
     assert dep.client.blocks == int(2.3 * 100) + int(2.3 * 200)
+
+
+def test_the_sweep_steps_the_parameter_the_traffic_file_names(monkeypatch, capsys):
+    """A mix whose rate is not in items (a later kind's requests a second)
+    names its own key under ``rate_key``; the sweep steps that one."""
+    import json
+    import types
+
+    from perfbench import run, study
+    from perfbench.generators import Window
+
+    offered = []
+
+    def run_mix(dep, params, seed, seconds, hooks):
+        offered.append((params["requests_per_s"], params["rate_items_per_s"]))
+        none = np.zeros(0)
+        return Window(seconds=seconds, open_ns=0, close_ns=1, attempted=3, failed=0,
+                      latency_ms=np.ones(3), due_ns=none, visible_items=3, late_ms=np.ones(3),
+                      passes=none, codes={}, unresolved=0, span_s=seconds)
+
+    params = dict(rate_key="requests_per_s", requests_per_s=1.0, rate_items_per_s=7.0)
+    cell = run.Cell(manifest={}, entry={}, params=params, check=None, kind=None,
+                    generator=types.SimpleNamespace(run=run_mix), dep=FakeDeployment(),
+                    device={}, clock=None, at_setup={}, root="")
+    monkeypatch.setattr(run, "set_up", lambda workload, seed, **kw: cell)
+    study.sweep("any.cell", [100.0, 200.0], 0.1, seed=4)
+    rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    assert offered == [(100.0, 7.0), (200.0, 7.0)]
+    assert [r["requests_per_s"] for r in rows] == [100.0, 200.0]
+    assert cell.dep.stops == 1 and params["requests_per_s"] == 1.0

@@ -27,7 +27,7 @@ def test_contract_keys_and_limits():
     assert len(json.dumps(MANIFEST)) < 64 * 1024
     for w in MANIFEST["workloads"]:
         assert sorted(w) == ["chips", "config", "name", "traffic", "why"]
-        assert w["chips"] == 1
+        assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
     for m in MANIFEST["end_to_end"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
     for m in MANIFEST["per_layer"]:
@@ -44,9 +44,9 @@ def test_every_cell_resolves_to_files_and_reports_enough(cell):
     assert M.config(entry["config"])["name"] == entry["config"]
     params = M.traffic(entry)
     assert os.path.exists(os.path.join(M.ROOT, "perfbench", "generators", params["generator"] + ".py"))
-    if params["generator"] == "open_loop_blocks":
-        # the offered rate is a literal in a data file, never worked out at run time
-        assert isinstance(params["rate_items_per_s"], (int, float))
+    # an offered rate is a literal in a data file, never worked out at run time
+    rate = params.get(params.get("rate_key", "rate_items_per_s"))
+    assert rate is None or type(rate) in (int, float)
     e2e = [m["name"] for m in M.metrics_of(MANIFEST, cell, "end_to_end")]
     assert "setup_s" in e2e and len(e2e) >= 2
     layer = M.metrics_of(MANIFEST, cell, "per_layer")
@@ -83,9 +83,40 @@ def _broken(edit):
     (lambda m: m["end_to_end"].pop(), "setup_s"),
     (lambda m: m["workloads"].append(dict(m["workloads"][0], name="again")), "pair appears twice"),
     (lambda m: m["configs"][0].update(source="elsewhere"), "source differs"),
+    (lambda m: m["workloads"][0].update(chips=2), "chips 2"),
 ])
 def test_faults_are_named(edit, word):
     assert any(word in p for p in _broken(edit)), _broken(edit)
+
+
+def _with_cells(m, keep, four):
+    """The manifest cut to its first ``keep`` cells, the first ``four`` of
+    them asking for four chips, and still sound otherwise."""
+    m["workloads"] = m["workloads"][:keep]
+    for w in m["workloads"][:four]:
+        w["chips"] = 4
+    cells = {w["name"] for w in m["workloads"]}
+    m["configs"] = [c for c in m["configs"] if any(w["config"] == c["name"] for w in m["workloads"])]
+    for group in ("end_to_end", "per_layer"):
+        for e in m[group]:
+            if "workloads" in e:
+                e["workloads"] = [w for w in e["workloads"] if w in cells]
+        m[group] = [e for e in m[group] if e.get("workloads", True)]
+
+
+@pytest.mark.parametrize("keep, four, most", [
+    (1, 1, None),  # one cell always may
+    (4, 2, None),  # half, rounded down
+    (3, 1, None),
+    (4, 3, 2),
+    (3, 2, 1),
+])
+def test_at_most_half_the_cells_may_ask_for_four_chips(keep, four, most):
+    """The driver's rule, which ``problems()`` meets first: refused in one
+    line that names the cells, or not at all."""
+    names = ", ".join(w["name"] for w in MANIFEST["workloads"][:four])
+    line = f"{four} of {keep} cells ask for four chips ({names}); at most {most} may"
+    assert _broken(lambda m: _with_cells(m, keep, four)) == ([line] if most else [])
 
 
 def _edit_file(root, rel, edit):

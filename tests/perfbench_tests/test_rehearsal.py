@@ -1,8 +1,12 @@
-"""Every generator rehearsed on the CPU at a tiny size, through the same
-``run_cell`` the command calls: sizes come in as a function argument, the
-command has no flag for them.  Also the two runs that must come out as not
-correct: a guarantee broken in the deployment (the control), and an answer
-altered where the client hands it over.
+"""Every cell of the committed manifest rehearsed on the CPU at a tiny size,
+through the same ``run_cell`` the command calls: sizes come in as a function
+argument, the command has no flag for them.  The sizes and the short
+parameters are data, found by the names the cell's entry gives
+(``tests/perfbench_tests/rehearsal/``), so a later PR's cell is rehearsed by
+adding files.  Also the two runs of the ``single_client`` kind that must come
+out as not correct: a guarantee broken in the deployment (the control), and
+an answer altered where the client hands it over; another kind brings its
+own, in a file of its own.
 
 All in one file, so that one worker pays the engine compiles."""
 
@@ -15,41 +19,18 @@ import pytest
 from perfbench import manifest as M
 from perfbench import run
 from perfbench.deployments import single_client as deployment
+from tests.perfbench_tests import rehearsal
 
 pytestmark = pytest.mark.jitted
 
-ENGINE = dict(max_resources=112, max_nodes=120, max_flow_rules=112, max_degrade_rules=112,
-              max_param_rules=8, batch_size=512, complete_batch_size=512)
-SIZES = {
-    "zipf-1m": {
-        "engine": ENGINE,
-        "resources": dict(n_ruled=48, id_universe=4095, n_tail_ruled=16),
-        "rules": dict(flow_qps=100.0, tail_qps=2.0, n_param_ruled=8, n_authority_ruled=4),
-        "traffic": dict(pool_batches=4),
-        "client": dict(entry_timeout_s=30.0),
-    },
-    "zipf-10k": {
-        "engine": ENGINE,
-        "resources": dict(n_ruled=48, id_universe=48),
-        "rules": dict(flow_qps=100.0),
-        "traffic": dict(pool_batches=4),
-        "client": dict(entry_timeout_s=30.0),
-    },
-}
-SHORT = {"prime_seconds": 0.3, "preroll_s": 0.5, "postroll_s": 0.2}
-PARAMS = {
-    "flood-128k": dict(SHORT, max_blocks_per_s=4000, replay={"ticks": 12, "step_ms": 60, "blocks_per_tick": [1]}),
-    "paced-4k": dict(SHORT, block_items=64, rate_items_per_s=12800,
-                     replay={"ticks": 40, "step_ms": 25, "blocks_per_tick": [1, 3, 2, 6]}),
-    "entry-8t": dict(SHORT, replay={"ticks": 100, "step_ms": 5}),
-}
 CELLS = [w["name"] for w in M.load()["workloads"]]
+REAL_RUN_CELL = run.run_cell
 
 
 def rehearse(cell, seed=2**31 + 17, seconds=1.5):
-    entry = M.cell(M.load(), cell)
-    return run.run_cell(cell, seed, seconds, False, sizes=SIZES[entry["config"]],
-                        require_tpu=False, params_override=PARAMS[entry["traffic"]])
+    sizes, params, _names = rehearsal.of(cell)
+    return run.run_cell(cell, seed, seconds, False, sizes=sizes, require_tpu=False,
+                        params_override=params)
 
 
 def printed(capsys):
@@ -57,7 +38,7 @@ def printed(capsys):
 
 
 def compared(capsys, lines=None):
-    return {l["compared"]: l for l in (lines or printed(capsys)) if "compared" in l}
+    return {l["compared"]: l for l in (lines or printed(capsys)) if "rule" in l}
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -66,7 +47,13 @@ def test_cell_rehearses_correct_with_the_result_lines_shape(cell, capsys):
     lines = printed(capsys)
     numbers = compared(capsys, lines)
     assert result["correct"] is True, numbers
-    assert sorted(result) == ["attempted", "correct", "device", "failed", "metrics"]
+    # the keys the driver reads, then what stood beside a window that lost
+    # requests (empty in a sound run), and last every number compared
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device", "beside", "compared"]
+    assert result["beside"] == {}
+    assert {k: (v["value"], v["ok"]) for k, v in result["compared"].items()} == {
+        k: (v["value"], v["ok"]) for k, v in numbers.items()}
+    assert all(("at least" in v) != ("at most" in v) for v in result["compared"].values())
     assert result["attempted"] > 0 and result["failed"] == 0
     assert result["device"]["platform"] == "cpu" and result["device"]["count"] >= 1
     assert {"kind", "memory_peak_bytes"} <= set(result["device"])
@@ -79,11 +66,18 @@ def test_cell_rehearses_correct_with_the_result_lines_shape(cell, capsys):
     assert len(window["memory_peak_bytes_per_chip"]) == result["device"]["count"]
     assert max(window["memory_peak_bytes_per_chip"]) == result["device"]["memory_peak_bytes"]
     assert [l["phase"] for l in lines if "phase" in l] == ["setup", "window", "replay"]
-    # every number compared is printed beside its limit, and the replay bit
-    assert numbers["replay_pass_count_mismatches"]["limit"] == 0
-    assert numbers["replay_blocked_items"]["value"] >= 1
-    assert numbers["replay_pairs_compared"]["value"] >= 1
+    # every number compared is printed beside its limit, and none fails
+    assert numbers and all({"value", "limit", "rule", "ok"} <= set(n) for n in numbers.values())
     assert all(n["ok"] for n in numbers.values())
+    # and the check cannot have passed with nothing compared: whatever its
+    # kind, something was held equal to the plain reference, and something
+    # that counts what was compared reached 1
+    names = rehearsal.of(cell)[2]
+    assert names["equal_to_the_reference"] and names["at_least_one"]
+    for name in names["equal_to_the_reference"]:
+        assert numbers[name]["limit"] == 0 and numbers[name]["rule"] == "at most"
+    for name in names["at_least_one"]:
+        assert numbers[name]["rule"] == "at least" and numbers[name]["value"] >= 1
 
 
 @pytest.mark.parametrize("cell", ["zipf-1m.paced", "zipf-10k.entry"])
@@ -124,6 +118,52 @@ def test_an_answer_altered_where_it_is_handed_over_is_not_correct(capsys, monkey
     numbers = compared(capsys)
     assert result["correct"] is False
     assert numbers["replay_pass_count_mismatches"]["value"] >= 1
+
+
+def test_a_block_answered_late_is_counted_and_printed_and_the_run_stays_correct(capsys, monkeypatch):
+    """The rest of a run driven over a standstill longer than the client's
+    timeout: the block it held is answered in full, late.  The result counts
+    it under ``failed``, says beside the window what was seen, and is correct,
+    since no answer was wrong; ``main`` prints the same as the last lines of
+    standard error."""
+    import time
+
+    from sentinel_tpu.runtime.client import SentinelClient
+
+    real, opened = SentinelClient.submit_block, run._Hooks.opened
+    state = {"open": None, "held": False}
+
+    def noting(self):
+        opened(self)
+        state["open"] = time.monotonic()
+
+    def holding(self, res, **cols):
+        if state["open"] and not state["held"] and time.monotonic() - state["open"] > 0.3:
+            state["held"] = True
+            time.sleep(1.5)
+        return real(self, res, **cols)
+
+    monkeypatch.setattr(run._Hooks, "opened", noting)
+    monkeypatch.setattr(SentinelClient, "submit_block", holding)
+    sizes, params, _names = rehearsal.of("zipf-1m.flood")
+    sizes = dict(sizes, client=dict(sizes["client"], entry_timeout_s=1.0))
+    monkeypatch.setattr(run, "run_cell", lambda *a, **kw: REAL_RUN_CELL(
+        "zipf-1m.flood", 2**31 + 19, 3.0, False, sizes=sizes, require_tpu=False, params_override=params))
+    assert run.main(["--workload", "zipf-1m.flood", "--seed", "1", "--seconds", "3", "--trace", "0"]) == 0
+    out = capsys.readouterr()
+    result = json.loads(out.out.splitlines()[-1])
+    # the held callback holds its resolver, and with it as many of the eight
+    # blocks in flight as the tick thread was waiting to hand over
+    assert result["correct"] is True and 1 <= result["failed"] <= 8
+    assert list(result)[-2:] == ["beside", "compared"]
+    beside = result["beside"]
+    assert beside["answered_late"] == result["failed"] and beside["failed_block_system_or_error"] == 0
+    assert beside["worst_latency_ms"] > 1500.0 and isinstance(beside["journal"], list)
+    assert result["compared"]["window_failed"] == {"value": 0, "at most": 0, "ok": True}
+    err = out.err.splitlines()
+    assert err[-len(result["compared"]) - 1].startswith("beside the window: {")
+    assert [l.split(":")[0] for l in err[-len(result["compared"]):]] == [
+        f"compared {name}" for name in result["compared"]]
 
 
 def test_without_a_tpu_the_command_prints_no_result(capsys):

@@ -11,6 +11,7 @@ import pytest
 
 from perfbench import manifest as M
 from perfbench import timeline, xplane
+from perfbench.deployments import single_client
 from perfbench.readers import Context, span_each, span_stat, span_unnamed
 
 US = 1_000
@@ -262,7 +263,7 @@ def traced_ticks(n=4, jitter_us=(0, 2, -2, 0)):
 
 def test_clock_tie_takes_the_median_over_the_ticks_and_reports_the_spread():
     spans, pd = traced_ticks()
-    tie = timeline.clock_tie(pd, spans, open_mono_ns=123)
+    tie = timeline.clock_tie(pd, spans, mark_mono_ns=123)
     # drain ends and assemble starts at the same instant here, t + 100
     assert tie.points == 4 and tie.offset_ns == OFFSET_US * US
     assert tie.spread_max_us == pytest.approx(2.0) and tie.spread_p50_us == pytest.approx(1.0)
@@ -272,7 +273,7 @@ def test_clock_tie_takes_the_median_over_the_ticks_and_reports_the_spread():
 def test_without_step_events_the_window_mark_ties_the_clocks():
     spans, pd = traced_ticks()
     pd.planes[0].lines.pop()
-    tie = timeline.clock_tie(pd, spans, open_mono_ns=5 * MS)
+    tie = timeline.clock_tie(pd, spans, mark_mono_ns=5 * MS)
     assert tie.points == 0 and tie.offset_ns == 5 * MS and tie.spread_p50_us is None
 
 
@@ -351,25 +352,33 @@ def test_a_slice_keeps_what_this_module_reads():
 
 
 def test_the_old_slice_reduces_to_what_it_did():
-    """``perfbench/xplane.py`` is untouched by the tracing PR: the recorded
-    slice of PR 23 still gives the numbers written down when it was taken."""
+    """The recorded slice of PR 23 still gives the device numbers written down
+    when it was taken.  Its idle seconds are attributed as ``idle_by_span_s``
+    always was (PR 27): assemble's interval reaches its tick's dispatch, so
+    0.0076 of the 0.0098 s that read ``host_other`` are the uploads after the
+    presort, and the presort keeps what it had."""
     with open(os.path.join(DATA, "trace_slice.json")) as f:
         rec = json.load(f)
-    s = xplane.summarize(xplane.from_json(rec["trace"]), rec["open_ns"], rec["spans"])
+    s = xplane.summarize(xplane.from_json(rec["trace"]), rec["open_ns"],
+                         single_client.host_intervals(rec["spans"]))
     assert s.window_s == 0.08 and s.busy_s == 0.025188909
     assert s.tick_busy_ms.tolist() == [8.396657, 8.396199, 8.396053]
     assert s.tick_kernels_ms.tolist() == [1.815539, 1.815542, 1.815545]
     assert s.clock_offset_ns == 1370953155124
     assert s.idle_gaps == [
-        ("tick.presort", 0.035699949), ("host_other", 0.009750543),
-        ("tick.assemble", 0.005189998), ("tick.resolve", 0.002239379),
+        ("tick.presort", 0.035699949), ("tick.assemble", 0.012803766),
+        ("tick.resolve", 0.002239379), ("host_other", 0.002136775),
         ("tick.readback", 0.001886839), ("in_program", 4.4383e-05)]
+    assert 0.012803766 + 0.002136775 == pytest.approx(0.005189998 + 0.009750543)
     assert [n for n, _ in s.device_ops[:3]] == [
         "branch_1_fun.32__mosaic", "sort.217", "branch_1_fun.30__mosaic"]
     # the old slice holds no step event: the one tie point is the window mark's
     pd = timeline.from_json(rec["trace"])
     tie = timeline.clock_tie(pd, rec["spans"], rec["open_ns"])
     assert tie.points == 0 and tie.offset_ns == s.clock_offset_ns
+    # and under one tie the two callers of the one attribution read the same
+    by_span = xplane.idle_by(pd, tie.offset_ns, single_client.host_intervals(rec["spans"]))
+    assert {n: v for n, v in by_span.items() if v > 0} == dict(s.idle_gaps)
 
 
 @pytest.fixture(scope="module")
@@ -404,6 +413,14 @@ def test_the_recorded_slice_ties_joins_and_names(recorded):
     names = {xplane.program(ev.name) for ev in pd.planes[1].lines[0].events
              } | {xplane.program(ev.name) for ev in pd.planes[1].lines[1].events}
     assert timeline.TICK_PROGRAM in names
+    # breakdown.idle_gaps and idle_by_span_s are one function under two ties of
+    # the clocks: the same names, the same seconds within 1 % of the window
+    host = single_client.host_intervals(spans)
+    gaps = dict(xplane.summarize(pd, recorded["open_ns"], host).idle_gaps)
+    by_span = xplane.idle_by(pd, tie.offset_ns, host)
+    assert set(gaps) == {n for n, v in by_span.items() if v > 0} > {"tick.idle", "tick.drain"}
+    assert all(abs(by_span[n] - v) < 0.01 * 0.02 for n, v in gaps.items())
+    assert max(gaps, key=gaps.get) == "tick.idle"  # an entry cell: was host_other
 
 
 def test_the_manifest_is_sound_with_the_new_metrics():
@@ -414,8 +431,42 @@ def test_the_manifest_is_sound_with_the_new_metrics():
         assert name in per_layer and per_layer[name]["source"] == "program_span"
         assert os.path.exists(os.path.join(M.ROOT, M.HERE, "readers",
                                            M.metric(name)["reader"] + ".py"))
-    # appended after what was there, which is as it was
-    assert [m["name"] for m in manifest["per_layer"]][-len(NEW_METRICS):] == list(NEW_METRICS)
+    # in the order they were appended in; what a later PR appends comes after
+    assert [n for n in per_layer if n in NEW_METRICS] == list(NEW_METRICS)
+
+
+def test_the_window_mark_carries_the_hosts_clock_at_its_opening():
+    """The mark opens a wake-up and a few statements after the window was due
+    to open: the tie is read beside the mark, not taken from the schedule."""
+    import time
+
+    from perfbench import run
+    from sentinel_tpu import obs
+
+    hooks = run._Hooks(True)
+    before = time.monotonic_ns()
+    hooks.opened()
+    try:
+        assert before <= hooks.mark_ns <= time.monotonic_ns() and obs.enabled()
+    finally:
+        hooks.closed()
+    assert not obs.enabled()
+    plain = run._Hooks(False)
+    plain.opened()
+    plain.closed()
+    assert plain.mark_ns is None and plain.setup_s > 0 and not obs.enabled()
+
+
+@pytest.mark.parametrize("cmd", ["trace", "spans"])
+def test_a_cell_of_another_kind_is_refused_in_one_line(cmd, monkeypatch, capsys):
+    """This module knows one kind's spans by name; the harness proper knows
+    none, and takes a kind's from its module."""
+    monkeypatch.setattr(timeline.M, "config", lambda name: {"deployment": "token_mesh"})
+    cell = M.load()["workloads"][0]["name"]
+    assert timeline.main([cmd, "--workload", cell, "--seed", "1"]) == 2
+    out = capsys.readouterr()
+    assert out.out == "" and out.err.count("\n") == 1
+    assert f"cells of kind 'single_client'; {cell} is of kind 'token_mesh'" in out.err
 
 
 # -- the served path rehearsed on the CPU with the spans on ----------------------
@@ -440,12 +491,12 @@ def big_ring(monkeypatch):
 def test_rehearsed_with_spans_on_the_tick_thread_is_tiled_and_the_ids_join(cell, big_ring):
     """The real threaded client under the cell's own generator, at the tiny
     size of ``test_rehearsal.py``, spans on and profiler off."""
-    from tests.perfbench_tests.test_rehearsal import PARAMS, SIZES
+    from tests.perfbench_tests import rehearsal
 
     entry = M.cell(M.load(), cell)
-    out = timeline.spans_run(cell, 2**31 + 17, 1.5, sizes=SIZES[entry["config"]],
-                             require_tpu=False, params_override=PARAMS[entry["traffic"]],
-                             untraced_first=False)
+    sizes, params, _names = rehearsal.of(cell)
+    out = timeline.spans_run(cell, 2**31 + 17, 1.5, sizes=sizes, require_tpu=False,
+                             params_override=params, untraced_first=False)
     spans, on = out["spans"], out["spans_on"]
     assert on["failed"] == 0 and on["attempted"] > 0
     assert on["ring_capacity"] == big_ring.capacity and on["ring_wrapped"] is False

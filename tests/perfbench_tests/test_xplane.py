@@ -56,11 +56,16 @@ def test_union_and_overlap():
     assert got.tolist() == [10., 20., 0.]
 
 
+def host(*named):
+    """``(name, start_us, dur_us)`` in the spans' clock, 7 ms ahead of the
+    file's, as ``deployments.host_intervals`` hands them over: in the order
+    they are to be asked."""
+    return [(n, np.array([7_000_000.0 + t0 * US]), np.array([7_000_000.0 + (t0 + dur) * US]))
+            for n, t0, dur in named]
+
+
 def test_hand_made_trace():
-    spans = [
-        {"name": "tick.presort", "t0_ns": 7_000_000 + 300 * US, "dur_ns": 200 * US},
-        {"name": "tick.assemble", "t0_ns": 7_000_000 + 250 * US, "dur_ns": 300 * US},
-    ]
+    spans = host(("tick.presort", 300, 200), ("tick.assemble", 250, 300))
     s = xplane.summarize(trace(OPS, MODULES), 7_000_000, spans)
     assert s.window_s == pytest.approx(1e-3)
     assert s.clock_offset_ns == 7_000_000
@@ -81,19 +86,35 @@ def test_hand_made_trace():
     assert gaps["tick.assemble"] == pytest.approx(100e-6)
     assert gaps["host_other"] == pytest.approx((90 + 20 + 40 + 190 + 99) * 1e-6)
     assert sum(gaps.values()) + s.busy_s == pytest.approx(s.window_s, rel=1e-3)
+    # the attribution behind it is the one function, under whatever offset
+    assert xplane.idle_by(trace(OPS, MODULES), 7_000_000, spans) == pytest.approx(gaps)
+    # the order given is the order asked: assemble first leaves the presort nothing
+    swapped = dict(xplane.summarize(trace(OPS, MODULES), 7_000_000, spans[::-1]).idle_gaps)
+    assert swapped["tick.assemble"] == pytest.approx(300e-6) and "tick.presort" not in swapped
+
+
+def test_a_kind_that_names_no_span_reads_in_program_and_host_other():
+    s = xplane.summarize(trace(OPS, MODULES), 7_000_000)
+    assert dict(s.idle_gaps) == {"in_program": pytest.approx(70e-6),
+                                 "host_other": pytest.approx((1000 - 191 - 70) * 1e-6)}
+    # and so does one whose spans are not the first kind's
+    doors = host(("door.answer", 230, 360))
+    assert dict(xplane.summarize(trace(OPS, MODULES), 7_000_000, doors).idle_gaps) == {
+        "in_program": pytest.approx(70e-6), "door.answer": pytest.approx(360e-6),
+        "host_other": pytest.approx((1000 - 191 - 70 - 360) * 1e-6)}
 
 
 def test_each_chips_own_busy_seconds_stand_beside_their_mean():
-    """The result's ``busy_s`` stays the mean over the device planes; beside
-    it the harness prints each plane's own.  (A chip on which nothing ran has
-    no plane: the harness prints how many of JAX's devices have none.)"""
+    """The result's ``busy_s`` is the mean over the chips the cell asks for;
+    beside it the harness prints each plane's own.  (A chip on which nothing
+    ran has no plane, and counts as idle: the next test.)"""
     one = xplane.summarize(trace(OPS, MODULES), 0, [])
     assert one.chip_busy_s == {"/device:TPU:0": pytest.approx(191e-6)}
     assert one.busy_s == one.chip_busy_s["/device:TPU:0"]
     two = trace(OPS, MODULES)
     two.planes.append(trace(OPS[:3], MODULES[:1]).planes[1])
     two.planes[-1].name = "/device:TPU:1"
-    s = xplane.summarize(two, 0, [])
+    s = xplane.summarize(two, 0, [], chips=2)
     assert s.chip_busy_s == {"/device:TPU:0": pytest.approx(191e-6),
                              "/device:TPU:1": pytest.approx(90e-6)}
     assert s.busy_s == pytest.approx((191e-6 + 90e-6) / 2)
@@ -156,7 +177,10 @@ def test_recorded_slice_against_a_plain_loop(recorded):
     ops = [e for e in lines["XLA Ops"] if e["start_ns"] + e["duration_ns"] > w0 and e["start_ns"] < w1]
     assert len(ops) > 1000
 
-    s = xplane.summarize(xplane.from_json(data), recorded["open_ns"], recorded["spans"])
+    from perfbench.deployments import single_client
+
+    s = xplane.summarize(xplane.from_json(data), recorded["open_ns"],
+                         single_client.host_intervals(recorded["spans"]))
     assert s.window_s == pytest.approx(0.08)
     assert s.busy_s * 1e9 == pytest.approx(_loop_busy(ops, w0, w1), rel=1e-9)
     assert 0 < s.busy_s < s.window_s
@@ -180,3 +204,26 @@ def test_recorded_slice_against_a_plain_loop(recorded):
     # what is busy and what is idle make up the window
     assert s.busy_s + sum(v for _n, v in s.idle_gaps) == pytest.approx(s.window_s, rel=0.01)
     assert all("__mosaic" in n or "tpu_custom_call" not in n for n, _v in s.device_ops)
+
+
+def test_a_chip_without_a_device_plane_counts_as_idle(recorded):
+    """A chip on which nothing ran has no plane in the trace (PERF.md, PR
+    26), so the mean is taken over the chips the cell asks for, not over the
+    planes there are: the recorded chip three times over and a fourth that is
+    missing read three quarters of its busy seconds for a four-chip cell, and
+    the recorded trace as it is reads the one plane's own for a one-chip cell."""
+    one = xplane.summarize(xplane.from_json(recorded["trace"]), recorded["open_ns"])
+    own = one.chip_busy_s["/device:TPU:0"]
+    assert one.busy_s == own == 0.025188909
+    data = dict(recorded["trace"], planes=list(recorded["trace"]["planes"]))
+    data["planes"] += [dict(data["planes"][1], name=f"/device:TPU:{i}") for i in (1, 2)]
+    four = xplane.summarize(xplane.from_json(data), recorded["open_ns"], chips=4)
+    assert four.chip_busy_s == {f"/device:TPU:{i}": own for i in range(3)}
+    assert four.busy_s == pytest.approx(3 * own / 4)
+    assert len(four.tick_busy_ms) == 3 * len(one.tick_busy_ms)
+    # its idle seconds count the missing chip's whole window, under the same names
+    idle, was = dict(four.idle_gaps), dict(one.idle_gaps)
+    assert four.busy_s + sum(idle.values()) == pytest.approx(four.window_s, rel=1e-9)
+    assert idle["in_program"] == pytest.approx(3 * was["in_program"] / 4)
+    assert idle["host_other"] == pytest.approx((3 * was["host_other"] + four.window_s) / 4)
+    assert dict(four.device_ops) == pytest.approx({n: 3 * v / 4 for n, v in one.device_ops})
