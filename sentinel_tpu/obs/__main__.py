@@ -59,8 +59,8 @@ from sentinel_tpu.obs import trace as OT
 
 #: the six pipelined tick stages every capture should surface.  tick.device
 #: is NOT device time: it runs from dispatch end to verdicts host-visible,
-#: exactly tick.resident + tick.wait (pipeline residency and the resolver
-#: pool's queue included); device time comes from a profiler trace only.
+#: exactly tick.resident + tick.wait (the resolver pool's queue and the
+#: resolver's blocking readback); device time comes from a profiler trace only.
 TICK_STAGES = (
     "tick.assemble",
     "tick.presort",

@@ -424,25 +424,31 @@ def event(name: str, trace: int = 0, attrs: Optional[dict] = None) -> None:
 # -- summaries ---------------------------------------------------------------
 
 
+#: string attrs that summarize() counts by value: which way a span went
+_COUNTED_ATTRS = ("path", "why")
+
+
 def summarize(spans: Iterable[dict], prefix: Optional[str] = None) -> Dict[str, dict]:
     """Per-name duration stats over snapshot()/chrome-trace spans:
     ``{name: {count, p50_ms, p99_ms, mean_ms, total_ms}}``; a name whose
-    spans carry a ``path`` attr (``tick.presort``) also gets ``path``:
-    how many spans took each."""
+    spans carry a ``path`` attr (``tick.presort``) or a ``why`` attr
+    (``tick.idle``) also gets that key: how many spans took each value."""
     import numpy as np
 
     by_name: Dict[str, List[float]] = {}
-    paths: Dict[str, Dict[str, int]] = {}
+    counted: Dict[str, Dict[str, Dict[str, int]]] = {}
     for s in spans:
         name = s["name"]
         if prefix is not None and not name.startswith(prefix):
             continue
         dur_ns = s["dur_ns"] if "dur_ns" in s else s.get("dur", 0.0) * 1000.0
         by_name.setdefault(name, []).append(dur_ns / 1e6)
-        path = (s.get("attrs") or {}).get("path")
-        if path is not None:
-            taken = paths.setdefault(name, {})
-            taken[path] = taken.get(path, 0) + 1
+        attrs = s.get("attrs") or {}
+        for key in _COUNTED_ATTRS:
+            value = attrs.get(key)
+            if value is not None:
+                taken = counted.setdefault(name, {}).setdefault(key, {})
+                taken[value] = taken.get(value, 0) + 1
     out: Dict[str, dict] = {}
     for name in sorted(by_name):
         a = np.asarray(by_name[name], np.float64)
@@ -452,9 +458,8 @@ def summarize(spans: Iterable[dict], prefix: Optional[str] = None) -> Dict[str, 
             "p99_ms": round(float(np.percentile(a, 99)), 4),
             "mean_ms": round(float(a.mean()), 4),
             "total_ms": round(float(a.sum()), 4),
+            **counted.get(name, {}),
         }
-        if name in paths:
-            out[name]["path"] = paths[name]
     return out
 
 

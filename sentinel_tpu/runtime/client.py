@@ -38,7 +38,6 @@ from __future__ import annotations
 import threading
 import time as _time
 from concurrent.futures import Future
-from concurrent.futures import TimeoutError as _FutTimeout
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -99,6 +98,7 @@ _H_RESOLVE = OBS.histogram(
 # tick.idle attrs: shared, so an idle span allocates nothing
 _IDLE_INTERVAL = {"why": "interval"}
 _IDLE_RESOLVERS = {"why": "resolvers"}
+_IDLE_DEPTH = {"why": "depth"}
 _G_OCCUPANCY = OBS.gauge(
     "sentinel_pipeline_occupancy", "dispatched-but-unresolved engine ticks"
 )
@@ -359,10 +359,12 @@ class ArrayBlock:
 class _PendingTick:
     """A dispatched engine tick whose outputs haven't been read back.
 
-    The tick loop resolves these up to ``pipeline_depth`` ticks behind
-    dispatch, so the device→host verdict transfer of tick t overlaps the
-    host build + device compute of tick t+1; depth 0 reads each tick
-    back before the next one is built."""
+    With ``pipeline_depth`` > 0 the tick loop hands each of these to the
+    resolver pool at dispatch, so the device→host verdict transfer of
+    tick t overlaps the host build + device compute of tick t+1, and
+    dispatches no tick that would leave more than ``pipeline_depth``
+    unresolved; depth 0 reads each tick back before the next one is
+    built."""
 
     acq: List[AcquireRequest]
     blocks: list  # [(ArrayBlock, src_off, take), ...] at batch offset n
@@ -392,6 +394,10 @@ class _PendingTick:
     state: str = "pending"  # pending | done | failed
     state_lock: threading.Lock = field(default_factory=threading.Lock)
     deadline_mono: float = 0.0  # mono_s() stall deadline (0 = unwatched)
+    #: set once the tick loop need not wait for this tick any more: its
+    #: resolver returned, the watchdog failed it over while the resolver
+    #: is still wedged, or a bounded wait gave it up (_await_resolved)
+    settled: threading.Event = field(default_factory=threading.Event)
 
 
 class Entry:
@@ -679,20 +685,19 @@ class SentinelClient:
         # bulk column-array submissions (ArrayBlock) + bulk completions
         self._acq_blocks: List[ArrayBlock] = []
         self._comp_blocks: List[tuple] = []
-        # dispatched-but-unread ticks; under sustained load the loop runs
-        # up to pipeline_depth ticks ahead of verdict readback so the
-        # device→host transfer overlaps compute (it always drains to empty
-        # before going idle, so latency at low rate is unchanged).  A small
-        # resolver pool fetches concurrently — transfers overlap each
-        # other AND the next tick's host build (host↔device transfer
-        # latency pipelines)
+        # dispatched-but-unresolved ticks: each is handed to the resolver
+        # pool at dispatch, so the device→host transfer overlaps the next
+        # tick's host build and compute, and the loop dispatches none that
+        # would leave more than pipeline_depth unresolved (it always drains
+        # to empty before going idle).  A small resolver pool fetches
+        # concurrently — transfers overlap each other AND the next tick's
+        # host build (host↔device transfer latency pipelines)
         self._pipeline_depth = max(0, int(pipeline_depth))
         self._pending_ticks: List[_PendingTick] = []
         # obs: top of the first drain that found nothing (0 = the tick thread
         # is not idle): the drain that next finds work records one tick.idle
         self._idle_since = 0
         self._resolver_pool = None  # created lazily (see _pool)
-        self._resolve_futs: List[Future] = []
         # serializes whole tick iterations: sync-mode clients call
         # tick_once from arbitrary request threads, and the pending-tick
         # bookkeeping above must not interleave.  Reentrant for SYNC-mode
@@ -728,9 +733,9 @@ class SentinelClient:
         self._stage_parity = 0
         # the presort's inverse permutation outlives its tick (the
         # _PendingTick holds it until its verdicts are unsorted, and the
-        # number of unresolved ticks has no bound a ring could be sized
-        # from), so it is lent from a free list keyed by batch shape: the
-        # resolver returns it, and steady serving allocates none
+        # cap on unresolved ticks can change on a live client, so no ring
+        # is sized from it): it is lent from a free list keyed by batch
+        # shape, the resolver returns it, and steady serving allocates none
         self._inv_free: Dict[int, List[np.ndarray]] = {}
         # packed-wire offset tables keyed by (cfg, batch shape)
         self._wire_layouts: Dict[tuple, Any] = {}
@@ -1116,7 +1121,7 @@ class SentinelClient:
             now_ms,
             qd,
             len(self._pending_ticks),
-            len(self._resolve_futs),
+            len(self._pending_ticks),
             load,
             cpu,
         )
@@ -1188,6 +1193,7 @@ class SentinelClient:
             )
             self._fail_tick(p)
             self._untrack_tick(p)
+            p.settled.set()  # the tick loop need not wait for the wedged resolver
 
     @staticmethod
     def _claim_tick(p: _PendingTick, state: str) -> bool:
@@ -2421,10 +2427,11 @@ class SentinelClient:
     def tick_once(self, now_ms: Optional[int] = None) -> None:
         """Drain queues and run engine ticks until empty.
 
-        Under sustained load, verdict readback runs up to pipeline_depth
-        ticks behind dispatch (see _PendingTick); the loop always resolves
-        everything before returning idle.  Whole iterations serialize on
-        _tick_mutex — sync-mode clients call this from request threads."""
+        Each tick is handed to a resolver at dispatch and at most
+        pipeline_depth are unresolved at a time (see _PendingTick); the
+        loop always resolves everything before returning idle.  Whole
+        iterations serialize on _tick_mutex — sync-mode clients call this
+        from request threads."""
         _t_lock = OT.t0()
         with self._tick_mutex:
             self._tick_once_locked(now_ms, _t_lock)  # stlint: disable=blocking-under-lock — the tick IS the device dispatch: _tick_mutex exists to serialize exactly this work; readbacks ride the resolver pool, not this lock
@@ -2617,6 +2624,15 @@ class SentinelClient:
                 self._drain_resolves()
                 return
             self._idle_since = 0
+            if 0 < self._pipeline_depth <= len(self._pending_ticks):
+                # pipeline_depth caps the dispatched-but-unresolved ticks:
+                # if this one would pass it, wait for the oldest first.
+                # Back-pressure on a host that dispatches faster than
+                # verdicts fan out, never a delay of a finished verdict
+                self._reap_resolved()
+                over = len(self._pending_ticks) - self._pipeline_depth
+                if over >= 0:
+                    self._await_resolved(over + 1, _IDLE_DEPTH)
             # while tracing: one host event per tick on the profiler's own
             # clock beside the spans on monotonic_ns, a tie point per tick and
             # the step number that joins a device execution to its tick id
@@ -2629,11 +2645,17 @@ class SentinelClient:
                     acq, comp if n_comp else None, now_ms, fronts=fronts,
                     blocks=blocks, tick_id=tick_id,
                 )
-            self._pending_ticks.append(pending)
-            # unconditional: the gauges are on the always-on /metrics
-            # surface (one float store each — cheaper than the flag test
-            # dance would be worth)
-            _G_OCCUPANCY.set(len(self._pending_ticks))
+            # hand the tick to a resolver NOW: its blocking readback returns
+            # when the device is done, and the verdicts fan out at once
+            pending.handed_ns = OT.t0()
+            if self._pipeline_depth > 0:
+                self._pool().submit(self._resolve_tick, pending).add_done_callback(
+                    lambda f, p=pending: self._resolution_done(p, f)
+                )
+                self._pending_ticks.append(pending)
+            else:
+                self._resolve_tick(pending)
+            self._reap_resolved()
             with self._lock:
                 more = (
                     bool(self._acquires)
@@ -2644,43 +2666,15 @@ class SentinelClient:
                 )
             if not more:
                 more = any(d.pending() > 0 for d in self._front_doors)
-            depth = self._pipeline_depth if more else 0
-            while len(self._pending_ticks) > depth:
-                p = self._pending_ticks.pop(0)
-                p.handed_ns = OT.t0()
-                if self._pipeline_depth > 0:
-                    self._resolve_futs.append(
-                        self._pool().submit(self._resolve_tick, p)
-                    )
-                else:
-                    self._resolve_tick(p)
-            if self._resolve_futs:
-                alive = []
-                for f in self._resolve_futs:
-                    if not f.done():
-                        alive.append(f)
-                        continue
-                    exc = f.exception()
-                    if exc is not None:
-                        # a lost resolution strands its tick's futures —
-                        # it must never vanish silently
-                        from sentinel_tpu.utils.record_log import record_log
-
-                        record_log().error(
-                            "tick resolution failed: %r", exc, exc_info=exc
-                        )
-                self._resolve_futs = alive
-            _G_RESOLVER_Q.set(len(self._resolve_futs))
             if pending.dispatched_ns:
-                # tick.handoff: dispatch end -> the hand-over loop and the
-                # sweep of finished resolutions are done
+                # tick.handoff: dispatch end -> the hand-over and the sweep
+                # of finished resolutions are done; both attrs read the
+                # unresolved count (every unresolved tick is with the pool)
+                n = len(self._pending_ticks)
                 OT.TRACER.record(
                     "tick.handoff", pending.dispatched_ns,
                     OT.now_ns() - pending.dispatched_ns, pending.tick_id,
-                    {
-                        "pending": len(self._pending_ticks),
-                        "resolvers": len(self._resolve_futs),
-                    },
+                    {"pending": n, "resolvers": n},
                 )
             if not more:
                 # wait out in-flight resolutions; their callbacks may
@@ -3649,47 +3643,67 @@ class SentinelClient:
         return self._resolver_pool
 
     def _drain_resolves(self) -> None:
-        """Flush deferred readbacks: pendings not yet handed to the pool,
-        then every in-flight pool resolution.  _resolve_tick fails its
-        own tick closed instead of raising, so this wait cannot abort
-        mid-drain and strand later ticks."""
-        while self._pending_ticks:
-            p = self._pending_ticks.pop(0)
-            p.handed_ns = OT.t0()
-            if self._pipeline_depth > 0:
-                self._resolve_futs.append(self._pool().submit(self._resolve_tick, p))
-            else:
-                self._resolve_tick(p)
-        futs, self._resolve_futs = self._resolve_futs, []
-        # bounded drain: _resolve_tick fails its own tick closed, so a
-        # future that does not complete means the resolver thread is
-        # WEDGED (a readback that never returns), and stop() holds
-        # _tick_mutex through this drain — an unbounded result() would
-        # hang shutdown forever while blocking every admission thread.
-        # One shared deadline across the batch: the ticks resolve
-        # concurrently, so waiting entry_timeout_s per future would pay
-        # N timeouts for one wedged device.
-        deadline = mono_s() + max(2.0 * self.entry_timeout_s, 5.0)
-        abandoned = 0
-        _t_idle = OT.t0() if futs else 0
-        for f in futs:
-            try:
-                f.result(timeout=max(0.0, deadline - mono_s()))  # stlint: disable=blocking-under-lock — the deadline above bounds the whole drain; see the wedge rationale
-            except _FutTimeout:
+        """Wait out every dispatched tick that is not resolved yet.
+        _resolve_tick fails its own tick closed instead of raising, so
+        this wait cannot abort mid-drain and strand later ticks."""
+        self._await_resolved(len(self._pending_ticks), _IDLE_RESOLVERS)
+
+    def _await_resolved(self, n: int, why: dict) -> None:
+        """Wait until the ``n`` oldest unresolved ticks are resolved, and
+        drop them (and whatever else finished meanwhile) from the books;
+        the wait is recorded as ``tick.idle`` with the attrs ``why``."""
+        waited, abandoned = self._pending_ticks[:n], 0
+        # bounded wait: _resolve_tick fails its own tick closed, so a tick
+        # that does not settle means the resolver thread is WEDGED (a
+        # readback that never returns), and the caller holds _tick_mutex —
+        # an unbounded wait would hang shutdown forever while blocking
+        # every admission thread.  One shared deadline across the batch:
+        # the ticks resolve concurrently, so waiting entry_timeout_s per
+        # tick would pay N timeouts for one wedged device.
+        budget = max(2.0 * self.entry_timeout_s, 5.0)
+        deadline = mono_s() + budget
+        _t_idle = OT.t0() if waited else 0
+        for p in waited:
+            if not p.settled.wait(max(0.0, deadline - mono_s())):
                 abandoned += 1  # still running; its watchdog fails it over
+                p.settled.set()  # off the books: never waited for twice
         if _t_idle:
-            OT.TRACER.record("tick.idle", _t_idle, OT.now_ns() - _t_idle, 0, _IDLE_RESOLVERS)
+            OT.TRACER.record("tick.idle", _t_idle, OT.now_ns() - _t_idle, 0, why)
         if abandoned:
             from sentinel_tpu.utils.record_log import record_log
 
             record_log().warning(
-                "resolve drain abandoned %d wedged tick(s) after %.1fs",
-                abandoned, max(2.0 * self.entry_timeout_s, 5.0),
+                "resolve wait abandoned %d wedged tick(s) after %.1fs",
+                abandoned, budget,
             )
-        # the pipeline is empty here — zero the gauges so /metrics never
-        # reports a stale occupancy while the loop idles
-        _G_OCCUPANCY.set(0)
-        _G_RESOLVER_Q.set(0)
+        self._reap_resolved()
+
+    @staticmethod
+    def _resolution_done(p: _PendingTick, fut: Future) -> None:
+        """Done-callback of a tick's pool future (resolver thread)."""
+        exc = fut.exception()
+        if exc is not None:
+            # a lost resolution strands its tick's futures — it must
+            # never vanish silently
+            from sentinel_tpu.utils.record_log import record_log
+
+            record_log().error("tick resolution failed: %r", exc, exc_info=exc)
+        p.settled.set()
+
+    def _reap_resolved(self) -> None:
+        """Drop the resolved ticks from the books and publish the count
+        that is left: both gauges read it, since every unresolved tick is
+        with the resolver pool from its dispatch on."""
+        if self._pending_ticks:
+            self._pending_ticks = [
+                p for p in self._pending_ticks if not p.settled.is_set()
+            ]
+        # unconditional: the gauges are on the always-on /metrics surface
+        # (one float store each — cheaper than the flag test dance would
+        # be worth), and an idle loop must never report a stale occupancy
+        n = len(self._pending_ticks)
+        _G_OCCUPANCY.set(n)
+        _G_RESOLVER_Q.set(n)
 
     def _resolve_tick(self, p: _PendingTick) -> None:
         """Read back one dispatched tick's outputs and fan verdicts out —
@@ -3700,8 +3714,8 @@ class SentinelClient:
         degrade-never-break contract the seg-overflow path follows."""
         if p.dispatched_ns:
             # tick.resident: dispatch end -> a resolver starts on the tick.
-            # It holds the residency rule's wait (up to handed_ns) and the
-            # resolver pool's queue (from handed_ns on).
+            # The tick is handed over at dispatch (handed_ns), so this is
+            # the resolver pool's queue and no more.
             p.resolving_ns = OT.now_ns()
             OT.TRACER.record(
                 "tick.resident", p.dispatched_ns, p.resolving_ns - p.dispatched_ns,
@@ -3826,7 +3840,7 @@ class SentinelClient:
             _C_WIRE["rx"].inc(verdict.nbytes)
         if p.dispatched_ns and OT.TRACER.enabled:
             # tick.device is NOT device time: dispatch -> verdicts
-            # host-visible = tick.resident (residency rule + resolver queue)
+            # host-visible = tick.resident (the resolver pool's queue)
             # + tick.wait (this thread blocked on the readback above).  Its
             # name and edges stay: the histogram, the adaptive controller and
             # the req_p99 SLO read it.

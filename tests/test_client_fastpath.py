@@ -11,6 +11,9 @@ job).
 
 from __future__ import annotations
 
+import threading
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -18,7 +21,7 @@ from sentinel_tpu.core import errors as ERR
 from sentinel_tpu.core.config import small_engine_config
 from sentinel_tpu.core.rules import FlowRule
 from sentinel_tpu.runtime.client import SentinelClient
-from sentinel_tpu.utils.time_source import VirtualTimeSource
+from sentinel_tpu.utils.time_source import TimeSource, VirtualTimeSource
 
 # single-rule lanes so the segment CHECK phase engages too (engine gates
 # seg_checks on *_rules_per_resource == 1)
@@ -491,3 +494,229 @@ def test_pipelined_ticks_do_not_share_presort_storage(vt, monkeypatch):
         assert len(c._inv_free[c.cfg.batch_size]) == 2
     finally:
         c.stop()
+
+
+# -- pipeline residency: handed over at dispatch, pipeline_depth as the cap --
+
+
+def _traced_ticks(spans):
+    """Per dispatched tick, in dispatch order: its tick.dispatch,
+    tick.resident and tick.handoff spans."""
+    by_tick = {}
+    for s in spans:
+        if s["name"] in ("tick.dispatch", "tick.resident", "tick.handoff"):
+            by_tick.setdefault(s["trace"], {})[s["name"]] = s
+    ticks = [t for t in by_tick.values() if len(t) == 3]
+    return sorted(ticks, key=lambda t: t["tick.dispatch"]["t0_ns"])
+
+
+@pytest.mark.parametrize("depth", [1, 2, 4])
+def test_a_tick_is_handed_to_its_resolver_before_the_next_is_dispatched(vt, depth):
+    """With more work queued across ticks, a tick goes to the resolver pool
+    when it is dispatched, not pipeline_depth ticks later; and the unresolved
+    count a tick sees at hand-over never passes the depth."""
+    from sentinel_tpu import obs
+
+    c = _mk(vt)
+    c._pipeline_depth = depth
+    ids = np.array([c.registry.resource_id(f"h{i}") for i in range(12)], np.int32)
+    c.flow_rules.load([FlowRule(resource="h0", count=3.0)])
+    c.start()
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        # one block over seven ticks: `more` stays true from tick to tick
+        n = 6 * c.cfg.batch_size + 40
+        verdicts, _w = c.submit_block(np.resize(ids, n)).result(timeout=60)
+    finally:
+        obs.disable()
+        c.stop()
+    assert len(verdicts) == n
+    ticks = _traced_ticks(obs.TRACER.snapshot())
+    assert len(ticks) == 7
+    for this, nxt in zip(ticks, ticks[1:]):
+        handed = this["tick.resident"]["attrs"]["handed_ns"]
+        d_this, d_nxt = this["tick.dispatch"], nxt["tick.dispatch"]
+        assert d_this["t0_ns"] + d_this["dur_ns"] <= handed
+        assert handed <= d_nxt["t0_ns"] + d_nxt["dur_ns"]
+    for t in ticks:
+        at = t["tick.handoff"]["attrs"]
+        assert 0 <= at["pending"] <= depth and at["resolvers"] == at["pending"]
+    assert c._pending_ticks == []
+
+
+class _Gate:
+    """Stands in for the sleep of a failpoint's ``delay``: a resolver that
+    fires it is held until the test opens the gate, so the test asserts
+    orderings and counts and never a duration."""
+
+    def __init__(self):
+        self.open = threading.Event()
+        self.held = threading.Semaphore(0)  # one release per resolver held
+
+    def sleep(self, _seconds):
+        self.held.release()
+        assert self.open.wait(60), "the test never opened the gate"
+
+
+class _FrozenTime(TimeSource):
+    """Engine time stands still, and the client stays threaded (a
+    VirtualTimeSource makes it sync): verdicts do not depend on how long
+    a tick thread took."""
+
+    def now_ms(self):
+        return 2_000
+
+
+def _stalled_client(monkeypatch, depth, **kw):
+    """A threaded client whose every readback stalls on a gate (the
+    stand-in for a hung device: runtime.watchdog.stall), with a spy on the
+    tick thread's wait for the cap."""
+    from sentinel_tpu.chaos import failpoints as FP
+    from sentinel_tpu.chaos.plans import FaultPlan, FaultSpec
+
+    gate = _Gate()
+    monkeypatch.setattr(FP, "_time", SimpleNamespace(sleep=gate.sleep))
+    c = SentinelClient(
+        cfg=small_engine_config(**SEG), time_source=_FrozenTime(), mode="threaded",
+        tick_interval_ms=1.0, entry_timeout_s=30.0, pipeline_depth=depth, **kw,
+    )
+    ids = np.array([c.registry.resource_id(f"s{i}") for i in range(12)], np.int32)
+    c.flow_rules.load([FlowRule(resource="s0", count=3.0)])
+    c.start()
+    at_cap = threading.Event()
+    real = c._await_resolved
+
+    def spy(n, why):
+        if why["why"] == "depth":
+            at_cap.set()
+        return real(n, why)
+
+    monkeypatch.setattr(c, "_await_resolved", spy)
+    plan = FaultPlan(
+        name="stall", seed=5,
+        faults=[FaultSpec("runtime.watchdog.stall", "delay", delay_ms=1000)],
+    )
+    return c, ids, gate, at_cap, FP.armed(plan)
+
+
+def _drained(c):
+    from sentinel_tpu.chaos import invariants as INV
+
+    # the loop goes idle once nothing is queued: wait for it, then check
+    with c._tick_mutex:
+        c._drain_resolves()
+    return INV.pipeline_drained(SimpleNamespace(client=c))
+
+
+def test_pipeline_depth_caps_the_unresolved_ticks(monkeypatch):
+    """Resolvers held at the readback: the tick thread dispatches
+    pipeline_depth ticks and waits at the cap (tick.idle why="depth");
+    released, every block resolves as a depth-0 run resolves it."""
+    from sentinel_tpu import obs
+
+    depth, n_ticks = 2, 5
+    c, ids, gate, at_cap, armed = _stalled_client(monkeypatch, depth)
+    items = np.resize(ids, n_ticks * c.cfg.batch_size)
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        with armed:
+            fut = c.submit_block(items)
+            assert at_cap.wait(60), "the tick thread never met the cap"
+            for _ in range(depth):
+                assert gate.held.acquire(timeout=60)
+            # depth resolvers are held and no third was started: the tick
+            # that would be the third unresolved one was not dispatched
+            assert not gate.held.acquire(blocking=False)
+            assert len(c._pending_ticks) == depth
+            assert not fut.done()
+            gate.open.set()
+            verdicts, _w = fut.result(timeout=60)
+            assert _drained(c).ok
+    finally:
+        gate.open.set()
+        obs.disable()
+        c.stop()
+    spans = obs.TRACER.snapshot()
+    assert len([s for s in spans if s["name"] == "tick.dispatch"]) == n_ticks
+    # counted where the benchmark's span_summary prints it
+    assert obs.summarize(spans)["tick.idle"]["why"]["depth"] >= 1
+    inline = SentinelClient(
+        cfg=small_engine_config(**SEG), time_source=_FrozenTime(), mode="sync",
+        pipeline_depth=0,
+    )
+    for i in range(12):
+        inline.registry.resource_id(f"s{i}")
+    inline.flow_rules.load([FlowRule(resource="s0", count=3.0)])
+    want, _w = inline.submit_block(items).result(timeout=60)
+    assert verdicts.tolist() == want.tolist()
+    assert ERR.BLOCK_SYSTEM not in set(verdicts.tolist())
+
+
+def test_the_watchdog_releases_the_cap_and_the_loop_moves_on(monkeypatch):
+    """Watchdog armed, resolvers held: each stalled tick fails closed and
+    the tick thread, waiting at the cap for it, dispatches the next while
+    the resolvers are still held."""
+    from sentinel_tpu.obs.registry import REGISTRY as OBS
+
+    depth, n_ticks = 2, 4
+    c, ids, gate, at_cap, armed = _stalled_client(
+        monkeypatch, depth, watchdog_timeout_s=0.2
+    )
+    fired = OBS.counter("sentinel_watchdog_fired_total")
+    before = fired.value
+    try:
+        with armed:
+            fut = c.submit_block(np.resize(ids, n_ticks * c.cfg.batch_size))
+            assert at_cap.wait(60), "the tick thread never met the cap"
+            # every tick is failed over while the gate is still shut
+            verdicts, _w = fut.result(timeout=60)
+            assert not gate.open.is_set()
+            assert set(verdicts.tolist()) == {int(ERR.BLOCK_SYSTEM)}
+            assert fired.value == before + n_ticks
+            gate.open.set()
+            assert _drained(c).ok
+        # the loop is sound afterwards
+        ok, _w = c.submit_block(ids[1:]).result(timeout=60)
+        assert set(ok.tolist()) == {int(ERR.PASS)}
+        assert _drained(c).ok
+    finally:
+        gate.open.set()
+        c.stop()
+
+
+def test_a_resolution_that_raises_is_logged_and_frees_the_loop(vt, monkeypatch):
+    """_resolve_tick fails its tick closed and does not raise; should it
+    ever, the loss is logged from the pool future's done-callback and the
+    tick leaves the books, so neither the cap nor the idle drain waits out
+    its deadline for it."""
+    from sentinel_tpu.utils import record_log as RL
+
+    logged = []
+    monkeypatch.setattr(
+        RL.record_log(), "error", lambda msg, *a, **kw: logged.append(msg % a)
+    )
+    c = _mk(vt)
+    c._pipeline_depth = 1
+    rid = c.registry.resource_id("lost")
+    c.start()
+    try:
+        lost = []
+
+        def broken(p):
+            lost.append(p)
+            raise RuntimeError("resolver broke")
+
+        monkeypatch.setattr(c, "_resolve_tick", broken)
+        # three ticks at depth 1: the second and third meet the cap
+        fut = c.submit_block(np.full(2 * c.cfg.batch_size + 8, rid, np.int32))
+        assert len(lost) == 3 and c._pending_ticks == []
+        assert not fut.done()  # stranded, which is why it must be loud
+        assert len([m for m in logged if "tick resolution failed" in m]) == 3
+    finally:
+        monkeypatch.undo()
+        for p in lost:
+            c._fail_tick(p)
+        c.stop()
+    assert fut.done()

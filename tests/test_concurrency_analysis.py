@@ -578,28 +578,32 @@ def test_ensure_connected_does_not_queue_behind_a_connect():
 
 
 def test_drain_resolves_abandons_wedged_ticks(monkeypatch):
-    """A resolver future that never completes (wedged device readback)
-    must not hang stop() under _tick_mutex forever: the drain shares one
-    deadline and abandons what is still running."""
-    from concurrent.futures import Future
-
+    """A resolver that never returns (wedged device readback) must not
+    hang stop() under _tick_mutex forever: the drain shares one deadline
+    and abandons what is still running."""
     from sentinel_tpu.core.config import small_engine_config
     from sentinel_tpu.runtime import client as RC
 
+    def pending(resolved):
+        p = RC._PendingTick(
+            acq=[], blocks=[], fronts=[], inv_a=None, out=None,
+            check_dropped=False, n_obj=0, n_blk=0,
+        )
+        if resolved:
+            p.settled.set()
+        return p
+
     c = RC.SentinelClient(cfg=small_engine_config(), mode="sync")
-    wedged = Future()  # never resolved
-    done = Future()
-    done.set_result(None)
-    c._pending_ticks = []
-    c._resolve_futs = [done, wedged]
+    done, wedged = pending(True), pending(False)  # wedged: never resolved
+    c._pending_ticks = [done, wedged]
 
     # virtual clock: the first mono_s() sets the deadline, every later
     # read is past it — the drain must take the timeout path instantly
     ticks = iter([100.0] + [1000.0] * 10)
     monkeypatch.setattr(RC, "mono_s", lambda: next(ticks))
     c._drain_resolves()
-    assert c._resolve_futs == []
-    assert not wedged.done()  # abandoned, not cancelled into a fake result
+    assert c._pending_ticks == []
+    assert wedged.state == "pending"  # abandoned, not claimed into a fake result
 
 
 def test_worker_waits_carry_timeouts():
