@@ -2,12 +2,13 @@
 
 One process (the only one that touches JAX) drives the system's main path
 once, end to end, through the entry points a user would call, at the full
-width of the deployment the repo benchmarks: the served 1 M-resource
-scenario (``bench.served_scenario`` — 16,368-row tables, 10,000 ruled
-resources each with a QPS FlowRule and a slow-ratio DegradeRule, 128
-ParamFlowRules, 16 AuthorityRules, one SystemRule, 2,048 tail rules on
-sketch ids, Zipf(1.3) over 2^20 ids, batch 131,072).  Data and traffic come
-from ``--seed``.
+width of the deployment the repo benchmarks: ``perfbench/configs/
+zipf-1m.json``, built by the deployment kind that file names, exactly as
+``perfbench/run.py`` builds it for the ledger's cells (16,368-row tables,
+10,000 ruled resources each with a QPS FlowRule and a slow-ratio
+DegradeRule, 128 ParamFlowRules, 16 AuthorityRules, one SystemRule, 2,048
+tail rules on sketch ids, Zipf(1.3) over 2^20 ids, batch 131,072).  Data
+and traffic come from ``--seed``.
 
 Phases (each reported with ok, wall seconds and, separately, XLA compile
 seconds — on a warm persistent cache that is the cache-load time):
@@ -35,14 +36,16 @@ seconds — on a warm persistent cache that is the cache-load time):
                 ``pipeline_depth=4``, dispatch running ahead of readback)
                 against the plain scatter path on the same chip — verdict
                 arrays bit-identical (batches are handed over in the
-                client's presort order, so both engines see one order).  The clock advances 5 ms per round so
-                that, as in ``bench.main``, a window holds enough ticks for
-                the ~0.2-per-batch tail ids to cross their 20/s rules: tail
-                ids must come back blocked here (on the real clock of the
-                serve phase the host cannot offer them 20/s).
+                client's presort order, so both engines see one order).
+                The clock advances 5 ms per round so that a window holds
+                enough ticks for the ~0.2-per-batch tail ids to cross their
+                20/s rules: tail ids must come back blocked here (on the
+                real clock of the serve phase the host cannot offer them
+                20/s).
 
 ``--rehearse-cpu`` (together with ``JAX_PLATFORMS=cpu``) walks the same code
-at a tiny size with the fast-path flags forced on, so the kernels run
+at a tiny size (``REHEARSAL_SIZES`` over the same file) with the fast-path
+flags forced on, so the kernels run
 interpreted through the same call sites; it reports ``"platform": "cpu"``
 and ``"rehearsal": true`` and is never what a bare run does.
 
@@ -82,6 +85,46 @@ _ZERO_COUNTERS = (
     "sentinel_seg_dropped_total",
     "sentinel_watchdog_fired_total",
 )
+
+#: the benchmark's configuration this smoke vouches for
+CONFIG = "zipf-1m"
+#: ``--rehearse-cpu``: the same file cut to what interpreted kernels walk in
+#: minutes, with the fast-path flags forced on (on the CPU
+#: ``platform_engine_config`` leaves them off); the futures get room, so a
+#: slow host cannot fail the walk
+REHEARSAL_SIZES = {
+    "engine": {
+        "max_resources": 112, "max_nodes": 120, "max_flow_rules": 112,
+        "max_degrade_rules": 112, "max_param_rules": 8, "batch_size": 512,
+        "complete_batch_size": 512, "use_mxu_tables": True,
+        "fused_effects": True, "seg_effects": True,
+    },
+    "resources": {"n_ruled": 48, "id_universe": 4095, "n_tail_ruled": 16},
+    "rules": {"flow_qps": 100.0, "tail_qps": 2.0, "n_param_ruled": 8,
+              "n_authority_ruled": 4},
+    "traffic": {"pool_batches": 8},
+    "client": {"entry_timeout_s": 30.0},
+}
+#: the equivalence phase's reference: the same deployment on the plain
+#: scatter engine, no pipelining
+_PLAIN = {
+    "engine": {"use_mxu_tables": False, "fused_effects": False,
+               "seg_effects": False},
+    "client": {"pipeline_depth": 0},
+}
+
+
+def build(seed: int, *sizes: dict):
+    """``CONFIG`` as ``perfbench/run.py`` builds it (the file's deployment
+    kind, not started), with the groups of ``sizes`` laid over the file in
+    order; none on the chip, where the serve phase runs the file as it is."""
+    from perfbench import manifest
+    from perfbench.deployments import with_sizes
+
+    cfg = manifest.config(CONFIG)
+    for s in sizes:
+        cfg = with_sizes(cfg, s)
+    return manifest.module("deployments", cfg["deployment"]).build(cfg, seed, None)
 
 
 class CompileClock:
@@ -394,16 +437,16 @@ def wait_for_seg_resize(timeout_s: float) -> bool:
     return True
 
 
-def serve_phase(bench, scale, seed, overrides, n_blocks, entry_timeout_s, state):
+def serve_phase(seed, sizes, n_blocks, state):
+    from sentinel_tpu.core.config import platform_engine_config
+
     counters0 = {k: metric_total(k) for k in _ZERO_COUNTERS}
     surprise0 = metric_total("sentinel_retraces_total", expected="false")
     resizes0 = metric_total("sentinel_seg_resizes_total")
 
-    c, traffic, info = bench.served_scenario(
-        scale, seed=seed, n_batches=8, cfg_overrides=overrides,
-        mode="threaded", pipeline_depth=4, entry_timeout_s=entry_timeout_s,
-    )
-    detail = {"tail_rules_promoted": info["tail_rules_promoted_to_exact_rows"]}
+    dep = build(seed, *sizes)
+    c, traffic = dep.client, dep.pool
+    detail = {"tail_rules_promoted": int((dep.tail_ids < dep.sketch_base).sum())}
     t0 = time.perf_counter()
     c.start()  # warms both tick shapes, then starts the tick thread
     detail["start_s"] = round(time.perf_counter() - t0, 3)
@@ -428,16 +471,11 @@ def serve_phase(bench, scale, seed, overrides, n_blocks, entry_timeout_s, state)
         c.stop()
     state["served_client"] = c
     if not failures:
-        d, f = token_requests(
-            bench.served_config(
-                scale,
-                **{
-                    **overrides,
-                    "batch_size": min(2048, scale.batch),
-                    "complete_batch_size": min(2048, scale.batch),
-                },
-            )
-        )
+        width = min(2048, dep.batch)
+        d, f = token_requests(platform_engine_config(**{
+            **dep.config["engine"], "batch_size": width,
+            "complete_batch_size": width,
+        }))
         detail.update(d)
         failures += f
     deltas = {k: metric_total(k) - v for k, v in counters0.items()}
@@ -483,7 +521,7 @@ def compiled_tick_text(c) -> str:
 # ---------------------------------------------------------------------------
 
 
-def evidence_phase(state, rehearsal: bool, overrides: dict):
+def evidence_phase(state, rehearsal: bool, sizes):
     from sentinel_tpu.ops import fused
 
     c = state["served_client"]
@@ -496,7 +534,9 @@ def evidence_phase(state, rehearsal: bool, overrides: dict):
     )
     detail = {
         "fast_path_flags": flags,
-        "flags_overridden_by_smoke": sorted(overrides),
+        "flags_overridden_by_smoke": sorted(
+            k for s in sizes for k in s.get("engine", {}) if k in flags
+        ),
         "interpret_mode": fused.interpret_mode(),
         "fused_available": fused.available(),
         "mosaic_custom_calls_in_served_tick": mosaic_calls,
@@ -512,8 +552,8 @@ def evidence_phase(state, rehearsal: bool, overrides: dict):
         if not fused.interpret_mode():
             failures.append("rehearsal expected interpreted kernels")
     else:
-        if overrides:
-            failures.append("the smoke overrode engine flags on the chip")
+        if sizes:
+            failures.append("the smoke changed the benchmark's configuration on the chip")
         if fused.interpret_mode():
             failures.append("Pallas kernels ran INTERPRETED on the chip")
         if mosaic_calls == 0:
@@ -525,10 +565,7 @@ def evidence_phase(state, rehearsal: bool, overrides: dict):
 # equivalence
 # ---------------------------------------------------------------------------
 
-_PLAIN = dict(use_mxu_tables=False, fused_effects=False, seg_effects=False)
-
-
-def equivalence_phase(bench, scale, seed, overrides, rounds, per_round=4):
+def equivalence_phase(seed, sizes, rounds, per_round=4):
     """Served configuration vs the plain scatter path, both on this
     backend, same virtual clock, same seeded traffic, full width."""
     import numpy as np
@@ -537,13 +574,14 @@ def equivalence_phase(bench, scale, seed, overrides, rounds, per_round=4):
     from sentinel_tpu.utils.time_source import VirtualTimeSource
 
     vt = VirtualTimeSource(start_ms=1_000)
-    common = dict(seed=seed, n_batches=per_round, mode="sync", time_source=vt)
-    served, traffic, _ = bench.served_scenario(
-        scale, cfg_overrides=overrides, pipeline_depth=4, **common
-    )
-    plain, traffic_p, _ = bench.served_scenario(
-        scale, cfg_overrides={**overrides, **_PLAIN}, **common
-    )
+    # the pool is seeded batch by batch: its first per_round batches are
+    # the serve phase's, and no more are drawn
+    sync = {"client": {"mode": "sync", "time_source": vt},
+            "traffic": {"pool_batches": per_round}}
+    dep_s = build(seed, *sizes, sync)
+    dep_p = build(seed, *sizes, sync, _PLAIN)
+    served, plain = dep_s.client, dep_p.client
+    traffic, traffic_p = dep_s.pool, dep_p.pool
     failures = []
     for a, b in zip(traffic, traffic_p):
         if not all(np.array_equal(x, y) for x, y in zip(a, b)):
@@ -662,7 +700,6 @@ def main(argv=None) -> int:
               "nothing was run", file=sys.stderr)
         return 2
 
-    import bench
     from sentinel_tpu.native import native_available
 
     dev = jax.devices()[0]
@@ -673,19 +710,9 @@ def main(argv=None) -> int:
     state = {}
 
     if args.rehearse_cpu:
-        scale = bench.ServedScale(
-            n_ruled=48, n_tail_ruled=16, n_total=4096, n_param_ruled=8,
-            n_authority_ruled=4, max_resources=112, max_nodes=120,
-            max_rules=112, max_param_rules=8, batch=512,
-            flow_qps=100.0, tail_qps=2.0,
-        )
-        overrides = dict(use_mxu_tables=True, fused_effects=True, seg_effects=True)
-        # interpreted kernels on shared CPU cores are not the product:
-        # give the futures room so a slow host cannot fail the walk
-        n_blocks, rounds, entry_timeout_s = 8, 3, 30.0
+        sizes, n_blocks, rounds = (REHEARSAL_SIZES,), 8, 3
     else:
-        scale, overrides = bench.ServedScale(), {}
-        n_blocks, rounds, entry_timeout_s = 12, 40, 5.0
+        sizes, n_blocks, rounds = (), 12, 40
 
     def environment():
         native = native_available()
@@ -703,12 +730,9 @@ def main(argv=None) -> int:
 
     phases = (
         ("environment", environment),
-        ("serve", lambda: serve_phase(
-            bench, scale, args.seed, overrides, n_blocks, entry_timeout_s,
-            state)),
-        ("evidence", lambda: evidence_phase(state, args.rehearse_cpu, overrides)),
-        ("equivalence", lambda: equivalence_phase(
-            bench, scale, args.seed, overrides, rounds)),
+        ("serve", lambda: serve_phase(args.seed, sizes, n_blocks, state)),
+        ("evidence", lambda: evidence_phase(state, args.rehearse_cpu, sizes)),
+        ("equivalence", lambda: equivalence_phase(args.seed, sizes, rounds)),
     )
     ok = all(report.run(name, fn) for name, fn in phases)
     compile_s, trace_s, hits = clock.snapshot()

@@ -14,9 +14,7 @@ latency flat at ~capacity goodput.  Everything is engine-time
 pure: the same inputs replay the same admissions, ladder transitions and
 latencies, which is what the chaos plane's seed-determinism check needs.
 
-Used by the ``overload_storm`` chaos scenario (pass/fail invariants) and
-the ``adaptive_overload`` bench row (numbers for BENCH_r0N) — one model,
-two consumers.
+Used by the ``overload_storm`` chaos scenario (pass/fail invariants).
 """
 
 from __future__ import annotations
@@ -28,10 +26,8 @@ import numpy as np
 
 
 def storm_controller_preset(op=None):
-    """Controller tuning for the simulator's scales, shared by BOTH
-    consumers (the ``overload_storm`` chaos scenario and the
-    ``adaptive_overload`` bench row) so the invariant-gated experiment
-    and the published BENCH numbers can never desynchronize: host-CPU
+    """Controller tuning for the simulator's scales (the
+    ``overload_storm`` chaos scenario): host-CPU
     input disabled (a busy CI box must not steer the ladder), blocking
     pressure on (the sim's overload shows up as sustained shedding),
     engine-time holds sized to the 10 ms step.
@@ -39,8 +35,7 @@ def storm_controller_preset(op=None):
     ``op`` is the serving ``workload.OperatingPoint`` (default
     ``sim_default_op()``): the admission queue bound follows its
     pipeline depth, so the preset can never drift from the point the
-    tuner/bench actually run — the same shared definition bench rows
-    consume."""
+    tuner actually runs."""
     from sentinel_tpu.adaptive.controller import AdaptiveConfig
 
     if op is None:

@@ -1,11 +1,11 @@
 """flops-bytes-budget: hot-path compile-time cost gated against goldens.
 
-`benchmarks/` measures wall clock AFTER merge; this pass gates the
+The benchmark measures wall clock AFTER merge; this pass gates the
 STATIC cost — XLA `cost_analysis` flops and bytes-accessed of each
 budget-eligible entry point — at PR time.  A change that doubles the
 tick's memory traffic (an accidental f32 upcast of a count plane, a
 gather that re-materializes the one-hot in HBM) shows up as a budget
-breach in CI instead of a regression in the next BENCH round.
+breach in CI instead of a regression in the next ledger row.
 
 Budgets live in `sentinel_tpu/analysis/jaxpr/budgets.json` as absolute
 ceilings, written by

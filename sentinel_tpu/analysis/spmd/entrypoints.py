@@ -50,10 +50,8 @@ def window_config():
 
 
 def sketch_tier_1m_config():
-    """The 1M-ruled-resource sketch-tier operating point (bench.py
-    ``sketch_tier_bench``) — the config whose per-shard footprint the
-    HBM budgeter projects.  Restated here field-for-field; bench.py
-    stays the authority for the measured numbers."""
+    """The 1M-ruled-resource sketch-tier operating point — the config
+    whose per-shard footprint the HBM budgeter projects."""
     from sentinel_tpu.core.config import EngineConfig
 
     return EngineConfig(

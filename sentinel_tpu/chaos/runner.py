@@ -1114,8 +1114,6 @@ def _scn_overload_storm(seed: int) -> ScenarioResult:
     slo.step(0)  # pre-storm anchor snapshot
     seq0 = FLIGHT.recorded_total()
     with session.window(plan):
-        # the preset is shared with bench.adaptive_overload_bench so the
-        # gated experiment and the BENCH_r0N numbers stay one experiment
         on = run_overload_sim(
             adaptive=True, adaptive_cfg=storm_controller_preset()
         )

@@ -239,7 +239,7 @@ class EngineConfig:
     # cold promoted rows back to the tail.  0 disables emission (the
     # traced program is unchanged).
     hotset_k: int = 32
-    hotset_eval_s: float = 1.0  # manager evaluation cadence (host seconds)
+    hotset_eval_s: float = 1.0  # manager evaluation cadence (s of the client's clock)
     hotset_promote_qps: float = 100.0  # windowed pass estimate to qualify
     hotset_demote_qps: float = 1.0  # exact windowed pass to demote below
     hotset_cooldown_s: float = 30.0  # re-promotion hysteresis after demote
@@ -403,7 +403,7 @@ def platform_engine_config(**kw) -> EngineConfig:
     Pallas overhead.
 
     This is the runtime client's default config factory: ``st.entry()``
-    on a TPU serves the same engine `bench.py` measures, the way the
+    on a TPU serves the same engine the benchmark measures, the way the
     reference's measured artifact IS its product hot path
     (sentinel-core/.../CtSph.java:117-157 — the JMH harness calls plain
     ``SphU.entry``).  Explicit keyword overrides win."""

@@ -67,7 +67,7 @@ class FlightRecorder:
         self._bundles: List[dict] = []  # last `keep`, oldest first
         self.keep = keep
         self.min_interval_s = float(min_interval_s)
-        self._last_trigger_ns = 0
+        self._last_trigger_ns: Optional[int] = None  # never fired
         self._lock = threading.Lock()  # guards bundles/providers, NOT note()
         self._bundle_seq = itertools.count(1)
         self._c_bundles: Dict[str, object] = {}  # reason -> counter
@@ -153,7 +153,8 @@ class FlightRecorder:
         to ``SENTINEL_FLIGHT_DIR`` when set."""
         now = OT.now_ns()
         with self._lock:
-            if now - self._last_trigger_ns < self.min_interval_s * 1e9:
+            last = self._last_trigger_ns
+            if last is not None and now - last < self.min_interval_s * 1e9:
                 self._c_rate_limited.inc()
                 return None
             self._last_trigger_ns = now
@@ -189,7 +190,7 @@ class FlightRecorder:
         """Let the next trigger() through immediately (test harnesses and
         the chaos runner pin bundle capture deterministically with this)."""
         with self._lock:
-            self._last_trigger_ns = 0
+            self._last_trigger_ns = None
 
     def bundles(self) -> List[dict]:
         with self._lock:

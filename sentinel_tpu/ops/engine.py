@@ -1266,7 +1266,7 @@ def _process_completions_fused(
         (2, 2, 1),
     )
 
-    # Job shaping rule (measured, benchmarks/probe_fused_hist*.py): every
+    # Job shaping rule (measured on v5e in round 2, before the ledger): every
     # MXU dot streams the whole item axis and costs ceil(n/16384) passes,
     # so tables are kept <= 16384 rows per job — real stat rows live below
     # max_nodes (the +8 node_rows tail is trash/padding only), per-depth

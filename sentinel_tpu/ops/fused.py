@@ -1,7 +1,7 @@
 """Fused Pallas effects-phase megakernels.
 
-Why this exists (measured on v5e in round 2, see
-benchmarks/probe_fused_hist.py): the XLA one-hot-matmul table path (ops/mxu_table.py) pays
+Why this exists (measured on v5e in round 2, before the ledger; the
+probe is gone): the XLA one-hot-matmul table path (ops/mxu_table.py) pays
 ~0.3-0.9 ms PER OP at B=128K regardless of FLOPs — every scatter/gather
 materializes [B, n_lo] one-hot tensors in HBM and takes its own fusion,
 and the tick makes ~25 such calls (19 ms total).  The fused formulation
@@ -39,7 +39,7 @@ import jax.numpy as jnp
 #: default items per grid step.  Multi-job kernels unroll one [tb, N_LO]
 #: LoV temporary per digit-dot; ~25 dots x tb=2048 x 128 x 2B ~= 13 MB
 #: stays inside Mosaic's 16 MB scoped-vmem stack (tb=4096 overflows on
-#: some job mixes) and measures within noise of 4096 at bench shapes.
+#: some job mixes) and measured within noise of 4096 at the served shapes.
 TILE = 2048
 #: gather kernels hold [tb, N_LO] f32 select products per unrolled digit
 TILE_GATHER = 2048

@@ -205,8 +205,7 @@ _G_DEV_SEG_LIVE = OBS.gauge(
 )
 # -- wire byte accounting: what actually crosses the host<->device link
 # and the cluster protocol per tick — the 5.37 MB/tick ROADMAP item 1
-# must shrink, so it is measured where it moves (bench emits the deltas
-# as the stage_breakdown_ms sibling key `wire_bytes`).
+# must shrink, so it is measured where it moves.
 _C_WIRE = {
     d: OBS.counter(
         "sentinel_wire_bytes_total",
@@ -797,8 +796,6 @@ class SentinelClient:
         self._seg_sample_ctr = 0
         self._seg_sample_ctr_c = 0  # completion side (ticks may lack acquires)
         self._seg_resizing = False
-        self._build_ms_sum = 0.0
-        self._build_ticks = 0
         # host mirror of the device window-rotation cadence: refresh is a
         # pure function of the stamped tick timestamp, so bucket-boundary
         # crossings and the slack-deferred purges are derivable here
@@ -2879,12 +2876,6 @@ class SentinelClient:
         drained into the same engine batches."""
         self._front_doors.append(door)
 
-    @property
-    def host_build_ms_avg(self) -> float:
-        """Mean host batch-build time per tick (assembly + presort +
-        upload dispatch) since start — the serial host share of serving."""
-        return self._build_ms_sum / self._build_ticks if self._build_ticks else 0.0
-
     def pending_acquires(self) -> int:
         """Depth of the un-ticked acquire queue (load-shedding probe)."""
         with self._lock:
@@ -3229,7 +3220,6 @@ class SentinelClient:
         # flip the staging parity: every _sbuf below hands out the slot
         # the PREVIOUS tick did not touch (double-buffered async safety)
         self._stage_parity ^= 1
-        t_build0 = _time.perf_counter()
         # process-unique trace id correlating this tick's spans across the
         # submitting thread and the resolver pool (per-client counters
         # would collide in multi-client processes sharing the ring)
@@ -3559,11 +3549,6 @@ class SentinelClient:
             # closed loop: signals row -> controller -> ladder + live
             # system-column ceilings (disabled mode: the one check above)
             self._adaptive_step(ad, t, load, cpu)
-        # running average of host batch-build time (assembly + presort +
-        # column upload dispatch) — the serial host share of a tick; read
-        # via host_build_ms_avg (benchmark decomposition, ops dashboards)
-        self._build_ms_sum += (_time.perf_counter() - t_build0) * 1000.0
-        self._build_ticks += 1
         with self._engine_lock:
             _t_call = OT.now_ns() if _t_disp else 0
             self._state, out = self._tick(

@@ -40,7 +40,6 @@ from sentinel_tpu.adaptive.degrade import Hysteresis
 from sentinel_tpu.chaos import failpoints as FP
 from sentinel_tpu.obs import flight as FL
 from sentinel_tpu.obs.registry import REGISTRY as _OBS
-from sentinel_tpu.utils.time_source import mono_s
 
 _FP_PROMOTE = FP.register(
     "runtime.hotset.promote",
@@ -123,7 +122,8 @@ class HotSetManager:
         # QPS so hotset_promote_qps and the demote side's passQps read
         # (both per-second) stay in one unit regardless of sketch window
         self._interval_s = E.sketch_config(cfg).interval_ms / 1000.0
-        self._last_eval = 0.0
+        # the cadence runs on the client's clock (virtual in tests), from now
+        self._last_eval = client.time.now_ms() / 1000.0
         self._cool: Dict[str, Hysteresis] = {}
         self._cold: Dict[str, int] = {}  # consecutive cold evaluations
         self._eval_n = 0
@@ -171,7 +171,7 @@ class HotSetManager:
         # check-and-stamp under the lock: sync-mode clients call tick_once
         # (and so this) from many request threads, and two winners would
         # run concurrent promote/demote passes
-        now = mono_s()
+        now = self._c.time.now_ms() / 1000.0
         with self._lock:
             if now - self._last_eval < self._c.cfg.hotset_eval_s:
                 return False
@@ -180,8 +180,8 @@ class HotSetManager:
         return True
 
     def evaluate_now(self) -> None:
-        """One promote/demote pass (tests call this directly — the cadence
-        gate above uses real time, which virtual-time tests bypass).
+        """One promote/demote pass (tests call this directly, past the
+        cadence gate above).
         Serialized on its own lock: the body mutates the promote/demote
         bookkeeping outside ``self._lock`` (which fold's hot path takes)."""
         with self._eval_lock:

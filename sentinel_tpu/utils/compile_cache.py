@@ -1,5 +1,5 @@
 """Persistent XLA compile cache for the process entry points that run on
-the chip (``chip_smoke.py``, ``bench.py``).
+the chip (``chip_smoke.py``, ``perfbench/run.py``).
 
 The client compiles the full tick for two batch shapes at ``start()`` and
 again whenever a rule load changes the feature set, and a chip call starts

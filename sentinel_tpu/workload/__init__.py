@@ -27,9 +27,6 @@ from sentinel_tpu.workload.generator import (
     drive_streaming,
 )
 from sentinel_tpu.workload.operating_point import (
-    BENCH_WINDOW_EXACT,
-    BENCH_WINDOW_MINUTE,
-    BENCH_WINDOW_MINUTE_SLACK,
     ENGINE_FIELDS,
     OperatingPoint,
     sim_default_op,
@@ -54,9 +51,6 @@ from sentinel_tpu.workload.tuner import (
 
 __all__ = [
     "AutoTuner",
-    "BENCH_WINDOW_EXACT",
-    "BENCH_WINDOW_MINUTE",
-    "BENCH_WINDOW_MINUTE_SLACK",
     "Constant",
     "Diurnal",
     "ENGINE_FIELDS",

@@ -2,20 +2,16 @@
 
 Before this module the knobs that decide how the engine is driven —
 batch size, pipeline depth, sketch window shape, `slack_frac`, audit
-cadence — lived as hard-coded per-row literals in `bench.py` and as
-scattered `SentinelClient` constructor arguments, so the benchmarked
-point and the served point could silently drift.  `OperatingPoint` is
-the single definition all three consumers share:
+cadence — lived as scattered `SentinelClient` constructor arguments.
+`OperatingPoint` is the single definition its consumers share:
 
-* **bench rows** (`bench.py` `_window_op_rate` / `workload_bench`) take
-  an `OperatingPoint` instead of loose keyword literals;
 * **the autotuner** (`workload/tuner.py`) explores a candidate grid of
   `OperatingPoint`s and applies the winner LIVE via
   `SentinelClient.apply_operating_point`;
 * **the overload simulator preset** (`adaptive/simload.
   storm_controller_preset`) derives its queue bound from the same
-  point, so the chaos scenario and the bench row can never
-  desynchronize from the tuner's world.
+  point, so the chaos scenario can never desynchronize from the
+  tuner's world.
 
 Engine-compiled knobs (batch/sketch shape) are separated from host-only
 knobs (pipeline depth, audit cadence) because applying them has very
@@ -42,7 +38,7 @@ ENGINE_FIELDS: Tuple[str, ...] = (
 
 @dataclass(frozen=True)
 class OperatingPoint:
-    """One serving configuration the tuner/bench/simulator agree on."""
+    """One serving configuration the tuner and the simulator agree on."""
 
     # engine-compiled knobs (changing any = one expected retrace)
     batch_size: int = 2048
@@ -85,7 +81,7 @@ class OperatingPoint:
         return dataclasses.asdict(self)
 
     def describe(self) -> str:
-        """Compact stable label (decision journals, bench rows)."""
+        """Compact stable label (decision journals)."""
         return (
             f"b{self.batch_size}/c{self.complete_batch_size}"
             f"/p{self.pipeline_depth}"
@@ -101,21 +97,3 @@ def sim_default_op() -> OperatingPoint:
     from sentinel_tpu.core.config import small_engine_config
 
     return OperatingPoint.from_engine_config(small_engine_config())
-
-
-#: bench.py window-compare rows (previously hard-coded literals at the
-#: ``_window_op_rate`` signature): the exact-tier second-window shape
-#: and the minute-scale rotation shape with/without slack.
-BENCH_WINDOW_EXACT = OperatingPoint(
-    batch_size=4096,
-    complete_batch_size=4096,
-    sketch_sample_count=10,
-    sketch_window_ms=100,
-    sketch_slack_frac=0.0,
-)
-BENCH_WINDOW_MINUTE = BENCH_WINDOW_EXACT.replace(
-    sketch_sample_count=60, sketch_window_ms=1000
-)
-BENCH_WINDOW_MINUTE_SLACK = BENCH_WINDOW_MINUTE.replace(
-    sketch_slack_frac=0.05
-)

@@ -1,6 +1,6 @@
-"""chip_smoke.py / bench.py refuse to run without a chip, the compile-cache
-helper places the cache from outside, and (slow) the CPU rehearsal walks
-the whole smoke."""
+"""chip_smoke.py refuses to run without a chip and builds the deployment
+the benchmark's cells measure, the compile-cache helper places the cache from
+outside, and (slow) the CPU rehearsal walks the whole smoke."""
 
 from __future__ import annotations
 
@@ -12,6 +12,7 @@ import sys
 
 import pytest
 
+import chip_smoke
 from sentinel_tpu.utils import compile_cache
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -26,18 +27,58 @@ def _run(*argv, timeout):
     )
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
-def test_no_chip_means_nonzero_exit_and_no_result(script):
-    r = _run(script, timeout=120)
+def test_no_chip_means_nonzero_exit_and_no_result():
+    r = _run("chip_smoke.py", timeout=120)
     assert r.returncode != 0
     assert "'cpu'" in r.stderr  # names the platform it found
     assert r.stdout.strip() == ""  # no JSON line a reader could take for a result
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
-def test_no_probe_subprocess(script):
-    src = open(os.path.join(REPO, script)).read()
+def test_no_probe_subprocess():
+    src = open(os.path.join(REPO, "chip_smoke.py")).read()
     assert not re.search(r"\bsubprocess\b|\bPopen\b|os\.system|multiprocessing", src)
+
+
+def _file_with(*sizes):
+    """perfbench/configs/zipf-1m.json, read here and not through the code
+    under test, with the groups of ``sizes`` laid over it."""
+    with open(os.path.join(REPO, "perfbench", "configs", "zipf-1m.json")) as f:
+        cfg = json.load(f)
+    for s in sizes:
+        for group, keys in s.items():
+            cfg[group] = {**cfg[group], **keys}
+    return cfg
+
+
+@pytest.mark.parametrize(
+    "sizes", [(), (chip_smoke.REHEARSAL_SIZES,)], ids=["chip", "rehearsal"]
+)
+def test_the_smoke_builds_the_deployment_the_cells_measure(sizes):
+    """On the chip the file as it is; in the rehearsal the same file, cut."""
+    want = _file_with(*sizes)
+    dep = chip_smoke.build(3, *sizes)
+    c = dep.client
+    try:
+        assert dep.config == want
+        assert {k: getattr(c.cfg, k) for k in want["engine"]} == want["engine"]
+        assert (c.mode, c._pipeline_depth, c.tick_interval_ms, c.entry_timeout_s) == tuple(
+            want["client"][k]
+            for k in ("mode", "pipeline_depth", "tick_interval_ms", "entry_timeout_s")
+        )
+        res, rules = want["resources"], want["rules"]
+        assert dep.ruled_names == [f"res-{i + 1}" for i in range(res["n_ruled"])]
+        flow = c.flow_rules.get()
+        assert [r.count for r in flow] == (
+            [rules["flow_qps"]] * res["n_ruled"] + [rules["tail_qps"]] * res["n_tail_ruled"]
+        )
+        assert len(c.degrade_rules.get()) == res["n_ruled"]
+        assert len(c.param_flow_rules.get()) == rules["n_param_ruled"]
+        assert len(c.authority_rules.get()) == rules["n_authority_ruled"]
+        assert [r.qps for r in c.system_rules.get()] == [rules["system_qps"]]
+        assert len(dep.pool) == want["traffic"]["pool_batches"]
+        assert {len(col) for batch in dep.pool for col in batch} == {c.cfg.batch_size}
+    finally:
+        c.stop()
 
 
 def test_compile_cache_dir_follows_env(monkeypatch, tmp_path):
@@ -55,11 +96,6 @@ def test_compile_cache_dir_default_is_fixed(monkeypatch):
 
 
 def test_result_line_has_exactly_the_contract_keys():
-    sys.path.insert(0, REPO)
-    try:
-        import chip_smoke
-    finally:
-        sys.path.remove(REPO)
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     line = chip_smoke.result_line(True, {**device, "extra": 0})
     assert "\n" not in line

@@ -5,8 +5,8 @@ native/ring.presort in _run_tick, bit-identical to np.lexsort + np.take) and
 maps verdicts back through the inverse permutation; seg_u
 grows automatically when traffic overflows the compacted capacity; fail-
 closed overflow drops are surfaced loudly.  On CPU the fused kernels run
-in Pallas interpret mode — semantics only (device speed is bench.py's
-job).
+in Pallas interpret mode — semantics only (device speed is
+perfbench/run.py's job).
 """
 
 from __future__ import annotations
@@ -720,3 +720,23 @@ def test_a_resolution_that_raises_is_logged_and_frees_the_loop(vt, monkeypatch):
             c._fail_tick(p)
         c.stop()
     assert fut.done()
+
+
+def test_a_full_tick_reads_back_exactly_its_layouts_bytes(client_factory):
+    """tests/test_wire.py holds a light tick to its packed layout's total;
+    the full shape is a layout of its own and is held to it here: one fused
+    read-back a tick (the timeline rows on their own path), not four."""
+    from sentinel_tpu.obs.registry import REGISTRY as OBS
+
+    def rx(path):
+        return OBS.get("sentinel_wire_bytes_total", {"path": path, "direction": "rx"}).value
+
+    c = client_factory(cfg=small_engine_config(batch_size=512, complete_batch_size=512))
+    ids = np.full(300, c.registry.resource_id("full/r"), np.int32)  # over 256: the full shape
+    c.submit_block(ids).result(timeout=60)  # compile this shape / const cols
+    dev0, tl0 = rx("device"), rx("timeline")
+    c.submit_block(ids).result(timeout=60)
+    lo = c._wire_layout(c.cfg, c.cfg.batch_size)
+    tl_bytes = lo.tl_rows * lo.tl_cols * 4
+    assert rx("device") - dev0 == lo.total * 4 - tl_bytes
+    assert rx("timeline") - tl0 == tl_bytes

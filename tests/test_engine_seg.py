@@ -2,7 +2,7 @@
 path: full-tick bit-identity, with and without capacity fallback.
 
 Runs on CPU with Pallas interpret kernels — semantics only; device speed
-is bench.py's job.
+is perfbench/run.py's job.
 """
 
 from __future__ import annotations

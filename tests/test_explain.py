@@ -88,7 +88,7 @@ def test_wire_layout_explain_section_and_gate():
     lo = WIRE.layout_for(cfg, 64)
     assert lo.expl_k == 32
     assert lo.total == lo.off_expl + 2 + lo.expl_k * WIRE.EXPLAIN_WORDS
-    assert (lo.total - lo.off_expl) * 4 == 520  # the BENCH_r20 wire cost
+    assert (lo.total - lo.off_expl) * 4 == 520  # the explain section's wire cost at K = 32
     # off: layout (and so the traced program) is unchanged
     lo_off = WIRE.layout_for(small_engine_config(packed_wire=True, explain_k=0), 64)
     assert lo_off.expl_k == 0 and lo_off.total == lo_off.off_expl
@@ -298,7 +298,7 @@ def test_main_section_still_fails_closed_with_explain_on(client_factory):
 def test_flash_crowd_stays_explainable(client_factory):
     """The acceptance bar: >=99% of blocked decisions resolve through
     explain() in a flash-crowd run (explain_k sized to the batch — the
-    operator knob for block-heavy workloads; BENCH_r20 measures 100%)."""
+    operator knob for block-heavy workloads)."""
     cfg = small_engine_config(explain_k=64)
     c = client_factory(cfg=cfg)
     names = [f"crowd/r{i}" for i in range(8)]

@@ -3,7 +3,7 @@ engine-path equivalence.
 
 On CPU the kernels run in Pallas interpret mode — semantics only; the
 device path is exercised on the chip by chip_smoke.py (full-width
-served-vs-plain verdict equivalence) and bench.py."""
+served-vs-plain verdict equivalence) and perfbench/run.py."""
 
 from __future__ import annotations
 

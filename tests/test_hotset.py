@@ -119,8 +119,7 @@ def test_cold_promoted_row_demotes_with_hysteresis(client_factory, vt):
     assert not c.registry.is_sketch_id(c.registry.peek_resource_id("fades"))
     # traffic stops; the window slides past -> two cold evaluations demote
     vt.advance(2_000)
-    c.tick_once()
-    c.hotset.evaluate_now()
+    c.tick_once()  # the cadence is due on the client's clock: one cold pass
     assert "fades" in c.hotset.promoted  # one cold eval holds
     c.hotset.evaluate_now()
     rid = c.registry.peek_resource_id("fades")

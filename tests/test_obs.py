@@ -630,6 +630,21 @@ def test_flight_trigger_rate_limit_and_keep_k(tmp_path, monkeypatch):
     assert rl is None or rl.value >= 0  # registered lazily per instance
 
 
+def test_flight_first_trigger_fires_on_a_freshly_booted_host(monkeypatch):
+    """"Never fired" is not "fired at boot": a host whose monotonic clock
+    reads less than ``min_interval_s`` still captures its first bundle."""
+    from sentinel_tpu.obs import flight
+
+    up_ns = [1_000_000_000]  # one second after boot
+    monkeypatch.setattr(flight.OT, "now_ns", lambda: up_ns[0])
+    fr = flight.FlightRecorder(capacity=8, keep=2, min_interval_s=3600.0)
+    assert fr.trigger("breach")["reason"] == "breach"
+    up_ns[0] += 1_000_000_000
+    assert fr.trigger("breach") is None  # inside the window
+    fr.reset_rate_limit()
+    assert fr.trigger("again")["reason"] == "again"
+
+
 def test_flight_note_disarmed_overhead_guard():
     """The journal append is the black box's hot hook: it must stay in
     the same <5 µs/call budget as t0() and disarmed failpoints."""
@@ -986,6 +1001,26 @@ def test_a_sync_callers_absence_is_not_recorded_as_idleness(client_factory):
     assert "tick.drain" in names and "tick.lock" in names
     assert not [s for s in obs.TRACER.snapshot()
                 if s["name"] == "tick.idle" and s["attrs"]["why"] == "interval"]
+
+
+def test_the_hot_set_cadence_runs_on_the_clients_clock(client_factory, vt):
+    """A pass is due ``hotset_eval_s`` after the last one on the client's
+    clock: wall time that passes under a frozen virtual clock makes none
+    due, so a slow host never folds a promote/demote pass into a test."""
+    import time
+
+    from sentinel_tpu.core.config import small_engine_config
+
+    c = client_factory(cfg=small_engine_config(sketch_stats=True, hotset_eval_s=0.05))
+    assert c.hotset.maybe_evaluate() is False  # the cadence starts at construction
+    vt.advance(50)
+    assert c.hotset.maybe_evaluate() is True
+    time.sleep(0.08)
+    assert c.hotset.maybe_evaluate() is False
+    vt.advance(49)
+    assert c.hotset.maybe_evaluate() is False
+    vt.advance(1)
+    assert c.hotset.maybe_evaluate() is True
 
 
 @pytest.mark.parametrize("due", [True, False])

@@ -206,6 +206,32 @@ def test_engine_tick_retrace_journal_steady_state_and_config_change(client):
     assert "second_window_ms" in last["cause"]
 
 
+def test_start_compiles_both_tick_shapes_and_serving_compiles_none():
+    """``start()`` leaves the tick compiled for the light and the full
+    batch shape; a light tick, a full one and a completion-only one then
+    serve from those two executables, with no retrace journalled."""
+    from sentinel_tpu.runtime.client import SentinelClient
+
+    c = SentinelClient(
+        cfg=small_engine_config(batch_size=512, complete_batch_size=512),
+        mode="threaded", entry_timeout_s=60.0,
+    )
+    c.start()
+    try:
+        assert c._tick._cache_size() == 2
+        journalled = len(PROF.RETRACE.recent())
+        ids = np.full(512, c.registry.resource_id("shapes/r"), np.int32)
+        for n in (4, 512):
+            verdicts, _waits = c.submit_block(ids[:n]).result(timeout=60.0)
+            assert len(verdicts) == n
+            c.submit_completion_block(ids[:n], np.ones(n, np.float32))
+        c.submit_block(ids[:1]).result(timeout=60.0)  # the completions are in by now
+        assert c._tick._cache_size() == 2
+        assert len(PROF.RETRACE.recent()) == journalled
+    finally:
+        c.stop()
+
+
 # -- deep-profile capture ----------------------------------------------------
 
 

@@ -182,7 +182,7 @@ def test_lease_request_roundtrips_on_the_wire(fleet):
 def test_lease_units_are_capped_both_sides(fleet):
     """An uncapped lease against a huge-threshold rule (slack × 1e9)
     would build a 250M-item engine batch and stall every flow on the
-    shard — found by the cluster_sharded bench.  Both the client sizing
+    shard.  Both the client sizing
     and the server grant clamp to MAX_LEASE_UNITS."""
     fid = owned_flow(fleet, "shard-0")
     fleet.load_flow_rules("default", [flow_rule(fid, 1e9)])

@@ -842,3 +842,37 @@ def test_cli_update_baseline_roundtrip(tmp_path):
         capture_output=True, text=True, env=env, timeout=120,
     )
     assert r3.returncode == 1
+
+
+def test_only_the_benchmark_times_a_tick():
+    """The ledger's harness (``perfbench/``) reads a tick's host time from
+    the tracer's spans.  Nothing imports the deleted ``bench`` module or
+    ``benchmarks/`` package, and the client keeps no meter of its own."""
+    importers = []
+    for root, dirs, files in os.walk(REPO_ROOT):
+        dirs[:] = [d for d in dirs if not d.startswith(".") and d != "chiprun_out"]
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(root, name)
+            with open(path) as f:
+                tree = ast.parse(f.read(), path)
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Import):
+                    mods = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom) and not node.level:
+                    mods = [node.module]
+                else:
+                    continue
+                if any(m.split(".")[0] in ("bench", "benchmarks") for m in mods):
+                    importers.append(f"{os.path.relpath(path, REPO_ROOT)}:{node.lineno}")
+    assert importers == []
+    with open(os.path.join(REPO_ROOT, "sentinel_tpu", "runtime", "client.py")) as f:
+        client = ast.parse(f.read())
+    cls = next(n for n in client.body
+               if isinstance(n, ast.ClassDef) and n.name == "SentinelClient")
+    methods = {n.name: n for n in cls.body if isinstance(n, ast.FunctionDef)}
+    assert "host_build_ms_avg" not in methods
+    clock_reads = [n.lineno for n in ast.walk(methods["_run_tick"])
+                   if isinstance(n, ast.Attribute) and n.attr == "perf_counter"]
+    assert clock_reads == []

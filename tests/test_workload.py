@@ -128,10 +128,6 @@ def test_operating_point_is_the_shared_definition():
 
     assert storm_controller_preset().queue_max == int(op.pipeline_depth)
     assert storm_controller_preset(op.replace(pipeline_depth=3)).queue_max == 3
-    # bench window rows are presets of the same dataclass
-    assert WL.BENCH_WINDOW_EXACT.sketch_slack_frac == 0.0
-    assert WL.BENCH_WINDOW_MINUTE.sketch_sample_count == 60
-    assert WL.BENCH_WINDOW_MINUTE_SLACK.sketch_slack_frac > 0.0
 
 
 def test_service_model_has_a_real_tradeoff_surface():
