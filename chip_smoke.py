@@ -494,7 +494,7 @@ def compiled_tick_text(c) -> str:
     persistent cache makes re-requesting it a load, not a compile."""
     import jax
 
-    from sentinel_tpu.ops import engine as E
+    from sentinel_tpu.ops import wire as WIRE
 
     def spec(tree):
         return jax.tree.map(
@@ -502,16 +502,12 @@ def compiled_tick_text(c) -> str:
         )
 
     cfg = c.cfg
-    tick, state, rules = c._tick, spec(c._state), spec(c._rules_dev)
-    scalar = jax.ShapeDtypeStruct((), "float32")
-    lowered = tick.lower(
-        state,
-        rules,
-        spec(E.empty_acquire(cfg, b=cfg.batch_size)),
-        spec(E.empty_complete(cfg, b=cfg.complete_batch_size)),
-        jax.ShapeDtypeStruct((), "int32"),
-        scalar,
-        scalar,
+    # the packed client's tick takes its whole input as one buffer
+    lo = WIRE.input_layout_for(cfg, cfg.batch_size, cfg.complete_batch_size)
+    lowered = c._tick.lower(
+        spec(c._state),
+        spec(c._rules_dev),
+        jax.ShapeDtypeStruct((lo.total,), "uint32"),
     )
     return lowered.compile().as_text()
 

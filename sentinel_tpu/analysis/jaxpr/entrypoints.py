@@ -23,6 +23,7 @@ a budget on that number would gate noise, not the datapath.
 from __future__ import annotations
 
 import functools
+import inspect
 import os
 import threading
 from typing import Dict, List, Optional
@@ -35,6 +36,7 @@ _ENTRY_MODULES = {
     "tick/mxu": "sentinel_tpu/ops/engine.py",
     "tick/fused-seg": "sentinel_tpu/ops/engine.py",
     "tick/packed-wire": "sentinel_tpu/ops/engine.py",
+    "tick/wire-in": "sentinel_tpu/ops/engine.py",
     "tick/sketch-salsa": "sentinel_tpu/sketch/salsa.py",
     "tick/cluster-token": "sentinel_tpu/cluster/token_service.py",
     "segscan/excl-cumsum": "sentinel_tpu/ops/segscan.py",
@@ -189,6 +191,7 @@ def _build_entries() -> List[TracedEntry]:
     from sentinel_tpu.ops import rank as RK
     from sentinel_tpu.ops import segscan as SS
     from sentinel_tpu.ops import window as W
+    from sentinel_tpu.ops import wire as WIRE
 
     entries: List[TracedEntry] = []
 
@@ -215,7 +218,26 @@ def _build_entries() -> List[TracedEntry]:
                 for f in out_struct._fields
                 if getattr(out_struct, f) is not None
             )
+            # ...and its upload surface: the program the packed client
+            # calls (E.make_tick(wire_in=True)) takes state, rules and ONE
+            # input buffer, observed the same way
+            wire_fn, wire_args = _wire_in_program(cfg, features, args)
+            jax.eval_shape(wire_fn, *wire_args)
+            ent.client_inputs = (
+                sum(
+                    p.default is p.empty
+                    for p in inspect.signature(wire_fn).parameters.values()
+                ),
+                len(jax.tree_util.tree_leaves(wire_args[2:])),
+            )
         return ent
+
+    def _wire_in_program(cfg, features, args):
+        lo = WIRE.input_layout_for(cfg, *WIRE.tick_shapes(cfg)[1])
+        return (
+            functools.partial(E.tick_wire_in, cfg=cfg, features=features),
+            (args[0], args[1], jnp.zeros((lo.total,), jnp.uint32)),
+        )
 
     cfg_plain = small_engine_config()
     cfg_mxu = small_engine_config(use_mxu_tables=True)
@@ -245,6 +267,19 @@ def _build_entries() -> List[TracedEntry]:
         timeline_k=8,
     )
     entries.append(tick_entry("tick/packed-wire", cfg_packed, E.ALL_FEATURES))
+    # the same tick as the packed client calls it: the whole per-tick input
+    # as one uint32 buffer, unpacked at the entry (ops/wire.py).  The
+    # timestamp rides in the buffer's header, so there is no time invar to
+    # seed; tick/packed-wire keeps that coverage for the same program body
+    entries.append(
+        _trace(
+            "tick/wire-in",
+            *_wire_in_program(
+                cfg_packed, E.ALL_FEATURES, tick_args_by_cfg[cfg_packed]
+            ),
+            cost=True,
+        )
+    )
     # the cluster token-decision engine: same tick, the feature set the
     # DefaultTokenService's dedicated decision client needs
     entries.append(tick_entry("tick/cluster-token", cfg_plain, DECISION_FEATURES))

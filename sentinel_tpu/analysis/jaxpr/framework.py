@@ -64,6 +64,11 @@ class TracedEntry:
     #: program via eval_shape, not re-derived from config.  Populated
     #: only for packed-wire tick entries; None elsewhere.
     readback_fields: Optional[Tuple[str, ...]] = None
+    #: (positional arguments, batch-input leaves after state and rules) of
+    #: the program the packed client calls, observed likewise; the
+    #: transfer-guard pass pins it to (3, 1): one upload a tick.  Populated
+    #: only for packed-wire tick entries; None elsewhere.
+    client_inputs: Optional[Tuple[int, int]] = None
 
     @property
     def pseudo_path(self) -> str:

@@ -25,6 +25,12 @@ from config); this pass flags any field outside the allowance: the wire
 buffer itself, ``wait_ms`` (the sidecar-overflow escape hatch, read only
 on the rare tick whose PASS_WAIT rows overflow the fixed sidecar), and
 ``seg_dropped`` (a plain-int trace constant, never read back packed).
+
+Packed-wire upload surface: the program the packed client calls takes
+the engine state, the rules and ONE input buffer (ops/wire.py).  A second
+batch-input leaf is a second host→device transfer on every tick, which is
+what the one buffer replaced; entrypoints records what the program
+accepts, and this pass flags anything but three arguments and one leaf.
 """
 
 from __future__ import annotations
@@ -77,6 +83,16 @@ class TransferGuardPass(JaxprPass):
                     "re-opens a per-array device->host sync in "
                     "_resolve_tick",
                 )
+        if entry.packed_wire and entry.client_inputs not in (None, (3, 1)):
+            n_args, n_leaves = entry.client_inputs
+            yield self.finding(
+                entry,
+                f"the packed client's tick takes {n_args} arguments with "
+                f"{n_leaves} batch-input leaves — it must take (state, "
+                "rules, wire_in) with the whole per-tick input in ONE "
+                "buffer (ops/wire.unpack_tick_input); every further leaf "
+                "is one more host->device transfer a tick in _run_tick",
+            )
         for eqn in walk_eqns(entry.closed_jaxpr):
             pname = eqn.primitive.name
             if "callback" in pname or pname in _EXACT:

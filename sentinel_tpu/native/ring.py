@@ -213,7 +213,9 @@ def presort(keys, n_live, order, inv, scratch, src=(), dst=(), wide=None,
       is <= its own, found by one search.
     - ``inv`` (int32, B, or None): the inverse, ``inv[order] == arange(B)``.
     - ``dst[i][:] = src[i][order]`` for every 4-byte column pair, and
-      ``wide_dst[:] = wide[order]`` for one ``(B, w)`` int32 column.
+      ``wide_dst[:] = wide[order].T`` for one ``(B, w)`` int32 column:
+      ``wide_dst`` is ``(w, B)``, lane by lane, as the input wire carries
+      it (ops/wire.py).
 
     ``scratch`` is uint64 of at least ``2 * n_live``.  Returns the path
     taken: ``radix`` / ``small`` natively (chosen on ``n_live``), ``numpy``
@@ -238,9 +240,12 @@ def presort(keys, n_live, order, inv, scratch, src=(), dst=(), wide=None,
     w = 0
     if wide is not None:
         w = wide.shape[1]
-        if wide.dtype != np.int32 or wide_dst.shape != (B, w):
-            raise ValueError("presort: wide column must be (B, w) int32")
-        _check_cols((wide, wide_dst), B, exact=False)
+        if wide.dtype != np.int32 or wide_dst.shape != (w, B):
+            raise ValueError(
+                "presort: wide column must be (B, w) int32, its destination (w, B)"
+            )
+        _check_cols((wide,), B, exact=False)
+        _check_cols((wide_dst,), w, exact=True)
     lib = load_native()
     if lib is None:
         _presort_numpy(keys, n, order, inv, src, dst, wide, wide_dst)
@@ -280,7 +285,8 @@ def _presort_numpy(keys, n, order, inv, src, dst, wide, wide_dst) -> None:
     for s, d in zip(src, dst):
         np.take(s, order, out=d)
     if wide is not None:
-        np.take(wide, order, axis=0, out=wide_dst)
+        for k in range(wide.shape[1]):
+            np.take(wide[:, k], order, out=wide_dst[k])
 
 
 def _batch_sort(keys, want_inv: bool):

@@ -903,7 +903,8 @@ int32_t sx_front_respond_ex(sx_front* f, int64_t n, const int32_t* corr,
 // one call: it argsorts the n LIVE rows of a B-row batch by nk int32 key
 // columns (keys[0] most significant), splices the padding rows n..B-1 in
 // where a stable sort of all B rows would put them, writes the inverse
-// permutation, and gathers every payload column through the result.
+// permutation, and gathers every payload column through the result (the
+// one wide column, w int32 a row, into w lanes of B: wdst[k * B + i]).
 //
 // The permutation is IDENTICAL to np.lexsort over the B-row key columns
 // (ring.presort's numpy fallback), which is what makes ranks and verdicts
@@ -1078,13 +1079,22 @@ int32_t sx_presort(int64_t n, int64_t B, int32_t nk,
         int32_t* d = dst[c];
         for (int64_t i = 0; i < B; ++i) d[i] = s[order[i]];
     }
-    if (w == 2) {  // the served param_dims: a row is one 8-byte move
-        for (int64_t i = 0; i < B; ++i)
-            memcpy(wdst + i * 2, wsrc + (int64_t)order[i] * 2, 8);
+    // the wide column lands lane by lane, wdst[k * B + i]: the input
+    // wire's layout (ops/wire.py), which the device reads without a
+    // transposition.  One read of a source row feeds every lane's stream.
+    if (w == 2) {  // the served param_dims
+        int32_t* d0 = wdst;
+        int32_t* d1 = wdst + B;
+        for (int64_t i = 0; i < B; ++i) {
+            const int32_t* s = wsrc + (int64_t)order[i] * 2;
+            d0[i] = s[0];
+            d1[i] = s[1];
+        }
     } else if (w > 0) {
-        for (int64_t i = 0; i < B; ++i)
-            memcpy(wdst + i * w, wsrc + (int64_t)order[i] * w,
-                   (size_t)w * sizeof(int32_t));
+        for (int64_t i = 0; i < B; ++i) {
+            const int32_t* s = wsrc + (int64_t)order[i] * w;
+            for (int32_t k = 0; k < w; ++k) wdst[(int64_t)k * B + i] = s[k];
+        }
     }
     return path;
 }

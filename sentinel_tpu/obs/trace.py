@@ -424,15 +424,17 @@ def event(name: str, trace: int = 0, attrs: Optional[dict] = None) -> None:
 # -- summaries ---------------------------------------------------------------
 
 
-#: string attrs that summarize() counts by value: which way a span went
-_COUNTED_ATTRS = ("path", "why")
+#: attrs that summarize() counts by value: which way a span went, and how
+#: many host-to-device transfers a tick's input took (``tick.assemble``)
+_COUNTED_ATTRS = ("path", "why", "puts")
 
 
 def summarize(spans: Iterable[dict], prefix: Optional[str] = None) -> Dict[str, dict]:
     """Per-name duration stats over snapshot()/chrome-trace spans:
     ``{name: {count, p50_ms, p99_ms, mean_ms, total_ms}}``; a name whose
-    spans carry a ``path`` attr (``tick.presort``) or a ``why`` attr
-    (``tick.idle``) also gets that key: how many spans took each value."""
+    spans carry a ``path`` attr (``tick.presort``), a ``why`` attr
+    (``tick.idle``) or a ``puts`` attr (``tick.assemble``) also gets that
+    key: how many spans took each value."""
     import numpy as np
 
     by_name: Dict[str, List[float]] = {}

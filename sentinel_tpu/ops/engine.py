@@ -690,48 +690,36 @@ def _tick_output(
     )
 
 
-def empty_acquire(cfg: EngineConfig, b: Optional[int] = None) -> AcquireBatch:
+def _empty_batch(cls, cfg: EngineConfig, b: int, fills, wire_dtypes: dict):
     # every leaf gets its OWN buffer — two pytree leaves sharing one device
     # buffer bakes a deduplicated parameter list into the executable that
     # compiles from that call, and a later call with a different sharing
     # pattern fails with a buffer-count mismatch (observed on jaxlib CPU:
-    # 'Execution supplied 57 buffers but compiled program expected 58')
-    b = b or cfg.batch_size
-    trash = cfg.trash_row
-    # packed_wire ships the range-bounded columns narrow (ops/wire.py);
-    # the empty batch must match the client's upload dtypes exactly or
-    # warmup would compile a signature serving never calls
-    wd = WIRE.acquire_wire_dtypes(cfg)
-    z = lambda f: jnp.zeros((b,), dtype=wd.get(f, np.int32))
-    return AcquireBatch(
-        res=jnp.full((b,), trash, dtype=jnp.int32),
-        count=z("count"),
-        prio=z("prio"),
-        origin_id=jnp.full((b,), -1, dtype=jnp.int32),
-        origin_node=jnp.full((b,), trash, dtype=jnp.int32),
-        ctx_node=jnp.full((b,), trash, dtype=jnp.int32),
-        ctx_name=jnp.full((b,), -1, dtype=jnp.int32),
-        inbound=z("inbound"),
-        param_hash=jnp.zeros((b, cfg.param_dims), dtype=jnp.int32),
-        pre_verdict=z("pre_verdict"),
+    # 'Execution supplied 57 buffers but compiled program expected 58').
+    # packed_wire ships the range-bounded columns narrow (ops/wire.py): an
+    # empty batch for the classic signature carries those dtypes
+    return cls(**{
+        f: jnp.full(
+            (b, cfg.param_dims) if f == "param_hash" else (b,),
+            fill,
+            dtype=np.float32 if f == "rt" else wire_dtypes.get(f, np.int32),
+        )
+        for f, fill in fills
+    })
+
+
+def empty_acquire(cfg: EngineConfig, b: Optional[int] = None) -> AcquireBatch:
+    """Every row padding: the fills of ops/wire.acquire_fills."""
+    return _empty_batch(
+        AcquireBatch, cfg, b or cfg.batch_size, WIRE.acquire_fills(cfg),
+        WIRE.acquire_wire_dtypes(cfg),
     )
 
 
 def empty_complete(cfg: EngineConfig, b: Optional[int] = None) -> CompleteBatch:
-    # distinct buffer per leaf — see empty_acquire
-    b = b or cfg.complete_batch_size
-    trash = cfg.trash_row
-    wd = WIRE.complete_wire_dtypes(cfg)
-    z = lambda f: jnp.zeros((b,), dtype=wd.get(f, np.int32))
-    return CompleteBatch(
-        res=jnp.full((b,), trash, dtype=jnp.int32),
-        origin_node=jnp.full((b,), trash, dtype=jnp.int32),
-        ctx_node=jnp.full((b,), trash, dtype=jnp.int32),
-        inbound=z("inbound"),
-        rt=jnp.zeros((b,), dtype=jnp.float32),
-        success=z("success"),
-        error=z("error"),
-        param_hash=jnp.zeros((b, cfg.param_dims), dtype=jnp.int32),
+    return _empty_batch(
+        CompleteBatch, cfg, b or cfg.complete_batch_size,
+        WIRE.complete_fills(cfg), WIRE.complete_wire_dtypes(cfg),
     )
 
 
@@ -2884,7 +2872,7 @@ def replace_system_columns(ruleset: RuleSet, system: RT.SystemTensors) -> RuleSe
     a plain device transfer: no retrace, no recompile, jaxpr
     fingerprints untouched.  Each leaf is device_put as its own buffer —
     two leaves must never share one (the XLA argument-dedup hazard
-    documented on SentinelClient._dev_col)."""
+    documented on _empty_batch)."""
     return ruleset._replace(system=jax.device_put(system))
 
 
@@ -3113,6 +3101,25 @@ def migrate_state(
     )
 
 
+def tick_wire_in(
+    state: EngineState,
+    rules: RuleSet,
+    wire_in: jax.Array,  # uint32 [InputLayout.total] — ops/wire.py
+    cfg: EngineConfig,
+    features: frozenset = ALL_FEATURES,
+) -> Tuple[EngineState, TickOutput]:
+    """The tick as the packed client calls it: the whole per-tick input
+    is ONE buffer, unpacked here into the batches and scalars ``tick``
+    takes, so everything below sees the classic int32 columns."""
+    with jax.named_scope("stage.unpack"):
+        lo = WIRE.input_layout_of(cfg, wire_in.shape[0])
+        acq, comp, now_ms, sys_load, sys_cpu = WIRE.unpack_tick_input(wire_in, lo)
+    return tick(
+        state, rules, acq, comp, now_ms, sys_load, sys_cpu, cfg=cfg,
+        features=features,
+    )
+
+
 _TICK_CACHE: dict = {}
 _TICK_CACHE_LOCK = threading.Lock()
 #: the jitted tick's program name (``jit_sentinel_tick`` in a trace)
@@ -3131,18 +3138,24 @@ def make_tick(
     donate: bool = True,
     jit: bool = True,
     features: frozenset = ALL_FEATURES,
+    wire_in: bool = False,
 ):
     """Build the compiled tick for a given engine config.
 
-    Cached per (cfg, donate, features) — EngineConfig is frozen/hashable —
-    so multiple clients with the same config share one compiled executable
-    (compile is the expensive part, especially on the first call).
+    Cached per (cfg, donate, features, wire_in) — EngineConfig is
+    frozen/hashable — so multiple clients with the same config share one
+    compiled executable (compile is the expensive part, especially on the
+    first call).
 
     ``features`` compiles only the stages the rule set needs — the SPI
     slot-chain analog; a flow-only service pays nothing for param/degrade/
     authority machinery, and "nodes" off drops the ctx/origin stat fan-out.
+
+    ``wire_in`` gives the packed client's form, ``(state, rules, wire_in)
+    -> (state, out)`` (:func:`tick_wire_in`), under the same program name;
+    the default is :func:`tick`'s own signature.
     """
-    key = (cfg, donate, jit, features)
+    key = (cfg, donate, jit, features, wire_in)
     # check-then-act under the cache lock: the background seg_u-resize
     # thread and the serving thread race here on a rule reload, and two
     # distinct jitted callables for one key would each pay the multi-
@@ -3151,7 +3164,9 @@ def make_tick(
     with _TICK_CACHE_LOCK:
         fn = _TICK_CACHE.get(key)
         if fn is None:
-            fn = functools.partial(tick, cfg=cfg, features=features)
+            fn = functools.partial(
+                tick_wire_in if wire_in else tick, cfg=cfg, features=features
+            )
             if jit:
                 # a bare partial compiles as ``jit__unknown``: the name is
                 # what a profiler trace lists the tick program under
@@ -3170,6 +3185,6 @@ def make_tick(
             # and counted expected/surprise.  Cache hits never reach here.
             PROF.RETRACE.observe(
                 "engine.tick", cfg=cfg, donate=donate, jit=jit,
-                features=features,
+                features=features, wire_in=wire_in,
             )
     return fn

@@ -3,8 +3,7 @@
 ROADMAP item 1: the engine decides at ~19 M dps pipelined but the client
 path ships ~5 MB of full-width columns per tick and reads verdicts,
 telemetry, timeline and hot-set rows back in FOUR separate transfers.
-This module is the wire half of the fix (runtime/client.py owns the
-dirty-column upload half):
+This module is the wire, both ways:
 
 Readback — ONE flat uint32 buffer per tick (``TickOutput.wire``),
 packed on-device so only packed bytes ever cross the transport::
@@ -44,19 +43,35 @@ CLOSED (runtime/client._resolve_tick).  ``unpack`` validates the main
 section ONLY and hands the explain words back raw — decode + sec_sum
 validation live in obs/explain.py behind their own chaos failpoint.
 
-Upload — batch columns whose value range is statically bounded travel
-narrow and widen on-device at tick entry (``widen_acquire`` /
-``widen_complete``): prio/inbound are 0/1 flags, pre_verdict is a
-verdict code, and count/success/error are clamped to
-``cfg.max_batch_count`` at the client's batch-build choke point whenever
-the fused path is active.  Dtypes are STATIC per config (a
-value-dependent encoding would change the jitted tick's signature and
-recompile mid-serving); the dirty-column skip lives in the client.
+Upload — ONE flat uint32 buffer per tick too (``input_layout_for``)::
+
+    word 0            WIRE_IN_MAGIC (layout/version tag)
+    word 1..3         now_ms (int32), sys_load, sys_cpu (float32), bitcast
+    [acquire]         every AcquireBatch column, in field order
+    [complete]        every CompleteBatch column, in field order
+
+Each column starts on an ``IN_ALIGN``-word boundary after the header's
+``IN_HDR_SPAN`` and travels at its wire dtype: batch columns whose value range is statically bounded are
+narrow (prio/inbound are 0/1 flags, pre_verdict is a verdict code, and
+count/success/error are clamped to ``cfg.max_batch_count`` at the
+client's batch-build choke point whenever the fused path is active),
+four int8 (two int16) rows to a word, which on the host is nothing but a
+view of the buffer; ``rt`` is float32 bitcast; the rest is int32, and
+``param_hash`` lies lane by lane (``[param_dims, rows]``: the order the
+device keeps it in, so nothing is transposed there).  The
+client's presort gathers straight into those views, the buffer crosses
+in one transfer, and ``unpack_tick_input`` rebuilds the classic int32
+batches and the three scalars at the tick's entry.  Dtypes are STATIC
+per config (a value-dependent encoding would change the jitted tick's
+signature and recompile mid-serving), so this layout too is a pure
+function of (EngineConfig, tick shape).  The classic per-column tick
+signature still takes narrow columns and widens them (``widen_acquire``
+/ ``widen_complete``).
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -371,3 +386,181 @@ def widen_acquire(acq):
 
 def widen_complete(comp):
     return _widen(comp, ("inbound", "success", "error"))
+
+
+# -- the input wire: one upload a tick ----------------------------------------
+
+#: layout/version tag of the input buffer — bump when its layout changes
+WIRE_IN_MAGIC = 0x53_E1_71_1A
+#: header words: magic, now_ms, sys_load, sys_cpu
+IN_HDR_WORDS = 4
+#: the header has the buffer's first (8, 128) tile of the device's 1-D
+#: 32-bit layout to itself, and every column starts on a multiple of
+#: IN_ALIGN words after it: at the served shape (131,072 rows) every
+#: column then starts on a tile, and the slice the unpack takes of it
+#: needs no shifting copy.  IN_ALIGN divides the light shape's 256 rows,
+#: so a side of more rows is longer and the buffer's length alone tells
+#: the two tick shapes apart (input_layout_of)
+IN_HDR_SPAN = 1024
+IN_ALIGN = 256
+
+
+class InputColumn(NamedTuple):
+    """Where one batch column lies in the input buffer."""
+
+    field: str  # AcquireBatch / CompleteBatch field name
+    off: int  # first word
+    words: int  # words its rows occupy
+    dtype: np.dtype  # wire dtype (the host view's dtype)
+    shape: tuple  # the batch column's: (rows,) or (rows, param_dims)
+    fill: float  # what a padding row, and every row of an idle side, holds
+
+
+class InputLayout(NamedTuple):
+    """Static offset table of one tick's input for (config, tick shape)."""
+
+    b: int  # acquire rows
+    b2: int  # completion rows
+    acq: Tuple[InputColumn, ...]  # in AcquireBatch field order
+    comp: Tuple[InputColumn, ...]  # in CompleteBatch field order
+    total: int  # whole-buffer length in words
+
+    @property
+    def nbytes(self) -> int:
+        return self.total * 4
+
+
+def tick_shapes(cfg: EngineConfig) -> Tuple[Tuple[int, int], Tuple[int, int]]:
+    """The two (acquire rows, completion rows) shapes a client ticks at,
+    light and full.  Exactly two, both sides sized together, so both
+    compile during warm-up and serving compiles none."""
+    b, b2 = cfg.batch_size, cfg.complete_batch_size
+    return (min(256, b), min(256, b2)), (b, b2)
+
+
+def acquire_fills(cfg: EngineConfig) -> tuple:
+    """``(field, fill)`` in AcquireBatch field order: what a padding row
+    holds, and every row of an idle side (``engine.empty_acquire``)."""
+    trash = cfg.trash_row
+    return (
+        ("res", trash), ("count", 0), ("prio", 0), ("origin_id", -1),
+        ("origin_node", trash), ("ctx_node", trash), ("ctx_name", -1),
+        ("inbound", 0), ("param_hash", 0), ("pre_verdict", 0),
+    )
+
+
+def complete_fills(cfg: EngineConfig) -> tuple:
+    """``(field, fill)`` in CompleteBatch field order."""
+    trash = cfg.trash_row
+    return (
+        ("res", trash), ("origin_node", trash), ("ctx_node", trash),
+        ("inbound", 0), ("rt", 0.0), ("success", 0), ("error", 0),
+        ("param_hash", 0),
+    )
+
+
+def input_layout_for(cfg: EngineConfig, b: int, b2: int) -> InputLayout:
+    """The input buffer's layout at tick shape ``(b, b2)``: the header,
+    then each side's columns at their wire dtypes, each aligned."""
+    m = cfg.param_dims
+    off = IN_HDR_SPAN
+
+    def side(rows, wide, fills):
+        nonlocal off
+        cols = []
+        for field, fill in fills:
+            dt = np.dtype(np.float32 if field == "rt" else wide.get(field, np.int32))
+            shape = (rows, m) if field == "param_hash" else (rows,)
+            words = -(-rows * (m if field == "param_hash" else 1) * dt.itemsize // 4)
+            cols.append(InputColumn(field, off, words, dt, shape, fill))
+            off += -(-words // IN_ALIGN) * IN_ALIGN
+        return tuple(cols)
+
+    acq = side(b, acquire_wire_dtypes(cfg), acquire_fills(cfg))
+    comp = side(b2, complete_wire_dtypes(cfg), complete_fills(cfg))
+    return InputLayout(b=b, b2=b2, acq=acq, comp=comp, total=off)
+
+
+def input_layout_of(cfg: EngineConfig, words: int) -> InputLayout:
+    """The layout of an input buffer of ``words`` words: one of the two
+    tick shapes'.  The jitted tick finds its shape by the buffer alone."""
+    for b, b2 in tick_shapes(cfg):
+        lo = input_layout_for(cfg, b, b2)
+        if lo.total == words:
+            return lo
+    raise ValueError(
+        f"input wire of {words} words fits neither tick shape of this config"
+    )
+
+
+class InputBuffer:
+    """One tick's input on the host: the flat buffer and a view of it a
+    column, at the column's wire dtype.  Whoever builds the tick writes
+    the views; the buffer is what crosses to the device.  A two-
+    dimensional column (param_hash) lies lane by lane, so its view is the
+    batch column's transpose, ``(param_dims, rows)``."""
+
+    __slots__ = ("layout", "buf", "acq", "comp", "_now", "_sys")
+
+    def __init__(self, lo: InputLayout):
+        self.layout = lo
+        self.buf = np.zeros(lo.total, np.uint32)
+        self.buf[0] = WIRE_IN_MAGIC
+        self._now = self.buf[1:2].view(np.int32)
+        self._sys = self.buf[2:IN_HDR_WORDS].view(np.float32)
+
+        def views(cols):
+            return {
+                c.field: self.buf[c.off : c.off + c.words]
+                .view(c.dtype)[: int(np.prod(c.shape))]
+                .reshape(c.shape[::-1])
+                for c in cols
+            }
+
+        self.acq = views(lo.acq)
+        self.comp = views(lo.comp)
+
+    def idle_acquire(self) -> None:
+        """An idle side is its fill."""
+        for c in self.layout.acq:
+            self.acq[c.field].fill(c.fill)
+
+    def idle_complete(self) -> None:
+        for c in self.layout.comp:
+            self.comp[c.field].fill(c.fill)
+
+    def set_header(self, now_ms: int, sys_load: float, sys_cpu: float) -> None:
+        self._now[0] = now_ms
+        self._sys[0] = sys_load
+        self._sys[1] = sys_cpu
+
+
+def unpack_tick_input(buf, lo: InputLayout):
+    """The device half: one flat uint32 buffer -> ``(AcquireBatch,
+    CompleteBatch, now_ms, sys_load, sys_cpu)`` as ``engine.tick`` takes
+    them, every narrow column widened to int32 — slices and bitcasts
+    only."""
+    from sentinel_tpu.ops.engine import AcquireBatch, CompleteBatch
+
+    def col(c: InputColumn):
+        w = jax.lax.slice(buf, (c.off,), (c.off + c.words,))
+        x = jax.lax.bitcast_convert_type(w, c.dtype)
+        if c.dtype.itemsize < 4:
+            # [words, rows a word] in the host's byte order -> rows
+            x = x.reshape(-1)[: c.shape[0]].astype(jnp.int32)
+        if len(c.shape) == 2:
+            # lane by lane on the wire: the device keeps [rows, few] with
+            # the rows along its lanes too, so this moves nothing, where
+            # row-major rows would have to be de-interleaved (1 ms a tick)
+            x = x.reshape(c.shape[::-1])
+            x = jnp.stack([x[k] for k in range(c.shape[1])], axis=1)
+        return x
+
+    hdr = buf[:IN_HDR_WORDS]
+    return (
+        AcquireBatch(**{c.field: col(c) for c in lo.acq}),
+        CompleteBatch(**{c.field: col(c) for c in lo.comp}),
+        jax.lax.bitcast_convert_type(hdr[1], jnp.int32),
+        jax.lax.bitcast_convert_type(hdr[2], jnp.float32),
+        jax.lax.bitcast_convert_type(hdr[3], jnp.float32),
+    )

@@ -218,10 +218,6 @@ _C_PACKED_DECODE = OBS.counter(
     "sentinel_packed_decode_failures_total",
     "fused wire readbacks rejected by the packed decoder (tick fails CLOSED)",
 )
-_C_COLS_SKIPPED = OBS.counter(
-    "sentinel_wire_cols_skipped_total",
-    "batch-column uploads skipped because the column matched the previous tick",
-)
 # -- window rotation cadence (r14 running-sum windows, ops/window.py):
 # refresh() is a pure function of the stamped tick timestamp, so the
 # host derives the device's rotation/skip decisions from the timestamps
@@ -354,6 +350,11 @@ class ArrayBlock:
     waits: Optional[np.ndarray] = None  # int32 [n] result buffer
 
 
+#: the acquire side's presort keys, most significant first: the segment
+#: keys of engine_seg.prepare_acquire
+_ACQ_SEG_KEYS = ("res", "ctx_node", "origin_node", "origin_id", "ctx_name")
+
+
 @dataclass
 class _PendingTick:
     """A dispatched engine tick whose outputs haven't been read back.
@@ -376,6 +377,11 @@ class _PendingTick:
     #: packed-wire offset table for this tick's batch shape (ops/wire.py);
     #: captured at DISPATCH so a concurrent cfg swap can't skew the decode
     wire_lo: Any = None
+    #: the host buffer this tick's input crossed from (ops/wire.InputBuffer),
+    #: lent from SentinelClient._wire_free.  The transfer may still read it
+    #: after dispatch, so it is this tick's alone until the tick has
+    #: resolved, which returns it; a tick that fails keeps it for good
+    wire_in: Any = None
     tick_id: int = 0  # obs trace correlation id (0 = tracing disabled)
     dispatched_ns: int = 0  # obs: dispatch-complete stamp for the device span
     handed_ns: int = 0  # obs: when the tick thread handed this tick to a resolver
@@ -665,9 +671,7 @@ class SentinelClient:
         self._ledger_name = f"client:{self.app_name}:{id(self):x}"
         with PROF.ledger_owner(self._ledger_name), \
                 PROF.expected_retrace("client-init"):
-            self._tick = E.make_tick(
-                self.cfg, donate=True, features=self._features
-            )
+            self._tick = self._make_tick(self.cfg, self._features)
             self._state = E.init_state(self.cfg)
             self._rules_dev = E.compile_ruleset(self.cfg, self.registry)
         self._system_static = compile_system_rules([], self.cfg)
@@ -708,35 +712,32 @@ class SentinelClient:
         # on a tick only the (currently waiting) tick thread can run and
         # stalls all traffic until its timeout
         self._tick_mutex = threading.RLock()
-        # device-resident constant columns keyed by (fill, dtype, length):
-        # a batch column equal to its fill everywhere re-uses one cached
-        # device array instead of re-uploading B values every tick — the
-        # per-tick host↔device transfer is what it saves, and most columns
-        # (prio, ctx, pre_verdict, counts of 1) are constant in bulk
-        # workloads
-        self._const_cols: Dict[tuple, Any] = {}
-        # dirty-column delta uploads: field -> (host column as last
-        # uploaded, its device array).  A varying-but-unchanged column
-        # (steady bulk traffic) reuses the device copy instead of
-        # re-crossing the transport; _dev_col keeps the ref fresh every
-        # tick so the two-slot staging below can never alias it.
-        self._col_last: Dict[str, tuple] = {}
         # two-slot staging for batch assembly: per-column host buffers
         # reused on alternating parity, so a slot filled for tick t is not
         # rewritten until t+2 — zero per-tick column allocation on the
-        # steady path.  The parity does NOT make a slot safe to upload
-        # from (a pipelined tick can stay queued longer than that, and on
-        # CPU the device array would BE the slot): _dev_col uploads a
-        # private copy
+        # steady path.  Nothing is uploaded from a slot (a pipelined tick
+        # can stay queued longer than that, and on CPU the device array
+        # would BE the slot): the presort gathers out of them into the
+        # tick's own input buffer
         self._stage: Dict[tuple, list] = {}
         self._stage_parity = 0
+        # the tick's input buffer (ops/wire.InputBuffer: one flat host
+        # buffer, a view a column) crosses in ONE transfer, which may read
+        # it after dispatch (and on the CPU backend an aligned buffer IS
+        # the device array).  So it is not copied but owned: the
+        # _PendingTick holds it until the tick has resolved and then
+        # returns it here, keyed by its layout.  At most pipeline_depth + 1
+        # are out a shape, and steady serving allocates none
+        self._wire_free: Dict[Any, list] = {}
+        self._wire_bytes = 0  # input buffers allocated (memory ledger)
         # the presort's inverse permutation outlives its tick (the
         # _PendingTick holds it until its verdicts are unsorted, and the
         # cap on unresolved ticks can change on a live client, so no ring
         # is sized from it): it is lent from a free list keyed by batch
         # shape, the resolver returns it, and steady serving allocates none
         self._inv_free: Dict[int, List[np.ndarray]] = {}
-        # packed-wire offset tables keyed by (cfg, batch shape)
+        # packed-wire offset tables keyed by (cfg, batch shape): the
+        # read-back's by (cfg, b), the input's by (cfg, b, b2)
         self._wire_layouts: Dict[tuple, Any] = {}
         # completions are fire-and-forget (no futures), so they ride the
         # native MPMC event ring: Entry.exit() from any request thread is
@@ -1438,9 +1439,7 @@ class SentinelClient:
             if changed:
                 self._features = feats
                 with PROF.expected_retrace("rule-feature-change"):
-                    self._tick = E.make_tick(
-                        self.cfg, donate=True, features=feats
-                    )
+                    self._tick = self._make_tick(self.cfg, feats)
         # the caller warms the changed tick for BOTH batch shapes once
         # _cluster_lock is released (_warm_after_recompile) so the first
         # post-reload entry doesn't eat the XLA compile inside its
@@ -2444,8 +2443,43 @@ class SentinelClient:
                 # (the cadence check alone gets no span)
                 OT.TRACER.record("tick.hotset", _t_hs, OT.now_ns() - _t_hs)
 
+    def _await_room(self) -> None:
+        """Back-pressure, before the drain so that what arrives during the
+        wait still joins the tick that waited: pipeline_depth caps the
+        dispatched-but-unresolved ticks, and a tick that would not be full
+        goes only behind at most ONE unresolved tick.  The device takes as
+        long over a part-filled tick as over a full one (there are two
+        shapes), so with one tick running and one queued behind it the
+        device cannot go idle, and a third, dispatched as soon as the host
+        had it built, would add a tick's worth of waiting to every request
+        in it and to those behind it, while a full one holds requests that
+        wait as long in the queue as on the device.  Never a delay of a
+        finished verdict; the watchdog's fail-over releases the wait."""
+        cap = self._pipeline_depth
+        if len(self._pending_ticks) < min(cap, 2):
+            return
+        self._reap_resolved()
+        if cap > 2 and not self._full_tick_queued():
+            cap = 2
+        over = len(self._pending_ticks) - cap
+        if over >= 0:
+            self._await_resolved(over + 1, _IDLE_DEPTH)
+
+    def _full_tick_queued(self) -> bool:
+        """Whether the acquire queues hold a full batch."""
+        room = self.cfg.batch_size
+        with self._lock:
+            room -= len(self._acquires)
+            for blk in self._acq_blocks:
+                if room <= 0:
+                    break
+                room -= len(blk.res) - blk.taken
+        return room <= 0
+
     def _tick_once_locked(self, now_ms: Optional[int], _t_lock: int = 0) -> None:
         while True:
+            if self._pipeline_depth > 0:
+                self._await_room()
             # tick.drain: this iteration's top to the call of _run_tick.  An
             # iteration that finds nothing opens an idle stretch instead.
             _t_drain = OT.t0()
@@ -2621,15 +2655,6 @@ class SentinelClient:
                 self._drain_resolves()
                 return
             self._idle_since = 0
-            if 0 < self._pipeline_depth <= len(self._pending_ticks):
-                # pipeline_depth caps the dispatched-but-unresolved ticks:
-                # if this one would pass it, wait for the oldest first.
-                # Back-pressure on a host that dispatches faster than
-                # verdicts fan out, never a delay of a finished verdict
-                self._reap_resolved()
-                over = len(self._pending_ticks) - self._pipeline_depth
-                if over >= 0:
-                    self._await_resolved(over + 1, _IDLE_DEPTH)
             # while tracing: one host event per tick on the profiler's own
             # clock beside the spans on monotonic_ns, a tie point per tick and
             # the step number that joins a device execution to its tick id
@@ -2770,32 +2795,18 @@ class SentinelClient:
         try:
             with PROF.ledger_owner(self._ledger_name), \
                     PROF.expected_retrace(cause):
-                new_tick = E.make_tick(
-                    new_cfg, donate=True, features=self._features
-                )
+                new_tick = self._make_tick(new_cfg, self._features)
             # pre-compile BOTH batch shapes against a throwaway state while
             # the old engine keeps serving: XLA compiles take seconds, and a
             # window whose budget migrated would legitimately EXPIRE during
             # that gap — compiling first makes the actual swap a few ms of
             # migration math
-            z = jnp.float32(0.0)
             # ledger_owner: the throwaway state re-claims this client's
             # windows/sketch pool entries at the NEW config's sizes — the
             # same shapes the migrated state lands in below
             with PROF.ledger_owner(self._ledger_name):
                 dummy = E.init_state(new_cfg)
-            for bs in {min(256, new_cfg.batch_size), new_cfg.batch_size}:
-                dummy, _ = new_tick(
-                    dummy,
-                    self._rules_dev,
-                    E.empty_acquire(new_cfg, b=bs),
-                    E.empty_complete(
-                        new_cfg, b=min(bs, new_cfg.complete_batch_size)
-                    ),
-                    jnp.int32(self.time.now_ms()),
-                    z,
-                    z,
-                )
+            dummy = self._warm_tick(new_tick, new_cfg, dummy)
             jax.block_until_ready(dummy.concurrency)
             with self._engine_lock:
                 old_cfg = self.cfg
@@ -2881,64 +2892,45 @@ class SentinelClient:
         with self._lock:
             return len(self._acquires)
 
-    def _dev_col(self, field: str, x: np.ndarray, fill) -> Any:
-        """Upload a batch column — or reuse a cached device-resident
-        constant when the column equals ``fill`` everywhere.  Bulk
-        workloads keep most columns constant (prio, ctx ids, pre_verdict,
-        counts of 1), so one equality pass per column (~50 µs at 128K)
-        buys skipping that column's host↔device transfer.  Safe because the
-        tick donates only the engine state, never batch inputs.
+    def _make_tick(self, cfg, features):
+        """The compiled tick this client calls: under packed_wire the
+        one-buffer form ``(state, rules, wire_in)``; else the classic
+        per-column signature, the golden tests' full-upload reference."""
+        return E.make_tick(
+            cfg, donate=True, features=features, wire_in=bool(cfg.packed_wire)
+        )
 
-        Keyed by FIELD, not just (fill, shape): two leaves must never
-        share one device buffer — XLA dedupes identical argument buffers
-        at compile time, and a call whose sharing pattern differs from the
-        compile-time call fails with a buffer-count mismatch.
+    def _upload(self, cfg, wb: WIRE.InputBuffer, t: int, load: float, cpu: float):
+        """Send one built tick input.  Returns the tick's arguments after
+        ``(state, rules)``, the transfers made and the bytes sent.
 
-        Varying columns get the dirty-skip: when the column is
-        bit-identical to the previous tick's upload, the cached device
-        array is reused.  The stored host ref is a PRIVATE COPY of the
-        uploaded column, never the staging buffer itself — staging slots
-        are reused on a parity cycle (and twice per round on paths that
-        tick more than once), so a borrowed ref could be silently
-        overwritten, or even BE the buffer under comparison, by the time
-        the next tick compares against it.  The copy costs one host
-        memcpy per CHANGED column; skipped ticks pay only the compare."""
-        if (x == fill).all():
-            key = (field, float(fill), x.dtype.str, x.shape)
-            c = self._const_cols.get(key)
-            if c is None:
-                c = jnp.asarray(x.copy())  # never the staging slot: see below
-                self._const_cols[key] = c
-                _C_WIRE["tx"].inc(x.nbytes)  # first (only) upload of the const
-                self._ledger_wire()  # cold: new (field, dtype, shape) const
-            # the dirty ref would go stale while const ticks bypass it —
-            # drop it so the next varying tick uploads fresh
-            self._col_last.pop(field, None)
-            return c
-        # the dirty-column delta path is part of the packed transport:
-        # packed_wire=False stays a true FULL-UPLOAD reference client
-        # (the golden tests compare the packed client against it)
-        if self.cfg.packed_wire:
-            prev = self._col_last.get(field)
-            if (
-                prev is not None
-                and prev[0].shape == x.shape
-                and prev[0].dtype == x.dtype
-                and np.array_equal(prev[0], x)
-            ):
-                _C_COLS_SKIPPED.inc()
-                return prev[1]
-        # Upload the private copy, not the staging slot.  jnp.asarray does
-        # NOT always copy: on the CPU backend a 64-byte-aligned host buffer
-        # becomes the device array itself (zero-copy), and on an attached
-        # chip the transfer reads the host buffer asynchronously — either
-        # way a slot rewritten while a pipelined tick is still queued would
-        # change that tick's input.  ``ref`` is never written again.
-        ref = x.copy()
-        dev = jnp.asarray(ref)
-        _C_WIRE["tx"].inc(x.nbytes)
-        self._col_last[field] = (ref, dev)
-        return dev
+        Packed: ONE transfer of the whole buffer, header included, and no
+        copy first — the buffer is its _PendingTick's until the tick has
+        resolved (see _wire_free).  Classic (packed_wire=False): every
+        column and scalar on its own, out of a buffer built for this tick
+        alone and never written again."""
+        if cfg.packed_wire:
+            wb.set_header(t, load, cpu)
+            return (jax.device_put(wb.buf),), 1, wb.buf.nbytes
+        a = E.AcquireBatch(**{f: jnp.asarray(v) for f, v in wb.acq.items()})
+        c = E.CompleteBatch(**{f: jnp.asarray(v) for f, v in wb.comp.items()})
+        nbytes = sum(v.nbytes for v in (*wb.acq.values(), *wb.comp.values()))
+        return (
+            (a, c, jnp.int32(t), jnp.float32(load), jnp.float32(cpu)),
+            len(wb.acq) + len(wb.comp) + 3,
+            nbytes + 12,
+        )
+
+    def _warm_tick(self, new_tick, cfg, state):
+        """Run ``new_tick`` on idle inputs once a tick shape against the
+        throwaway ``state``, so that serving compiles neither."""
+        for b, b2 in dict.fromkeys(WIRE.tick_shapes(cfg)):
+            wb = WIRE.InputBuffer(WIRE.input_layout_for(cfg, b, b2))
+            wb.idle_acquire()
+            wb.idle_complete()
+            args, _puts, _nb = self._upload(cfg, wb, self.time.now_ms(), 0.0, 0.0)
+            state, _ = new_tick(state, self._rules_dev, *args)
+        return state
 
     def _sbuf(self, name: str, shape, dt) -> np.ndarray:
         """Current-parity slot of the two-slot host staging buffer for one
@@ -2952,13 +2944,12 @@ class SentinelClient:
 
     def _ledger_wire(self) -> None:
         """Re-claim the wire pool (obs/profile.LEDGER) after a cold
-        allocation: two-slot host staging buffers plus cached
-        device-resident constant columns.  The dirty-column device copies
-        (_col_last) churn with traffic and are excluded — ledger entries
-        must change only on allocation events, never per tick."""
-        nb = sum(
+        allocation: two-slot host staging buffers plus the input buffers
+        allocated so far (one a failed tick kept stays counted).  Ledger
+        entries change only on allocation events, never per tick."""
+        nb = self._wire_bytes + sum(
             s[0].nbytes + s[1].nbytes for s in self._stage.values()
-        ) + sum(int(c.nbytes) for c in self._const_cols.values())
+        )
         with PROF.ledger_owner(self._ledger_name):
             PROF.LEDGER.set("wire", "client.staging", nb)
 
@@ -2969,6 +2960,22 @@ class SentinelClient:
         if lo is None:
             lo = self._wire_layouts[key] = WIRE.layout_for(cfg, b)
         return lo
+
+    def _input_buffer(self, cfg, b: int, b2: int) -> WIRE.InputBuffer:
+        """The buffer the tick being built writes its input into: under
+        packed_wire one lent from _wire_free (a resolved tick's), else,
+        and for the classic reference path always, a new one."""
+        key = (cfg, b, b2)
+        lo = self._wire_layouts.get(key)
+        if lo is None:
+            lo = self._wire_layouts[key] = WIRE.input_layout_for(cfg, b, b2)
+        if cfg.packed_wire:
+            free = self._wire_free.setdefault(lo, [])
+            if free:
+                return free.pop()
+            self._wire_bytes += lo.nbytes
+            self._ledger_wire()  # cold: one more input buffer
+        return WIRE.InputBuffer(lo)
 
     # -- segment-capacity adaptation ---------------------------------------
 
@@ -3051,21 +3058,9 @@ class SentinelClient:
             new_cfg = dataclasses.replace(self.cfg, seg_u=int(new_u))
             with PROF.ledger_owner(self._ledger_name), \
                     PROF.expected_retrace("segment-resize"):
-                new_tick = E.make_tick(new_cfg, donate=True, features=feats)
-                z = jnp.float32(0.0)
+                new_tick = self._make_tick(new_cfg, feats)
                 dummy = E.init_state(new_cfg)
-            for bs in sorted({min(256, new_cfg.batch_size), new_cfg.batch_size}):
-                dummy, _ = new_tick(
-                    dummy,
-                    self._rules_dev,
-                    E.empty_acquire(new_cfg, b=bs),
-                    E.empty_complete(
-                        new_cfg, b=min(bs, new_cfg.complete_batch_size)
-                    ),
-                    jnp.int32(self.time.now_ms()),
-                    z,
-                    z,
-                )
+            dummy = self._warm_tick(new_tick, new_cfg, dummy)
             jax.block_until_ready(dummy.concurrency)  # stlint: disable=host-sync — blocks on a THROWAWAY warmup state; threaded mode runs this off-loop
             with self._cluster_lock, self._engine_lock:
                 if (
@@ -3257,9 +3252,7 @@ class SentinelClient:
             len(acq) + n_blk + n_front <= 256
             and (comp is None or len(comp[0]) <= 256)
         )
-        B, B2 = cfg.batch_size, cfg.complete_batch_size
-        if light:
-            B, B2 = min(256, B), min(256, B2)
+        B, B2 = WIRE.tick_shapes(cfg)[0 if light else 1]
 
         from sentinel_tpu.ops.engine import _use_fused
 
@@ -3274,6 +3267,11 @@ class SentinelClient:
         # native/ring.presort sorts the live rows only, in linear time,
         # and gathers every column in the same call.
         presort = cfg.seg_effects and clamp
+
+        # the tick's input: every column below is written, once, into a
+        # view of this one buffer (ops/wire.py), which then crosses whole
+        wb = self._input_buffer(cfg, B, B2)
+        va, vc = wb.acq, wb.comp
 
         inv_a = None
         _au_cols = None
@@ -3339,13 +3337,18 @@ class SentinelClient:
                 # the engine lands in the sketch.  The staging buffers
                 # are not reused before observe() runs below this tick.
                 _au_cols = (res_np, cnt_np)
-            prio_np = arr("prio", 0, np.int32, f_prio)
-            oid_np = arr("origin_id", -1, np.int32)
-            onode_np = arr("origin_node", trash, np.int32)
-            cnode_np = arr("ctx_node", trash, np.int32)
-            cname_np = arr("ctx_name", -1, np.int32)
-            inb_np = arr("inbound", 0, np.int32)
-            pre_np = arr("pre_verdict", 0, np.int32)
+            # the columns in the order sx_presort gathers them
+            cols = {
+                "res": res_np,
+                "count": cnt_np,
+                "prio": arr("prio", 0, np.int32, f_prio),
+                "origin_id": arr("origin_id", -1, np.int32),
+                "origin_node": arr("origin_node", trash, np.int32),
+                "ctx_node": arr("ctx_node", trash, np.int32),
+                "ctx_name": arr("ctx_name", -1, np.int32),
+                "inbound": arr("inbound", 0, np.int32),
+                "pre_verdict": arr("pre_verdict", 0, np.int32),
+            }
             ph_np = _ph_cols()
             if presort:
                 _tp = OT.t0()
@@ -3356,25 +3359,24 @@ class SentinelClient:
                 # call (native/ring.presort, with a bit-identical numpy
                 # fallback) sorts the live rows, splices the padding run,
                 # writes the inverse permutation and gathers the columns
-                # into the s.* staging slots.
+                # straight into the input buffer's views; a narrow column
+                # (the gather moves 4-byte rows) goes through an s.* slot
                 _n_a = n + n_blk + n_front
-                src = (res_np, cnt_np, prio_np, oid_np, onode_np, cnode_np,
-                       cname_np, inb_np, pre_np)
-                dst = tuple(
-                    self._sbuf(f"s.{i}", B, np.int32) for i in range(len(src))
-                )
-                ph_dst = self._sbuf("s.ph", (B, M), np.int32)
+                dst = {
+                    f: va[f] if va[f].dtype == np.int32
+                    else self._sbuf("s." + f, B, np.int32)
+                    for f in cols
+                }
                 free = self._inv_free.setdefault(B, [])
                 inv_a = free.pop() if free else np.empty(B, np.int32)
                 _path = RING.presort(
-                    (res_np, cnode_np, onode_np, oid_np, cname_np), _n_a,
+                    tuple(cols[f] for f in _ACQ_SEG_KEYS), _n_a,
                     self._sbuf("s.order", B, np.int32), inv_a,
                     self._sbuf("s.scratch", 2 * max(B, B2), np.uint64),
-                    src, dst, ph_np, ph_dst,
+                    tuple(cols.values()), tuple(dst.values()),
+                    ph_np, va["param_hash"],
                 )
-                (res_np, cnt_np, prio_np, oid_np, onode_np, cnode_np,
-                 cname_np, inb_np, pre_np) = dst
-                ph_np = ph_dst
+                cols, ph_np = dst, va["param_hash"]
                 if _tp:
                     _tp0 = _tp0 or _tp
                     _ns_presort += OT.now_ns() - _tp
@@ -3384,38 +3386,21 @@ class SentinelClient:
                 if B <= 4096 or (self._seg_sample_ctr & 7) == 0:
                     self._note_seg_count(
                         self._host_seg_count(
-                            (res_np, cnode_np, onode_np, oid_np, cname_np)
+                            tuple(cols[f] for f in _ACQ_SEG_KEYS)
                         ),
                         B,
                     )
-            wd_a = WIRE.acquire_wire_dtypes(cfg)
-
-            def _nar(name, key, x, fill):
-                # narrow upload (ops/wire.py): flag / verdict-code /
-                # clamped-count values fit the wire dtype by construction,
-                # so the downcast is exact; the engine widens at tick entry
-                dt = wd_a.get(key)
-                if dt is not None and x.dtype != dt:
-                    nx = self._sbuf("w." + name, x.shape, dt)
-                    np.copyto(nx, x, casting="unsafe")
-                    x = nx
-                return self._dev_col(name, x, fill)
-
-            a = E.AcquireBatch(
-                res=self._dev_col("a.res", res_np, trash),
-                count=_nar("a.count", "count", cnt_np, 1),
-                prio=_nar("a.prio", "prio", prio_np, 0),
-                origin_id=self._dev_col("a.oid", oid_np, -1),
-                origin_node=self._dev_col("a.onode", onode_np, trash),
-                ctx_node=self._dev_col("a.cnode", cnode_np, trash),
-                ctx_name=self._dev_col("a.cname", cname_np, -1),
-                inbound=_nar("a.inb", "inbound", inb_np, 0),
-                param_hash=self._dev_col("a.ph", ph_np, 0),
-                pre_verdict=_nar("a.pre", "pre_verdict", pre_np, 0),
-            )
+            # what the presort did not already put there: the narrow
+            # columns (flag / verdict-code / clamped-count values fit the
+            # wire dtype by construction, so the downcast is exact; the
+            # tick widens at its entry), or every column without a presort
+            for f, x in cols.items():
+                if x is not va[f]:
+                    np.copyto(va[f], x, casting="unsafe")
+            if ph_np is not va["param_hash"]:
+                np.copyto(va["param_hash"], ph_np.T)  # lane by lane
         else:
-            # an idle side rides an empty batch of the tick's shape
-            a = E.empty_acquire(cfg, b=B)
+            wb.idle_acquire()  # an idle side is its fill
         if comp is not None:
             from sentinel_tpu.native.ring import FLAG_INBOUND
 
@@ -3425,15 +3410,18 @@ class SentinelClient:
             if self._adaptive is not None and n:
                 # BBR minRT input: this tick's completion RT floor
                 self._adaptive.signals.note_completions(n, float(rt_a.min()))
+            placed = ()  # the fields whose live rows the presort put in place
             if presort and n > 1:
                 _tp = OT.t0()
                 # completions carry no futures — sorted for good, no unsort
                 # (all completion effects are order-independent sums/minima);
-                # only the aux lanes that become param_hash are carried
+                # only the aux lanes that become param_hash are carried.
+                # The 4-byte columns that cross as they are (the aux lanes
+                # among them) land in their views; the ones narrowed,
+                # clamped or masked on the way (below) go through sc.* slots
                 _n_c = n
                 # (a producer may hand a wider integer column, as
-                # submit_completion_block's flags are: narrowed here, as
-                # pad() below would on the way to the wire anyway)
+                # submit_completion_block's flags are: narrowed here)
                 src = tuple(
                     np.ascontiguousarray(
                         x, np.float32 if x is rt_a else np.int32
@@ -3441,9 +3429,12 @@ class SentinelClient:
                     for x in (res_a, cnt_a, org_a, ctx_a, flags_a, rt_a,
                               err_a, *aux_a[:M])
                 )
-                dst = tuple(
-                    self._sbuf(f"sc.{i}", B2, x.dtype)[:n]
-                    for i, x in enumerate(src)
+                placed = ("res", "origin_node", "ctx_node", "rt", "param_hash")
+                slot = lambda i: self._sbuf(f"sc.{i}", B2, np.int32)[:n]
+                dst = (
+                    vc["res"][:n], slot(1), vc["origin_node"][:n],
+                    vc["ctx_node"][:n], slot(4), vc["rt"][:n], slot(6),
+                    *(vc["param_hash"][k, :n] for k in range(len(src) - 7)),
                 )
                 _path_c = RING.presort(
                     (src[0], src[3], src[2]), n,
@@ -3463,53 +3454,50 @@ class SentinelClient:
                         B2,
                     )
 
-            wd_c = WIRE.complete_wire_dtypes(cfg)
+            # live rows [:n] (unless the presort gathered them in place),
+            # then the tail [n:] filled in place; narrow wire dtypes
+            # downcast exactly (0/1 flags, counts clamped to
+            # max_batch_count, the acquire side's envelope)
+            live = {f: v[..., :n] for f, v in vc.items()}
 
-            def pad(name, a, fill, dt):
-                # staged assembly; narrow wire dtypes downcast exactly
-                # (0/1 flags, counts pre-clamped to max_batch_count)
-                out = self._sbuf(name, B2, dt)
-                out.fill(fill)
-                out[:n] = a
-                return self._dev_col(name, out, fill)
+            def put(f, x):
+                if f not in placed:
+                    np.copyto(live[f], x, casting="unsafe")
 
-            ph_np = self._sbuf("c.ph", (B2, M), np.int32)
-            ph_np.fill(0)
-            for k in range(min(M, len(aux_a))):
-                ph_np[:n, k] = aux_a[k]
-            c = E.CompleteBatch(
-                res=pad("c.res", res_a, trash, np.int32),
-                origin_node=pad("c.onode", org_a, trash, np.int32),
-                ctx_node=pad("c.cnode", ctx_a, trash, np.int32),
-                inbound=pad(
-                    "c.inb",
-                    (flags_a & FLAG_INBOUND),
-                    0,
-                    wd_c.get("inbound", np.int32),
-                ),
-                rt=pad("c.rt", rt_a, 0.0, np.float32),
-                # same max_batch_count envelope as the acquire side
-                success=pad(
-                    "c.succ",
-                    np.minimum(cnt_a, cfg.max_batch_count)
-                    if clamp
-                    else cnt_a,
-                    0,
-                    wd_c.get("success", np.int32),
-                ),
-                error=pad(
-                    "c.err",
-                    np.minimum(err_a, cfg.max_batch_count)
-                    if clamp
-                    else err_a,
-                    0,
-                    wd_c.get("error", np.int32),
-                ),
-                param_hash=self._dev_col("c.ph", ph_np, 0),
+            def put_count(f, x):
+                if clamp:
+                    np.minimum(
+                        x, cfg.max_batch_count, out=live[f], casting="unsafe"
+                    )
+                else:
+                    put(f, x)
+
+            put("res", res_a)
+            put("origin_node", org_a)
+            put("ctx_node", ctx_a)
+            np.bitwise_and(
+                flags_a, FLAG_INBOUND, out=live["inbound"], casting="unsafe"
             )
+            put("rt", rt_a)
+            put_count("success", cnt_a)
+            put_count("error", err_a)
+            lanes = live["param_hash"]  # (M, n): lane by lane
+            for k in range(M):
+                if k >= len(aux_a):
+                    lanes[k] = 0
+                elif "param_hash" not in placed:
+                    np.copyto(lanes[k], aux_a[k], casting="unsafe")
+            for c in wb.layout.comp:
+                vc[c.field][..., n:] = c.fill
         else:
-            c = E.empty_complete(cfg, b=B2)
+            wb.idle_complete()
 
+        load, cpu = self._sys.sample()
+        t = now_ms if now_ms is not None else self.time.now_ms()
+        t += FP.skew_ms(_FP_TICK_CLOCK)  # chaos: deterministic clock skew
+        _t_put = OT.now_ns() if _t_asm else 0
+        batch_args, puts, tx_bytes = self._upload(cfg, wb, t, load, cpu)
+        _C_WIRE["tx"].inc(tx_bytes)
         _t_disp = OT.t0()
         if _t_asm:
             OT.stage_ns(
@@ -3518,7 +3506,12 @@ class SentinelClient:
                 (_t_disp or OT.now_ns()) - _t_asm - _ns_presort,
                 _H_ASSEMBLE,
                 trace=tick_id,
-                attrs={"b": B, "b2": B2},
+                # puts: host-to-device transfers made for this tick's
+                # input; put_ns: the time they held this thread
+                attrs={
+                    "b": B, "b2": B2, "puts": puts, "tx_bytes": tx_bytes,
+                    "put_ns": (_t_disp or OT.now_ns()) - _t_put,
+                },
             )
             if _ns_presort:
                 # path is the acquire side's when it sorted, else the
@@ -3528,9 +3521,6 @@ class SentinelClient:
                     "tick.presort", _tp0, _ns_presort, _H_PRESORT, trace=tick_id,
                     attrs={"n_a": _n_a, "n_c": _n_c, "path": _path},
                 )
-        load, cpu = self._sys.sample()
-        t = now_ms if now_ms is not None else self.time.now_ms()
-        t += FP.skew_ms(_FP_TICK_CLOCK)  # chaos: deterministic clock skew
         self._count_rotations(int(t))
         au = self._audit
         if au is not None:
@@ -3552,13 +3542,7 @@ class SentinelClient:
         with self._engine_lock:
             _t_call = OT.now_ns() if _t_disp else 0
             self._state, out = self._tick(
-                self._state,
-                self._rules_dev,
-                a,
-                c,
-                jnp.int32(t),
-                jnp.float32(load),
-                jnp.float32(cpu),
+                self._state, self._rules_dev, *batch_args
             )
             _call_ns = OT.now_ns() - _t_call if _t_disp else 0
         _disp_done = 0
@@ -3580,6 +3564,7 @@ class SentinelClient:
             n_obj=len(acq),
             n_blk=n_blk,
             wire_lo=self._wire_layout(cfg, B) if cfg.packed_wire else None,
+            wire_in=wb if cfg.packed_wire else None,
             tick_id=tick_id,
             dispatched_ns=_disp_done,
             now_ms=int(t),
@@ -3936,6 +3921,13 @@ class SentinelClient:
             # buffer to a later tick (see _inv_free)
             inv, p.inv_a = p.inv_a, None
             self._inv_free[inv.shape[0]].append(inv)
+        if p.wire_in is not None:
+            # its verdicts are read, so the tick ran and its input's
+            # transfer is over: the buffer is free for a later tick.  (A
+            # tick that fails, by the watchdog or here, never gets this
+            # far: it may still run on the device, and keeps its buffer.)
+            wb, p.wire_in = p.wire_in, None
+            self._wire_free[wb.layout].append(wb)
         if self._adaptive is not None:
             if stats is not None:
                 # device accounting: valid items ARE the real items (all

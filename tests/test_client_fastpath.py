@@ -20,6 +20,7 @@ import pytest
 from sentinel_tpu.core import errors as ERR
 from sentinel_tpu.core.config import small_engine_config
 from sentinel_tpu.core.rules import FlowRule
+from sentinel_tpu.ops import wire as WIRE
 from sentinel_tpu.runtime.client import SentinelClient
 from sentinel_tpu.utils.time_source import TimeSource, VirtualTimeSource
 
@@ -249,39 +250,69 @@ def test_platform_engine_config_detects_backend(monkeypatch):
     assert not (cfg2.use_mxu_tables or cfg2.fused_effects or cfg2.seg_effects)
 
 
-def test_dev_col_never_aliases_the_staging_slot(vt):
-    """jnp.asarray is zero-copy for a 64-byte-aligned host buffer on the
-    CPU backend, so a column uploaded straight from a staging slot would
-    change under a queued (pipelined) tick when the slot is rewritten —
-    chip_smoke's equivalence phase caught exactly that.  Both _dev_col
-    paths must hand the tick a buffer nothing rewrites."""
-    c = _mk(vt)
-    n = c.cfg.batch_size
-    raw = np.empty(n + 16, np.int32)
-    off = (-raw.ctypes.data % 64) // 4
-    slot = raw[off:off + n]  # what a lucky np.empty hands _sbuf
-    assert slot.ctypes.data % 64 == 0
+def test_a_wire_buffer_is_its_ticks_own_until_the_tick_has_resolved(vt):
+    """The tick's input crosses from its host buffer uncopied, and the
+    transfer may read that buffer after the dispatch (on the CPU backend an
+    aligned buffer IS the device array): so with pipeline_depth ticks
+    dispatched and unresolved no buffer is written again, a resolved tick's
+    buffer is the next one lent, and a tick the watchdog failed over, which
+    may still run on the device, never gives its buffer back."""
+    depth = 3
+    c = SentinelClient(
+        cfg=small_engine_config(**SEG), time_source=vt, mode="sync",
+        pipeline_depth=depth,
+    )
+    try:  # never started: the ticks below are driven by hand
+        rng = np.random.default_rng(30)
 
-    slot[:] = np.arange(n)
-    varying = c._dev_col("t.varying", slot, -1)
-    slot[:] = 5
-    const = c._dev_col("t.const", slot, 5)
-    slot[:] = 9  # the slot's next use
-    assert np.array_equal(np.asarray(varying), np.arange(n))
-    assert (np.asarray(const) == 5).all()
-    assert (np.asarray(c._dev_col("t.const", np.full(n, 5, np.int32), 5)) == 5).all()
+        def tick():
+            acq, blocks, comp = _mixed_tick(c.cfg, rng, 4, 30, 30)
+            return c._run_tick(acq, comp, None, blocks=blocks)
+
+        ticks = [tick() for _ in range(depth)]
+        bufs = [p.wire_in for p in ticks]
+        sent = [wb.buf.copy() for wb in bufs]
+        for i, x in enumerate(bufs):
+            assert x.buf.nbytes == x.layout.nbytes
+            assert not any(np.shares_memory(x.buf, y.buf) for y in bufs[:i])
+        # one more while all of them are unresolved: a buffer of its own,
+        # and not a word of theirs was written
+        extra = tick()
+        assert not any(extra.wire_in is wb for wb in bufs)
+        for wb, was in zip(bufs, sent):
+            assert wb.buf.tobytes() == was.tobytes()
+        # resolved: its buffer is the next one lent
+        first = bufs[0]
+        c._resolve_tick(ticks[0])
+        assert ticks[0].wire_in is None
+        assert c._wire_free[first.layout] == [first]
+        again = tick()
+        assert again.wire_in is first and c._wire_free[first.layout] == []
+        # failed over by the watchdog while its resolver is still out: the
+        # resolver comes home later, loses the claim, and the buffer stays
+        # with the tick
+        failed = ticks[1]
+        assert c._claim_tick(failed, "failed")
+        c._fail_tick(failed)
+        c._resolve_tick(failed)
+        assert failed.wire_in is bufs[1]
+        for p in (*ticks[2:], extra, again):
+            c._resolve_tick(p)
+        free = c._wire_free[first.layout]
+        assert bufs[1] not in free and len(free) == depth
+    finally:
+        c.stop()
 
 
 @pytest.mark.jitted  # the POINT: no disable_jit — pin jit-only buffer behavior
 def test_jitted_const_column_cache_and_empty_batches(vt):
-    """ADVICE r5 low #4: the jit-only buffer-dedup failure class (per-leaf
-    empty_acquire buffers, the field-keyed _dev_col constant cache —
-    'Execution supplied N buffers but compiled program expected N+1')
-    only manifests under REAL jit dispatch, which the eager-heavy fixture
-    normally bypasses.  Interleave empty ticks (every column a cached
-    device constant), all-default batches (most columns hit the _dev_col
-    cache), and distinct-value batches (cache misses) through one jitted
-    tick and require exact verdicts throughout."""
+    """ADVICE r5 low #4: the jit-only buffer failure class ('Execution
+    supplied N buffers but compiled program expected N+1', and an input
+    buffer lent again while a tick still reads it) only manifests under
+    REAL jit dispatch, which the eager-heavy fixture normally bypasses.
+    Interleave empty ticks (both sides their fill), all-default batches and
+    distinct-value batches through one jitted tick, every one out of a
+    reused input buffer, and require exact verdicts throughout."""
     c = _mk(vt)
     names = [f"j{i}" for i in range(8)]
     for n in names:
@@ -291,25 +322,25 @@ def test_jitted_const_column_cache_and_empty_batches(vt):
          FlowRule(resource=names[1], count=1000.0)]
     )
 
-    # repeated EMPTY batches: tick_once with nothing queued reuses the
-    # empty_acquire/empty_complete constants call after call
+    # repeated EMPTY batches: tick_once with nothing queued sends both
+    # sides as their fill, call after call
     for _ in range(3):
         c.tick_once()
         vt.advance(10)
 
     for round_ in range(3):
         # all-default columns (count=1, no origin/ctx/params): every
-        # column except res equals its fill -> _dev_col cache round-trips
+        # column except res and count equals its fill
         out = c.check_batch([names[0], names[1], names[2]])
         assert [v for v, _ in out] == [
             ERR.BLOCK_FLOW, ERR.PASS, ERR.PASS,
         ], f"round {round_}"
-        # distinct values force fresh uploads on the same executable
+        # distinct values in the same columns of the same executable
         out2 = c.check_batch(
             [names[1], names[1]], counts=[2, 3], origins=["peer", ""]
         )
         assert [v for v, _ in out2] == [ERR.PASS, ERR.PASS]
-        # back to empty: the cached constants must still be aliasing-safe
+        # back to empty: nothing of the last batch may be left behind
         c.tick_once()
         vt.advance(25)
 
@@ -403,9 +434,12 @@ def test_run_tick_uploads_the_parents_presorted_columns(vt, fill, monkeypatch):
 
     uploaded = {}
 
-    def fake_tick(state, rules, a, cb, *_rest):
-        uploaded["a"], uploaded["c"] = a, cb
-        return state, None  # the device never runs; nothing is resolved
+    def fake_tick(state, rules, wire_in):
+        # what crossed, unpacked as the tick's entry unpacks it (narrow
+        # columns widened to int32; nothing else runs on the device)
+        lo = WIRE.input_layout_of(cfg, wire_in.shape[0])
+        uploaded["a"], uploaded["c"], *_hdr = WIRE.unpack_tick_input(wire_in, lo)
+        return state, None  # nothing is resolved
 
     monkeypatch.setattr(RM, "presort", spy)
     monkeypatch.setattr(c, "_tick", fake_tick)
@@ -418,9 +452,13 @@ def test_run_tick_uploads_the_parents_presorted_columns(vt, fill, monkeypatch):
     a = uploaded["a"]
     got = (a.res, a.count, a.prio, a.origin_id, a.origin_node, a.ctx_node,
            a.ctx_name, a.inbound, a.pre_verdict)
-    for g, want in zip(got, cols):
-        g = np.asarray(g)
-        assert g.tobytes() == want.astype(g.dtype).tobytes()
+    wd = WIRE.acquire_wire_dtypes(cfg)
+    names = ("res", "count", "prio", "origin_id", "origin_node", "ctx_node",
+             "ctx_name", "inbound", "pre_verdict")
+    for name, g, want in zip(names, got, cols):
+        # through the wire dtype and back, as the parent's narrow upload went
+        want = want.astype(wd.get(name, np.int32)).astype(np.int32)
+        assert np.asarray(g).tobytes() == want.tobytes(), name
     assert np.asarray(a.param_hash).tobytes() == ph.tobytes()
     assert int(np.asarray(a.count).max()) == cfg.max_batch_count  # clamped first
 
@@ -453,6 +491,83 @@ def test_run_tick_uploads_the_parents_presorted_columns(vt, fill, monkeypatch):
     )
     for name, w in want.items():
         assert cb[name].tobytes() == w.tobytes(), name
+
+
+class _Door:
+    """A front door's response ring: keeps what the resolver answers."""
+
+    def __init__(self):
+        self.answers = []
+
+    def respond(self, corr, verdicts, waits):
+        self.answers.append((corr.copy(), verdicts.copy(), waits.copy()))
+
+
+_MIXED_B = 96  # small, so that the ticks cost little here; one shape
+
+
+@pytest.fixture(scope="module")
+def mixed_clients():
+    """The packed client and the packed_wire=False reference, same rules,
+    same clock; module-scoped: the second tick runs on the first's state."""
+    from sentinel_tpu.core.rules import CONTROL_RATE_LIMITER
+
+    out = {}
+    for packed in (True, False):
+        # seg_u with room for a full tick's segments: no resize, no recompile
+        c = _mk(VirtualTimeSource(start_ms=1_000), batch_size=_MIXED_B,
+                complete_batch_size=_MIXED_B, seg_u=_MIXED_B, packed_wire=packed)
+        assert c.cfg.packed_wire is packed
+        names = [f"m{i}" for i in range(1, 48)]
+        assert [c.registry.resource_id(n) for n in names] == list(range(1, 48))
+        c.flow_rules.load(
+            [FlowRule(resource=n, count=2.0 + i) for i, n in enumerate(names[:20])]
+            + [FlowRule(resource=names[20], count=5.0, max_queueing_time_ms=2_000,
+                        control_behavior=CONTROL_RATE_LIMITER)]
+        )
+        out[packed] = c
+    return out
+
+
+def _serve_mixed_tick(c, fill: str):
+    """One seeded mixed tick (object requests + array blocks + front-door
+    items + completions) through a client, resolved inline: every verdict
+    and wait it answered, by consumer, in order."""
+    from concurrent.futures import Future
+
+    rng = np.random.default_rng(30)
+    n_obj, n_front = 8, 8
+    n_blk = (_MIXED_B // 3 if fill == "third" else _MIXED_B) - n_obj - n_front
+    acq, blocks, comp = _mixed_tick(c.cfg, rng, n_obj, n_blk, n_blk)
+    for r in acq:
+        r.future = Future()
+    door = _Door()
+    i32 = lambda lo, hi: rng.integers(lo, hi, n_front).astype(np.int32)
+    front = (i32(1, 48), i32(1, 4), i32(0, 2), np.arange(n_front), i32(0, 9), i32(0, 9))
+    p = c._run_tick(acq, comp, None, fronts=[(door, front)], blocks=blocks)
+    assert (p.wire_in is not None) is bool(c.cfg.packed_wire)
+    assert p.out.wait_ms.shape == (_MIXED_B,)
+    c._resolve_tick(p)
+    c.time.advance(300)
+    return (
+        [r.future.result(timeout=0) for r in acq],
+        [(b.verdicts[3:].tolist(), b.waits[3:].tolist()) for b, _o, _t in blocks],
+        [(v.tolist(), w.tolist()) for _c, v, w in door.answers],
+    )
+
+
+@pytest.mark.parametrize("fill", ["third", "full"])
+def test_a_mixed_tick_is_bit_identical_packed_and_classic(mixed_clients, fill):
+    """The one-buffer upload against the packed_wire=False reference client,
+    which sends every column on its own on the classic signature: the same
+    verdicts and waits for every consumer of the same seeded tick."""
+    got = _serve_mixed_tick(mixed_clients[True], fill)
+    want = _serve_mixed_tick(mixed_clients[False], fill)
+    assert got == want
+    objs, blks, doors = got
+    verdicts = {v for v, _w in objs} | {v for vs, _ws in blks for v in vs}
+    assert {int(ERR.PASS), int(ERR.BLOCK_FLOW)} <= verdicts
+    assert len(doors) == 1 and len(doors[0][0]) == 8
 
 
 def test_pipelined_ticks_do_not_share_presort_storage(vt, monkeypatch):
@@ -652,6 +767,38 @@ def test_pipeline_depth_caps_the_unresolved_ticks(monkeypatch):
     want, _w = inline.submit_block(items).result(timeout=60)
     assert verdicts.tolist() == want.tolist()
     assert ERR.BLOCK_SYSTEM not in set(verdicts.tolist())
+
+
+@pytest.mark.parametrize("fill", ["part", "full"])
+def test_a_part_filled_tick_goes_behind_one_unresolved_tick_at_most(vt, monkeypatch, fill):
+    """Before its drain the tick thread waits until a dispatch would leave at
+    most pipeline_depth ticks unresolved; and a tick that would not be full,
+    which costs the device as much as a full one, until it goes behind at
+    most ONE unresolved tick (one running, one queued: the device cannot go
+    idle, and a third would only wait there).  A depth under 2 stays the cap."""
+    from sentinel_tpu.runtime.client import ArrayBlock
+
+    waits = []
+    for depth in (4, 2, 1):
+        c = SentinelClient(
+            cfg=small_engine_config(**SEG), time_source=vt, mode="sync",
+            pipeline_depth=depth,
+        )
+        n = c.cfg.batch_size if fill == "full" else c.cfg.batch_size - 1
+        c._acq_blocks.append(ArrayBlock(res=np.ones(n + 5, np.int32), taken=5))
+        monkeypatch.setattr(
+            c, "_await_resolved", lambda k, why, d=depth: waits.append((d, k, why["why"]))
+        )
+        for unresolved in range(depth + 1):
+            c._pending_ticks = [
+                SimpleNamespace(settled=threading.Event()) for _ in range(unresolved)
+            ]
+            c._await_room()
+    cap4 = 4 if fill == "full" else 2
+    assert waits == (
+        [(4, u - cap4 + 1, "depth") for u in range(cap4, 5)]
+        + [(2, 1, "depth"), (1, 1, "depth")]
+    )
 
 
 def test_the_watchdog_releases_the_cap_and_the_loop_moves_on(monkeypatch):

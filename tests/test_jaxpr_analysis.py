@@ -164,6 +164,18 @@ def test_transfer_guard_flags_readbacks_outside_fused_wire():
     assert any("no fused 'wire' buffer" in m for m in msgs)
     assert any("'verdict'" in m for m in msgs)
 
+    # ...and so is a client-facing program that takes its batch as columns
+    # again: every leaf after (state, rules) is an upload a tick
+    e = _entry(
+        lambda x: x + 1,
+        jnp.zeros((4,), jnp.float32),
+        packed_wire=True,
+        readback_fields=("wait_ms", "seg_dropped", "wire"),
+        client_inputs=(7, 21),
+    )
+    (got,) = list(TransferGuardPass().run(e))
+    assert "7 arguments with 21 batch-input leaves" in got.message
+
 
 def test_transfer_guard_packed_allowance_is_clean():
     e = _entry(
@@ -171,6 +183,7 @@ def test_transfer_guard_packed_allowance_is_clean():
         jnp.zeros((4,), jnp.float32),
         packed_wire=True,
         readback_fields=("wait_ms", "seg_dropped", "wire"),
+        client_inputs=(3, 1),
     )
     assert list(TransferGuardPass().run(e)) == []
 
@@ -186,6 +199,12 @@ def test_packed_wire_entry_readback_surface_is_fused():
     assert e.packed_wire and e.readback_fields is not None
     assert "wire" in e.readback_fields
     assert set(e.readback_fields) <= {"wire", "wait_ms", "seg_dropped"}
+    # ...and one upload: the program the packed client calls takes state,
+    # rules and one input buffer, and is fingerprinted under its own name
+    assert e.client_inputs == (3, 1)
+    assert len(ents["tick/wire-in"].closed_jaxpr.jaxpr.invars) == (
+        len(e.closed_jaxpr.jaxpr.invars) - 21 + 1
+    )
     # the classic entries keep the multi-array form and are not gated
     assert ents["tick/plain"].packed_wire is False
 

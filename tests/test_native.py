@@ -243,7 +243,7 @@ def test_presort_matches_lexsort_and_take(B, n, kind, m, force_fallback, monkeyp
 
     order, inv = np.empty(B, np.int32), np.empty(B, np.int32)
     dst = [np.empty_like(x) for x in cols]
-    wide_dst = np.empty_like(wide)
+    wide_dst = np.empty(wide.shape[::-1], np.int32)  # lane by lane
     if force_fallback:
         monkeypatch.setattr(RM, "load_native", lambda: None)
     path = RM.presort(
@@ -254,7 +254,7 @@ def test_presort_matches_lexsort_and_take(B, n, kind, m, force_fallback, monkeyp
     assert inv.tobytes() == want_inv.tobytes()
     for got, want in zip(dst, want_cols):
         assert got.tobytes() == want.tobytes()
-    assert wide_dst.tobytes() == want_wide.tobytes()
+    assert wide_dst.tobytes() == np.ascontiguousarray(want_wide.T).tobytes()
     if kind == "served" and 255 <= n < B:
         # the run of padding rows sits inside the order, not at its end
         at = int(inv[n])

@@ -1,9 +1,10 @@
 """Packed host↔device wire format (ops/wire.py) and the client's fused
-readback / delta-upload path: codec round-trips bit-exactly for every
-verdict code, padding rows and the PASS_WAIT sidecar (incl. overflow);
-the packed engine tick and the packed client are bit-identical to the
-unpacked reference on the same traffic; delta uploads never change
-verdicts; and a mangled fused readback fails the tick CLOSED."""
+readback / one-buffer upload path: codec round-trips bit-exactly for
+every verdict code, padding rows and the PASS_WAIT sidecar (incl.
+overflow); the input wire unpacks on the device to the batches the classic
+path builds; the packed engine tick and the packed client are
+bit-identical to the unpacked reference on the same traffic; a packed tick
+makes one upload; and a mangled fused readback fails the tick CLOSED."""
 
 from __future__ import annotations
 
@@ -211,7 +212,7 @@ def test_empty_batch_dtypes_match_wire_uploads():
         assert np.dtype(getattr(comp, f).dtype) == want, f
 
 
-# -- client path: packed vs reference, delta uploads, fail-closed ------------
+# -- client path: packed vs reference, one upload, fail-closed ---------------
 
 
 def _drive(c, rules, rounds=6):
@@ -235,7 +236,7 @@ def _drive(c, rules, rounds=6):
 
 
 def test_packed_client_bit_identical_to_reference_client(client_factory, vt):
-    """The packed client (fused readback + narrow/delta uploads) must
+    """The packed client (fused readback + one narrow-column upload) must
     produce bit-identical verdicts and waits to a packed_wire=False
     reference client over identical traffic."""
     from sentinel_tpu.utils.time_source import VirtualTimeSource
@@ -262,33 +263,145 @@ def test_packed_client_bit_identical_to_reference_client(client_factory, vt):
     assert any(v == ERR.PASS_WAIT and w > 0 for v, w in ref)
 
 
-def test_client_defaults_to_packed_and_delta_skips_clean_columns(client):
+def test_client_defaults_to_packed_and_makes_one_upload_a_tick(client, monkeypatch):
     """Tri-state default: the client resolves packed_wire=None to True.
-    Repeating identical traffic must skip re-uploading unchanged columns
-    (delta path) without changing verdicts; changed traffic must not be
-    served from the stale cache."""
+    A packed tick makes exactly one host-to-device transfer, of exactly
+    its input layout's bytes, says so on tick.assemble and counts it; and
+    changed traffic changes the verdicts (every tick crosses whole: there
+    is no cache a stale column could be served from)."""
+    from sentinel_tpu import obs
+
     assert client.cfg.packed_wire is True
     client.flow_rules.load([FlowRule(resource="delta/r", count=4.0)])
     names = ["delta/r"] * 6
     first = client.check_batch(names, inbound=True)
-    skip0 = _metric("sentinel_wire_cols_skipped_total")
-    tx0 = _metric(
-        "sentinel_wire_bytes_total", path="device", direction="tx"
-    )
-    second = client.check_batch(names, inbound=True)
-    assert _metric("sentinel_wire_cols_skipped_total") > skip0
-    # identical traffic, fewer uploaded bytes than a full-column tick
-    assert [v for v, _ in second].count(int(ERR.PASS)) == 0  # window used up
-    assert len(first) == len(second) == 6
-    # now CHANGE one column the delta path previously skipped — the
-    # verdicts must track the new traffic, proving no stale device reuse
-    client.time.advance(client.cfg.second_window_ms * client.cfg.second_sample_count + 10)
-    third = client.check_batch(["delta/r"] * 2 + ["delta/other"] * 4)
-    assert len(third) == 6
-    assert [v for v, _ in third][:2] == [int(ERR.PASS)] * 2
+
+    sent = []  # every numpy array handed to the device while spying
+
+    def spy(real):
+        def f(x, *a, **kw):
+            if isinstance(x, np.ndarray):
+                sent.append(x.nbytes)
+            return real(x, *a, **kw)
+        return f
+
+    monkeypatch.setattr(jax, "device_put", spy(jax.device_put))
+    monkeypatch.setattr(jnp, "asarray", spy(jnp.asarray))
+    tx0 = _metric("sentinel_wire_bytes_total", path="device", direction="tx")
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        second = client.check_batch(names, inbound=True)
+    finally:
+        obs.disable()
+        monkeypatch.undo()
+    b, b2 = WIRE.tick_shapes(client.cfg)[0]  # six items: the light shape
+    nbytes = WIRE.input_layout_for(client.cfg, b, b2).nbytes
+    assert sent == [nbytes]
     assert _metric(
         "sentinel_wire_bytes_total", path="device", direction="tx"
-    ) > tx0
+    ) - tx0 == nbytes
+    spans = obs.TRACER.snapshot()
+    (asm,) = [s for s in spans if s["name"] == "tick.assemble"]
+    assert asm["attrs"]["puts"] == 1 and asm["attrs"]["tx_bytes"] == nbytes
+    # counted where the benchmark's span_summary prints it
+    assert obs.summarize(spans)["tick.assemble"]["puts"] == {1: 1}
+    # identical traffic, window used up
+    assert [v for v, _ in second].count(int(ERR.PASS)) == 0
+    assert len(first) == len(second) == 6
+    # changed traffic: the verdicts track it
+    client.time.advance(client.cfg.second_window_ms * client.cfg.second_sample_count + 10)
+    third = client.check_batch(["delta/r"] * 2 + ["delta/other"] * 4)
+    assert [v for v, _ in third] == [int(ERR.PASS)] * 6
+
+
+# -- the input wire: host pack -> device unpack ------------------------------
+
+_INPUT_CASES = {
+    # name: (engine overrides, the dtype the clamped counts cross at)
+    "uint8-counts": (dict(param_dims=1, max_batch_count=255), np.uint8),
+    "int16-counts": (dict(param_dims=2, max_batch_count=0x7FFF), np.int16),
+    "int32-counts": (dict(param_dims=3, max_batch_count=70000), np.int32),
+    "unfused": (dict(param_dims=4, fused_effects=False, seg_effects=False), np.int32),
+}
+
+
+@pytest.mark.parametrize("shape", ["light", "full"])
+@pytest.mark.parametrize("case", sorted(_INPUT_CASES))
+def test_input_wire_round_trip(case, shape):
+    """Host pack -> unpack_tick_input on the device gives, leaf for leaf
+    (values and dtypes), the batches the classic path builds from the same
+    narrow columns and widens at the tick's entry; the header's now_ms,
+    load and cpu come back exact; an idle side is its fill, each way."""
+    over, count_dt = _INPUT_CASES[case]
+    cfg = small_engine_config(**{
+        **dict(packed_wire=True, use_mxu_tables=True, fused_effects=True,
+               seg_effects=True, batch_size=300, complete_batch_size=280),
+        **over,
+    })
+    assert WIRE._count_dtype(cfg) is count_dt
+    b, b2 = WIRE.tick_shapes(cfg)[shape == "full"]
+    lo = WIRE.input_layout_for(cfg, b, b2)
+    assert WIRE.input_layout_of(cfg, lo.total) == lo
+    assert [c.field for c in lo.acq] == list(E.AcquireBatch._fields)
+    assert [c.field for c in lo.comp] == list(E.CompleteBatch._fields)
+    rng = np.random.default_rng(30)
+
+    def column(c):  # a batch column, at its wire dtype
+        if c.dtype == np.float32:
+            return rng.random(c.shape).astype(np.float32) * 100
+        info = np.iinfo(c.dtype)
+        return rng.integers(
+            max(info.min, -(2**31)), min(info.max, 2**31 - 1), c.shape,
+            dtype=np.int64, endpoint=True,
+        ).astype(c.dtype)
+
+    def host(x):  # as the wire carries it: a 2-D column lane by lane
+        return x.T if x.ndim == 2 else x
+
+    unpack = jax.jit(WIRE.unpack_tick_input, static_argnums=1)
+    for idle in (None, "acq", "comp"):
+        wb = WIRE.InputBuffer(lo)
+        want_a = {c.field: column(c) for c in lo.acq}
+        want_c = {c.field: column(c) for c in lo.comp}
+        if idle == "acq":
+            wb.idle_acquire()
+            want_a = {c.field: np.full(c.shape, c.fill, c.dtype) for c in lo.acq}
+        else:
+            for f, x in want_a.items():
+                wb.acq[f][...] = host(x)
+        if idle == "comp":
+            wb.idle_complete()
+            want_c = {c.field: np.full(c.shape, c.fill, c.dtype) for c in lo.comp}
+        else:
+            for f, x in want_c.items():
+                wb.comp[f][...] = host(x)
+        now, load, cpu = int(rng.integers(-(2**31), 2**31)), 0.1 + rng.random(), rng.random()
+        wb.set_header(now, load, cpu)
+        assert wb.buf.nbytes == lo.nbytes and int(wb.buf[0]) == WIRE.WIRE_IN_MAGIC
+        got_a, got_c, t, ld, cp = unpack(jnp.asarray(wb.buf), lo)
+        ref_a = WIRE.widen_acquire(
+            E.AcquireBatch(**{f: jnp.asarray(x) for f, x in want_a.items()})
+        )
+        ref_c = WIRE.widen_complete(
+            E.CompleteBatch(**{f: jnp.asarray(x) for f, x in want_c.items()})
+        )
+        for got, ref in ((got_a, ref_a), (got_c, ref_c)):
+            for f in ref._fields:
+                g, r = getattr(got, f), getattr(ref, f)
+                assert g.dtype == r.dtype and g.shape == r.shape, (f, idle)
+                assert np.asarray(g).tobytes() == np.asarray(r).tobytes(), (f, idle)
+        assert t.dtype == jnp.int32 and int(t) == now
+        assert ld.dtype == jnp.float32 and float(ld) == float(np.float32(load))
+        assert cp.dtype == jnp.float32 and float(cp) == float(np.float32(cpu))
+        if idle == "acq":
+            ref = E.empty_acquire(cfg, b=b)
+            for f in ref._fields:
+                assert np.array_equal(np.asarray(getattr(got_a, f)), np.asarray(getattr(ref, f)))
+        if idle == "comp":
+            ref = E.empty_complete(cfg, b=b2)
+            for f in ref._fields:
+                assert np.array_equal(np.asarray(getattr(got_c, f)), np.asarray(getattr(ref, f)))
 
 
 def test_corrupt_fused_readback_fails_tick_closed(client_factory):
