@@ -820,7 +820,10 @@ def invalidate_cache() -> None:
 
 # -- tier-1 consumption: locks held at function entry ------------------------
 
-_MOD_ENTRY_CACHE: Dict[int, Dict[str, FrozenSet[str]]] = {}
+#: keyed by ``id(mod.tree)``; an entry keeps its tree, so that the id cannot
+#: pass to another module's tree while the entry stands (a freed tree's id
+#: is handed out again, and a helper then read another module's locks)
+_MOD_ENTRY_CACHE: Dict[int, Tuple[ast.AST, Dict[str, FrozenSet[str]]]] = {}
 
 
 def module_entry_locks(mod: ParsedModule) -> Dict[str, FrozenSet[str]]:
@@ -838,8 +841,9 @@ def module_entry_locks(mod: ParsedModule) -> Dict[str, FrozenSet[str]]:
     """
     cid = id(mod.tree)
     with _CACHE_LOCK:
-        if cid in _MOD_ENTRY_CACHE:
-            return _MOD_ENTRY_CACHE[cid]
+        hit = _MOD_ENTRY_CACHE.get(cid)
+        if hit is not None and hit[0] is mod.tree:
+            return hit[1]
     # build a throwaway single-module DB in SOURCE-name space: identity
     # canonicalizer keeps `self._lock` / `_LOCK` spelled as written, so
     # the result intersects directly with tier-1 site locksets
@@ -889,5 +893,5 @@ def module_entry_locks(mod: ParsedModule) -> Dict[str, FrozenSet[str]]:
             )
     out = {n: ls for n, ls in out.items() if ls}
     with _CACHE_LOCK:
-        _MOD_ENTRY_CACHE[cid] = out
+        _MOD_ENTRY_CACHE[cid] = (mod.tree, out)
     return out

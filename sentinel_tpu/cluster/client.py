@@ -92,9 +92,13 @@ class ClusterTokenClient(TokenService):
         timeout_ms: int = C.DEFAULT_REQUEST_TIMEOUT_MS,
         reconnect_interval_s: float = 2.0,
         reconnect_backoff_cap_s: float = 30.0,
+        shard: Optional[str] = None,
     ):
         self.host = host
         self.port = port
+        #: the ring member this connection leads to, where it is one of a
+        #: fleet's (``ShardedTokenClient``): an attr of its cluster.rpc spans
+        self.shard = shard
         self.namespace = namespace
         self.timeout_ms = timeout_ms
         self.reconnect_interval_s = reconnect_interval_s
@@ -340,6 +344,8 @@ class ClusterTokenClient(TokenService):
             req.trace_id = tid
             req.span_id = OT.new_span_id()
             _attrs = {"type": req.type, "span_id": req.span_id}
+            if self.shard is not None:
+                _attrs["shard"] = self.shard
             if parent:
                 _attrs["parent"] = parent
         try:
@@ -468,6 +474,8 @@ class ClusterTokenClient(TokenService):
             req.trace_id = tid
             req.span_id = OT.new_span_id()
             _attrs = {"type": C.MSG_TYPE_BATCH, "n": n, "span_id": req.span_id}
+            if self.shard is not None:
+                _attrs["shard"] = self.shard
             if parent:
                 _attrs["parent"] = parent
         try:

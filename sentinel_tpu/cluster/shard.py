@@ -263,6 +263,7 @@ class ShardedTokenClient(TokenService):
                 namespace=namespace,
                 timeout_ms=timeout_ms,
                 reconnect_interval_s=reconnect_interval_s,
+                shard=name,
             )
             self._shards[name] = _ShardState(name, cli)
         self._rule_counts: Dict[int, float] = {}
@@ -318,6 +319,12 @@ class ShardedTokenClient(TokenService):
     def shard_degraded(self, name: str) -> bool:
         return self._shards[name].degraded_active
 
+    def set_timeout_ms(self, timeout_ms: int) -> None:
+        """How long a call waits for a shard's answer from now on (every
+        shard's ``ClusterTokenClient.timeout_ms``, read at each call)."""
+        for st in self._shards.values():
+            st.client.timeout_ms = int(timeout_ms)
+
     def describe(self) -> dict:
         now = mono_s()
         with self._rules_lock:
@@ -337,6 +344,10 @@ class ShardedTokenClient(TokenService):
                     "name": st.name,
                     "addr": f"{st.client.host}:{st.client.port}",
                     "connected": st.client.connected,
+                    # the wire protocol negotiated on this connection (1
+                    # until it is open; batch frames need 2)
+                    "protocol": st.client.peer_version,
+                    "timeout_ms": st.client.timeout_ms,
                     "degraded": st.degraded_active,
                     "cooldown_remaining_s": round(
                         max(st.degraded_until - now, 0.0), 3
@@ -990,10 +1001,13 @@ class ShardFleet:
     engine client behind its own TCP ``ClusterTokenServer``;
     ``client_factory`` builds the decision engines (tests pass their
     fixture factory — identical configs share the XLA compile cache, so
-    N shards cost one compile).  ``kill``/``rejoin`` stop and restart a
-    shard's server on its original port, the fleet-level fault the chaos
-    ``shard_failover`` scenario and the bench failover-blip measurement
-    drive."""
+    N shards cost one compile).  ``devices`` gives each shard's token
+    column a chip of its own, one device per shard in ``names`` order
+    (``None``: every column on JAX's default device); the decision
+    engines stay where ``client_factory`` builds them.  ``kill``/``rejoin``
+    stop and restart a shard's server on its original port, the
+    fleet-level fault the chaos ``shard_failover`` scenario and the bench
+    failover-blip measurement drive."""
 
     def __init__(
         self,
@@ -1003,17 +1017,22 @@ class ShardFleet:
         host: str = "127.0.0.1",
         lease_ttl_ms: int = C.DEFAULT_LEASE_TTL_MS,
         warm: bool = True,
+        devices: Optional[Sequence] = None,
         **sharded_kw,
     ):
         from sentinel_tpu.cluster.server import ClusterTokenServer
         from sentinel_tpu.cluster.token_service import DefaultTokenService
 
         self.names: List[str] = list(names or (f"shard-{i}" for i in range(n_shards)))
+        if devices is not None and len(devices) != len(self.names):
+            raise ValueError(
+                f"{len(devices)} devices for {len(self.names)} shards: one a shard"
+            )
         self.services: Dict[str, DefaultTokenService] = {}
         self.servers: Dict[str, Optional[ClusterTokenServer]] = {}
         members: Dict[str, Tuple[str, int]] = {}
         try:
-            for name in self.names:
+            for k, name in enumerate(self.names):
                 decision = client_factory()
                 if warm:
                     # pay the decision engine's first-tick XLA compile NOW,
@@ -1026,7 +1045,12 @@ class ShardFleet:
                     f = decision.submit_acquire(f"shard/warm/{name}")
                     if f is not None:
                         f.result(timeout=120.0)
-                svc = DefaultTokenService(decision, lease_ttl_ms=lease_ttl_ms)
+                svc = DefaultTokenService(
+                    decision,
+                    lease_ttl_ms=lease_ttl_ms,
+                    device=None if devices is None else devices[k],
+                    shard=name,
+                )
                 server = ClusterTokenServer(svc, host=host, port=0)
                 server.start()
                 self.services[name] = svc

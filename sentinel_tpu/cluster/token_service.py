@@ -265,7 +265,7 @@ class TokenColumnBatcher:
     #: window is updated between chunks)
     CAPACITY = 256
 
-    def __init__(self, service: "DefaultTokenService"):
+    def __init__(self, service: "DefaultTokenService", device=None):
         # lazy heavyweight imports: the cluster codec/client modules must
         # stay importable without dragging jax in
         from sentinel_tpu.native import ring as NR
@@ -286,7 +286,7 @@ class TokenColumnBatcher:
         self._tl_rids: Dict[int, int] = {}
         self._q_lock = threading.Lock()
         self._cv = threading.Condition(self._q_lock)
-        self._pending: List[tuple] = []  # (flow_id, units, partial, Future)
+        self._pending: List[tuple] = []  # (flow_id, units, partial, forced, submit ns, Future)
         self._s_lock = threading.Lock()  # slots + device state
         self._slots: Dict[int, int] = {}
         self._free: List[int] = []
@@ -296,7 +296,14 @@ class TokenColumnBatcher:
         # the worker thread reads it lock-free)
         self._limits_by_fid: Dict[int, float] = {}
         self._cap = 8
-        self._state = TC.init_state(self._cap)
+        #: the device this column's ledger lives on (a shard's own chip);
+        #: None = wherever JAX puts an uncommitted array.  Only the state
+        #: is ever committed: a call's inputs are host arrays and follow it
+        self.device = device
+        #: entries this column has decided (the process-wide
+        #: sentinel_cluster_batched_decisions_total, for this column alone)
+        self.decided = 0
+        self._state = self._put(TC.init_state(self._cap))
         # memory ledger (obs/profile.py): token-column device state under
         # a per-batcher owner so close() releases exactly this claim
         self._ledger_name = f"tokencol:{id(self):x}"
@@ -308,6 +315,13 @@ class TokenColumnBatcher:
             target=self._run, name="sentinel-token-col", daemon=True
         )
         self._worker.start()
+
+    def _put(self, x):
+        """``x`` (an array or a pytree of them) as device arrays, committed
+        to this column's device where it has one."""
+        import jax
+
+        return jax.device_put(x, self.device)
 
     def pending_entries(self) -> int:
         return len(self._pending)
@@ -326,7 +340,8 @@ class TokenColumnBatcher:
             if self._closed:
                 f.set_exception(RuntimeError("token column batcher closed"))
                 return f
-            self._pending.append((flow_id, units, partial, forced, f))
+            # OT.t0(): the submit instant for token.col.queue, 0 when off
+            self._pending.append((flow_id, units, partial, forced, OT.t0(), f))
             self._cv.notify()
         return f
 
@@ -363,8 +378,6 @@ class TokenColumnBatcher:
         """Rebuild slot map + per-slot limits from a rule/census push.
         Retained flows keep their slot AND their standing window ledger;
         recycled and grown rows start zeroed."""
-        import jax.numpy as jnp
-
         TC = self._TC
         W = TC.W
         with self._s_lock:
@@ -412,13 +425,13 @@ class TokenColumnBatcher:
                     run_rt[zero_rows] = 0.0
                     run_rt_min[zero_rows] = W.RT_MIN_INIT
                 win = W.WindowState(
-                    counts=jnp.asarray(counts),
-                    rt_sum=jnp.asarray(rt_sum),
-                    rt_min=jnp.asarray(rt_min),
+                    counts=self._put(counts),
+                    rt_sum=self._put(rt_sum),
+                    rt_min=self._put(rt_min),
                     epochs=self._state.win.epochs,
-                    run=jnp.asarray(run),
-                    run_rt=jnp.asarray(run_rt),
-                    run_rt_min=jnp.asarray(run_rt_min),
+                    run=self._put(run),
+                    run_rt=self._put(run_rt),
+                    run_rt_min=self._put(run_rt_min),
                     rot_wid=self._state.win.rot_wid,
                 )
                 grew = cap != self._cap
@@ -432,7 +445,7 @@ class TokenColumnBatcher:
             for fid, thr in thresholds.items():
                 limits[self._slots[fid]] = thr
             self._limits_by_fid = dict(thresholds)
-            self._state = TC.set_limits(self._state, jnp.asarray(limits))
+            self._state = TC.set_limits(self._state, self._put(limits))
             if grew:
                 # rule pushes pay the new shape's compile, requests don't
                 self._warm_locked()
@@ -463,11 +476,18 @@ class TokenColumnBatcher:
 
     def _decide_chunk(self, chunk: List[tuple], now: int) -> None:
         n = len(chunk)
+        # token.col: this chunk taken -> its granted units on the host;
+        # token.col.queue: each entry's submit() -> taken.  Off, one flag check
+        _t = OT.t0()
+        if _t:
+            for *_, queued, _f in chunk:
+                if queued:
+                    OT.stage_ns("token.col.queue", queued, _t - queued)
         raw_slots = np.zeros(n, np.int32)
         raw_units = np.zeros(n, np.int32)
         raw_partial = np.zeros(n, bool)
         raw_forced = np.zeros(n, bool)
-        for i, (fid, u, p, fo, _f) in enumerate(chunk):
+        for i, (fid, u, p, fo, _q, _f) in enumerate(chunk):
             s = self._slots.get(fid, -1)
             if s >= 0 and u > 0:
                 raw_slots[i] = s
@@ -491,17 +511,30 @@ class TokenColumnBatcher:
             heads[:n] = np.maximum.accumulate(
                 np.where(newseg, np.arange(n), 0)
             ).astype(np.int32)
+        _t_call = OT.now_ns() if _t else 0
         g, obs, self._state = self._decide(
             self._state, np.int32(now), slots, units, heads, partial, forced
         )
+        _t_read = OT.now_ns() if _t else 0
+        g, obs = np.asarray(g), np.asarray(obs)  # the one blocking read-back
+        if _t:
+            # the jit call, then the read-back, then the span's end with
+            # nothing between: each is an interval a reader can place
+            _t_end = OT.now_ns()
+            OT.stage_ns(
+                "token.col", _t, _t_end - _t,
+                attrs={"n": n, "shard": self.svc.shard,
+                       "call_ns": _t_read - _t_call, "read_ns": _t_end - _t_read},
+            )
         granted = np.empty(n, np.int32)
-        granted[order] = np.asarray(g)[:n]
+        granted[order] = g[:n]
         observed = np.empty(n, np.float32)
-        observed[order] = np.asarray(obs)[:n]
+        observed[order] = obs[:n]
         _C_BATCHED.inc(n)
+        self.decided += n  # the worker thread alone writes it
         self._note_timeline(chunk, granted, now)
         lims = self._limits_by_fid
-        for i, (fid, _u, _p, _fo, f) in enumerate(chunk):
+        for i, (fid, _u, _p, _fo, _q, f) in enumerate(chunk):
             if not f.done():
                 f.set_result(
                     (int(granted[i]), float(observed[i]), lims.get(fid, 0.0))
@@ -525,7 +558,7 @@ class TokenColumnBatcher:
             # cumulative rows; only the open window needs an accumulator
             self._tl_wid = wid
             self._tl_acc.clear()
-        for i, (fid, u, p, fo, _f) in enumerate(chunk):
+        for i, (fid, u, p, fo, _q, _f) in enumerate(chunk):
             rid = self._tl_rids.get(fid)
             if rid is None:
                 rid = self.svc.client.registry.resource_id(flow_resource(fid))
@@ -561,6 +594,11 @@ class DefaultTokenService(TokenService):
     one (engine occupy-ahead, DefaultController.tryOccupyNext) and surface as
     STATUS_SHOULD_WAIT with the wait until that bucket starts — the client
     sleeps and enters, matching TokenResultStatus.SHOULD_WAIT semantics.
+
+    ``device`` is the chip the token column's ledger lives on (a shard's
+    own; ``None`` = JAX's default).  The decision client is not moved.
+    ``shard`` is the ring member this service answers for, where it is one
+    of a fleet's (``ShardFleet``): it labels the column's ``token.col`` spans.
     """
 
     def __init__(
@@ -571,14 +609,17 @@ class DefaultTokenService(TokenService):
         concurrent_ttl_ms: int = 5000,
         lease_ttl_ms: int = C.DEFAULT_LEASE_TTL_MS,
         use_token_column: bool = True,
+        device=None,
+        shard: str = "",
     ):
         self.client = decision_client
+        self.shard = shard
         self.lease_ttl_ms = lease_ttl_ms
         self.config = config or ClusterServerConfigManager()
         self.connected_count_fn = connected_count_fn or (lambda ns: 1)
         # device column batcher first: _reproject (fired by every rule
         # push below) projects thresholds into it
-        self.col = TokenColumnBatcher(self) if use_token_column else None
+        self.col = TokenColumnBatcher(self, device) if use_token_column else None
         self.flow_rules = ClusterFlowRuleManager(on_change=self._reproject)
         self.param_rules = ClusterParamFlowRuleManager(on_change=self._reproject)
         self.limiter = GlobalRequestLimiter(self.config)

@@ -118,10 +118,19 @@ def set_limits(state: TokenColState, limits: jax.Array) -> TokenColState:
     return TokenColState(win=state.win, limits=limits.astype(jnp.float32))
 
 
+#: the jitted column's program name (``jit_sentinel_token_col`` in a trace)
+COLUMN_PROGRAM = "sentinel_token_col"
+
+
 @functools.lru_cache(maxsize=None)
 def jitted_decide(cfg: W.WindowConfig = DEFAULT_CFG):
     """Process-shared jitted decide_batch for one window config — every
     TokenColumnBatcher instance reuses the same compiled executables
-    (keyed by shape), so a test suite constructing many services pays
-    XLA compilation once per (slots, batch) shape, not per service."""
-    return jax.jit(functools.partial(decide_batch, cfg=cfg))
+    (keyed by shape and by the device the state is committed to), so a
+    test suite constructing many services pays XLA compilation once per
+    (slots, batch) shape, not per service."""
+    fn = functools.partial(decide_batch, cfg=cfg)
+    # a bare partial compiles as ``jit__unknown``: the name is what a
+    # profiler trace lists the column's program under, on every chip
+    fn.__name__ = COLUMN_PROGRAM
+    return jax.jit(fn)

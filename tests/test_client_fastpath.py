@@ -824,7 +824,10 @@ def test_the_watchdog_releases_the_cap_and_the_loop_moves_on(monkeypatch):
             assert fired.value == before + n_ticks
             gate.open.set()
             assert _drained(c).ok
-        # the loop is sound afterwards
+        # the loop is sound afterwards; the stalls are over, so the watchdog
+        # gets the patience a sound tick needs on a starved host (read at
+        # every dispatch), not the 0.2 s that made it fire above
+        c.watchdog_timeout_s = 30.0
         ok, _w = c.submit_block(ids[1:]).result(timeout=60)
         assert set(ok.tolist()) == {int(ERR.PASS)}
         assert _drained(c).ok

@@ -481,6 +481,35 @@ def test_unguarded_global_single_guarded_site_never_mismatches():
     assert _run(UnguardedGlobalPass(), mod) == []
 
 
+def test_unguarded_global_entry_locks_are_never_another_trees():
+    """The helper-inherits-its-callers'-lock cache is keyed by the tree's
+    id, and a freed tree's id is handed out again: an entry answers only for
+    the tree it was computed from."""
+    from sentinel_tpu.analysis.concurrency import summaries
+
+    mod = _mod(
+        """
+        import threading
+        _REG = {}
+        _LOCK = threading.Lock()
+
+        def _store(k):
+            _REG[k] = 1
+
+        def put(k):
+            with _LOCK:
+                _store(k)
+        """
+    )
+    stale = ast.parse("x = 1")
+    summaries._MOD_ENTRY_CACHE[id(mod.tree)] = (stale, {})  # as if the id had been another tree's
+    try:
+        assert summaries.module_entry_locks(mod) == {"_store": frozenset({"_LOCK"})}
+        assert _run(UnguardedGlobalPass(), mod) == []
+    finally:
+        summaries.invalidate_cache()
+
+
 # ---------------------------------------------------------------------------
 # suppression machinery
 # ---------------------------------------------------------------------------
