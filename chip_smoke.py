@@ -41,7 +41,12 @@ seconds — on a warm persistent cache that is the cache-load time):
                 enough ticks for the ~0.2-per-batch tail ids to cross their
                 20/s rules: tail ids must come back blocked here (on the
                 real clock of the serve phase the host cannot offer them
-                20/s).
+                20/s).  Every round also sends a part-filled batch, a
+                seeded sample of the first one that just fills the tick
+                shape below the full one (the middle shape of the ladder,
+                ops/wire.tick_shapes; at rehearsal size the light one),
+                held bit-identical like the full ones, and by the bytes
+                the served client uploads for it to that shape.
 
 ``--rehearse-cpu`` (together with ``JAX_PLATFORMS=cpu``) walks the same code
 at a tiny size (``REHEARSAL_SIZES`` over the same file) with the fast-path
@@ -448,7 +453,7 @@ def serve_phase(seed, sizes, n_blocks, state):
     c, traffic = dep.client, dep.pool
     detail = {"tail_rules_promoted": int((dep.tail_ids < dep.sketch_base).sum())}
     t0 = time.perf_counter()
-    c.start()  # warms both tick shapes, then starts the tick thread
+    c.start()  # warms every tick shape, then starts the tick thread
     detail["start_s"] = round(time.perf_counter() - t0, 3)
     try:
         seg_u0 = c.cfg.seg_u
@@ -563,10 +568,12 @@ def evidence_phase(state, rehearsal: bool, sizes):
 
 def equivalence_phase(seed, sizes, rounds, per_round=4):
     """Served configuration vs the plain scatter path, both on this
-    backend, same virtual clock, same seeded traffic, full width."""
+    backend, same virtual clock, same seeded traffic, full width and one
+    part-filled batch a round."""
     import numpy as np
 
     from sentinel_tpu.core.errors import BLOCK_FLOW, BLOCK_SYSTEM, PASS
+    from sentinel_tpu.ops import wire as WIRE
     from sentinel_tpu.utils.time_source import VirtualTimeSource
 
     vt = VirtualTimeSource(start_ms=1_000)
@@ -600,39 +607,68 @@ def equivalence_phase(seed, sizes, rounds, per_round=4):
     # the MXU path carries RT on a 1/8 ms grid (documented); on-grid
     # inputs keep both paths bit-comparable
     rt = np.round(rt * 8.0) / 8.0
+    # the part-filled batch: a sample of the first batch, still in segment-
+    # key order, as many rows as the tick shape below the full one holds
+    # (the served client's ladder; the plain one takes what its own gives)
+    shapes = WIRE.tick_shapes(served.cfg)
+    part_shape = shapes[-2] if len(shapes) > 1 else shapes[-1]
+    pick = np.sort(np.random.default_rng(seed).choice(
+        served.cfg.batch_size, min(part_shape), replace=False
+    ))
+    full = [ids, onode, oid, ph, inb, rt]
+    part = [x[pick] for x in full]
+    part_bytes = WIRE.input_layout_for(served.cfg, *part_shape).nbytes
     node_rows = served.cfg.node_rows
-    mismatched = ticks = tail_blocked = 0
+    mismatched = ticks = tail_blocked = items = 0
     mix = collections.Counter()
     t0 = time.perf_counter()
+
+    def upload_bytes():
+        return metric_total("sentinel_wire_bytes_total", path="device", direction="tx")
+
     try:
         for _ in range(rounds):
-            if failures:
-                break
-            verdicts = []
-            for cl in (served, plain):
-                fut = cl.submit_block(
-                    ids, origin_node=onode, origin_id=oid, param_hash=ph,
-                    inbound=inb,
-                )
-                cl.submit_completion_block(ids, rt, inbound=inb, param_hash=ph)
-                verdicts.append(fut.result(timeout=60.0)[0])
-            vs, vp = verdicts
-            ticks += 2 * per_round
-            bad = int((vs != vp).sum())
-            if bad:
-                mismatched += bad
-                i = int(np.flatnonzero(vs != vp)[0])
-                failures.append(
-                    f"round verdicts differ at {bad} item(s); first: item {i} "
-                    f"res {int(ids[i])} served {int(vs[i])} plain {int(vp[i])}"
-                )
-            vals, counts = np.unique(vs, return_counts=True)
-            mix.update(dict(zip(vals.tolist(), counts.tolist())))
-            tail_blocked += int(((vs == BLOCK_FLOW) & (ids >= node_rows)).sum())
+            for ids, onode, oid, ph, inb, rt in (full, part):
+                if failures:
+                    break
+                verdicts = []
+                for cl in (served, plain):
+                    tx0 = upload_bytes()
+                    fut = cl.submit_block(
+                        ids, origin_node=onode, origin_id=oid, param_hash=ph,
+                        inbound=inb,
+                    )
+                    cl.submit_completion_block(ids, rt, inbound=inb, param_hash=ph)
+                    verdicts.append(fut.result(timeout=60.0)[0])
+                    if cl is served and ids is part[0]:
+                        # one tick for its acquires, one for its completions
+                        if upload_bytes() - tx0 != 2 * part_bytes:
+                            failures.append(
+                                f"the part-filled batch of {len(ids)} rows uploaded "
+                                f"{upload_bytes() - tx0} B, not two ticks of shape "
+                                f"{part_shape} ({part_bytes} B each)"
+                            )
+                vs, vp = verdicts
+                n_ticks = -(-len(ids) // served.cfg.batch_size)
+                ticks += 2 * n_ticks
+                items += len(ids)
+                bad = int((vs != vp).sum())
+                if bad:
+                    mismatched += bad
+                    i = int(np.flatnonzero(vs != vp)[0])
+                    failures.append(
+                        f"verdicts of a block of {len(ids)} differ at {bad} item(s); first: "
+                        f"item {i} res {int(ids[i])} served {int(vs[i])} plain {int(vp[i])}"
+                    )
+                vals, counts = np.unique(vs, return_counts=True)
+                mix.update(dict(zip(vals.tolist(), counts.tolist())))
+                tail_blocked += int(((vs == BLOCK_FLOW) & (ids >= node_rows)).sum())
             vt.advance(5)
     finally:
         served.stop()
         plain.stop()
+    if not part_shape < shapes[-1]:
+        failures.append(f"the served ladder {shapes} has no shape under the full one")
     if mix.get(BLOCK_SYSTEM):
         failures.append(f"{mix[BLOCK_SYSTEM]} BLOCK_SYSTEM verdict(s)")
     if not mix.get(PASS) or not mix.get(BLOCK_FLOW):
@@ -641,7 +677,9 @@ def equivalence_phase(seed, sizes, rounds, per_round=4):
         failures.append("tail rules loaded but no tail id came back blocked")
     detail = {
         "ticks_per_client": ticks,
-        "items_compared": int(rounds * len(ids)),
+        "items_compared": items,
+        "part_filled_rows": len(pick),
+        "part_filled_shape": list(part_shape),
         "items_mismatched": mismatched,
         "verdict_mix": {str(k): v for k, v in sorted(mix.items())},
         "tail_blocked": tail_blocked,

@@ -233,7 +233,7 @@ def _build_entries() -> List[TracedEntry]:
         return ent
 
     def _wire_in_program(cfg, features, args):
-        lo = WIRE.input_layout_for(cfg, *WIRE.tick_shapes(cfg)[1])
+        lo = WIRE.input_layout_for(cfg, *WIRE.tick_shapes(cfg)[-1])
         return (
             functools.partial(E.tick_wire_in, cfg=cfg, features=features),
             (args[0], args[1], jnp.zeros((lo.total,), jnp.uint32)),

@@ -50,18 +50,27 @@ from sentinel_tpu.ops import segment as SG
 from sentinel_tpu.ops import segscan as SC
 from sentinel_tpu.ops import tables as T
 from sentinel_tpu.ops import window as W
+from sentinel_tpu.ops import wire as WIRE
 
 #: rowmin sentinel (> any valid rt; replaced by drop row before scatter)
 _RT_ABSENT = 3.0e38
 
 
-def seg_capacity(cfg: EngineConfig, b: int) -> int:
-    """Static compacted-axis capacity: explicit cfg.seg_u, else sized for
-    Zipf-like traffic (distinct keys ~9-17% of B, measured) plus the
-    256-block split overhead, with headroom."""
+def seg_capacity(cfg: EngineConfig, b: int, full: Optional[int] = None) -> int:
+    """Static compacted-axis capacity of a side of ``b`` rows, ``full`` at
+    the full tick shape (default: the acquire side's, cfg.batch_size):
+    explicit cfg.seg_u, else sized for Zipf-like traffic (distinct keys
+    ~9-17% of a full batch, measured) plus the 256-block split overhead,
+    with headroom.  A middle tick shape (ops/wire.tick_shapes) holds a
+    smaller sample of the same stream, whose keys repeat less (16,384 rows
+    of Zipf(1.3) read 2,560-2,860 segments, 16-17% of the rows, and the
+    middle ticks of 32,768 up to 4,696: PERF.md section 6), and no seg_u
+    resize recovers its overflow (runtime/client._note_seg_count), so it
+    gets a quarter of its rows."""
     if cfg.seg_u:
         return cfg.seg_u
-    return min(b, b // 8 + b // SG.BLOCK + 64)
+    share = 4 if WIRE.LIGHT_ROWS < b < (full or cfg.batch_size) else 8
+    return min(b, b // share + b // SG.BLOCK + 64)
 
 
 def dropped_items(ctx: SG.SegCtx, valid: Optional[jax.Array] = None) -> jax.Array:
@@ -123,7 +132,7 @@ def prepare_completions(cfg: EngineConfig, comp, features: frozenset):
         jnp.where(valid & (rt1 > 0), rt1, jnp.float32(_RT_ABSENT)),
         _RT_ABSENT,
     )
-    U = seg_capacity(cfg, comp.res.shape[0])
+    U = seg_capacity(cfg, comp.res.shape[0], cfg.complete_batch_size)
     ctx, carried = SG.build_from_head(
         head,
         U,

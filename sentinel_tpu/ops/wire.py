@@ -400,7 +400,7 @@ IN_HDR_WORDS = 4
 #: column then starts on a tile, and the slice the unpack takes of it
 #: needs no shifting copy.  IN_ALIGN divides the light shape's 256 rows,
 #: so a side of more rows is longer and the buffer's length alone tells
-#: the two tick shapes apart (input_layout_of)
+#: the tick shapes apart (input_layout_of)
 IN_HDR_SPAN = 1024
 IN_ALIGN = 256
 
@@ -430,12 +430,35 @@ class InputLayout(NamedTuple):
         return self.total * 4
 
 
-def tick_shapes(cfg: EngineConfig) -> Tuple[Tuple[int, int], Tuple[int, int]]:
-    """The two (acquire rows, completion rows) shapes a client ticks at,
-    light and full.  Exactly two, both sides sized together, so both
-    compile during warm-up and serving compiles none."""
+#: rows a side of the light tick shape, and the share of the batch a side
+#: of the middle one has (see tick_shapes)
+LIGHT_ROWS = 256
+MIDDLE_DIV = 4
+
+
+def tick_shapes(cfg: EngineConfig) -> Tuple[Tuple[int, int], ...]:
+    """The (acquire rows, completion rows) shapes a client ticks at, in
+    rising order: light, middle (a quarter of the batch), full.  A tick
+    runs at the smallest that holds its live rows, since the device pays
+    for the padded rows and not for the live ones.  A fixed ladder, both
+    sides sized together, so every shape compiles during warm-up and
+    serving compiles none; duplicates are dropped, so a batch under
+    ``LIGHT_ROWS * MIDDLE_DIV`` rows has the two shapes light and full,
+    and one of at most ``LIGHT_ROWS`` has one."""
     b, b2 = cfg.batch_size, cfg.complete_batch_size
-    return (min(256, b), min(256, b2)), (b, b2)
+    light = (min(LIGHT_ROWS, b), min(LIGHT_ROWS, b2))
+    middle = (max(light[0], b // MIDDLE_DIV), max(light[1], b2 // MIDDLE_DIV))
+    return tuple(dict.fromkeys((light, middle, (b, b2))))
+
+
+def tick_shape_for(cfg: EngineConfig, n_acq: int, n_comp: int) -> Tuple[int, int]:
+    """The smallest tick shape that holds ``n_acq`` acquire rows and
+    ``n_comp`` completion rows: what the tick holds decides, and nothing
+    remembered from the tick before."""
+    shapes = tick_shapes(cfg)
+    return next(
+        (s for s in shapes if n_acq <= s[0] and n_comp <= s[1]), shapes[-1]
+    )
 
 
 def acquire_fills(cfg: EngineConfig) -> tuple:
@@ -482,14 +505,14 @@ def input_layout_for(cfg: EngineConfig, b: int, b2: int) -> InputLayout:
 
 
 def input_layout_of(cfg: EngineConfig, words: int) -> InputLayout:
-    """The layout of an input buffer of ``words`` words: one of the two
-    tick shapes'.  The jitted tick finds its shape by the buffer alone."""
+    """The layout of an input buffer of ``words`` words: one of the tick
+    shapes'.  The jitted tick finds its shape by the buffer alone."""
     for b, b2 in tick_shapes(cfg):
         lo = input_layout_for(cfg, b, b2)
         if lo.total == words:
             return lo
     raise ValueError(
-        f"input wire of {words} words fits neither tick shape of this config"
+        f"input wire of {words} words fits no tick shape of this config"
     )
 
 

@@ -1274,7 +1274,7 @@ class SentinelClient:
         return changed
 
     def _warm_after_recompile(self, changed: bool) -> None:
-        """Pre-compile a changed tick for both batch shapes, OUTSIDE
+        """Pre-compile a changed tick for every tick shape, OUTSIDE
         _cluster_lock.  Lock order: _tick_mutex is the canonical OUTER
         lock — tick_once holds it across the serving tick, and the
         sync-mode seg-resize acquires _cluster_lock under it — so the
@@ -1440,7 +1440,7 @@ class SentinelClient:
                 self._features = feats
                 with PROF.expected_retrace("rule-feature-change"):
                     self._tick = self._make_tick(self.cfg, feats)
-        # the caller warms the changed tick for BOTH batch shapes once
+        # the caller warms the changed tick for EVERY tick shape once
         # _cluster_lock is released (_warm_after_recompile) so the first
         # post-reload entry doesn't eat the XLA compile inside its
         # entry_timeout_s window; warming under _tick_mutex keeps the
@@ -2448,8 +2448,9 @@ class SentinelClient:
         wait still joins the tick that waited: pipeline_depth caps the
         dispatched-but-unresolved ticks, and a tick that would not be full
         goes only behind at most ONE unresolved tick.  The device takes as
-        long over a part-filled tick as over a full one (there are two
-        shapes), so with one tick running and one queued behind it the
+        long over a tick as over a full one of its shape (a fixed ladder
+        of shapes, ops/wire.tick_shapes: a part-filled tick is padded to
+        the next), so with one tick running and one queued behind it the
         device cannot go idle, and a third, dispatched as soon as the host
         had it built, would add a tick's worth of waiting to every request
         in it and to those behind it, while a full one holds requests that
@@ -2796,7 +2797,7 @@ class SentinelClient:
             with PROF.ledger_owner(self._ledger_name), \
                     PROF.expected_retrace(cause):
                 new_tick = self._make_tick(new_cfg, self._features)
-            # pre-compile BOTH batch shapes against a throwaway state while
+            # pre-compile EVERY tick shape against a throwaway state while
             # the old engine keeps serving: XLA compiles take seconds, and a
             # window whose budget migrated would legitimately EXPIRE during
             # that gap — compiling first makes the actual swap a few ms of
@@ -2923,8 +2924,8 @@ class SentinelClient:
 
     def _warm_tick(self, new_tick, cfg, state):
         """Run ``new_tick`` on idle inputs once a tick shape against the
-        throwaway ``state``, so that serving compiles neither."""
-        for b, b2 in dict.fromkeys(WIRE.tick_shapes(cfg)):
+        throwaway ``state``, so that serving compiles none."""
+        for b, b2 in WIRE.tick_shapes(cfg):
             wb = WIRE.InputBuffer(WIRE.input_layout_for(cfg, b, b2))
             wb.idle_acquire()
             wb.idle_complete()
@@ -3001,18 +3002,22 @@ class SentinelClient:
             segs += 1 + (pad_to - 1) // SG.BLOCK - n // SG.BLOCK
         return segs
 
-    def _note_seg_count(self, segs: int, b: int) -> None:
-        """Track observed live-segment counts; grow ``seg_u`` (recompile +
+    def _note_seg_count(self, segs: int, b: int, full: int) -> None:
+        """Track observed live-segment counts of a side of ``b`` rows
+        (``full`` at the full tick shape); grow ``seg_u`` (recompile +
         hot-swap the tick) when traffic persistently overflows the
         compacted capacity.  With seg_fallback=True overflow ticks are
         exact but ride the slower per-item kernels, so the resize is a
         performance recovery; with seg_fallback=False it stops the
-        fail-closed drops."""
+        fail-closed drops.  seg_u is one capacity for every tick shape, so
+        a resize never goes under the full shape's own: a light or middle
+        tick's overflow that the full shape's capacity covers starts
+        none, and stays exact on the per-item kernels."""
         from sentinel_tpu.ops import engine_seg as ES
 
         if segs > self._seg_obs_peak:
             self._seg_obs_peak = segs
-        cap = ES.seg_capacity(self.cfg, b)
+        cap = ES.seg_capacity(self.cfg, b, full)
         if segs <= cap:
             return
         self._seg_over_ticks += 1
@@ -3174,26 +3179,15 @@ class SentinelClient:
         return est[:, W.EV_PASS] + est[:, W.EV_BLOCK]
 
     def _warm_shapes(self) -> None:
-        """Compile the tick for both batch shapes (small + full) with
-        no-op batches so serving never waits on XLA."""
-        _tw = _time.perf_counter()
-        self._resolve_tick(self._run_tick([], None, self.time.now_ms()))
-        PROF.RETRACE.observe_compile_ms(
-            "engine.tick", (_time.perf_counter() - _tw) * 1000.0
-        )
-        if self.cfg.batch_size > 256:
-            filler = AcquireRequest(
-                res=self.cfg.trash_row, count=0, prio=0, origin_id=-1,
-                origin_node=self.cfg.trash_row, ctx_node=self.cfg.trash_row,
-                ctx_name=-1, inbound=0,
-                param_hash=(0,) * self.cfg.param_dims,
-            )
-            # 257 trash-row entries force the full-shape executable (both
-            # sides — see _run_tick's shape choice); trash rows are engine
-            # no-ops and carry no futures to resolve
+        """Compile the tick for every tick shape (ops/wire.tick_shapes)
+        with idle batches so serving never waits on XLA.  One shape
+        after the other: tracing and lowering the next shape on a helper
+        thread while one loads and runs was measured and cost set-up 5 s
+        more than it saved (PERF.md section 6, PR 32)."""
+        for shape in WIRE.tick_shapes(self.cfg):
             _tw = _time.perf_counter()
             self._resolve_tick(
-                self._run_tick([filler] * 257, None, self.time.now_ms())
+                self._run_tick([], None, self.time.now_ms(), shape=shape)
             )
             PROF.RETRACE.observe_compile_ms(
                 "engine.tick", (_time.perf_counter() - _tw) * 1000.0
@@ -3207,6 +3201,7 @@ class SentinelClient:
         fronts=(),  # [(door, (row, count, prio, corr, a0, a1)), ...]
         blocks=(),  # [(ArrayBlock, src_off, take), ...]
         tick_id: int = 0,  # drawn by the drain while tracing is on
+        shape=None,  # the warm-up's: this tick shape, whatever the rows
     ) -> _PendingTick:
         cfg = self.cfg
         M = cfg.param_dims
@@ -3238,21 +3233,21 @@ class SentinelClient:
             front = None
         n_front = 0 if front is None else len(front[0])
 
-        # adaptive batch shape: a light tick (both queues <= 256) runs at
-        # a small padded shape, anything bigger at the full configured
-        # batch — a mostly-idle CPU-backed tick drops ~10x in cost.
-        # Exactly TWO shapes exist, (small, small) and (full, full), so
-        # both compile during start()/rule-load/resize warmup: the two
-        # sides are sized TOGETHER (sizing them independently reaches
-        # four executables, two of them first compiled inside a serving
-        # tick), and an open-ended power-of-two ladder would push
-        # multi-second XLA compiles into the serving path at the first
-        # load spike.
-        light = (
-            len(acq) + n_blk + n_front <= 256
-            and (comp is None or len(comp[0]) <= 256)
+        # adaptive batch shape: the tick runs at the SMALLEST shape of the
+        # ladder (ops/wire.tick_shapes: light, middle, full) that holds the
+        # live rows of both sides, since the device, the fill of the
+        # columns and the upload pay for the padded rows: on the v5e a
+        # tick of (256, 256) costs 1.1 ms of device time and one of
+        # (131072, 131072) 8.7 ms whatever its fill.  The ladder is FIXED
+        # and short, so every shape compiles during start()/rule-load/
+        # resize warm-up: the two sides are sized TOGETHER (sizing them
+        # independently squares the executables, most of them first
+        # compiled inside a serving tick), and an open-ended power-of-two
+        # ladder would push multi-second XLA compiles into the serving
+        # path at the first load spike.
+        B, B2 = shape or WIRE.tick_shape_for(
+            cfg, len(acq) + n_blk + n_front, 0 if comp is None else len(comp[0])
         )
-        B, B2 = WIRE.tick_shapes(cfg)[0 if light else 1]
 
         from sentinel_tpu.ops.engine import _use_fused
 
@@ -3388,7 +3383,7 @@ class SentinelClient:
                         self._host_seg_count(
                             tuple(cols[f] for f in _ACQ_SEG_KEYS)
                         ),
-                        B,
+                        B, cfg.batch_size,
                     )
             # what the presort did not already put there: the narrow
             # columns (flag / verdict-code / clamped-count values fit the
@@ -3451,7 +3446,7 @@ class SentinelClient:
                 if B2 <= 4096 or (self._seg_sample_ctr_c & 7) == 0:
                     self._note_seg_count(
                         self._host_seg_count((res_a, ctx_a, org_a), pad_to=B2),
-                        B2,
+                        B2, cfg.complete_batch_size,
                     )
 
             # live rows [:n] (unless the presort gathered them in place),

@@ -1,7 +1,7 @@
 """Persistent XLA compile cache for the process entry points that run on
 the chip (``chip_smoke.py``, ``perfbench/run.py``).
 
-The client compiles the full tick for two batch shapes at ``start()`` and
+The client compiles the full tick for every tick shape at ``start()`` and
 again whenever a rule load changes the feature set, and a chip call starts
 cold — without a cache every run pays every compile again.
 
@@ -30,9 +30,15 @@ def compile_cache_dir() -> str:
 
 def enable_compile_cache() -> str:
     """Turn the persistent compile cache on; returns its directory."""
+    import jax
+
     path = compile_cache_dir()
     if not os.environ.get(_ENV):
-        import jax
-
         jax.config.update("jax_compilation_cache_dir", path)
+    # Every program, however quick to compile.  Beside the tick's shapes a
+    # start compiles some sixty small programs (the wire's unpack, the
+    # telemetry folds, eager helpers) of a tenth of a second each; JAX's
+    # default leaves out what compiled in under a second, so every start
+    # compiled them again (PERF.md section 6, PR 32).
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path

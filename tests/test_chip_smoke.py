@@ -95,6 +95,20 @@ def test_compile_cache_dir_default_is_fixed(monkeypatch):
     assert compile_cache.compile_cache_dir() == path
 
 
+def test_enabling_the_compile_cache_keeps_the_small_programs_too(monkeypatch, tmp_path):
+    """A start compiles some sixty programs of a tenth of a second beside the
+    tick's shapes: the cache takes them all, not only what took a second."""
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    was = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
+
+
 def test_result_line_has_exactly_the_contract_keys():
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     line = chip_smoke.result_line(True, {**device, "extra": 0})

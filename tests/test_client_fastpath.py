@@ -872,21 +872,162 @@ def test_a_resolution_that_raises_is_logged_and_frees_the_loop(vt, monkeypatch):
     assert fut.done()
 
 
-def test_a_full_tick_reads_back_exactly_its_layouts_bytes(client_factory):
+@pytest.mark.parametrize("shape,batch", [("full", 512), ("middle", 2048)])
+def test_a_full_tick_reads_back_exactly_its_layouts_bytes(client_factory, shape, batch):
     """tests/test_wire.py holds a light tick to its packed layout's total;
-    the full shape is a layout of its own and is held to it here: one fused
-    read-back a tick (the timeline rows on their own path), not four."""
+    the full shape and the middle one are layouts of their own and are held
+    to them here: one fused read-back a tick (the timeline rows on their
+    own path), not four."""
     from sentinel_tpu.obs.registry import REGISTRY as OBS
 
     def rx(path):
         return OBS.get("sentinel_wire_bytes_total", {"path": path, "direction": "rx"}).value
 
-    c = client_factory(cfg=small_engine_config(batch_size=512, complete_batch_size=512))
-    ids = np.full(300, c.registry.resource_id("full/r"), np.int32)  # over 256: the full shape
+    c = client_factory(cfg=small_engine_config(batch_size=batch, complete_batch_size=batch))
+    # over 256 rows: the full shape of a batch of 512, the middle one (512) of 2,048
+    assert WIRE.tick_shape_for(c.cfg, 300, 0) == (512, 512)
+    assert (WIRE.tick_shapes(c.cfg)[-1] == (512, 512)) is (shape == "full")
+    ids = np.full(300, c.registry.resource_id("full/r"), np.int32)
     c.submit_block(ids).result(timeout=60)  # compile this shape / const cols
     dev0, tl0 = rx("device"), rx("timeline")
     c.submit_block(ids).result(timeout=60)
-    lo = c._wire_layout(c.cfg, c.cfg.batch_size)
+    lo = c._wire_layout(c.cfg, 512)
     tl_bytes = lo.tl_rows * lo.tl_cols * 4
     assert rx("device") - dev0 == lo.total * 4 - tl_bytes
     assert rx("timeline") - tl0 == tl_bytes
+
+
+# -- the ladder of tick shapes ------------------------------------------------
+
+
+def test_a_tick_takes_the_smallest_shape_that_holds_both_sides():
+    """ops/wire.tick_shape_for at its edges, on the served batch of 131,072
+    (light 256, middle 32,768): a row more than a shape holds takes the
+    next; the longer side decides; a batch of at most 1,027 rows keeps
+    two shapes and one of at most 256 rows one."""
+    cfg = small_engine_config(batch_size=131072, complete_batch_size=131072)
+    light, middle, full = WIRE.tick_shapes(cfg)
+    assert (light, middle, full) == ((256, 256), (32768, 32768), (131072, 131072))
+    for n_acq, n_comp, want in [
+        (0, 0, light), (256, 256, light), (257, 0, middle), (0, 257, middle),
+        (32768, 32768, middle), (32769, 0, full), (0, 32769, full),
+        (4, 9000, middle), (9000, 4, middle),  # a long side beside a short one
+        (4, 131072, full), (131072, 131072, full),
+        (131073, 0, full),  # more than a tick holds is the drain's fault, not a new shape
+    ]:
+        assert WIRE.tick_shape_for(cfg, n_acq, n_comp) == want, (n_acq, n_comp)
+
+    def ladder(b, b2):
+        return WIRE.tick_shapes(small_engine_config(batch_size=b, complete_batch_size=b2))
+
+    assert ladder(1023, 1023) == ((256, 256), (1023, 1023))  # 1023 // 4 < 256
+    assert ladder(1027, 1027) == ((256, 256), (1027, 1027))
+    assert ladder(1028, 1028) == ((256, 256), (257, 257), (1028, 1028))
+    assert ladder(4096, 300) == ((256, 256), (1024, 256), (4096, 300))
+    assert ladder(64, 64) == ((64, 64),)
+    # every shape of a ladder is a buffer length of its own: the jitted tick
+    # finds its layout by the buffer alone
+    for cfg in (cfg, small_engine_config(batch_size=1028, complete_batch_size=1028)):
+        totals = [WIRE.input_layout_for(cfg, b, b2).total for b, b2 in WIRE.tick_shapes(cfg)]
+        assert len(set(totals)) == len(totals)
+        for (b, b2), total in zip(WIRE.tick_shapes(cfg), totals):
+            assert WIRE.input_layout_of(cfg, total)[:2] == (b, b2)
+
+
+def test_the_middle_shapes_capacity_and_the_resize_guard(vt):
+    """engine_seg.seg_capacity leaves the light and the full shape what they
+    were and gives the middle one a quarter of its rows; a middle tick's
+    overflow that the full shape's capacity covers starts no seg_u resize,
+    however often it comes."""
+    from sentinel_tpu.ops import engine_seg as ES
+
+    cfg = small_engine_config(**SEG, batch_size=131072, complete_batch_size=131072)
+    assert ES.seg_capacity(cfg, 256) == 97
+    assert ES.seg_capacity(cfg, 131072) == 131072 // 8 + 512 + 64
+    assert ES.seg_capacity(cfg, 32768) == 32768 // 4 + 128 + 64
+    # a side is part-filled against its OWN full shape
+    assert ES.seg_capacity(cfg, 32768, 32768) == 32768 // 8 + 128 + 64
+    assert ES.seg_capacity(small_engine_config(seg_u=40), 32768) == 40
+
+    B = 16384  # middle 4,096
+    c = _mk(vt, batch_size=B, complete_batch_size=B)  # never started: no compile
+    started = []
+    c._resize_seg_u = started.append
+    mid = ES.seg_capacity(c.cfg, B // 4, B)
+    assert mid < ES.seg_capacity(c.cfg, B)
+    for _ in range(16):
+        c._note_seg_count(mid + 40, B // 4, B)
+    assert c._seg_over_ticks == 16 and started == []
+    # the full shape's own overflow still does
+    for _ in range(4):
+        c._note_seg_count(ES.seg_capacity(c.cfg, B) + 1, B, B)
+    assert len(started) == 1 and started[0] > ES.seg_capacity(c.cfg, B)
+
+
+def test_a_batch_is_decided_alike_at_the_middle_and_at_the_full_shape(vt, monkeypatch):
+    """One seeded mixed tick of some thousand rows (objects, blocks,
+    completions), then a second on the first's state, through two clients
+    of one configuration: one pads it to the middle shape, the other (the
+    ladder without its middle rung, as before the middle shape existed) to
+    the full one.  Padding rows are trash rows, engine no-ops: every
+    verdict and wait, the whole decoded TickOutput and every leaf of the
+    state are equal; but for the telemetry row's count of live segments,
+    which counts the padding run's 256-row block heads by definition."""
+    import jax
+
+    from sentinel_tpu.ops import engine as E
+    from sentinel_tpu.ops import segment as SG
+
+    B = 8192  # light 256, middle 2,048
+
+    def serve(with_middle: bool):
+        real = WIRE.tick_shapes
+        if not with_middle:
+            monkeypatch.setattr(
+                WIRE, "tick_shapes", lambda cfg: (real(cfg)[0], real(cfg)[-1])
+            )
+        c = _mk(VirtualTimeSource(start_ms=1_000), batch_size=B, complete_batch_size=B)
+        names = [f"m{i}" for i in range(1, 48)]
+        assert [c.registry.resource_id(n) for n in names] == list(range(1, 48))
+        c.flow_rules.load(
+            [FlowRule(resource=n, count=20.0 + 9 * i) for i, n in enumerate(names[:24])]
+        )
+        rng = np.random.default_rng(32)
+        got = []
+        for n_blk in (1900, 1400):
+            acq, blocks, comp = _mixed_tick(c.cfg, rng, 8, n_blk, n_blk - 100)
+            p = c._run_tick(acq, comp, None, blocks=blocks)
+            rows = int(p.out.wait_ms.shape[0])
+            inv = p.inv_a.copy()
+            frame = WIRE.unpack(
+                np.asarray(p.out.wire).tobytes(), c._wire_layout(c.cfg, rows)
+            )
+            c._resolve_tick(p)
+            n = 8 + n_blk
+            stats = frame.stats.copy()
+            seg_live, stats[E.STAT_SEG_LIVE] = int(stats[E.STAT_SEG_LIVE]), 0
+            got.append((
+                rows, seg_live,
+                [(b.verdicts[3:].tolist(), b.waits[3:].tolist()) for b, _o, _t in blocks],
+                frame.verdict[inv][:n].tolist(), frame.wait[inv][:n].tolist(),
+                frame.n_wait, frame.seg_dropped,
+                *(None if x is None else x.tolist()
+                  for x in (stats, frame.res_stats, frame.hot, frame.expl)),
+            ))
+            c.time.advance(300)
+        state = [np.asarray(x) for x in jax.tree_util.tree_leaves(c._state)]
+        monkeypatch.undo()
+        return got, state
+
+    (mid1, mid2), mid_state = serve(with_middle=True)
+    (full1, full2), full_state = serve(with_middle=False)
+    assert (mid1[0], mid2[0]) == (2048, 2048) and (full1[0], full2[0]) == (B, B)
+    assert mid1[2:] == full1[2:] and mid2[2:] == full2[2:]
+    for mid, full in ((mid1, full1), (mid2, full2)):
+        assert full[1] - mid[1] == (B - 2048) // SG.BLOCK
+    verdicts = set(mid1[3]) | set(mid2[3])
+    assert {int(ERR.PASS), int(ERR.BLOCK_FLOW)} <= verdicts
+    assert len(mid_state) == len(full_state)
+    for a, b in zip(mid_state, full_state):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()

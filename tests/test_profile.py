@@ -206,30 +206,72 @@ def test_engine_tick_retrace_journal_steady_state_and_config_change(client):
     assert "second_window_ms" in last["cause"]
 
 
-def test_start_compiles_both_tick_shapes_and_serving_compiles_none():
-    """``start()`` leaves the tick compiled for the light and the full
-    batch shape; a light tick, a full one and a completion-only one then
-    serve from those two executables, with no retrace journalled."""
+#: a ladder of tick shapes -> (batch rows a side, its shapes, the acquire and
+#: completion rows that take each rung).  "uneven": the acquire side is too
+#: narrow for a middle rung of its own (1,024 // 4 = 256, the light one's)
+#: and the completion side is not, so only completions reach the middle shape
+_LADDERS = {
+    "even": (
+        (4096, 4096), ((256, 256), (1024, 1024), (4096, 4096)),
+        ((4, 4), (300, 300), (4096, 4096)),
+    ),
+    "uneven": (
+        (1024, 2048), ((256, 256), (256, 512), (1024, 2048)),
+        ((4, 4), (4, 300), (300, 600)),
+    ),
+}
+
+
+@pytest.fixture(scope="module", params=list(_LADDERS))
+def ladder_client(request):
+    """A started threaded client whose batch is wide enough for the whole
+    ladder of tick shapes: light, middle, full."""
     from sentinel_tpu.runtime.client import SentinelClient
 
+    (b, b2), _shapes, _rows = _LADDERS[request.param]
     c = SentinelClient(
-        cfg=small_engine_config(batch_size=512, complete_batch_size=512),
+        cfg=small_engine_config(batch_size=b, complete_batch_size=b2),
         mode="threaded", entry_timeout_s=60.0,
     )
     c.start()
+    yield c, request.param
+    c.stop()
+
+
+@pytest.mark.parametrize("rung", [0, 1, 2], ids=["light", "middle", "full"])
+def test_start_compiles_every_tick_shape_and_serving_compiles_none(ladder_client, rung):
+    """``start()`` leaves the tick compiled for every shape of the ladder,
+    also where a rung is wider than the one below on one side only; a tick
+    of each shape, acquire and completion side, then serves from those
+    executables, at the smallest shape that holds it, with no retrace
+    journalled."""
+    from sentinel_tpu.ops import wire as WIRE
+
+    c, ladder = ladder_client
+    _batch, want, rows = _LADDERS[ladder]
+    shapes = WIRE.tick_shapes(c.cfg)
+    assert shapes == want
+    assert c._tick._cache_size() == len(shapes)
+    journalled = len(PROF.RETRACE.recent())
+    n, n2 = rows[rung]
+    ids = np.full(max(n, n2), c.registry.resource_id("shapes/r"), np.int32)
+    OT.TRACER.reset()
+    OT.TRACER.enable()
     try:
-        assert c._tick._cache_size() == 2
-        journalled = len(PROF.RETRACE.recent())
-        ids = np.full(512, c.registry.resource_id("shapes/r"), np.int32)
-        for n in (4, 512):
-            verdicts, _waits = c.submit_block(ids[:n]).result(timeout=60.0)
-            assert len(verdicts) == n
-            c.submit_completion_block(ids[:n], np.ones(n, np.float32))
+        verdicts, _waits = c.submit_block(ids[:n]).result(timeout=60.0)
+        assert len(verdicts) == n
+        c.submit_completion_block(ids[:n2], np.ones(n2, np.float32))
         c.submit_block(ids[:1]).result(timeout=60.0)  # the completions are in by now
-        assert c._tick._cache_size() == 2
-        assert len(PROF.RETRACE.recent()) == journalled
     finally:
-        c.stop()
+        OT.TRACER.disable()
+    ran = {
+        (s["attrs"]["b"], s["attrs"]["b2"])
+        for s in OT.TRACER.snapshot() if s["name"] == "tick.assemble"
+    }
+    # the block's tick and the completions' (one tick or two); the rest light
+    assert shapes[rung] in ran and ran <= {shapes[0], shapes[rung]}
+    assert c._tick._cache_size() == len(shapes)
+    assert len(PROF.RETRACE.recent()) == journalled
 
 
 # -- deep-profile capture ----------------------------------------------------

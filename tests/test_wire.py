@@ -263,17 +263,30 @@ def test_packed_client_bit_identical_to_reference_client(client_factory, vt):
     assert any(v == ERR.PASS_WAIT and w > 0 for v, w in ref)
 
 
-def test_client_defaults_to_packed_and_makes_one_upload_a_tick(client, monkeypatch):
+#: rung of ops/wire.tick_shapes -> (its index, batch rows, rows a tick of
+#: that shape carries here): a batch of 1,200 has the ladder 256 / 300 / 1,200
+_RUNGS = {"light": (0, 64, 6), "middle": (1, 1200, 300), "full": (-1, 1200, 301)}
+
+
+@pytest.mark.parametrize("shape", list(_RUNGS))
+def test_client_defaults_to_packed_and_makes_one_upload_a_tick(
+    client_factory, monkeypatch, shape
+):
     """Tri-state default: the client resolves packed_wire=None to True.
-    A packed tick makes exactly one host-to-device transfer, of exactly
-    its input layout's bytes, says so on tick.assemble and counts it; and
-    changed traffic changes the verdicts (every tick crosses whole: there
-    is no cache a stale column could be served from)."""
+    A packed tick of any shape makes exactly one host-to-device transfer,
+    of exactly its input layout's bytes, says so on tick.assemble and
+    counts it; and changed traffic changes the verdicts (every tick
+    crosses whole: there is no cache a stale column could be served
+    from)."""
     from sentinel_tpu import obs
 
+    rung, batch, n = _RUNGS[shape]
+    client = client_factory(
+        cfg=small_engine_config(batch_size=batch, complete_batch_size=batch)
+    )
     assert client.cfg.packed_wire is True
     client.flow_rules.load([FlowRule(resource="delta/r", count=4.0)])
-    names = ["delta/r"] * 6
+    names = ["delta/r"] * n
     first = client.check_batch(names, inbound=True)
 
     sent = []  # every numpy array handed to the device while spying
@@ -295,7 +308,8 @@ def test_client_defaults_to_packed_and_makes_one_upload_a_tick(client, monkeypat
     finally:
         obs.disable()
         monkeypatch.undo()
-    b, b2 = WIRE.tick_shapes(client.cfg)[0]  # six items: the light shape
+    b, b2 = WIRE.tick_shapes(client.cfg)[rung]  # the smallest that holds n rows
+    assert b >= n and (rung == 0 or WIRE.tick_shapes(client.cfg)[rung - 1][0] < n)
     nbytes = WIRE.input_layout_for(client.cfg, b, b2).nbytes
     assert sent == [nbytes]
     assert _metric(
@@ -304,11 +318,13 @@ def test_client_defaults_to_packed_and_makes_one_upload_a_tick(client, monkeypat
     spans = obs.TRACER.snapshot()
     (asm,) = [s for s in spans if s["name"] == "tick.assemble"]
     assert asm["attrs"]["puts"] == 1 and asm["attrs"]["tx_bytes"] == nbytes
+    assert (asm["attrs"]["b"], asm["attrs"]["b2"]) == (b, b2)
     # counted where the benchmark's span_summary prints it
     assert obs.summarize(spans)["tick.assemble"]["puts"] == {1: 1}
     # identical traffic, window used up
     assert [v for v, _ in second].count(int(ERR.PASS)) == 0
-    assert len(first) == len(second) == 6
+    assert len(first) == len(second) == n
+    assert [v for v, _ in first].count(int(ERR.PASS)) == 4
     # changed traffic: the verdicts track it
     client.time.advance(client.cfg.second_window_ms * client.cfg.second_sample_count + 10)
     third = client.check_batch(["delta/r"] * 2 + ["delta/other"] * 4)
@@ -326,7 +342,7 @@ _INPUT_CASES = {
 }
 
 
-@pytest.mark.parametrize("shape", ["light", "full"])
+@pytest.mark.parametrize("shape", list(_RUNGS))
 @pytest.mark.parametrize("case", sorted(_INPUT_CASES))
 def test_input_wire_round_trip(case, shape):
     """Host pack -> unpack_tick_input on the device gives, leaf for leaf
@@ -336,11 +352,12 @@ def test_input_wire_round_trip(case, shape):
     over, count_dt = _INPUT_CASES[case]
     cfg = small_engine_config(**{
         **dict(packed_wire=True, use_mxu_tables=True, fused_effects=True,
-               seg_effects=True, batch_size=300, complete_batch_size=280),
+               seg_effects=True, batch_size=1200, complete_batch_size=1120),
         **over,
     })
     assert WIRE._count_dtype(cfg) is count_dt
-    b, b2 = WIRE.tick_shapes(cfg)[shape == "full"]
+    assert WIRE.tick_shapes(cfg) == ((256, 256), (300, 280), (1200, 1120))
+    b, b2 = WIRE.tick_shapes(cfg)[_RUNGS[shape][0]]
     lo = WIRE.input_layout_for(cfg, b, b2)
     assert WIRE.input_layout_of(cfg, lo.total) == lo
     assert [c.field for c in lo.acq] == list(E.AcquireBatch._fields)
