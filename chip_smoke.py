@@ -48,6 +48,17 @@ seconds — on a warm persistent cache that is the cache-load time):
                 held bit-identical like the full ones, and by the bytes
                 the served client uploads for it to that shape.
 
+  param_store   the benchmark's second ``SentinelClient`` deployment,
+                ``perfbench/configs/param-1m-hot-keys.json`` (256
+                ParamFlowRules over 1,024,000 (route, client) pairs, the
+                hot-parameter store 2^22 cells wide), built by the kind
+                that file names; a short replay of its own traffic at
+                virtual times through ``submit_block`` and the compiled
+                tick, held to the exact shadow ``perfbench/reference/
+                param_shadow.py`` by the cell's own check: nothing admitted
+                that an exact count would block, the share refused wrongly
+                under the check's limit, both verdict codes and no third.
+
 ``--rehearse-cpu`` (together with ``JAX_PLATFORMS=cpu``) walks the same code
 at a tiny size (``REHEARSAL_SIZES`` over the same file) with the fast-path
 flags forced on, so the kernels run
@@ -110,6 +121,20 @@ REHEARSAL_SIZES = {
     "traffic": {"pool_batches": 8},
     "client": {"entry_timeout_s": 30.0},
 }
+#: the second deployment this smoke vouches for, and its rehearsal cut (a
+#: store of 2^15 cells is still a wide one: ops/param.wide)
+PARAM_CONFIG = "param-1m-hot-keys"
+PARAM_REHEARSAL_SIZES = {
+    "engine": {
+        "max_resources": 112, "max_nodes": 120, "max_flow_rules": 112,
+        "max_degrade_rules": 112, "max_param_rules": 16, "batch_size": 512,
+        "complete_batch_size": 512, "param_width": 1 << 15,
+        "use_mxu_tables": True, "fused_effects": True, "seg_effects": True,
+    },
+    "resources": {"n_routes": 16, "clients_per_route": 50, "universe_pairs": 800},
+    "traffic": {"pool_batches": 4, "client_rotation": 7},
+    "client": {"entry_timeout_s": 30.0},
+}
 #: the equivalence phase's reference: the same deployment on the plain
 #: scatter engine, no pipelining
 _PLAIN = {
@@ -119,14 +144,14 @@ _PLAIN = {
 }
 
 
-def build(seed: int, *sizes: dict):
-    """``CONFIG`` as ``perfbench/run.py`` builds it (the file's deployment
+def build(seed: int, *sizes: dict, config: str = CONFIG):
+    """``config`` as ``perfbench/run.py`` builds it (the file's deployment
     kind, not started), with the groups of ``sizes`` laid over the file in
     order; none on the chip, where the serve phase runs the file as it is."""
     from perfbench import manifest
     from perfbench.deployments import with_sizes
 
-    cfg = manifest.config(CONFIG)
+    cfg = manifest.config(config)
     for s in sizes:
         cfg = with_sizes(cfg, s)
     return manifest.module("deployments", cfg["deployment"]).build(cfg, seed, None)
@@ -692,6 +717,34 @@ def equivalence_phase(seed, sizes, rounds, per_round=4):
 # ---------------------------------------------------------------------------
 
 
+def param_store_phase(seed, sizes, ticks):
+    """``PARAM_CONFIG`` built as its cell builds it and a short replay of its
+    traffic held to the exact shadow by the cell's own check."""
+    from perfbench.checks import param_replay
+    from perfbench.generators import open_loop_param_blocks
+
+    dep = build(seed, *sizes, config=PARAM_CONFIG)
+    c = dep.client
+    block = min(4096, dep.batch // 8)
+    params = {"block_items": block,
+              "replay": {"ticks": ticks, "step_ms": 25, "blocks_per_tick": [4]}}
+    try:
+        replayed = open_loop_param_blocks.replay(dep, params, seed)
+        numbers, summary = param_replay.compare_replay(dep, replayed)
+        summary.update(param_replay.store_occupancy(dep))
+    finally:
+        c.stop()
+    held = ("replay_pairs_compared", "replay_blocked_items", "replay_param_over_admitted",
+            "replay_param_false_block_share", "replay_other_codes",
+            "replay_item_keys_past_the_rules_count")
+    detail = {"param_width": c.cfg.param_width, "rules": len(c.param_flow_rules.get()),
+              "universe_pairs": dep.universe, "pool_pairs": dep.pool_pairs,
+              **{n.name: n.value for n in numbers}, **summary}
+    failures = [f"{n.name} is {n.value}, limit {n.limit}" for n in numbers
+                if n.name in held and not n.ok]
+    return detail, failures
+
+
 def result_line(ok: bool, device: dict) -> str:
     """The last line of stdout: exactly the keys the chip check reads."""
     return json.dumps({
@@ -745,8 +798,10 @@ def main(argv=None) -> int:
 
     if args.rehearse_cpu:
         sizes, n_blocks, rounds = (REHEARSAL_SIZES,), 8, 3
+        param_sizes, param_ticks = (PARAM_REHEARSAL_SIZES,), 12
     else:
         sizes, n_blocks, rounds = (), 12, 40
+        param_sizes, param_ticks = (), 24
 
     def environment():
         native = native_available()
@@ -767,6 +822,7 @@ def main(argv=None) -> int:
         ("serve", lambda: serve_phase(args.seed, sizes, n_blocks, state)),
         ("evidence", lambda: evidence_phase(state, args.rehearse_cpu, sizes)),
         ("equivalence", lambda: equivalence_phase(args.seed, sizes, rounds)),
+        ("param_store", lambda: param_store_phase(args.seed, param_sizes, param_ticks)),
     )
     ok = all(report.run(name, fn) for name, fn in phases)
     compile_s, trace_s, hits = clock.snapshot()

@@ -96,6 +96,13 @@ def app_name() -> str:
     return get_config("csp.sentinel.app.name") or "sentinel-tpu-app"
 
 
+#: widest hot-parameter store whose per-depth table a fused scatter job
+#: keeps whole in fast memory beside its one-hot factors (ops/fused.py)
+PARAM_NARROW_WIDTH = 1 << 14
+#: widest store at all: scatter_sorted keeps one depth's f32 table resident
+PARAM_MAX_WIDTH = 1 << 22
+
+
 @dataclass(frozen=True)
 class EngineConfig:
     """Capacity & window-shape configuration of the device engine.
@@ -135,7 +142,22 @@ class EngineConfig:
     # hash(rule, value) in [0, param_width) per depth; all rules share one
     # bucket grid of param_sample_count x param_bucket_ms; distinct rule
     # durations group into <= param_classes window classes; each entry
-    # carries param_dims hashed argument lanes
+    # carries param_dims hashed argument lanes.
+    # What a width holds: the store is a count-min sketch, so it never
+    # under-counts a (rule, value) pair and refuses an admission an exact
+    # count would allow when in every depth the pair's cell also counts
+    # another pair: with K pairs admitted a window about (K / width)**depth
+    # of the pairs, fewer of the admissions.  Counted at depth 2 on an API
+    # gateway's stream (perfbench/configs/param-1m-hot-keys.json: 1,024,000
+    # pairs, 211,000 admitted a second at 2.8 M items/s, PERF.md section 6):
+    # 2^14 cells refuse three admissions in four that an exact count allows,
+    # 2^21 0.7 %, 2^22 0.2 %; the default holds some hundreds of pairs a
+    # window at that share.
+    # A width up to PARAM_NARROW_WIDTH keeps each depth's table whole in a
+    # fused kernel's fast memory; a wider store (a multiple of it, up to
+    # PARAM_MAX_WIDTH) is laid out [depth, bucket, cell] and written by a
+    # kernel that sorts a tick's cells and visits only the stretches of the
+    # table they fall in (ops/fused.py scatter_sorted)
     param_depth: int = 2
     param_width: int = 1 << 14
     param_sample_count: int = 8
@@ -299,6 +321,15 @@ class EngineConfig:
             raise ValueError(
                 f"param_dims must be 1..4 (ring transport carries four "
                 f"release lanes); got {self.param_dims}"
+            )
+        if self.param_width > PARAM_NARROW_WIDTH and (
+            self.param_width % PARAM_NARROW_WIDTH or self.param_width > PARAM_MAX_WIDTH
+        ):
+            raise ValueError(
+                f"param_width {self.param_width}: a store wider than "
+                f"{PARAM_NARROW_WIDTH} cells is written in stretches of that many, "
+                f"so it has to be a multiple of it, and at most {PARAM_MAX_WIDTH} "
+                f"(one depth's table must fit a kernel's fast memory)"
             )
         # seg_effects rides the fused megakernels; without them the flag
         # would silently do nothing (tick gates on seg_effects AND fused)

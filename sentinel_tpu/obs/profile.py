@@ -151,7 +151,7 @@ class MemoryLedger:
 
     #: the pools the plane accounts (free-form strings are accepted;
     #: these are the documented ones)
-    POOLS = ("rules", "windows", "sketch", "wire", "tokens")
+    POOLS = ("rules", "windows", "param_store", "sketch", "wire", "tokens")
 
     def __init__(self, registry: MetricRegistry = REGISTRY):
         self._registry = registry

@@ -121,7 +121,7 @@ class EngineState(NamedTuple):
     cb_counts: jax.Array  # int32 [D+1, nbc, 3]
     cb_epochs: jax.Array  # int32 [D+1, nbc]
     # hashed (rule,value) param store (ops/param.py v2)
-    pcms: jax.Array  # int32 [depth, Q, nbp] windowed counts
+    pcms: jax.Array  # int32 [depth, Q, nbp] windowed counts ([depth, nbp, Q] when P.wide(cfg))
     pcms_epochs: jax.Array  # int32 [nbp] global bucket epochs
     pconc: jax.Array  # int32 [depth, Q] per-(rule,value) concurrency
     # global observability sketch for tail resources (ops/gsketch.py);
@@ -396,13 +396,13 @@ def _device_res_stats(cfg: EngineConfig, state: EngineState, now_ms):
 
 def init_state(cfg: EngineConfig) -> EngineState:
     state = _init_state(cfg)
-    # memory ledger (obs/profile.py): the window rings + breaker/param/
-    # rtq state are the "windows" pool; the global sketch is accounted
-    # separately by its own init (salsa/gsketch), so subtract its leaves
+    # memory ledger (obs/profile.py): the hot-parameter store is a pool of
+    # its own, the window rings + breaker/rtq state the "windows" pool; the
+    # global sketch is accounted by its own init (salsa/gsketch)
+    store = PROF.LEDGER.track("param_store", "engine.init_state", (state.pcms, state.pconc))
     PROF.LEDGER.set(
-        "windows",
-        "engine.init_state",
-        PROF.tree_nbytes(state) - PROF.tree_nbytes(state.gs),
+        "windows", "engine.init_state",
+        PROF.tree_nbytes(state) - PROF.tree_nbytes(state.gs) - store,
     )
     return state
 
@@ -430,7 +430,7 @@ def _init_state(cfg: EngineConfig) -> EngineState:
         cb_counts=jnp.zeros((Dn + 1, cfg.cb_sample_count, 3), dtype=jnp.int32),
         cb_epochs=jnp.full((Dn + 1, cfg.cb_sample_count), -10, dtype=jnp.int32),
         pcms=jnp.zeros(
-            (cfg.param_depth, cfg.param_width, cfg.param_sample_count),
+            P.store_shape(cfg),  # [depth, Q, nb]; [depth, nb, Q] when wide
             dtype=jnp.int32,
         ),
         pcms_epochs=jnp.full(
@@ -1445,7 +1445,7 @@ def _acquire_effects_fused(
     fslots,  # [B*K] flow slots from _check_flow (None without "flow")
     occ_grant,  # (grant_lane, oslots, ocnt) or None
     rl_info,  # (rl_ok, cost) from _check_flow or None
-    param_ctx,  # (pcms, pcms_epochs, pcms_idx, prows, q_add, thread_add) or None
+    param_ctx,  # (prows, q_add, thread_add) or None
 ) -> EngineState:
     """Acquire-side effects in ONE Pallas megakernel: stat fan histogram,
     CMS sketch, warm-up drain accounting, occupy-ahead booking, the
@@ -1536,7 +1536,7 @@ def _acquire_effects_fused(
 
     # --- param pass + THREAD concurrency (values masked, rows shared) -----
     if param_ctx is not None:
-        pcms, pcms_epochs, pcms_idx, prows, q_add, thread_add = param_ctx
+        prows, q_add, thread_add = param_ctx
         KP = cfg.param_rules_per_resource
         adm = _fan(passed, KP)
         cnt_p = _fan(acq.count, KP)
@@ -1649,13 +1649,13 @@ def _acquire_effects_fused(
             occ_epoch=jnp.where(add > 0, cur_wid + 1, state.occ_epoch),
         )
 
+    p_upd = None
     if param_ctx is not None:
+        # what the tick lands in the store: (counts, concurrency), [depth, Q]
         upd = jnp.round(p_out).astype(jnp.int32)  # [depth, Q, 2]
-        pcms = pcms.at[:, :, pcms_idx].add(upd[:, :, 0])
-        pconc = jnp.maximum(state.pconc + upd[:, :, 1], 0)
-        state = state._replace(pcms=pcms, pcms_epochs=pcms_epochs, pconc=pconc)
+        p_upd = (upd[:, :, 0], upd[:, :, 1])
 
-    return state
+    return state, p_upd
 
 
 @jax.named_scope("stage.authority")
@@ -1748,8 +1748,8 @@ def _check_param(
     budget, THREAD grade as per-value concurrency; paramIdx dispatch via
     per-resource hash lanes).
 
-    Returns (blocked[B], pcms, pcms_epochs, cur_idx, prows, qps_add_mask,
-    thread_add_mask).
+    Reads the store as the tick refreshed it (``tick``: stage.param_refresh).
+    Returns (blocked[B], prows, qps_add_mask, thread_add_mask).
     """
     KP = cfg.param_rules_per_resource
     b = acq.res.shape[0]
@@ -1757,8 +1757,6 @@ def _check_param(
     slots = T.big_gather(cfg, rules.param.res_params, res_l, cfg.max_resources + 1, max_int=cfg.max_param_rules)
     slots_f = slots.reshape(-1)
     item = jnp.repeat(jnp.arange(b), KP)
-
-    pcms, pcms_epochs, cur_idx = P.refresh(state.pcms, state.pcms_epochs, now_ms, cfg)
 
     pg = T.small_gather_fields(
         cfg,
@@ -1790,7 +1788,7 @@ def _check_param(
 
     prows = P.pair_rows(slots_f, ph, cfg.param_depth, cfg.param_width)  # [N, depth]
     wtab = P.class_tables(
-        pcms, pcms_epochs, jnp.asarray(rules.param.class_k), now_ms, cfg
+        state.pcms, state.pcms_epochs, jnp.asarray(rules.param.class_k), now_ms, cfg
     )
     if _use_fused(cfg):
         est = P.estimate_fused(cfg, wtab, prows, cls)
@@ -1831,7 +1829,7 @@ def _check_param(
     blocked = (blocked_f & elig_f).reshape(b, KP).any(axis=1)
     qps_add = applicable & ~is_thread
     thread_add = applicable & is_thread
-    return blocked, pcms, pcms_epochs, cur_idx, prows, qps_add, thread_add
+    return blocked, prows, qps_add, thread_add
 
 
 def _fold_occupied(cfg: EngineConfig, state: EngineState, now_ms):
@@ -2404,9 +2402,8 @@ def _run_checks_plain(
        wait_ms, occupying, occ_grant, fslots, rl_info, degrade_block,
        cb_state, latest_passed)
 
-    with param_state = (pcms, pcms_epochs, pcms_idx, prows, qps_add,
-    thread_add) or None, and every *_block already masked by its stage's
-    eligibility."""
+    with param_state = (prows, qps_add, thread_add) or None, and every
+    *_block already masked by its stage's eligibility."""
     b = acq.res.shape[0]
     zero_block = jnp.zeros((b,), bool)
 
@@ -2425,17 +2422,11 @@ def _run_checks_plain(
     eligible = eligible & ~sys_block
 
     if "param" in features:
-        (
-            param_block,
-            pcms,
-            pcms_epochs,
-            pcms_idx,
-            prows,
-            p_qps_add,
-            p_thread_add,
-        ) = _check_param(cfg, state, rules, acq, now_ms, eligible)
+        param_block, prows, p_qps_add, p_thread_add = _check_param(
+            cfg, state, rules, acq, now_ms, eligible
+        )
         param_block = param_block & eligible
-        param_state = (pcms, pcms_epochs, pcms_idx, prows, p_qps_add, p_thread_add)
+        param_state = (prows, p_qps_add, p_thread_add)
     else:
         param_block = zero_block
         param_state = None
@@ -2594,6 +2585,17 @@ def tick(
     valid = acq.res != cfg.trash_row
     forced = valid & (acq.pre_verdict > 0)
 
+    # the hot-parameter store's stale bucket is cleared, and further down its
+    # current one written, HERE and not inside the check and effects phases:
+    # a lax.cond branch that updates its operand copies it whole, and a wide
+    # store is 256 MiB (PERF.md section 6, PR 33: four such copies a tick)
+    if "param" in features:
+        with jax.named_scope("stage.param_refresh"):
+            pcms, pcms_epochs, pcms_idx = P.refresh(
+                state.pcms, state.pcms_epochs, now_ms, cfg
+            )
+            state = state._replace(pcms=pcms, pcms_epochs=pcms_epochs)
+
     # 3. rule checks in reference slot order; each stage's blocks remove
     #    the item from later stages' rank accounting.  With segmented
     #    effects + single-rule lanes the whole phase switches between the
@@ -2652,7 +2654,7 @@ def tick(
     if latest_passed is not None:
         state = state._replace(latest_passed_ms=latest_passed)
     if "param" in features:
-        (pcms, pcms_epochs, pcms_idx, prows, p_qps_add, p_thread_add) = param_state
+        prows, p_qps_add, p_thread_add = param_state
 
     with jax.named_scope("stage.verdict"):
         passed = valid & ~forced & ~(
@@ -2699,10 +2701,10 @@ def tick(
         with jax.named_scope("stage.effects"):
             param_ctx = None
             if "param" in features:
-                param_ctx = (pcms, pcms_epochs, pcms_idx, prows, p_qps_add, p_thread_add)
+                param_ctx = (prows, p_qps_add, p_thread_add)
             if use_seg:
                 if cfg.seg_fallback:
-                    state = jax.lax.cond(
+                    state, p_upd = jax.lax.cond(
                         ctx_a.ok,
                         lambda: ES.acquire_effects_seg(
                             cfg, state, rules, acq, now_ms, features, passed,
@@ -2716,27 +2718,22 @@ def tick(
                         ),
                     )
                 else:
-                    state = ES.acquire_effects_seg(
+                    state, p_upd = ES.acquire_effects_seg(
                         cfg, state, rules, acq, now_ms, features, passed,
                         occupying, valid, fslots, occ_grant, rl_info,
                         param_ctx, ctx_a, carry_a,
                     )
                     seg_dropped = seg_dropped + ES.dropped_items(ctx_a, valid)
             else:
-                state = _acquire_effects_fused(
-                    cfg,
-                    state,
-                    rules,
-                    acq,
-                    now_ms,
-                    features,
-                    passed,
-                    occupying,
-                    valid,
-                    fslots,
-                    occ_grant,
-                    rl_info,
-                    param_ctx,
+                state, p_upd = _acquire_effects_fused(
+                    cfg, state, rules, acq, now_ms, features, passed,
+                    occupying, valid, fslots, occ_grant, rl_info, param_ctx,
+                )
+            if p_upd is not None:
+                counts, conc = p_upd  # int32 [depth, Q] each
+                state = state._replace(
+                    pcms=P.land(cfg, state.pcms, pcms_idx, counts),
+                    pconc=jnp.maximum(state.pconc + conc, 0),
                 )
         return state, _telemetry_and_output(
             cfg, state, rules, acq, verdict, wait_ms, valid, forced, fslots,
@@ -2837,7 +2834,7 @@ def tick(
             KP = cfg.param_rules_per_resource
             adm = _fan(passed, KP)
             pcms = P.add(
-                pcms,
+                state.pcms,
                 pcms_idx,
                 jnp.where((p_qps_add & adm)[:, None], prows, -1),
                 _fan(acq.count, KP),
@@ -2855,7 +2852,7 @@ def tick(
                 ),
                 lambda: state.pconc,
             )
-            state = state._replace(pcms=pcms, pcms_epochs=pcms_epochs, pconc=pconc)
+            state = state._replace(pcms=pcms, pconc=pconc)
 
     return state, _telemetry_and_output(
         cfg, state, rules, acq, verdict, wait_ms, valid, forced, fslots,

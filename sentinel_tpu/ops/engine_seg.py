@@ -370,9 +370,6 @@ def run_checks_seg(
         if with_param:
             # KP == 1 statically (the seg_checks gate) -> shared slot gather
             pslot_u = slot_vals["param"]
-            pcms, pcms_epochs, pcms_idx = P.refresh(
-                state.pcms, state.pcms_epochs, now_ms, cfg
-            )
             pgu = T.small_gather_fields(
                 cfg,
                 T.pack_fields(
@@ -600,7 +597,8 @@ def run_checks_seg(
             p_app = p_en_i & (ph != 0)
             prows = P.pair_rows(pslot_i, ph, cfg.param_depth, cfg.param_width)
             wtab = P.class_tables(
-                pcms, pcms_epochs, jnp.asarray(rules.param.class_k), now_ms, cfg
+                state.pcms, state.pcms_epochs, jnp.asarray(rules.param.class_k),
+                now_ms, cfg,
             )
             est = P.estimate_fused(cfg, wtab, prows, cls_i)
             any_thread = jnp.any(
@@ -626,10 +624,7 @@ def run_checks_seg(
             (p_rank,) = grouped_exclusive_cumsum(key, [cnt], elig_p)
             over = jnp.where(p_thread_i, conc_est, est) + p_rank + cnt > pthr
             param_block = p_app & over & elig_p & eligible
-            param_state = (
-                pcms, pcms_epochs, pcms_idx, prows,
-                p_app & ~p_thread_i, p_app & p_thread_i,
-            )
+            param_state = (prows, p_app & ~p_thread_i, p_app & p_thread_i)
         else:
             param_block = zero_block
             param_state = None
@@ -1297,7 +1292,7 @@ def acquire_effects_seg(
     # --- param pass + THREAD concurrency: item-axis kernel ---------------
     p_out = None
     if param_ctx is not None:
-        pcms, pcms_epochs, pcms_idx, prows, q_add, thread_add = param_ctx
+        prows, q_add, thread_add = param_ctx
         KP = cfg.param_rules_per_resource
         adm = E._fan(passed, KP)
         cnt_p = E._fan(acq.count, KP)
@@ -1391,10 +1386,9 @@ def acquire_effects_seg(
             occ_epoch=jnp.where(add > 0, cur_wid + 1, state.occ_epoch),
         )
 
+    p_upd = None
     if p_out is not None:
         upd = jnp.round(p_out).astype(jnp.int32)
-        pcms = pcms.at[:, :, pcms_idx].add(upd[:, :, 0])
-        pconc = jnp.maximum(state.pconc + upd[:, :, 1], 0)
-        state = state._replace(pcms=pcms, pcms_epochs=pcms_epochs, pconc=pconc)
+        p_upd = (upd[:, :, 0], upd[:, :, 1])
 
-    return state
+    return state, p_upd

@@ -36,6 +36,8 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from sentinel_tpu.core.config import PARAM_NARROW_WIDTH
+
 #: default items per grid step.  Multi-job kernels unroll one [tb, N_LO]
 #: LoV temporary per digit-dot; ~25 dots x tb=2048 x 128 x 2B ~= 13 MB
 #: stays inside Mosaic's 16 MB scoped-vmem stack (tb=4096 overflows on
@@ -126,6 +128,12 @@ def _pad_axis(x: jax.Array, axis: int, to: int, fill) -> jax.Array:
     return jnp.pad(x, widths, constant_values=fill)
 
 
+#: table rows past which a job leaves scatter_many for scatter_sorted: the
+#: kernel here keeps the job's whole f32 table and a [rows / N_LO, tile]
+#: one-hot in fast memory (32 bytes a row at the default tile), and contracts
+#: every item against all of it
+MAX_RESIDENT_ROWS = PARAM_NARROW_WIDTH
+
 #: max digit-dot units per pallas call — Mosaic's 16 MB scoped-vmem stack
 #: holds ~25-30 unrolled [tb, N_LO] temporaries at tb=2048; larger job
 #: mixes (e.g. rules_per_resource > 1 configs) split across calls
@@ -154,6 +162,19 @@ def scatter_many(jobs: Sequence[Job], tb: int = TILE, interpret: Optional[bool] 
 
     if interpret is None:
         interpret = interpret_mode()
+
+    def sorted_path(j: Job) -> bool:
+        # a table this wide costs items x rows here whatever the items hold;
+        # scatter_sorted takes it if its digit planes pack into one word
+        return j.n > MAX_RESIDENT_ROWS and sum(j.digits) <= 4
+
+    if any(sorted_path(j) for j in jobs):
+        rest = [j for j in jobs if not sorted_path(j)]
+        done = iter(scatter_many(rest, tb=tb, interpret=interpret) if rest else ())
+        return [
+            scatter_sorted(j, interpret=interpret) if sorted_path(j) else next(done)
+            for j in jobs
+        ]
 
     total_units = sum(_job_units(j) for j in jobs)
     if total_units > _MAX_UNITS_PER_CALL and len(jobs) > 1:
@@ -432,3 +453,129 @@ def gather_many(
     for out, (P, n_hi, pd, digits, n) in zip(outs, plans):
         results.append(out.transpose(1, 0, 2).reshape(P, Np)[:, :N].T)  # [N, P]
     return results
+
+
+# ---------------------------------------------------------------------------
+# wide tables: sort a job's cells, visit only the stretches they fall in
+# ---------------------------------------------------------------------------
+
+#: table rows of one stretch a grid step contracts against: N_LO lanes by as
+#: many sublane rows, so a stretch is one square one-hot product
+STRETCH = N_LO * N_LO
+
+
+def scatter_sorted(job: Job, tb: int = TILE, interpret: Optional[bool] = None):
+    """One job's scatter for a table too wide for scatter_many: f32
+    [n, P], the same digit-plane exactness.
+
+    The one-hot contraction costs items x table rows, which at 2^20 rows is
+    a thousand times the items' own work.  Sorted by row, a tile of items
+    touches only a short stretch of the table: the kernel walks the tiles in
+    order and contracts each against the STRETCH-row windows between its
+    first and its last row, whose count it is handed ahead of the grid
+    (scalar prefetch).  A full tile of a dense tick spans one or two windows,
+    a sparse tick's tile many short ones; an all-padding tile none.  The cost
+    follows the items and the spread of their rows, not the table: what is
+    fixed is the sort, and zeroing and writing back the resident table
+    (n x planes x 4 bytes of fast memory: core/config.PARAM_MAX_WIDTH).
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = interpret_mode()
+    R, N0 = job.rows.shape
+    per_row = job.values.ndim == 3
+    P = job.values.shape[-2]
+    digits = tuple(job.digits)
+    pd = sum(digits)
+    if pd > 4:
+        raise ValueError(f"job {job.name}: {pd} digit planes do not pack into one sort payload")
+    n = job.n
+    n_win = (n + STRETCH - 1) // STRETCH
+    n_hi = n_win * N_LO
+    sentinel = n_win * STRETCH  # sorts last, matches no window
+
+    rows = job.rows.astype(jnp.int32).reshape(-1)
+    vals = job.values.astype(jnp.int32)
+    vals = vals.transpose(1, 0, 2) if per_row else jnp.broadcast_to(vals[:, None, :], (P, R, N0))
+    packed, shift = jnp.zeros((R * N0,), jnp.int32), 0
+    for p in range(P):  # every plane's digits side by side in one word
+        packed = packed | (vals[p].reshape(-1) << shift)
+        shift += 8 * digits[p]
+    N = R * N0
+    tb = min(tb, max(256, -(-N // 256) * 256))  # a light tick's one tile is its own size
+    nT = max((N + tb - 1) // tb, 1)
+    rows = _pad_axis(jnp.where((rows >= 0) & (rows < n), rows, sentinel), 0, nT * tb, sentinel)
+    packed = _pad_axis(packed, 0, nT * tb, 0)
+    rows, packed = jax.lax.sort([rows, packed], num_keys=1, is_stable=False)
+
+    # the windows each tile spans: from its first row's to its last live row's
+    r2 = rows.reshape(nT, tb)
+    last = jnp.max(jnp.where(r2 < n, r2, -1), axis=1)
+    first_win = jnp.minimum(r2[:, 0], n - 1) // STRETCH
+    windows = jnp.where(last >= 0, last // STRETCH - first_win + 1, 0).astype(jnp.int32)
+
+    def kernel(first_ref, windows_ref, rows_ref, vals_ref, out_ref):
+        t = pl.program_id(0)
+
+        @pl.when(t == 0)
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        k = rows_ref[0, :]
+        v = vals_ref[0, :]
+        hi = k // N_LO
+        lo = k - hi * N_LO
+        iota_l = jax.lax.broadcasted_iota(jnp.int32, (tb, N_LO), 1)
+        iota_h = jax.lax.broadcasted_iota(jnp.int32, (N_LO, tb), 0)
+        Lo = (lo[:, None] == iota_l).astype(jnp.bfloat16)
+        wide = jnp.concatenate(
+            [Lo * ((v >> (8 * d)) & 0xFF)[:, None].astype(jnp.bfloat16) for d in range(pd)],
+            axis=1,
+        )  # [tb, pd*N_LO]
+
+        def window(w, carry):
+            base = pl.multiple_of((first_ref[t] + w) * N_LO, N_LO)
+            # a sentinel row's hi is past every window: it matches nothing
+            HiT = ((hi[None, :] - base) == iota_h).astype(jnp.bfloat16)
+            res = jax.lax.dot(HiT, wide, preferred_element_type=jnp.float32)
+            for k2 in range(pd):
+                out_ref[k2, pl.ds(base, N_LO), :] += res[:, k2 * N_LO : (k2 + 1) * N_LO]
+            return carry
+
+        jax.lax.fori_loop(0, windows_ref[t], window, 0)
+
+    out = run_pallas(pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nT,),
+            in_specs=[
+                pl.BlockSpec((1, tb), lambda t, *_: (0, t), memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, tb), lambda t, *_: (0, t), memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec(
+                (pd, n_hi, N_LO), lambda t, *_: (0, 0, 0), memory_space=pltpu.VMEM
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((pd, n_hi, N_LO), jnp.float32),
+        interpret=interpret,
+        name="scatter_sorted",
+        compiler_params=pltpu.CompilerParams(
+            # the resident table, twice (the pipeline's two buffers), and room
+            # for a tile's temporaries; v5e has 128 MB a core
+            vmem_limit_bytes=min(2 * pd * n_hi * N_LO * 4 + 24 * 1024 * 1024, 100 * 1024 * 1024)
+        ),
+    ), first_win.astype(jnp.int32), windows, rows[None, :], packed[None, :],
+        key=("scatter_sorted", R, P, per_row, digits, n, nT, tb, bool(interpret)))
+
+    flat = out.reshape(pd, n_hi * N_LO)[:, :n]
+    cols, off = [], 0
+    for p in range(P):
+        acc = flat[off]
+        for d in range(1, digits[p]):
+            acc = acc + flat[off + d] * float(1 << (8 * d))
+        cols.append(acc)
+        off += digits[p]
+    return jnp.stack(cols, axis=1)  # [n, P]

@@ -13,6 +13,14 @@ dense contraction:
     epochs : int32 [nb]             ONE global bucket grid (param_bucket_ms)
     pconc  : int32 [depth, Q]       per-(rule,value) concurrency (THREAD grade)
 
+A WIDE store (``wide(cfg)``: more than PARAM_NARROW_WIDTH cells a depth, e.g.
+the 2^22 an API gateway's million (route, client) pairs take) keeps pcms as
+[depth, nb, Q]: a bucket is then one dense row a depth, so the refresh and
+the landing touch that row and nothing else, where [depth, Q, nb] pads every
+cell's 8 buckets to a lane tile and a column update walks the whole table.
+Reads stay per-item lane-packed gathers; writes leave the one-hot kernels
+(rows x width multiply-adds a plane) for ops/fused.scatter_sorted.
+
 - All rules share the global bucket grid, so the current column is a single
   dense histogram target (ops/tables.py MXU path) and stale-column reset is
   the same epoch scheme as ops/window.py.
@@ -39,7 +47,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from sentinel_tpu.core.config import EngineConfig
+from sentinel_tpu.core.config import PARAM_NARROW_WIDTH, EngineConfig
 from sentinel_tpu.ops import tables as T
 
 # depth-row hash multipliers (odd constants, splitmix-ish)
@@ -72,6 +80,21 @@ def pair_rows(slots: jax.Array, hashes: jax.Array, depth: int, width: int) -> ja
     return cms_cell(mixed.astype(jnp.int32), depth, width)
 
 
+#: lanes of a wide store's row gathers (ops/tables.lane_gather_1col)
+WIDE_LANES = 128
+
+
+def wide(cfg: EngineConfig) -> bool:
+    """True for a store laid out [depth, nb, Q] (see the module docstring)."""
+    return cfg.param_width > PARAM_NARROW_WIDTH
+
+
+def store_shape(cfg: EngineConfig) -> Tuple[int, int, int]:
+    if wide(cfg):
+        return (cfg.param_depth, cfg.param_sample_count, cfg.param_width)
+    return (cfg.param_depth, cfg.param_width, cfg.param_sample_count)
+
+
 def _wid(now_ms, cfg: EngineConfig):
     return (now_ms // cfg.param_bucket_ms).astype(jnp.int32)
 
@@ -87,6 +110,8 @@ def refresh(
     wid = _wid(now_ms, cfg)
     idx = wid % nb
     keep = (epochs[idx] == wid).astype(pcms.dtype)
+    if wide(cfg):
+        return pcms.at[:, idx, :].multiply(keep), epochs.at[idx].set(wid), idx
     return pcms.at[:, :, idx].multiply(keep), epochs.at[idx].set(wid), idx
 
 
@@ -97,7 +122,8 @@ def class_tables(
     now_ms,
     cfg: EngineConfig,
 ) -> jax.Array:
-    """f32 [depth, Q, C]: windowed totals per duration class.
+    """f32 [depth, Q, C] ([depth, C, Q] of a wide store): windowed totals
+    per duration class.
 
     Class c sums buckets whose epoch lies in (wid - k_c, wid] — the k_c
     most recent grid positions (masked elementwise; stale columns excluded
@@ -106,7 +132,7 @@ def class_tables(
     # [C, nb] validity masks
     valid = (epochs[None, :] > wid - class_k[:, None]) & (epochs[None, :] <= wid)
     return jnp.einsum(
-        "dqb,cb->dqc",
+        "dbq,cb->dcq" if wide(cfg) else "dqb,cb->dqc",
         pcms.astype(jnp.float32),
         valid.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
@@ -120,6 +146,8 @@ def estimate(
     cls: jax.Array,  # int32 [N] — rule's duration class per item
 ) -> jax.Array:
     """f32 [N] — windowed CMS estimate (min over depth) for each item."""
+    if wide(cfg):
+        return _estimate_wide(cfg, wtab, rows, cls)
     C = wtab.shape[2]
     # class selection as a tiny one-hot contraction — take_along_axis lowers
     # to a serialized per-item gather on TPU
@@ -164,6 +192,8 @@ def estimate_fused(
     the pallas one-hot digit kernel (~1.3 ms at B=128K).  Saturation at
     256**param_est_digits - 1 and min-over-depth are bit-identical to
     estimate(), so every cross-path equivalence suite holds unchanged."""
+    if wide(cfg):
+        return _estimate_wide(cfg, wtab, rows, cls)
     depth, Q, C = wtab.shape
     cap = jnp.int32(256**cfg.param_est_digits - 1)
     idx = jnp.clip(rows, 0, Q - 1) * C + jnp.clip(cls, 0, C - 1)[:, None]
@@ -174,10 +204,31 @@ def estimate_fused(
     return jnp.min(jnp.stack(ests, axis=0), axis=0).astype(jnp.float32)
 
 
+def _estimate_wide(cfg: EngineConfig, wtab: jax.Array, rows: jax.Array, cls: jax.Array):
+    """estimate() of a wide store, whose class tables are [depth, C, Q]:
+    one lane-packed row gather a depth, whatever the backend.  Nothing is
+    saturated: no digit plane carries the value, and a windowed total stays
+    f32-exact far past any threshold."""
+    depth, C, Q = wtab.shape
+    idx = jnp.clip(cls, 0, C - 1)[:, None] * Q + jnp.clip(rows, 0, Q - 1)
+    ests = [
+        T.lane_gather_1col(cfg, wtab[d].reshape(-1), idx[:, d], C * Q, lanes=WIDE_LANES)
+        for d in range(depth)
+    ]
+    return jnp.min(jnp.stack(ests, axis=0), axis=0)
+
+
 def conc_estimate(
     cfg: EngineConfig, pconc: jax.Array, rows: jax.Array
 ) -> jax.Array:
     """f32 [N] — current concurrency estimate (min over depth)."""
+    if wide(cfg):
+        # the one-hot gather below is rows x width multiply-adds a digit
+        ests = [
+            T.lane_gather_1col(cfg, pconc[d], rows[:, d], cfg.param_width, lanes=WIDE_LANES)
+            for d in range(pconc.shape[0])
+        ]
+        return jnp.min(jnp.stack(ests, axis=0), axis=0)
     ests = []
     cap = jnp.int32((1 << 24) - 1)
     for d in range(pconc.shape[0]):
@@ -200,10 +251,17 @@ def add(
     cfg: EngineConfig,
 ) -> jax.Array:
     """Histogram admitted counts into every depth row of the current bucket."""
-    for d in range(pcms.shape[0]):
-        hist = T.histogram(cfg, rows[:, d], counts, cfg.param_width)
-        pcms = pcms.at[d, :, cur_idx].add(hist.astype(pcms.dtype))
-    return pcms
+    hists = [
+        T.histogram(cfg, rows[:, d], counts, cfg.param_width) for d in range(pcms.shape[0])
+    ]
+    return land(cfg, pcms, cur_idx, jnp.stack(hists).astype(pcms.dtype))
+
+
+def land(cfg: EngineConfig, pcms: jax.Array, cur_idx, upd: jax.Array) -> jax.Array:
+    """Add ``upd`` [depth, Q] to the current bucket of every depth."""
+    if wide(cfg):
+        return pcms.at[:, cur_idx, :].add(upd)
+    return pcms.at[:, :, cur_idx].add(upd)
 
 
 def conc_add(

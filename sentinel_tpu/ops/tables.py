@@ -62,7 +62,7 @@ def big_gather(
 
 
 def lane_gather_1col(
-    cfg: EngineConfig, table: jax.Array, idx: jax.Array, n: int
+    cfg: EngineConfig, table: jax.Array, idx: jax.Array, n: int, lanes: int = 8
 ) -> jax.Array:
     """f32 table[idx] for a ONE-COLUMN table, zeros for ids outside [0, n).
 
@@ -72,18 +72,23 @@ def lane_gather_1col(
     index-axis pass per digit plane.  Packing the column as [n/8, 8] and
     selecting the lane with a DATA-DEPENDENT one-hot keeps the row read
     8 lanes wide and cannot be narrowed.  Exact: native row gather +
-    multiply by exact 0/1 (same trick as param.estimate_fused)."""
+    multiply by exact 0/1 (same trick as param.estimate_fused).
+
+    ``lanes`` (a power of two): a [n/8, 8] view of a table of millions of
+    cells is materialized padded to the 128-lane tile, sixteen times the
+    table, every call; such a table is read at ``lanes=128``, where the view
+    is the table itself."""
     ok = (idx >= 0) & (idx < n)
     safe = jnp.clip(idx, 0, n - 1)
     if not cfg.use_mxu_tables:
         return jnp.where(ok, table[safe].astype(jnp.float32), 0.0)
     t = table.astype(jnp.float32)
-    pad = (-n) % 8
+    pad = (-n) % lanes
     if pad:
         t = jnp.concatenate([t, jnp.zeros((pad,), jnp.float32)])
-    g = t.reshape(-1, 8)[safe >> 3]  # [N, 8] row gather
+    g = t.reshape(-1, lanes)[safe >> (lanes.bit_length() - 1)]  # [N, lanes] row gather
     oh = (
-        (safe & 7)[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, 8), 1)
+        (safe & (lanes - 1))[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
     ).astype(jnp.float32)
     return jnp.where(ok, jnp.sum(g * oh, axis=1), 0.0)
 
@@ -306,23 +311,26 @@ def small_gather_fields(
 def small_gather_int(cfg: EngineConfig, table: jax.Array, slots: jax.Array) -> jax.Array:
     """Exact int32 gather from a small table via f32 matmuls.
 
-    A raw int32 (e.g. a param hash) does not survive an f32 matmul above
-    2^24; splitting into unsigned 16-bit halves keeps each half exact and
-    the int32 recombination restores the original bits."""
+    A raw int32 (e.g. a param hash) does not survive the matmul whole: at
+    PRECISION the chip rounds BOTH sides to bfloat16, which holds 8 bits, so
+    the table crosses as its four bytes, each exact, and the int32
+    recombination restores the original bits.  (16-bit halves were rounded
+    on the chip: no ParamFlowItem hash ever matched there.)"""
     if not cfg.use_mxu_tables:
         S = table.shape[0]
         return table[jnp.clip(slots, 0, S - 1)]
     t = jnp.asarray(table)
     flat = t.reshape(t.shape[0], -1).astype(jnp.uint32)
-    hi = (flat >> 16).astype(jnp.float32)
-    lo = (flat & 0xFFFF).astype(jnp.float32)
-    packed = jnp.concatenate([hi, lo], axis=1)
-    g = small_gather_fields(cfg, packed, slots)
     F = flat.shape[1]
-    hi_i = jnp.round(g[:, :F]).astype(jnp.uint32)
-    lo_i = jnp.round(g[:, F:]).astype(jnp.uint32)
-    out = ((hi_i << 16) | lo_i).astype(jnp.int32)
-    return out.reshape((slots.shape[0],) + t.shape[1:])
+    shifts = (24, 16, 8, 0)
+    packed = jnp.concatenate(
+        [((flat >> s) & 0xFF).astype(jnp.float32) for s in shifts], axis=1
+    )
+    g = jnp.round(small_gather_fields(cfg, packed, slots)).astype(jnp.uint32)
+    out = jnp.zeros((slots.shape[0], F), jnp.uint32)
+    for i, s in enumerate(shifts):
+        out = out | (g[:, i * F : (i + 1) * F] << s)
+    return out.astype(jnp.int32).reshape((slots.shape[0],) + t.shape[1:])
 
 
 def small_scatter_add(

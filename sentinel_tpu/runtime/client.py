@@ -171,6 +171,10 @@ _C_DEV_VERDICTS: Dict[str, Any] = {
         "block_degrade",
     )
 }
+_C_PARAM_BLOCKED = OBS.counter(
+    "sentinel_param_blocked_total",
+    "items a hot-parameter rule blocked (BLOCK_PARAM), from the device telemetry row",
+)
 _C_DEV_TOKENS = {
     r: OBS.counter(
         "sentinel_device_tokens_total",
@@ -617,6 +621,7 @@ class SentinelClient:
         self._cluster_param_by_res: Dict[str, R.ParamFlowRule] = {}
         self._auth_host_rules: Dict[str, list] = {}
         self._param_lanes_by_res: Dict[str, list] = {}
+        self._param_ruled = np.zeros(1, bool)
         # the shared degrade-hysteresis primitive (adaptive/degrade.py):
         # enter-on-failure with cooldown, exit on first healthy probe —
         # same journal kinds / counters / gauge as before the refactor
@@ -1391,6 +1396,14 @@ class SentinelClient:
         if self._cluster_degraded_active:
             local_flow += [r for r in cluster_flow if r.cluster_fallback_to_local]
             local_param += cluster_param
+        # resource ids under a local hot-parameter rule (tick.resolve's
+        # param_rows counts a tick's items that carried a value under one)
+        ruled = np.zeros(self.cfg.max_resources + 2, bool)
+        for r in local_param:
+            rid = self.registry.resource_id(r.resource)  # interned, as the compile below does
+            if rid is not None and rid <= self.cfg.max_resources:
+                ruled[rid] = True
+        self._param_ruled = ruled
 
         # engine specialization: with the client presorting every batch
         # (see _run_tick), a ruleset of single-lane DIRECT/default-limitApp
@@ -3109,6 +3122,8 @@ class SentinelClient:
             n = int(s[idx])
             if n:
                 _C_DEV_VERDICTS[key].inc(n)
+                if idx == E.STAT_BLOCK_PARAM:
+                    _C_PARAM_BLOCKED.inc(n)
         n = int(s[E.STAT_FORCED])
         if n:
             _C_DEV_FORCED.inc(n)
@@ -3908,6 +3923,7 @@ class SentinelClient:
             return  # the watchdog failed this tick over while we read back
         self._untrack_tick(p)
         _t_res = OT.t0()
+        _param_rows = None
         if p.inv_a is not None:
             # map sorted-batch verdicts back to submission order
             verdict = verdict[p.inv_a]
@@ -3922,6 +3938,8 @@ class SentinelClient:
             # tick that fails, by the watchdog or here, never gets this
             # far: it may still run on the device, and keeps its buffer.)
             wb, p.wire_in = p.wire_in, None
+            if _t_res:
+                _param_rows = self._param_rows(wb)
             self._wire_free[wb.layout].append(wb)
         if self._adaptive is not None:
             if stats is not None:
@@ -3970,10 +3988,35 @@ class SentinelClient:
                     p.fronts_done += 1
                     off += k
         if _t_res:
-            OT.stage(
-                "tick.resolve", _t_res, _H_RESOLVE, trace=p.tick_id,
-                attrs={"n_obj": p.n_obj, "n_blk": p.n_blk},
-            )
+            attrs = {"n_obj": p.n_obj, "n_blk": p.n_blk}
+            if _param_rows is not None:
+                # of the tick's items under a hot-parameter rule, those it blocked
+                attrs["param_rows"] = _param_rows
+                attrs["param_blocked"] = int(np.count_nonzero(verdict == ERR.BLOCK_PARAM))
+            OT.stage("tick.resolve", _t_res, _H_RESOLVE, trace=p.tick_id, attrs=attrs)
+
+    def _param_rows(self, wb) -> int:
+        """Items of a tick's input that carried a parameter value under a
+        local hot-parameter rule (read on the resolver's thread, with the
+        tracer on only, from the input buffer before it is lent on)."""
+        ruled = self._param_ruled
+        res = np.minimum(wb.acq["res"], len(ruled) - 1)
+        return int(np.count_nonzero(ruled[res] & (wb.acq["param_hash"] != 0).any(axis=0)))
+
+    def param_store_occupancy(self) -> dict:
+        """Cells of the hot-parameter store's newest bucket that count
+        something, a depth: how loaded the count-min sketch is.  A whole-
+        table read: for a summary after serving, never on the tick thread."""
+        from sentinel_tpu.ops import param as P
+
+        with self._engine_lock:
+            pcms, epochs = self._state.pcms, self._state.pcms_epochs
+        newest = int(np.argmax(np.asarray(epochs)))
+        bucket = pcms[:, newest, :] if P.wide(self.cfg) else pcms[:, :, newest]
+        return {
+            "store_cells": int(self.cfg.param_width),
+            "store_cells_counting": np.asarray(jnp.count_nonzero(bucket, axis=1)).tolist(),
+        }
 
 
 def _mask_min_rt(v: float) -> float:

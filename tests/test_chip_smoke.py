@@ -109,6 +109,43 @@ def test_enabling_the_compile_cache_keeps_the_small_programs_too(monkeypatch, tm
         jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
 
 
+@pytest.mark.parametrize(
+    "sizes", [(), (chip_smoke.PARAM_REHEARSAL_SIZES,)], ids=["chip", "rehearsal"]
+)
+def test_the_smoke_builds_the_param_deployment_its_cell_measures(sizes):
+    with open(os.path.join(REPO, "perfbench", "configs", "param-1m-hot-keys.json")) as f:
+        want = json.load(f)
+    for s in sizes:
+        for group, keys in s.items():
+            want[group] = {**want[group], **keys}
+    dep = chip_smoke.build(3, *sizes, config=chip_smoke.PARAM_CONFIG)
+    c = dep.client
+    try:
+        assert dep.config == want
+        assert {k: getattr(c.cfg, k) for k in want["engine"]} == want["engine"]
+        rules = c.param_flow_rules.get()
+        assert len(rules) == want["resources"]["n_routes"] == len(dep.route_ids)
+        assert {(r.count, r.param_flow_item_list[0].count) for r in rules} == {(5, 10)}
+        assert not c.flow_rules.get() and not c.degrade_rules.get()
+        assert dep.universe == want["resources"]["universe_pairs"]
+        assert len(dep.pool) == want["traffic"]["pool_batches"]
+    finally:
+        c.stop()
+
+
+def test_the_param_store_phase_holds_a_replay_to_the_exact_shadow():
+    """The phase itself at rehearsal size, on the plain CPU path (the
+    rehearsal's forced fast-path flags are the slow test's)."""
+    sizes = dict(chip_smoke.PARAM_REHEARSAL_SIZES)
+    sizes["engine"] = {k: v for k, v in sizes["engine"].items()
+                       if k not in ("use_mxu_tables", "fused_effects", "seg_effects")}
+    detail, failures = chip_smoke.param_store_phase(5, (sizes,), 12)
+    assert failures == []
+    assert detail["param_width"] == 1 << 15 and detail["rules"] == 16
+    assert detail["replay_param_over_admitted"] == 0 and detail["replay_blocked_items"] >= 1
+    assert detail["store_cells"] == 1 << 15 and all(n > 0 for n in detail["store_cells_counting"])
+
+
 def test_result_line_has_exactly_the_contract_keys():
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     line = chip_smoke.result_line(True, {**device, "extra": 0})
@@ -129,6 +166,6 @@ def test_cpu_rehearsal_walks_every_phase():
     assert final["device"]["platform"] == "cpu"
     assert summary["ok"] is True and summary["rehearsal"] is True
     assert list(summary["phases"]) == [
-        "environment", "serve", "evidence", "equivalence",
+        "environment", "serve", "evidence", "equivalence", "param_store",
     ]
     assert all(p["ok"] for p in summary["phases"].values())

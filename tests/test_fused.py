@@ -203,3 +203,86 @@ def test_fused_tick_matches_mxu_path(sketch):
     paths = [str(p) for p, _ in jax.tree_util.tree_flatten_with_path(st1)[0]]
     for p, x, y in zip(paths, l1, l2):
         np.testing.assert_array_equal(x, y, err_msg=p)
+
+
+# -- scatter_sorted: a table too wide for scatter_many's resident form ------
+
+
+def _ref_scatter(n, rows, vals):
+    """numpy oracle for one Job: rows [R, N], vals [P, N] or [R, P, N]."""
+    R, P = rows.shape[0], vals.shape[-2]
+    ref = np.zeros((n, P), np.int64)
+    for r in range(R):
+        ok = (rows[r] >= 0) & (rows[r] < n)
+        for p in range(P):
+            v = vals[r, p] if vals.ndim == 3 else vals[p]
+            np.add.at(ref[:, p], rows[r][ok], v[ok])
+    return ref
+
+
+@pytest.mark.parametrize(
+    "n,N,R,per_row,digits,tb",
+    [
+        (1 << 15, 700, 1, False, (1, 1), 256),  # two stretches, a sparse tick
+        (1 << 16, 3000, 2, True, (1, 1), 256),  # per-row-vector values
+        (1 << 15, 256, 1, False, (2,), 256),  # one tile spans every stretch
+        (1 << 20, 4096, 1, True, (1, 1), 2048),  # the gateway's width
+        ((1 << 15) + 640, 900, 1, False, (1, 2), 512),  # a last stretch part-filled
+    ],
+)
+def test_scatter_sorted_exact_vs_numpy(n, N, R, per_row, digits, tb):
+    rng = np.random.default_rng(n % 1000 + N)
+    rows = rng.integers(-5, n + 5, (R, N)).astype(np.int32)
+    rows[:, : N // 4] = rows[0, 0]  # a hot cell, hit hundreds of times
+    P = len(digits)
+    shape = (R, P, N) if per_row else (P, N)
+    vals = np.stack(
+        [rng.integers(0, 256 ** d, shape[:-2] + (N,)) for d in digits], axis=-2
+    ).astype(np.int32)
+    job = FU.Job("wide", n, jnp.asarray(rows), jnp.asarray(vals), digits)
+    got = np.asarray(FU.scatter_sorted(job, tb=tb, interpret=True))
+    assert got.shape == (n, P)
+    assert np.array_equal(got.astype(np.int64), _ref_scatter(n, rows, vals))
+
+
+def test_scatter_sorted_of_nothing_is_zero():
+    rows = np.full((1, 300), -1, np.int32)
+    job = FU.Job("idle", 1 << 15, jnp.asarray(rows), jnp.ones((1, 300), jnp.int32), (1,))
+    assert not np.asarray(FU.scatter_sorted(job, tb=256, interpret=True)).any()
+
+
+def test_scatter_many_sends_a_wide_job_to_scatter_sorted_and_keeps_the_order():
+    rng = np.random.default_rng(3)
+    N = 400
+    narrow = rng.integers(-2, 90, (1, N)).astype(np.int32)
+    wide = rng.integers(-2, 1 << 15, (1, N)).astype(np.int32)
+    v = rng.integers(0, 200, (1, N)).astype(np.int32)
+    jobs = [
+        FU.Job("n0", 77, jnp.asarray(narrow), jnp.asarray(v), (1,)),
+        FU.Job("w", 1 << 15, jnp.asarray(wide), jnp.asarray(v), (1,)),
+        FU.Job("n1", 90, jnp.asarray(narrow), jnp.asarray(v), (1,)),
+    ]
+    assert FU.MAX_RESIDENT_ROWS < 1 << 15
+    outs = FU.scatter_many(jobs, tb=256, interpret=True)
+    for out, (n, rows) in zip(outs, ((77, narrow), (1 << 15, wide), (90, narrow))):
+        assert np.array_equal(np.asarray(out).astype(np.int64), _ref_scatter(n, rows, v))
+
+
+def test_a_wide_param_store_ticks_alike_on_the_plain_and_the_seg_path():
+    """Whole ticks with QPS and THREAD hot-parameter rules over a store of
+    2^15 cells a depth (wide: [depth, bucket, cell], written by
+    scatter_sorted on the fused paths): the verdicts and the store equal the
+    plain scatter path's.  (RT sums differ between the two by the fused
+    paths' 1/8 ms quantum, as at any width.)"""
+    from sentinel_tpu.core.config import small_engine_config
+
+    base = dict(batch_size=96, complete_batch_size=96, param_width=1 << 15, param_rules_per_resource=1)
+    st0, out0 = _tick_once(small_engine_config(**base), sort_batches=True)
+    assert st0.pcms.shape == (2, 8, 1 << 15) and st0.pcms.any() and st0.pconc.any()
+    assert any((v == 3).any() for v in out0)  # BLOCK_PARAM was produced
+    seg = small_engine_config(**base, use_mxu_tables=True, fused_effects=True, seg_effects=True)
+    st1, out1 = _tick_once(seg, sort_batches=True)
+    for a, b in zip(out0, out1):
+        np.testing.assert_array_equal(a, b)
+    for leaf in ("pcms", "pcms_epochs", "pconc"):
+        np.testing.assert_array_equal(getattr(st0, leaf), getattr(st1, leaf), err_msg=leaf)

@@ -248,3 +248,27 @@ def test_lane_gather_multi_matches_oracle():
                     np.asarray(got[c]).astype(np.int64), want,
                     err_msg=f"n={n} k={k} col={c}",
                 )
+
+
+@pytest.mark.parametrize("n_rows", [257, 2049])  # the flat one-hot, and the Hi/Lo plan
+def test_small_gather_int_survives_the_chips_bfloat16_matmul(monkeypatch, n_rows):
+    """On the chip a matmul at tables.PRECISION rounds both sides to
+    bfloat16 (8 bits).  With the table's side rounded the same way here,
+    raw int32 hashes must still come back bit for bit: the table crosses as
+    bytes.  (As 16-bit halves no ParamFlowItem hash matched on the chip.)"""
+    from sentinel_tpu.core.config import small_engine_config
+    from sentinel_tpu.ops import tables as T
+
+    real = T.small_gather_fields
+
+    def as_the_chip(cfg, packed, slots):
+        return real(cfg, packed.astype(jnp.bfloat16).astype(jnp.float32), slots)
+
+    monkeypatch.setattr(T, "small_gather_fields", as_the_chip)
+    rng = np.random.default_rng(11)
+    table = rng.integers(-(2**31), 2**31 - 1, (n_rows, 3)).astype(np.int32)
+    table[0] = (0, -1, 2**31 - 1)
+    slots = rng.integers(0, n_rows, 500).astype(np.int32)
+    cfg = small_engine_config(use_mxu_tables=True)
+    got = np.asarray(T.small_gather_int(cfg, jnp.asarray(table), jnp.asarray(slots)))
+    assert got.dtype == np.int32 and (got == table[slots]).all()

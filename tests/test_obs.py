@@ -914,8 +914,8 @@ def test_the_lowered_tick_names_its_program_kernels_and_stages():
     scopes = set(re.findall(r"stage\.[a-z_]+", text))
     assert cfg.packed_wire and cfg.seg_effects and cfg.sketch_stats
     assert scopes == {
-        "stage.widen", "stage.seg_prepare", "stage.exits", "stage.warmup", "stage.checks",
-        "stage.segment_reads", "stage.authority", "stage.system", "stage.param", "stage.flow",
+        "stage.widen", "stage.seg_prepare", "stage.exits", "stage.warmup", "stage.param_refresh",
+        "stage.checks", "stage.segment_reads", "stage.authority", "stage.system", "stage.param", "stage.flow",
         "stage.tail_flow", "stage.degrade", "stage.verdict", "stage.effects", "stage.sketch",
         "stage.telemetry", "stage.pack"}, sorted(scopes)
 

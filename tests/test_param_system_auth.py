@@ -231,3 +231,70 @@ def test_flow_rule_limit_app_specific_and_other(client, vt):
         got_z = sum(1 for _ in range(8) if client.try_entry("mix"))
     assert got_a == 2  # specific rule
     assert got_z == 5  # "other" rule
+
+
+# ---------------- a wide hot-parameter store ----------------
+
+
+def test_param_width_past_the_fused_tables_reach_fails_at_construction_in_one_line():
+    import pytest
+
+    from sentinel_tpu.core.config import PARAM_MAX_WIDTH, PARAM_NARROW_WIDTH, EngineConfig
+
+    assert EngineConfig().param_width == PARAM_NARROW_WIDTH  # today's store: narrow
+    assert EngineConfig(param_width=1 << 20).param_width == 1 << 20
+    for width in (PARAM_NARROW_WIDTH + 128, 3 * PARAM_NARROW_WIDTH // 2, 2 * PARAM_MAX_WIDTH):
+        with pytest.raises(ValueError, match=f"param_width {width}") as e:
+            EngineConfig(param_width=width, use_mxu_tables=True, fused_effects=True)
+        assert "\n" not in str(e.value)
+
+
+def test_param_flow_holds_its_budgets_over_a_wide_store(client_factory, vt):
+    """The per-value budget, the exception item and the window, with the
+    store 2^15 cells wide: laid out [depth, bucket, cell]."""
+    from sentinel_tpu.core.config import small_engine_config
+    from sentinel_tpu.obs import profile as PROF
+
+    client = client_factory(cfg=small_engine_config(param_width=1 << 15))
+    assert client._state.pcms.shape == (2, 8, 1 << 15)
+    client.param_flow_rules.load([
+        st.ParamFlowRule(resource="api", count=2, duration_in_sec=1,
+                         param_flow_item_list=[ParamFlowItem(object="vip", count=5)])
+    ])
+    assert sum(1 for _ in range(6) if client.try_entry("api", args=["a"])) == 2
+    assert sum(1 for _ in range(8) if client.try_entry("api", args=["vip"])) == 5
+    assert client.try_entry("api") is not None
+    occupied = client.param_store_occupancy()
+    assert occupied == {"store_cells": 1 << 15, "store_cells_counting": [2, 2]}
+    vt.advance(1100)
+    assert client.try_entry("api", args=["a"]) is not None
+    # the store is a pool of its own in the memory ledger: counts and concurrency
+    assert PROF.LEDGER.pool_bytes("param_store") >= (2 * 8 + 2) * (1 << 15) * 4
+
+
+def test_tick_resolve_carries_param_rows_and_param_blocked(client_factory, vt):
+    import numpy as np
+
+    from sentinel_tpu import obs
+    from sentinel_tpu.core.rule_tensors import hash_param
+    from sentinel_tpu.obs.registry import REGISTRY
+
+    client = client_factory()
+    client.param_flow_rules.load([st.ParamFlowRule(resource="api", count=3)])
+    api, other = client.registry.resource_id("api"), client.registry.resource_id("plain")
+    blocked_before = REGISTRY.get("sentinel_param_blocked_total").value
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        ph = np.zeros((10, client.cfg.param_dims), np.int32)
+        ph[:8, 0] = hash_param("a")  # 7 under the rule with a value, 1 elsewhere, 2 without
+        res = np.array([api] * 7 + [other] + [api] * 2, np.int32)
+        fut = client.submit_block(res, param_hash=ph)
+        client.tick_once()
+        verdicts = fut.result(timeout=5)[0]
+    finally:
+        obs.disable()
+    assert (verdicts == 3).sum() == 4 and (verdicts == 0).sum() == 6
+    resolves = [s for s in obs.TRACER.snapshot() if s["name"] == "tick.resolve"]
+    assert [(s["attrs"]["param_rows"], s["attrs"]["param_blocked"]) for s in resolves] == [(7, 4)]
+    assert REGISTRY.get("sentinel_param_blocked_total").value == blocked_before + 4
