@@ -155,9 +155,14 @@ class EngineConfig:
     # window at that share.
     # A width up to PARAM_NARROW_WIDTH keeps each depth's table whole in a
     # fused kernel's fast memory; a wider store (a multiple of it, up to
-    # PARAM_MAX_WIDTH) is laid out [depth, bucket, cell] and written by a
-    # kernel that sorts a tick's cells and visits only the stretches of the
-    # table they fall in (ops/fused.py scatter_sorted)
+    # PARAM_MAX_WIDTH) is laid out [depth, bucket, cell / 128, 128] and
+    # written by a kernel that sorts a tick's cells and visits only the
+    # stretches of the table they fall in (ops/fused.py scatter_sorted).
+    # The cell axis is split because the chip tiles an array's last two axes
+    # (8 x 128): as [depth, bucket, cell] the eight buckets were a tile's
+    # sublanes and one bucket's row a part of every tile, so clearing or
+    # writing it passed over the whole store; split, a bucket's row is
+    # contiguous (ops/param.py)
     param_depth: int = 2
     param_width: int = 1 << 14
     param_sample_count: int = 8

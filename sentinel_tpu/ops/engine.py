@@ -121,7 +121,9 @@ class EngineState(NamedTuple):
     cb_counts: jax.Array  # int32 [D+1, nbc, 3]
     cb_epochs: jax.Array  # int32 [D+1, nbc]
     # hashed (rule,value) param store (ops/param.py v2)
-    pcms: jax.Array  # int32 [depth, Q, nbp] windowed counts ([depth, nbp, Q] when P.wide(cfg))
+    # (a wide store, P.wide(cfg), keeps its cell axis as (Q/128, 128) tiles:
+    # pcms [depth, nbp, Q/128, 128], pconc [depth, Q/128, 128])
+    pcms: jax.Array  # int32 [depth, Q, nbp] windowed counts
     pcms_epochs: jax.Array  # int32 [nbp] global bucket epochs
     pconc: jax.Array  # int32 [depth, Q] per-(rule,value) concurrency
     # global observability sketch for tail resources (ops/gsketch.py);
@@ -430,13 +432,13 @@ def _init_state(cfg: EngineConfig) -> EngineState:
         cb_counts=jnp.zeros((Dn + 1, cfg.cb_sample_count, 3), dtype=jnp.int32),
         cb_epochs=jnp.full((Dn + 1, cfg.cb_sample_count), -10, dtype=jnp.int32),
         pcms=jnp.zeros(
-            P.store_shape(cfg),  # [depth, Q, nb]; [depth, nb, Q] when wide
+            P.store_shape(cfg),  # [depth, Q, nb]; [depth, nb, Q/128, 128] when wide
             dtype=jnp.int32,
         ),
         pcms_epochs=jnp.full(
             (cfg.param_sample_count,), -(cfg.param_sample_count + 1), dtype=jnp.int32
         ),
-        pconc=jnp.zeros((cfg.param_depth, cfg.param_width), dtype=jnp.int32),
+        pconc=jnp.zeros(P.conc_shape(cfg), dtype=jnp.int32),
         gs=_sketch(cfg).init_sketch(sketch_config(cfg))
         if cfg.sketch_stats
         else GS.SketchState(
@@ -1198,6 +1200,26 @@ def _clean_rows(cfg: EngineConfig, x):
     return jnp.where(x == cfg.trash_row, jnp.int32(2**30), x)
 
 
+def _param_upd(cfg: EngineConfig, p_out):
+    """What the tick lands in the store, from the ``param{d}`` jobs' tables
+    ([depth, Q, 2]; [depth, 2, Q/128, 128] of a wide store, _param_tiles):
+    int32 (counts, concurrency), P.conc_shape(cfg) each; None without."""
+    if p_out is None:
+        return None
+    upd = jnp.round(p_out).astype(jnp.int32)
+    if P.wide(cfg):
+        return upd[:, 0], upd[:, 1]
+    return upd[:, :, 0], upd[:, :, 1]
+
+
+def _param_tiles(pjobs):
+    """A WIDE store's ``param{d}`` / ``prel{d}`` jobs: f32 [depth, P, Q/128,
+    128], each depth's planes as scatter_sorted wrote them.  The store keeps
+    the same tiles (ops/param.py), so [Q, P] is never formed and no plane is
+    laid out anew on its way to P.land."""
+    return jnp.stack([FU.scatter_sorted_tiles(j) for j in pjobs])
+
+
 def _process_completions_fused(
     cfg: EngineConfig,
     state: EngineState,
@@ -1287,10 +1309,12 @@ def _process_completions_fused(
         # ride as row-vectors with per-row release counts
         pr = jnp.where(rel[:, None], prows_c, -1).reshape(b, KPp, cfg.param_depth)
         rel_cnt = rel_cnt_f.reshape(b, KPp).T[:, None, :]  # [KPp, 1, B]
-        for d in range(cfg.param_depth):
-            jobs.append(
-                FU.Job(f"prel{d}", cfg.param_width, pr[:, :, d].T, rel_cnt, (cd,))
-            )
+        prel_jobs = [
+            FU.Job(f"prel{d}", cfg.param_width, pr[:, :, d].T, rel_cnt, (cd,))
+            for d in range(cfg.param_depth)
+        ]
+        if not P.wide(cfg):
+            jobs.extend(prel_jobs)
 
     # --- circuit-breaker columns + probe flags -----------------------------
     with_degrade = "degrade" in features
@@ -1351,7 +1375,9 @@ def _process_completions_fused(
         sk_out = jnp.stack(outs[oi : oi + cfg.sketch_depth])  # [depth, width, 3]
         oi += cfg.sketch_depth
     prel_out = None
-    if with_param:
+    if with_param and P.wide(cfg):
+        prel_out = _param_tiles(prel_jobs)[:, 0]  # [depth, Q/128, 128]
+    elif with_param:
         prel_out = jnp.stack(
             [outs[oi + d][:, 0] for d in range(cfg.param_depth)]
         )  # [depth, Q]
@@ -1408,7 +1434,7 @@ def _process_completions_fused(
     concurrency = jnp.maximum(state.concurrency - hist[:, W.EV_SUCCESS], 0)
 
     if prel_out is not None:
-        dec = jnp.round(prel_out).astype(jnp.int32)  # [depth, Q]
+        dec = jnp.round(prel_out).astype(jnp.int32)  # P.conc_shape(cfg)
         state = state._replace(pconc=jnp.maximum(state.pconc - dec, 0))
 
     if not with_degrade:
@@ -1547,16 +1573,18 @@ def _acquire_effects_fused(
             ]
         )  # [2, B*KP]
         p_vals_r = p_vals.reshape(2, b, KP).transpose(2, 0, 1)  # [KP, 2, B]
-        for d in range(cfg.param_depth):
-            jobs.append(
-                FU.Job(
-                    f"param{d}",
-                    cfg.param_width,
-                    prows[:, d].reshape(b, KP).T,
-                    p_vals_r,
-                    (cd, cd),
-                )
+        param_jobs = [
+            FU.Job(
+                f"param{d}",
+                cfg.param_width,
+                prows[:, d].reshape(b, KP).T,
+                p_vals_r,
+                (cd, cd),
             )
+            for d in range(cfg.param_depth)
+        ]
+        if not P.wide(cfg):
+            jobs.extend(param_jobs)
 
     outs = _scatter_with_stat_fan(
         cfg, jobs, acq.res, acq.ctx_node, acq.origin_node, valid,
@@ -1578,7 +1606,9 @@ def _acquire_effects_fused(
         occ_out = outs[oi]  # [max_nodes, 1]
         oi += 1
     p_out = None
-    if param_ctx is not None:
+    if param_ctx is not None and P.wide(cfg):
+        p_out = _param_tiles(param_jobs)  # [depth, 2, Q/128, 128]
+    elif param_ctx is not None:
         p_out = jnp.stack(outs[oi : oi + cfg.param_depth])  # [depth, Q, 2]
         oi += cfg.param_depth
 
@@ -1649,13 +1679,7 @@ def _acquire_effects_fused(
             occ_epoch=jnp.where(add > 0, cur_wid + 1, state.occ_epoch),
         )
 
-    p_upd = None
-    if param_ctx is not None:
-        # what the tick lands in the store: (counts, concurrency), [depth, Q]
-        upd = jnp.round(p_out).astype(jnp.int32)  # [depth, Q, 2]
-        p_upd = (upd[:, :, 0], upd[:, :, 1])
-
-    return state, p_upd
+    return state, _param_upd(cfg, p_out)
 
 
 @jax.named_scope("stage.authority")
@@ -2730,7 +2754,7 @@ def tick(
                     occupying, valid, fslots, occ_grant, rl_info, param_ctx,
                 )
             if p_upd is not None:
-                counts, conc = p_upd  # int32 [depth, Q] each
+                counts, conc = p_upd  # int32 P.conc_shape(cfg) each
                 state = state._replace(
                     pcms=P.land(cfg, state.pcms, pcms_idx, counts),
                     pconc=jnp.maximum(state.pconc + conc, 0),

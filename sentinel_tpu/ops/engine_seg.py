@@ -1043,12 +1043,14 @@ def process_completions_seg(
                 FU.Job(f"prel{d}", cfg.param_width, pr[:, :, d].T, rel_cnt, (cd,))
                 for d in range(cfg.param_depth)
             ]
+            if P.wide(cfg):
+                return E._param_tiles(pjobs)[:, 0]
             return jnp.stack([o[:, 0] for o in FU.scatter_many(pjobs)])
 
         prel_out = jax.lax.cond(
             jnp.any(rel),
             _rel_scatter,
-            lambda: jnp.zeros((cfg.param_depth, cfg.param_width), jnp.float32),
+            lambda: jnp.zeros(P.conc_shape(cfg), jnp.float32),
         )
 
     # --- land (same tail as the per-item fused path) ---------------------
@@ -1313,7 +1315,10 @@ def acquire_effects_seg(
             )
             for d in range(cfg.param_depth)
         ]
-        p_out = jnp.stack(FU.scatter_many(pjobs))  # [depth, Q, 2]
+        if P.wide(cfg):
+            p_out = E._param_tiles(pjobs)  # [depth, 2, Q/128, 128]
+        else:
+            p_out = jnp.stack(FU.scatter_many(pjobs))  # [depth, Q, 2]
 
     # --- land (same tail as the per-item fused path) ---------------------
     pass_h, block_h, occ_h = _recombine(stat_out, spec3)
@@ -1386,9 +1391,4 @@ def acquire_effects_seg(
             occ_epoch=jnp.where(add > 0, cur_wid + 1, state.occ_epoch),
         )
 
-    p_upd = None
-    if p_out is not None:
-        upd = jnp.round(p_out).astype(jnp.int32)
-        p_upd = (upd[:, :, 0], upd[:, :, 1])
-
-    return state, p_upd
+    return state, E._param_upd(cfg, p_out)

@@ -466,7 +466,18 @@ STRETCH = N_LO * N_LO
 
 def scatter_sorted(job: Job, tb: int = TILE, interpret: Optional[bool] = None):
     """One job's scatter for a table too wide for scatter_many: f32
-    [n, P], the same digit-plane exactness.
+    [n, P], the same digit-plane exactness (scatter_many's form; a caller
+    that keeps its table in tiles takes scatter_sorted_tiles)."""
+    tiles = scatter_sorted_tiles(job, tb=tb, interpret=interpret)
+    return tiles.reshape(tiles.shape[0], -1)[:, : job.n].T
+
+
+def scatter_sorted_tiles(job: Job, tb: int = TILE, interpret: Optional[bool] = None):
+    """scatter_sorted's table PLANE-MAJOR IN TILES: f32 [P, n_hi, N_LO], row
+    r of the table at [r // N_LO, r % N_LO], the form the kernel writes in,
+    so nothing is laid out anew between it and a store kept in the same
+    tiles (ops/param.py); n_hi * N_LO is n rounded up to a whole STRETCH,
+    and the rows past n read 0.
 
     The one-hot contraction costs items x table rows, which at 2^20 rows is
     a thousand times the items' own work.  Sorted by row, a tile of items
@@ -570,12 +581,11 @@ def scatter_sorted(job: Job, tb: int = TILE, interpret: Optional[bool] = None):
     ), first_win.astype(jnp.int32), windows, rows[None, :], packed[None, :],
         key=("scatter_sorted", R, P, per_row, digits, n, nT, tb, bool(interpret)))
 
-    flat = out.reshape(pd, n_hi * N_LO)[:, :n]
-    cols, off = [], 0
+    planes, off = [], 0
     for p in range(P):
-        acc = flat[off]
+        acc = out[off]
         for d in range(1, digits[p]):
-            acc = acc + flat[off + d] * float(1 << (8 * d))
-        cols.append(acc)
+            acc = acc + out[off + d] * float(1 << (8 * d))
+        planes.append(acc)
         off += digits[p]
-    return jnp.stack(cols, axis=1)  # [n, P]
+    return jnp.stack(planes)  # [P, n_hi, N_LO]

@@ -14,12 +14,26 @@ dense contraction:
     pconc  : int32 [depth, Q]       per-(rule,value) concurrency (THREAD grade)
 
 A WIDE store (``wide(cfg)``: more than PARAM_NARROW_WIDTH cells a depth, e.g.
-the 2^22 an API gateway's million (route, client) pairs take) keeps pcms as
-[depth, nb, Q]: a bucket is then one dense row a depth, so the refresh and
-the landing touch that row and nothing else, where [depth, Q, nb] pads every
-cell's 8 buckets to a lane tile and a column update walks the whole table.
-Reads stay per-item lane-packed gathers; writes leave the one-hot kernels
-(rows x width multiply-adds a plane) for ops/fused.scatter_sorted.
+the 2^22 an API gateway's million (route, client) pairs take) keeps every
+plane's cell axis as (Q / 128, 128) TILES:
+
+    pcms   : int32 [depth, nb, Q/128, 128]
+    pconc  : int32 [depth, Q/128, 128]
+
+The chip tiles an array's LAST TWO axes (8 sublanes x 128 lanes).  Laid out
+[depth, Q, nb], every cell's 8 buckets pad to a lane tile and a column
+update walks the whole table; laid out [depth, nb, Q], the 8 buckets are one
+tile's 8 sublanes, so a bucket's row is a sublane of EVERY tile and its
+refresh and landing each pass over the whole store (measured on the chip,
+PERF.md section 6, PR 33: 1.1 ms each at 256 MiB).  With the cell axis split
+a tile is 1,024 consecutive cells of ONE bucket, a bucket's row is
+contiguous memory, and the two updates touch that row and nothing else.
+Cell q lies at [q // 128, q % 128]: a row-major reshape, which is the form
+ops/fused.scatter_sorted writes in ([planes, Q/128, 128]) and the estimate's
+lane-packed gathers read at (WIDE_LANES), so no plane changes form between
+the kernel and the store.  Reads stay per-item lane-packed gathers; writes
+leave the one-hot kernels (rows x width multiply-adds a plane) for
+scatter_sorted.
 
 - All rules share the global bucket grid, so the current column is a single
   dense histogram target (ops/tables.py MXU path) and stale-column reset is
@@ -85,14 +99,26 @@ WIDE_LANES = 128
 
 
 def wide(cfg: EngineConfig) -> bool:
-    """True for a store laid out [depth, nb, Q] (see the module docstring)."""
+    """True for a store kept in tiles (see the module docstring)."""
     return cfg.param_width > PARAM_NARROW_WIDTH
 
 
-def store_shape(cfg: EngineConfig) -> Tuple[int, int, int]:
+def plane_shape(cfg: EngineConfig) -> Tuple[int, ...]:
+    """One depth's cell axis: (Q,), or the (Q / 128, 128) tiles of a wide
+    store (EngineConfig holds a wide width to a multiple of 2^14)."""
     if wide(cfg):
-        return (cfg.param_depth, cfg.param_sample_count, cfg.param_width)
+        return (cfg.param_width // WIDE_LANES, WIDE_LANES)
+    return (cfg.param_width,)
+
+
+def store_shape(cfg: EngineConfig) -> Tuple[int, ...]:
+    if wide(cfg):
+        return (cfg.param_depth, cfg.param_sample_count) + plane_shape(cfg)
     return (cfg.param_depth, cfg.param_width, cfg.param_sample_count)
+
+
+def conc_shape(cfg: EngineConfig) -> Tuple[int, ...]:
+    return (cfg.param_depth,) + plane_shape(cfg)
 
 
 def _wid(now_ms, cfg: EngineConfig):
@@ -111,7 +137,7 @@ def refresh(
     idx = wid % nb
     keep = (epochs[idx] == wid).astype(pcms.dtype)
     if wide(cfg):
-        return pcms.at[:, idx, :].multiply(keep), epochs.at[idx].set(wid), idx
+        return pcms.at[:, idx].multiply(keep), epochs.at[idx].set(wid), idx
     return pcms.at[:, :, idx].multiply(keep), epochs.at[idx].set(wid), idx
 
 
@@ -122,8 +148,8 @@ def class_tables(
     now_ms,
     cfg: EngineConfig,
 ) -> jax.Array:
-    """f32 [depth, Q, C] ([depth, C, Q] of a wide store): windowed totals
-    per duration class.
+    """f32 [depth, Q, C] ([depth, C, Q/128, 128] of a wide store): windowed
+    totals per duration class.
 
     Class c sums buckets whose epoch lies in (wid - k_c, wid] — the k_c
     most recent grid positions (masked elementwise; stale columns excluded
@@ -132,7 +158,7 @@ def class_tables(
     # [C, nb] validity masks
     valid = (epochs[None, :] > wid - class_k[:, None]) & (epochs[None, :] <= wid)
     return jnp.einsum(
-        "dbq,cb->dcq" if wide(cfg) else "dqb,cb->dqc",
+        "dbrl,cb->dcrl" if wide(cfg) else "dqb,cb->dqc",
         pcms.astype(jnp.float32),
         valid.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
@@ -205,11 +231,13 @@ def estimate_fused(
 
 
 def _estimate_wide(cfg: EngineConfig, wtab: jax.Array, rows: jax.Array, cls: jax.Array):
-    """estimate() of a wide store, whose class tables are [depth, C, Q]:
-    one lane-packed row gather a depth, whatever the backend.  Nothing is
+    """estimate() of a wide store, whose class tables are [depth, C, Q/128,
+    128]: one lane-packed row gather a depth, whatever the backend, at the
+    tiles' own width, so the view it reads is the table itself.  Nothing is
     saturated: no digit plane carries the value, and a windowed total stays
     f32-exact far past any threshold."""
-    depth, C, Q = wtab.shape
+    depth, C = wtab.shape[:2]
+    Q = cfg.param_width
     idx = jnp.clip(cls, 0, C - 1)[:, None] * Q + jnp.clip(rows, 0, Q - 1)
     ests = [
         T.lane_gather_1col(cfg, wtab[d].reshape(-1), idx[:, d], C * Q, lanes=WIDE_LANES)
@@ -225,7 +253,9 @@ def conc_estimate(
     if wide(cfg):
         # the one-hot gather below is rows x width multiply-adds a digit
         ests = [
-            T.lane_gather_1col(cfg, pconc[d], rows[:, d], cfg.param_width, lanes=WIDE_LANES)
+            T.lane_gather_1col(
+                cfg, pconc[d].reshape(-1), rows[:, d], cfg.param_width, lanes=WIDE_LANES
+            )
             for d in range(pconc.shape[0])
         ]
         return jnp.min(jnp.stack(ests, axis=0), axis=0)
@@ -254,19 +284,21 @@ def add(
     hists = [
         T.histogram(cfg, rows[:, d], counts, cfg.param_width) for d in range(pcms.shape[0])
     ]
-    return land(cfg, pcms, cur_idx, jnp.stack(hists).astype(pcms.dtype))
+    upd = jnp.stack(hists).astype(pcms.dtype).reshape(conc_shape(cfg))
+    return land(cfg, pcms, cur_idx, upd)
 
 
 def land(cfg: EngineConfig, pcms: jax.Array, cur_idx, upd: jax.Array) -> jax.Array:
-    """Add ``upd`` [depth, Q] to the current bucket of every depth."""
+    """Add ``upd`` (``conc_shape(cfg)``: [depth, Q], in tiles for a wide
+    store) to the current bucket of every depth."""
     if wide(cfg):
-        return pcms.at[:, cur_idx, :].add(upd)
+        return pcms.at[:, cur_idx].add(upd)
     return pcms.at[:, :, cur_idx].add(upd)
 
 
 def conc_add(
     cfg: EngineConfig,
-    pconc: jax.Array,  # [depth, Q]
+    pconc: jax.Array,  # conc_shape(cfg)
     rows: jax.Array,  # [N, depth]
     inc: jax.Array,  # int32 [N] nonnegative acquire counts (0 no-op)
     dec: jax.Array,  # int32 [N] nonnegative release counts (0 no-op)
@@ -278,5 +310,6 @@ def conc_add(
     for d in range(pconc.shape[0]):
         delta = jnp.stack([inc, dec], axis=1)
         hist = T.histogram(cfg, rows[:, d], delta, cfg.param_width, max_int=65535)
-        pconc = pconc.at[d].add((hist[:, 0] - hist[:, 1]).astype(pconc.dtype))
+        net = (hist[:, 0] - hist[:, 1]).astype(pconc.dtype)
+        pconc = pconc.at[d].add(net.reshape(plane_shape(cfg)))
     return jnp.maximum(pconc, 0)

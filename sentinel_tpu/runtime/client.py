@@ -4012,10 +4012,12 @@ class SentinelClient:
         with self._engine_lock:
             pcms, epochs = self._state.pcms, self._state.pcms_epochs
         newest = int(np.argmax(np.asarray(epochs)))
-        bucket = pcms[:, newest, :] if P.wide(self.cfg) else pcms[:, :, newest]
+        # a wide store's bucket is [depth, Q/128, 128]: count over both tile axes
+        bucket = pcms[:, newest] if P.wide(self.cfg) else pcms[:, :, newest]
+        counting = jnp.count_nonzero(bucket.reshape(bucket.shape[0], -1), axis=1)
         return {
             "store_cells": int(self.cfg.param_width),
-            "store_cells_counting": np.asarray(jnp.count_nonzero(bucket, axis=1)).tolist(),
+            "store_cells_counting": np.asarray(counting).tolist(),
         }
 
 

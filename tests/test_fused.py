@@ -270,7 +270,7 @@ def test_scatter_many_sends_a_wide_job_to_scatter_sorted_and_keeps_the_order():
 
 def test_a_wide_param_store_ticks_alike_on_the_plain_and_the_seg_path():
     """Whole ticks with QPS and THREAD hot-parameter rules over a store of
-    2^15 cells a depth (wide: [depth, bucket, cell], written by
+    2^15 cells a depth (wide: [depth, bucket, cell / 128, 128], written by
     scatter_sorted on the fused paths): the verdicts and the store equal the
     plain scatter path's.  (RT sums differ between the two by the fused
     paths' 1/8 ms quantum, as at any width.)"""
@@ -278,7 +278,7 @@ def test_a_wide_param_store_ticks_alike_on_the_plain_and_the_seg_path():
 
     base = dict(batch_size=96, complete_batch_size=96, param_width=1 << 15, param_rules_per_resource=1)
     st0, out0 = _tick_once(small_engine_config(**base), sort_batches=True)
-    assert st0.pcms.shape == (2, 8, 1 << 15) and st0.pcms.any() and st0.pconc.any()
+    assert st0.pcms.shape == (2, 8, (1 << 15) // 128, 128) and st0.pcms.any() and st0.pconc.any()
     assert any((v == 3).any() for v in out0)  # BLOCK_PARAM was produced
     seg = small_engine_config(**base, use_mxu_tables=True, fused_effects=True, seg_effects=True)
     st1, out1 = _tick_once(seg, sort_batches=True)

@@ -251,12 +251,12 @@ def test_param_width_past_the_fused_tables_reach_fails_at_construction_in_one_li
 
 def test_param_flow_holds_its_budgets_over_a_wide_store(client_factory, vt):
     """The per-value budget, the exception item and the window, with the
-    store 2^15 cells wide: laid out [depth, bucket, cell]."""
+    store 2^15 cells wide: laid out [depth, bucket, cell / 128, 128]."""
     from sentinel_tpu.core.config import small_engine_config
     from sentinel_tpu.obs import profile as PROF
 
     client = client_factory(cfg=small_engine_config(param_width=1 << 15))
-    assert client._state.pcms.shape == (2, 8, 1 << 15)
+    assert client._state.pcms.shape == (2, 8, (1 << 15) // 128, 128)
     client.param_flow_rules.load([
         st.ParamFlowRule(resource="api", count=2, duration_in_sec=1,
                          param_flow_item_list=[ParamFlowItem(object="vip", count=5)])
