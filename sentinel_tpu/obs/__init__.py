@@ -23,6 +23,9 @@ analyzes flight bundles (``--postmortem``).
 Tracing defaults OFF: call ``obs.enable()`` (or set ``SENTINEL_TRACE=1``)
 to start recording.  Disabled-mode cost at every instrumented call site
 is a single flag check — no allocation, no formatting, no clock read.
+``obs.enable()`` also starts the process's own recorders (``obs.proc``:
+how late a waiting thread wakes, the collector's pauses) and
+``obs.disable()`` stops them; off, no thread and no callback exists.
 The flight journal is always on (rare events, O(1) appends).
 """
 
@@ -48,6 +51,7 @@ from sentinel_tpu.obs.profile import (
     expected_retrace,
     ledger_owner,
 )
+from sentinel_tpu.obs import proc as _proc
 from sentinel_tpu.obs.trace import (
     TRACER,
     SpanTracer,
@@ -68,15 +72,21 @@ from sentinel_tpu.obs.trace import (
 #: every process that imports the obs plane identifies itself on /metrics
 register_build_info()
 register_scrape_id()
+if TRACER.enabled:  # SENTINEL_TRACE=1: on from import, the process's recorders with it
+    _proc.start()
 
 
 def enable(jax_annotations: bool = False) -> None:
     """Turn span recording on (optionally mirroring spans into
-    ``jax.profiler.TraceAnnotation`` so they land in XLA device traces)."""
+    ``jax.profiler.TraceAnnotation`` so they land in XLA device traces),
+    and the process's recorders with it (``obs.proc``: one thread, one
+    ``gc.callbacks`` entry, however often this is called)."""
     TRACER.enable(jax_annotations=jax_annotations)
+    _proc.start()
 
 
 def disable() -> None:
+    _proc.stop()
     TRACER.disable()
 
 

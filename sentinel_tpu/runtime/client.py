@@ -2453,8 +2453,12 @@ class SentinelClient:
             _t_hs = OT.t0()
             if hs.maybe_evaluate() and _t_hs:
                 # tick.hotset: a promote/demote pass that ran on this thread
-                # (the cadence check alone gets no span)
-                OT.TRACER.record("tick.hotset", _t_hs, OT.now_ns() - _t_hs)
+                # (the cadence check alone gets no span); the pass's own
+                # hotset.* spans carry the same number as their trace id
+                OT.TRACER.record(
+                    "tick.hotset", _t_hs, OT.now_ns() - _t_hs, 0,
+                    {"pass": hs._eval_n},
+                )
 
     def _await_room(self) -> None:
         """Back-pressure, before the drain so that what arrives during the
@@ -3015,9 +3019,10 @@ class SentinelClient:
             segs += 1 + (pad_to - 1) // SG.BLOCK - n // SG.BLOCK
         return segs
 
-    def _note_seg_count(self, segs: int, b: int, full: int) -> None:
+    def _note_seg_count(self, segs: int, b: int, full: int) -> Tuple[int, int]:
         """Track observed live-segment counts of a side of ``b`` rows
-        (``full`` at the full tick shape); grow ``seg_u`` (recompile +
+        (``full`` at the full tick shape); returns ``(segs, capacity)``,
+        which a traced tick.presort carries.  Grows ``seg_u`` (recompile +
         hot-swap the tick) when traffic persistently overflows the
         compacted capacity.  With seg_fallback=True overflow ticks are
         exact but ride the slower per-item kernels, so the resize is a
@@ -3032,19 +3037,27 @@ class SentinelClient:
             self._seg_obs_peak = segs
         cap = ES.seg_capacity(self.cfg, b, full)
         if segs <= cap:
-            return
+            return segs, cap
         self._seg_over_ticks += 1
+        # a tick of this shape that left the compacted path (exact on the
+        # per-item kernels, and slow) or, without the fallback, dropped
+        OBS.counter(
+            "sentinel_seg_overflow_ticks_total",
+            "sampled ticks whose live segments exceeded the compacted "
+            "capacity of their shape (rows a side)",
+            labels={"shape": str(b)},
+        ).inc()
         # fail-closed configs resize at the FIRST overflow (drops are
         # happening); fallback configs wait out a transient burst
         threshold = 1 if not self.cfg.seg_fallback else 4
         if self._seg_over_ticks < threshold or self._seg_resizing:
-            return
+            return segs, cap
         b_full = self.cfg.batch_size
         new_u = min(
             b_full, -(-int(self._seg_obs_peak * 1.25 + 128) // 128) * 128
         )
         if new_u <= ES.seg_capacity(self.cfg, b_full):
-            return  # the full-shape capacity already covers the peak
+            return segs, cap  # the full-shape capacity already covers the peak
         self._seg_resizing = True
         if self.mode == "threaded":
             threading.Thread(
@@ -3055,6 +3068,7 @@ class SentinelClient:
             ).start()
         else:
             self._resize_seg_u(new_u)
+        return segs, cap
 
     def _resize_seg_u(self, new_u: int) -> None:
         """Compile a tick with the larger compacted capacity against a
@@ -3237,6 +3251,7 @@ class SentinelClient:
         _ns_presort = 0
         _n_a = _n_c = 0  # live rows presorted a side; with _path, span attrs
         _path = ""
+        _segs = None  # (segments, capacity) of a side whose segments were counted
         # concatenate every attached door's drained engine items; responses
         # route back per door by slice
         if fronts:
@@ -3394,7 +3409,7 @@ class SentinelClient:
                 # passes over B — resize detection doesn't need every tick
                 self._seg_sample_ctr += 1
                 if B <= 4096 or (self._seg_sample_ctr & 7) == 0:
-                    self._note_seg_count(
+                    _segs = self._note_seg_count(
                         self._host_seg_count(
                             tuple(cols[f] for f in _ACQ_SEG_KEYS)
                         ),
@@ -3459,10 +3474,11 @@ class SentinelClient:
                     _ns_presort += OT.now_ns() - _tp
                 self._seg_sample_ctr_c += 1
                 if B2 <= 4096 or (self._seg_sample_ctr_c & 7) == 0:
-                    self._note_seg_count(
+                    _segs_c = self._note_seg_count(
                         self._host_seg_count((res_a, ctx_a, org_a), pad_to=B2),
                         B2, cfg.complete_batch_size,
                     )
+                    _segs = _segs or _segs_c
 
             # live rows [:n] (unless the presort gathered them in place),
             # then the tail [n:] filled in place; narrow wire dtypes
@@ -3526,10 +3542,15 @@ class SentinelClient:
             if _ns_presort:
                 # path is the acquire side's when it sorted, else the
                 # completion side's: radix / small (chosen on the live row
-                # count) or numpy (no native library)
+                # count) or numpy (no native library); segs / seg_cap
+                # likewise, on the ticks whose segments were counted (all
+                # at 4,096 rows a side or fewer, else one in eight)
+                attrs = {"n_a": _n_a, "n_c": _n_c, "path": _path}
+                if _segs:
+                    attrs["segs"], attrs["seg_cap"] = _segs
                 OT.stage_ns(
                     "tick.presort", _tp0, _ns_presort, _H_PRESORT, trace=tick_id,
-                    attrs={"n_a": _n_a, "n_c": _n_c, "path": _path},
+                    attrs=attrs,
                 )
         self._count_rotations(int(t))
         au = self._audit
@@ -3784,6 +3805,15 @@ class SentinelClient:
         # the stand-in for a hung device tick the watchdog must fail over
         out = p.out
         frame = None
+        _t_ready = 0
+        if p.resolving_ns:
+            # tracer on: wait for the device's hand-over apart from the
+            # read below, so that tick.wait says which part of it lay after
+            # the buffer was ready (copy_ns: the transfer's way home, this
+            # thread's wake-up and the unpack's first touch)
+            # stlint: disable-next-line=host-sync — the designed readback point, split in two while tracing
+            (out.wire if out.wire is not None else out.verdict).block_until_ready()
+            _t_ready = OT.now_ns()
         if out.wire is not None:
             # THE single fused readback: verdict bitmap + wait sidecar +
             # telemetry row + timeline top-K + hot-set candidates in one
@@ -3827,7 +3857,8 @@ class SentinelClient:
             _seen = OT.now_ns()
             if p.resolving_ns:
                 OT.TRACER.record(
-                    "tick.wait", p.resolving_ns, _seen - p.resolving_ns, p.tick_id
+                    "tick.wait", p.resolving_ns, _seen - p.resolving_ns, p.tick_id,
+                    {"copy_ns": _seen - _t_ready},
                 )
             OT.stage_ns(
                 "tick.device",

@@ -32,13 +32,14 @@ from __future__ import annotations
 
 import math
 import threading
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 from sentinel_tpu.adaptive.degrade import Hysteresis
 from sentinel_tpu.chaos import failpoints as FP
 from sentinel_tpu.obs import flight as FL
+from sentinel_tpu.obs import trace as OT
 from sentinel_tpu.obs.registry import REGISTRY as _OBS
 
 _FP_PROMOTE = FP.register(
@@ -191,6 +192,10 @@ class HotSetManager:
         c = self._c
         cfg = c.cfg
         reg = c.registry
+        # tracer on: the pass by its parts, spans that tile it end to start
+        # and carry the pass number as their trace id (the caller's
+        # tick.hotset carries it as ``pass``)
+        _t = OT.t0()
         with self._lock:
             snapshot = sorted(
                 self._cand.items(), key=lambda kv: kv[1], reverse=True
@@ -203,6 +208,7 @@ class HotSetManager:
 
         self._eval_n += 1
         recompile = False
+        was_promoted = len(self.promoted)
         for rid, est in snapshot:
             if est < cfg.hotset_promote_qps:
                 break  # sorted — nothing colder qualifies
@@ -225,10 +231,22 @@ class HotSetManager:
             if self._is_ruled(name):
                 recompile = True
 
-        recompile = self._demote_cold() or recompile
-        if recompile:
+        if _t:
+            _t = self._part(
+                "hotset.scan", _t, candidates=len(snapshot),
+                promoted=len(self.promoted) - was_promoted,
+            )
+        moved, rows, stats_reads, demoted = self._demote_cold()
+        if _t:
+            _t = self._part(
+                "hotset.demote", _t,
+                rows=rows, stats_reads=stats_reads, demoted=demoted,
+            )
+        if moved or recompile:
             # move rules between the tail tables and exact rows
             c._recompile_rules()
+            if _t:
+                _t = self._part("hotset.recompile", _t)
         # bound the per-name bookkeeping: cooldowns that lapsed on names
         # no longer promoted, and cold/promoted-at stamps for rows that
         # left the hot set, would otherwise grow for the process lifetime
@@ -240,7 +258,18 @@ class HotSetManager:
         for d in (self._cold, self._promoted_at):
             for name in [n for n in d if n not in self.promoted]:
                 d.pop(name, None)
-        self._publish_sketch_health()
+        lock_ns, read_ns = self._publish_sketch_health()
+        if _t:
+            # from the demotions' (or the recompile's) end: the sweep above
+            # and the gauges; recorded with zeros where the tier is off
+            self._part("hotset.health", _t, lock_ns=lock_ns, read_ns=read_ns)
+
+    def _part(self, name: str, t0_ns: int, **attrs) -> int:
+        """Record one part of the pass, ``t0_ns`` to now; returns now, where
+        the next part starts."""
+        now = OT.now_ns()
+        OT.TRACER.record(name, t0_ns, now - t0_ns, self._eval_n, attrs or None)
+        return now
 
     def _is_ruled(self, name: str) -> bool:
         c = self._c
@@ -249,12 +278,14 @@ class HotSetManager:
             for r in c.flow_rules.get() + c.degrade_rules.get()
         )
 
-    def _demote_cold(self) -> bool:
+    def _demote_cold(self) -> Tuple[bool, int, int, int]:
         """Demote promoted rows cold for two consecutive evaluations.
-        Returns True when a ruled resource moved (caller recompiles)."""
+        Returns whether a ruled resource moved (caller recompiles), and
+        what the pass cost: rows looked at, stats reads, rows demoted."""
         c = self._c
         cfg = c.cfg
         moved = False
+        rows, stats_reads, demoted = len(self.promoted), 0, 0
         for name in list(self.promoted):
             rid = c.registry.peek_resource_id(name)
             if rid is None or c.registry.is_sketch_id(rid):
@@ -264,6 +295,7 @@ class HotSetManager:
                 # promoted THIS evaluation: the exact row has not had a
                 # window to accumulate stats yet — grade it next time
                 continue
+            stats_reads += 1
             try:
                 qps = float(c.stats.resource(name).get("passQps", 0.0))
             except Exception:  # stlint: disable=fail-open — a failed stats read only SKIPS this demotion check (the row stays exact, strictly the conservative direction); next evaluation retries
@@ -281,6 +313,7 @@ class HotSetManager:
             self.promoted.pop(name, None)
             self._cold.pop(name, None)
             _C_DEMOTIONS.inc()
+            demoted += 1
             hys = self._cool.get(name)
             if hys is None:
                 hys = self._cool[name] = Hysteresis(
@@ -291,15 +324,19 @@ class HotSetManager:
             hys.enter()
             if self._is_ruled(name):
                 moved = True
-        return moved
+        return moved, rows, stats_reads, demoted
 
-    def _publish_sketch_health(self) -> None:
+    def _publish_sketch_health(self) -> Tuple[int, int]:
         """Merged-word + error-bound gauges (salsa tier only): effective
-        width shrinks as words merge, widening eps = e / width_eff."""
+        width shrinks as words merge, widening eps = e / width_eff.
+        Returns the nanoseconds spent waiting for ``_engine_lock`` and in
+        the histogram's read, for ``hotset.health``; zeros with tracing off
+        or where the tier is."""
         cfg = self._c.cfg
+        lock_ns = read_ns = 0
         if not cfg.sketch_salsa:
             _G_EPS.set(math.e / cfg.sketch_width)
-            return
+            return lock_ns, read_ns
         try:
             from sentinel_tpu.ops import engine as E
             from sentinel_tpu.sketch import salsa as SA
@@ -307,14 +344,19 @@ class HotSetManager:
             # under _engine_lock like every host-side gs reader: the tick
             # donates its state buffers, and an unlocked read mid-tick
             # hits a deleted buffer exactly when the system is busiest
+            _t = OT.t0()
             with self._c._engine_lock:
+                _t_held = OT.now_ns() if _t else 0
                 hist = np.asarray(
                     SA.level_histogram(self._c._state.gs, E.sketch_config(cfg))
                 )
+                if _t:
+                    lock_ns, read_ns = _t_held - _t, OT.now_ns() - _t_held
         except Exception:  # stlint: disable=fail-open — health gauges only; a racing window-shape swap skips one publish
-            return
+            return lock_ns, read_ns
         n0, n1, n2 = (float(x) for x in hist)
         total = max(n0 + n1 + n2, 1.0)
         width_eff = cfg.sketch_width * (n0 + n1 / 2.0 + n2 / 4.0) / total
         _G_MERGED.set(n1 + n2)
         _G_EPS.set(math.e / max(width_eff, 1.0))
+        return lock_ns, read_ns

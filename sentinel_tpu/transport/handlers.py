@@ -231,14 +231,14 @@ class DefaultHandlerGroup:
         ``python -m sentinel_tpu.obs --summary``.  ``?enable=true|false``
         flips tracing on the instance first (an ops toggle, like
         setSwitch)."""
-        from sentinel_tpu.obs import TRACER
+        from sentinel_tpu import obs
 
         enable = (req.param("enable") or "").lower()
         if enable == "true":
-            TRACER.enable()
+            obs.enable()
         elif enable == "false":
-            TRACER.disable()
-        return CommandResponse.of_success(TRACER.chrome_trace())
+            obs.disable()
+        return CommandResponse.of_success(obs.TRACER.chrome_trace())
 
     @command_mapping("api/flight", "flight-recorder bundle (black-box post-mortem)")
     def api_flight(self, req: CommandRequest) -> CommandResponse:

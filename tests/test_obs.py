@@ -10,6 +10,7 @@ self-capture printing p50/p99 for all six tick stages.
 from __future__ import annotations
 
 import json
+import os
 import threading
 
 import numpy as np
@@ -25,7 +26,7 @@ def _tracer_state():
     """Never leak an enabled/poisoned global tracer into other tests."""
     was = obs.TRACER.enabled
     yield
-    obs.TRACER.disable()
+    obs.disable()
     obs.TRACER.reset()
     if was:  # pragma: no cover — the suite never leaves it on
         obs.TRACER.enable()
@@ -821,7 +822,10 @@ def test_traced_presort_says_how_many_rows_it_sorted_and_by_which_path(
     finally:
         obs.disable()
     spans = [s for s in obs.TRACER.snapshot() if s["name"] == "tick.presort"]
-    assert spans and all(set(s["attrs"]) == {"n_a", "n_c", "path"} for s in spans)
+    assert spans and all(
+        set(s["attrs"]) == {"n_a", "n_c", "path", "segs", "seg_cap"} for s in spans
+    )  # at test size every tick's segments are counted
+    assert all(0 < s["attrs"]["segs"] <= s["attrs"]["seg_cap"] for s in spans)
     assert {s["attrs"]["path"] for s in spans} == {path}
     assert 48 in [s["attrs"]["n_a"] for s in spans]  # the block's tick
     assert all(0 <= s["attrs"]["n_c"] <= c.cfg.complete_batch_size for s in spans)
@@ -972,7 +976,8 @@ def test_an_idle_tick_thread_records_nothing_and_one_idle_span_closes_the_stretc
     finally:
         obs.disable()
         c.stop()
-    assert quiet == []
+    # (the process's own beat, proc.wake, is recorded whenever the tracer is on)
+    assert [s for s in quiet if not s["name"].startswith("proc.")] == []
     spans = obs.TRACER.snapshot()
     drains = [s for s in spans if s["name"] == "tick.drain" and s["attrs"]["n_obj"]]
     assert len(drains) == 1
@@ -1042,3 +1047,240 @@ def test_a_hot_set_pass_on_the_tick_thread_gets_a_span_and_the_cadence_check_non
     passes = [s for s in obs.TRACER.snapshot() if s["name"] == "tick.hotset"]
     assert len(passes) == (1 if due else 0)
     assert c.hotset.maybe_evaluate() is False  # stamped, or not yet due
+
+
+# ---------------------------------------------------------------------------
+# what a thread waited for (PR 36): proc.wake, proc.gc, the hot-set pass by
+# its parts, tick.wait's copy_ns, the overflow counter
+# ---------------------------------------------------------------------------
+
+
+def _wake_threads():
+    return [t for t in threading.enumerate() if t.name == "sentinel-obs-wake"]
+
+
+def test_the_wake_thread_lives_exactly_as_long_as_tracing_is_on():
+    import gc
+
+    from sentinel_tpu.obs import proc
+
+    assert not _wake_threads() and proc._on_gc not in gc.callbacks  # after import, off
+    obs.enable()
+    try:
+        (first,) = _wake_threads()
+        assert first.daemon
+        obs.enable()
+        assert _wake_threads() == [first]
+        assert gc.callbacks.count(proc._on_gc) == 1
+    finally:
+        obs.disable()
+    assert not first.is_alive()  # joined, not left to die
+    assert not _wake_threads() and proc._on_gc not in gc.callbacks
+    obs.disable()  # twice is harmless
+
+
+@pytest.mark.parametrize("env, threads", [("", 0), ("0", 0), ("1", 1)])
+def test_after_import_the_recorders_exist_only_where_the_environment_turned_tracing_on(env, threads):
+    """A fresh interpreter: ``SENTINEL_TRACE=1`` has the tracer on from
+    import, and its recorders with it; otherwise no thread, no callback."""
+    import subprocess
+    import sys
+
+    code = (
+        "import gc, threading\n"
+        "from sentinel_tpu import obs\n"
+        "from sentinel_tpu.obs import proc\n"
+        "n = sum(t.name == 'sentinel-obs-wake' for t in threading.enumerate())\n"
+        "print(int(obs.enabled()), n, gc.callbacks.count(proc._on_gc))\n"
+        "obs.disable()\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=120,
+        env={**os.environ, "SENTINEL_TRACE": env},
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [str(threads)] * 3
+
+
+def test_a_beat_records_how_late_it_woke_and_a_late_one_the_cpu_time(monkeypatch):
+    """The clock is stubbed: it jumps ``hold`` ahead once, between a beat's
+    due instant and its wake-up, as a thread kept from running would see."""
+    from sentinel_tpu.obs import proc
+    from sentinel_tpu.obs import trace as OT
+
+    hold = 3 * proc.WAKE_CPU_NS
+    real, reads, shift = OT.now_ns, [0], [0]
+
+    def clock():
+        reads[0] += 1
+        if reads[0] == 6:  # the third beat's wake-up read
+            shift[0] = hold
+        return real() + shift[0]
+
+    monkeypatch.setattr(OT, "now_ns", clock)
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        deadline = real() + 5_000_000_000
+        while reads[0] < 12 and real() < deadline:
+            threading.Event().wait(0.005)
+    finally:
+        obs.disable()
+    beats = [s for s in obs.TRACER.snapshot() if s["name"] == "proc.wake"]
+    assert len(beats) >= 5
+    (held,) = [s for s in beats if s["dur_ns"] >= hold]
+    assert 0 <= held["attrs"]["cpu_ns"] < hold  # the process did not burn the hold
+    assert all("cpu_ns" not in s["attrs"] for s in beats if s["dur_ns"] <= proc.WAKE_CPU_NS)
+    # a beat's t0 is the instant it was due: a period after the read before it
+    assert all(s["dur_ns"] >= 0 for s in beats)
+    assert obs.REGISTRY.get("sentinel_proc_wake_late_ms").count >= len(beats)
+
+
+def test_a_full_collection_records_a_span_and_the_callback_goes_with_tracing():
+    import gc
+
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        junk = [[] for _ in range(1000)]
+        for a in junk:
+            a.append(a)  # cycles: the collector has something to find
+        del junk, a
+        gc.collect(2)
+    finally:
+        obs.disable()
+    pauses = [s for s in obs.TRACER.snapshot() if s["name"] == "proc.gc"]
+    full = [s for s in pauses if s["attrs"]["gen"] == 2]
+    assert full and full[-1]["attrs"]["collected"] >= 1000 and full[-1]["dur_ns"] > 0
+    # a quick young collection records nothing; one of a millisecond would
+    from sentinel_tpu.obs import proc
+
+    assert all(s["attrs"]["gen"] == 2 or s["dur_ns"] >= proc.GC_SLOW_NS for s in pauses)
+    obs.TRACER.reset()
+    gc.collect(2)
+    assert obs.TRACER.snapshot() == []  # the callback is gone
+
+
+def _hotset_pass(client_factory, **cfg_kw):
+    from sentinel_tpu.core.config import small_engine_config
+
+    c = _load(client_factory(cfg=small_engine_config(sketch_stats=True, **cfg_kw)))
+    _serve(c, n_entries=1, block=8)
+    c.hotset._last_eval = -1e18
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        c.tick_once()
+    finally:
+        obs.disable()
+    spans = obs.TRACER.snapshot()
+    (whole,) = [s for s in spans if s["name"] == "tick.hotset"]
+    return c, whole, [s for s in spans if s["name"].startswith("hotset.")]
+
+
+@pytest.mark.parametrize("salsa", [True, False])
+def test_the_hot_set_pass_is_tiled_by_its_parts(client_factory, salsa):
+    c, whole, parts = _hotset_pass(client_factory, sketch_salsa=salsa)
+    assert whole["attrs"] == {"pass": c.hotset._eval_n}
+    assert [s["name"] for s in parts] == ["hotset.scan", "hotset.demote", "hotset.health"]
+    assert {s["trace"] for s in parts} == {whole["attrs"]["pass"]}
+    for a, b in zip(parts, parts[1:]):  # end to start, on one clock read
+        assert a["t0_ns"] + a["dur_ns"] == b["t0_ns"]
+    assert parts[0]["t0_ns"] >= whole["t0_ns"]
+    assert parts[-1]["t0_ns"] + parts[-1]["dur_ns"] <= whole["t0_ns"] + whole["dur_ns"]
+    # nine tenths of the pass (with the tier off the pass is some 40 us, and
+    # the cadence gate's two locks outside the parts get 0.2 ms of grace)
+    uncovered = whole["dur_ns"] - sum(s["dur_ns"] for s in parts)
+    assert uncovered <= max(0.1 * whole["dur_ns"], 200_000)
+    scan, demote, health = (s["attrs"] for s in parts)
+    assert set(scan) == {"candidates", "promoted"}
+    assert demote == {"rows": 0, "stats_reads": 0, "demoted": 0}
+    if salsa:
+        assert health["read_ns"] > 0 and health["lock_ns"] >= 0
+    else:  # the tier is off: recorded all the same, with zeros
+        assert health == {"lock_ns": 0, "read_ns": 0}
+
+
+def test_a_recompiling_pass_records_the_recompile_between_demote_and_health(
+    client_factory, monkeypatch
+):
+    from sentinel_tpu.core.config import small_engine_config
+
+    c = _load(client_factory(cfg=small_engine_config(sketch_stats=True)))
+    monkeypatch.setattr(c.hotset, "_demote_cold", lambda: (True, 3, 2, 1))
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        c.hotset.evaluate_now()
+    finally:
+        obs.disable()
+    parts = [s for s in obs.TRACER.snapshot() if s["name"].startswith("hotset.")]
+    assert [s["name"] for s in parts] == [
+        "hotset.scan", "hotset.demote", "hotset.recompile", "hotset.health"]
+    assert parts[1]["attrs"] == {"rows": 3, "stats_reads": 2, "demoted": 1}
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_tick_wait_says_how_much_of_it_lay_after_the_buffer_was_ready(client_factory, packed):
+    from sentinel_tpu.core.config import small_engine_config
+
+    c = _load(client_factory(cfg=small_engine_config(packed_wire=packed), pipeline_depth=2))
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        _serve(c)
+    finally:
+        obs.disable()
+    waits = [s for s in obs.TRACER.snapshot() if s["name"] == "tick.wait"]
+    assert waits and all(0 < s["attrs"]["copy_ns"] <= s["dur_ns"] for s in waits)
+
+
+def test_tracing_off_a_tick_and_a_hot_set_pass_read_no_clock_at_the_new_sites(
+    client_factory, monkeypatch
+):
+    """PR 36's sites under PR 24's contract: the resolver's hand-over wait,
+    the pass's parts and the health read are flag checks with tracing off.
+    A read is counted as well as refused: the resolver and the health read
+    fail closed, and would swallow the refusal."""
+    from sentinel_tpu.core.config import small_engine_config
+    from sentinel_tpu.core.errors import BLOCK_SYSTEM
+    from sentinel_tpu.obs import trace as OT
+
+    reads = []
+
+    def no_clock():
+        reads.append(1)
+        raise AssertionError("a tracing site read the clock with tracing off")
+
+    c = _load(client_factory(cfg=small_engine_config(sketch_stats=True), pipeline_depth=2))
+    obs.TRACER.reset()
+    assert not OT.TRACER.enabled
+    monkeypatch.setattr(OT, "now_ns", no_clock)
+    c.hotset._last_eval = -1e18
+    verdicts, _waits = _serve(c)  # its tick_once runs the pass that is due
+    assert len(verdicts) == 48 and int(BLOCK_SYSTEM) not in set(verdicts.tolist())
+    assert c.hotset._eval_n >= 1
+    assert not reads and obs.TRACER.snapshot() == []
+    assert not _wake_threads()
+
+
+def _overflow_count(shape):
+    m = obs.REGISTRY.get("sentinel_seg_overflow_ticks_total", {"shape": str(shape)})
+    return m.value if m is not None else 0.0
+
+
+@pytest.mark.parametrize("side", ["acquire", "completion"])
+def test_an_overflowing_tick_moves_its_shapes_counter_by_one(client_factory, side):
+    from sentinel_tpu.ops import engine_seg as ES
+
+    c = _seg_client(client_factory)
+    b = c.cfg.batch_size if side == "acquire" else c.cfg.complete_batch_size
+    full = b
+    cap = ES.seg_capacity(c.cfg, b, full)
+    before = _overflow_count(b)
+    assert c._note_seg_count(cap, b, full) == (cap, cap)
+    assert _overflow_count(b) == before  # at the capacity: not over it
+    assert c._note_seg_count(cap + 1, b, full) == (cap + 1, cap)
+    assert _overflow_count(b) == before + 1
+    _serve(c)  # a served tick of 48 rows of one resource overflows nothing
+    assert _overflow_count(b) == before + 1

@@ -21,6 +21,18 @@ from sentinel_tpu.ops import fused as FU
 pytestmark = pytest.mark.jitted
 
 
+@pytest.fixture(autouse=True)
+def _whole_locations():
+    """A kernel's name reaches the compiled text through its operation's
+    location, whole.  ``perfbench/run.py``'s set-up cuts locations to one
+    frame for the process (its compile-cache key) and leaves them cut: a
+    rehearsal that ran earlier in this worker would take the names away."""
+    was = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
+    yield
+    jax.config.update("jax_include_full_tracebacks_in_locations", was)
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     from jax.experimental import topologies
