@@ -156,6 +156,21 @@ def build(seed: int, *sizes: dict, config: str = CONFIG):
         cfg = with_sizes(cfg, s)
     return manifest.module("deployments", cfg["deployment"]).build(cfg, seed, None)
 
+#: the third: circuit breakers on more rows than the other tables hold
+BREAKER_CONFIG = "degrade-100k-slow-ratio"
+BREAKER_REHEARSAL_SIZES = {
+    "engine": {
+        "max_resources": 160, "max_nodes": 168, "max_flow_rules": 16,
+        "max_degrade_rules": 160, "max_param_rules": 8, "batch_size": 512,
+        "complete_batch_size": 512, "use_mxu_tables": True,
+        "fused_effects": True, "seg_effects": True,
+    },
+    "resources": {"n_services": 96},
+    "traffic": {"pool_batches": 1},
+    "check_params": {"rows_past": 40},
+    "client": {"entry_timeout_s": 30.0},
+}
+
 
 class CompileClock:
     """Sums JAX's own compile-time events (jax.monitoring listeners)."""
@@ -745,6 +760,66 @@ def param_store_phase(seed, sizes, ticks):
     return detail, failures
 
 
+def breaker_phase(seed, sizes):
+    """``BREAKER_CONFIG`` built as its cell builds it, and six services whose
+    rows lie past the other configurations' tables (``check_params.
+    rows_past``) driven by hand at virtual times through what a breaker can
+    do, each step held to ``perfbench/reference/plain_breaker.py``: four slow
+    exits of five trip, three of five (exactly the threshold) do not, an exit
+    while OPEN counts and moves nothing, one probe at the retry time, a slow
+    probe reopens with a new deadline, a fast one closes, a closed breaker
+    admits again."""
+    import numpy as np
+
+    from perfbench.checks import breaker_replay
+
+    dep = build(seed, *sizes, config=BREAKER_CONFIG)
+    c = dep.client
+    r = dep.config["rules"]
+    slow, fast, retry = r["count"] + 1, r["count"], r["time_window"] * 1000
+    far = np.flatnonzero(dep.ids > dep.config["check_params"]["rows_past"])[:6]
+    trip, tie, idle = far[:2], far[2:4], far[4:]
+    ref = breaker_replay.reference_of(dep)
+    # (ms after the start, {rank: its exits' rts}, entries a service)
+    steps = [
+        (0, {}, 5),
+        (25, {**{k: [slow] * 4 + [fast] for k in trip}, **{k: [slow] * 3 + [fast] * 2 for k in tie}}, 5),
+        (50, {k: [slow, fast] for k in trip}, 3),  # exits while OPEN
+        (25 + retry - 1, {}, 3),  # a millisecond early: no probe
+        (25 + retry, {}, 3),  # one probe each
+        (50 + retry, {trip[0]: [slow], trip[1]: [fast]}, 3),  # one reopens, one closes
+        (75 + retry, {}, 3),
+        (50 + 2 * retry, {}, 3),  # the reopened breaker's second probe
+        (75 + 2 * retry, {trip[0]: [fast]}, 3),
+    ]
+    failures, t0 = [], c.time.now_ms() + 10_000
+    try:
+        for at, exits, n in steps:
+            x_rank = np.array([k for k, rts in exits.items() for _ in rts], np.int64)
+            x_rt = np.array([rt for rts in exits.values() for rt in rts], np.float32)
+            if len(x_rank):
+                c.submit_completion_block(dep.ids[x_rank].astype(np.int32), x_rt)
+            ranks = np.repeat(far, n)
+            fut = c.submit_block(dep.ids[ranks].astype(np.int32))
+            c.tick_once(now_ms=t0 + at)
+            verdicts = fut.result(timeout=c.entry_timeout_s)[0]
+            uniq, _n, want = ref.tick(t0 + at, x_rank, x_rt, ranks)
+            got = np.bincount(np.searchsorted(uniq, ranks), weights=verdicts == 0).astype(np.int64)
+            state = dep.breaker_states()
+            if (got != want).any() or (state[far] != ref.state[far]).any():
+                failures.append(f"at +{at} ms admitted {got.tolist()} for {want.tolist()}, "
+                                f"states {state[far].tolist()} for {ref.state[far].tolist()}")
+    finally:
+        c.stop()
+    seen = ref.seen
+    for what in ("opened", "half_opened", "closed_again", "reopened", "exits_while_open", "ratio_ties"):
+        if not seen[what]:
+            failures.append(f"the script never reached {what}")
+    detail = {"services": len(dep.ids), "max_resources": c.cfg.max_resources,
+              "rows": dep.ids[far].tolist(), "steps": len(steps), **seen}
+    return detail, failures
+
+
 def result_line(ok: bool, device: dict) -> str:
     """The last line of stdout: exactly the keys the chip check reads."""
     return json.dumps({
@@ -799,9 +874,11 @@ def main(argv=None) -> int:
     if args.rehearse_cpu:
         sizes, n_blocks, rounds = (REHEARSAL_SIZES,), 8, 3
         param_sizes, param_ticks = (PARAM_REHEARSAL_SIZES,), 12
+        breaker_sizes = (BREAKER_REHEARSAL_SIZES,)
     else:
         sizes, n_blocks, rounds = (), 12, 40
         param_sizes, param_ticks = (), 24
+        breaker_sizes = ()
 
     def environment():
         native = native_available()
@@ -823,6 +900,7 @@ def main(argv=None) -> int:
         ("evidence", lambda: evidence_phase(state, args.rehearse_cpu, sizes)),
         ("equivalence", lambda: equivalence_phase(args.seed, sizes, rounds)),
         ("param_store", lambda: param_store_phase(args.seed, param_sizes, param_ticks)),
+        ("breaker", lambda: breaker_phase(args.seed, breaker_sizes)),
     )
     ok = all(report.run(name, fn) for name, fn in phases)
     compile_s, trace_s, hits = clock.snapshot()

@@ -250,7 +250,14 @@ STAT_ENTRY_CONC = 19  # global inbound concurrency
 STAT_CEIL_QPS = 20  # active SystemTensors qps ceiling (-1 = unset)
 STAT_CEIL_THREAD = 21  # active SystemTensors max_thread ceiling
 STAT_CEIL_UTIL = 22  # windowed ENTRY pass / qps ceiling (0 when unset)
-N_STATS = 24  # slot 23 reserved; 96 bytes per tick
+# circuit breakers that moved in this tick, by where they went, and those
+# not CLOSED after it (0 where the degrade stage is not compiled)
+STAT_CB_OPENED = 23  # CLOSED -> OPEN: the trip rule, on this tick's exits
+STAT_CB_HALF_OPENED = 24  # OPEN -> HALF_OPEN: a probe elected among this tick's entries
+STAT_CB_CLOSED = 25  # HALF_OPEN -> CLOSED: an exit that was not slow
+STAT_CB_REOPENED = 26  # HALF_OPEN -> OPEN: an exit that was
+STAT_CB_OPEN_NOW = 27  # OPEN or HALF_OPEN after the tick
+N_STATS = 28  # 112 bytes per tick
 
 
 def _device_stats(
@@ -263,6 +270,7 @@ def _device_stats(
     now_ms,
     seg_dropped,
     seg_live,
+    cb_moves=None,
 ):
     """Build the TickOutput.stats row (see the STAT_* index block).
 
@@ -310,7 +318,7 @@ def _device_stats(
         qps,
         jnp.asarray(rules.system.max_thread, jnp.float32),
         util,
-        0,
+        *(cb_moves or (0,) * 5),
     ]
     assert len(vals) == N_STATS
     return jnp.stack(
@@ -2509,9 +2517,26 @@ def _run_checks_plain(
     )
 
 
+def _cb_moves(before, exited, after):
+    """The STAT_CB_* counts of a tick from the breakers' state as the tick
+    found it, after its exits and after its checks: five reductions over the
+    rule axis (the pad slot is never enabled and stays CLOSED)."""
+
+    def n(was, frm, now, to):
+        return jnp.sum((was == frm) & (now == to))
+
+    return (
+        n(before, D.CB_CLOSED, exited, D.CB_OPEN),
+        n(exited, D.CB_OPEN, after, D.CB_HALF_OPEN),
+        n(before, D.CB_HALF_OPEN, exited, D.CB_CLOSED),
+        n(before, D.CB_HALF_OPEN, exited, D.CB_OPEN),
+        jnp.sum(after != D.CB_CLOSED),
+    )
+
+
 def _telemetry_and_output(
     cfg, state, rules, acq, verdict, wait_ms, valid, forced, fslots, now_ms,
-    seg_dropped, n_seg,
+    seg_dropped, n_seg, cb_moves=None,
 ) -> TickOutput:
     """The tick's last two stages, shared by the fused and the plain path:
     the device telemetry reads, then the output (packed wire or columns)."""
@@ -2520,7 +2545,8 @@ def _telemetry_and_output(
         res_stats = None
         if cfg.device_telemetry:
             stats = _device_stats(
-                cfg, state, rules, acq, verdict, valid, now_ms, seg_dropped, n_seg
+                cfg, state, rules, acq, verdict, valid, now_ms, seg_dropped, n_seg,
+                cb_moves,
             )
             if timeline_k(cfg) > 0:
                 res_stats = _device_res_stats(cfg, state, now_ms)
@@ -2575,6 +2601,7 @@ def tick(
 
     # 1. exits first: they release concurrency and update breakers
     seg_dropped = jnp.int32(0)
+    cb_before = state.cb_state
     with jax.named_scope("stage.exits"):
         if use_seg:
             if cfg.seg_fallback:
@@ -2674,6 +2701,9 @@ def tick(
         cb_state,
         latest_passed,
     ) = checks
+    cb_moves = None
+    if "degrade" in features and cfg.device_telemetry:
+        cb_moves = _cb_moves(cb_before, state.cb_state, cb_state)
     state = state._replace(cb_state=cb_state)
     if latest_passed is not None:
         state = state._replace(latest_passed_ms=latest_passed)
@@ -2761,7 +2791,7 @@ def tick(
                 )
         return state, _telemetry_and_output(
             cfg, state, rules, acq, verdict, wait_ms, valid, forced, fslots,
-            now_ms, seg_dropped, ctx_a.n_seg if use_seg else 0,
+            now_ms, seg_dropped, ctx_a.n_seg if use_seg else 0, cb_moves,
         )
 
     with jax.named_scope("stage.effects"):
@@ -2880,7 +2910,7 @@ def tick(
 
     return state, _telemetry_and_output(
         cfg, state, rules, acq, verdict, wait_ms, valid, forced, fslots,
-        now_ms, 0, 0,
+        now_ms, 0, 0, cb_moves,
     )
 
 

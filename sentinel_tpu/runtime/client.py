@@ -175,6 +175,36 @@ _C_PARAM_BLOCKED = OBS.counter(
     "sentinel_param_blocked_total",
     "items a hot-parameter rule blocked (BLOCK_PARAM), from the device telemetry row",
 )
+# circuit breakers that moved, from the same row (ops/engine.STAT_CB_*):
+# upstream's EventObserverRegistry state-change observers, a tick at a time
+_C_BREAKER_MOVES = {
+    to: (idx, OBS.counter(
+        "sentinel_breaker_transitions_total",
+        "circuit breakers that changed state, by the state they went to "
+        "(reopen: a HALF_OPEN breaker whose probe failed)",
+        labels={"to": to},
+    ))
+    for to, idx in (
+        ("open", E.STAT_CB_OPENED),
+        ("half_open", E.STAT_CB_HALF_OPENED),
+        ("closed", E.STAT_CB_CLOSED),
+        ("reopen", E.STAT_CB_REOPENED),
+    )
+}
+_G_BREAKERS_OPEN = OBS.gauge(
+    "sentinel_breakers_open",
+    "circuit breakers OPEN or HALF_OPEN after the last tick",
+)
+#: what tick.resolve carries of the row where the degrade stage is compiled
+_RESOLVE_BREAKER_ATTRS = (
+    ("items", E.STAT_VALID),
+    ("degrade_blocked", E.STAT_BLOCK_DEGRADE),
+    ("cb_opened", E.STAT_CB_OPENED),
+    ("cb_half_opened", E.STAT_CB_HALF_OPENED),
+    ("cb_closed", E.STAT_CB_CLOSED),
+    ("cb_reopened", E.STAT_CB_REOPENED),
+    ("cb_open_now", E.STAT_CB_OPEN_NOW),
+)
 _C_DEV_TOKENS = {
     r: OBS.counter(
         "sentinel_device_tokens_total",
@@ -668,6 +698,7 @@ class SentinelClient:
         # SPI slot-chain analog: absent slots cost nothing); rule loads that
         # change the feature set swap in a freshly compiled tick
         self._features = self._select_features()
+        self._breaker_noted: Dict[str, int] = {}  # journal kind -> the second it was last noted in
         # memory-ledger ownership (obs/profile.py): every device buffer
         # built FOR this client — engine state (the sketch tier registers
         # itself inside init_state), ruleset tensors, wire staging — is
@@ -3152,6 +3183,27 @@ class SentinelClient:
         _G_DEV_CONC.set(float(s[E.STAT_ENTRY_CONC]))
         _G_DEV_CEIL_UTIL.set(float(s[E.STAT_CEIL_UTIL]))
         _G_DEV_SEG_LIVE.set(float(s[E.STAT_SEG_LIVE]))
+        if "degrade" in self._features:
+            self._fold_breaker_moves(s)
+
+    def _fold_breaker_moves(self, s) -> None:
+        """The row's STAT_CB_* counts: the transition counters and the open
+        gauge, and the flight journal's note of the first trip and of the
+        first close of each second (the journal keeps rare events: a mesh
+        whose services take turns being slow moves breakers every tick)."""
+        _G_BREAKERS_OPEN.set(float(s[E.STAT_CB_OPEN_NOW]))
+        moved = {to: int(s[idx]) for to, (idx, _c) in _C_BREAKER_MOVES.items()}
+        if not any(moved.values()):
+            return
+        for to, n in moved.items():
+            if n:
+                _C_BREAKER_MOVES[to][1].inc(n)
+        second = int(mono_s())
+        for kind, n in (("breaker.trip", moved["open"] + moved["reopen"]),
+                        ("breaker.close", moved["closed"])):
+            if n and self._breaker_noted.get(kind) != second:
+                self._breaker_noted[kind] = second
+                FL.FLIGHT.note(kind, n=n, open_now=int(s[E.STAT_CB_OPEN_NOW]), **moved)
 
     def _record_seg_dropped(self, n: int) -> None:
         """Surface fail-closed segment-overflow drops: counter + block log
@@ -3871,7 +3923,7 @@ class SentinelClient:
         # residual host reads (drop count, wait column) — the device span
         # above already owns the blocking verdict transfer
         _t_rb = OT.t0()
-        # device telemetry row (ops/engine.STAT_*): one 96-byte transfer in
+        # device telemetry row (ops/engine.STAT_*): one 112-byte transfer in
         # the same readback phase; replaces the host-side verdict re-scans
         # below (PASS_WAIT probe, adaptive pass/block accounting)
         stats = None
@@ -4024,6 +4076,9 @@ class SentinelClient:
                 # of the tick's items under a hot-parameter rule, those it blocked
                 attrs["param_rows"] = _param_rows
                 attrs["param_blocked"] = int(np.count_nonzero(verdict == ERR.BLOCK_PARAM))
+            if stats is not None and "degrade" in self._features:
+                # the tick's breaker blocks over its items, and its STAT_CB_* row
+                attrs.update((k, int(stats[i])) for k, i in _RESOLVE_BREAKER_ATTRS)
             OT.stage("tick.resolve", _t_res, _H_RESOLVE, trace=p.tick_id, attrs=attrs)
 
     def _param_rows(self, wb) -> int:

@@ -94,6 +94,29 @@ def _clean_context():
     CTX.clear()
 
 
+@pytest.fixture(scope="module")
+def closes_token_services():
+    """A token service's column batcher keeps a daemon worker thread until
+    the service is closed.  A module that builds services without closing
+    them names this fixture (``pytestmark = pytest.mark.usefixtures(...)``):
+    every ``DefaultTokenService`` built while it runs is closed when it ends,
+    so its worker's later files do not inherit the threads."""
+    from sentinel_tpu.cluster.token_service import DefaultTokenService
+
+    built, init = [], DefaultTokenService.__init__
+
+    def noted(self, *args, **kwargs):
+        built.append(self)
+        init(self, *args, **kwargs)
+
+    DefaultTokenService.__init__ = noted
+    yield
+    DefaultTokenService.__init__ = init
+    for service in built:
+        if getattr(service, "col", None) is not None:
+            service.close()
+
+
 @pytest.fixture()
 def vt():
     """Fresh virtual time source starting at a non-zero, non-aligned ms."""

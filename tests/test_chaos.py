@@ -27,6 +27,9 @@ from sentinel_tpu.core import errors as ERR
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# the token services built here are closed when the module ends (tests/conftest.py)
+pytestmark = pytest.mark.usefixtures("closes_token_services")
+
 
 @pytest.fixture(autouse=True)
 def _disarm_guard():

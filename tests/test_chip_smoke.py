@@ -146,6 +146,18 @@ def test_the_param_store_phase_holds_a_replay_to_the_exact_shadow():
     assert detail["store_cells"] == 1 << 15 and all(n > 0 for n in detail["store_cells_counting"])
 
 
+def test_the_breaker_phase_walks_six_far_rows_through_every_transition():
+    """The phase itself at rehearsal size, on the plain CPU path."""
+    sizes = dict(chip_smoke.BREAKER_REHEARSAL_SIZES)
+    sizes["engine"] = {k: v for k, v in sizes["engine"].items()
+                       if k not in ("use_mxu_tables", "fused_effects", "seg_effects")}
+    detail, failures = chip_smoke.breaker_phase(5, (sizes,))
+    assert failures == []
+    assert len(detail["rows"]) == 6 and min(detail["rows"]) > 40
+    assert (detail["opened"], detail["reopened"], detail["closed_again"]) == (2, 1, 2)
+    assert detail["half_opened"] == 3 and detail["exits_while_open"] == 4 and detail["ratio_ties"] == 2
+
+
 def test_result_line_has_exactly_the_contract_keys():
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     line = chip_smoke.result_line(True, {**device, "extra": 0})
@@ -166,6 +178,6 @@ def test_cpu_rehearsal_walks_every_phase():
     assert final["device"]["platform"] == "cpu"
     assert summary["ok"] is True and summary["rehearsal"] is True
     assert list(summary["phases"]) == [
-        "environment", "serve", "evidence", "equivalence", "param_store",
+        "environment", "serve", "evidence", "equivalence", "param_store", "breaker",
     ]
     assert all(p["ok"] for p in summary["phases"].values())

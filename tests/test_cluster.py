@@ -22,6 +22,9 @@ from sentinel_tpu.core import errors as ERR
 from sentinel_tpu.core import rules as R
 from sentinel_tpu.utils.host_window import HostWindow
 
+# the token services built here are closed when the module ends (tests/conftest.py)
+pytestmark = pytest.mark.usefixtures("closes_token_services")
+
 
 def cluster_flow_rule(flow_id=101, count=5.0, threshold_type=C.FLOW_THRESHOLD_GLOBAL):
     return R.FlowRule(

@@ -141,3 +141,46 @@ def test_window_expiry_resets_ratio(client, vt):
     # fresh fast traffic keeps it closed
     for _ in range(6):
         assert _roundtrip(client, vt, "w", 1)
+
+
+def test_a_breaker_that_moves_tells_the_host(client, vt):
+    """The tick's stats row carries the breakers that moved and those open
+    after it: with the tracer off they feed the transition counters, the
+    open gauge and the flight journal; with it on, ``tick.resolve``."""
+    from sentinel_tpu import obs
+    from sentinel_tpu.obs.registry import REGISTRY
+
+    def moved(to):
+        return REGISTRY.get("sentinel_breaker_transitions_total", labels={"to": to}).value
+
+    client.degrade_rules.load([st.DegradeRule(
+        resource="svc9", grade=st.CB_STRATEGY_SLOW_REQUEST_RATIO, count=10,
+        slow_ratio_threshold=0.5, stat_interval_ms=1000, time_window=1, min_request_amount=3)])
+    before = {to: moved(to) for to in ("open", "half_open", "closed", "reopen")}
+    assert not obs.enabled()
+    for _ in range(3):
+        assert _roundtrip(client, vt, "svc9", 50)
+    assert not _roundtrip(client, vt, "svc9", 1)  # open
+    assert moved("open") == before["open"] + 1
+    assert REGISTRY.get("sentinel_breakers_open").value == 1
+    vt.advance(1500)
+    assert _roundtrip(client, vt, "svc9", 80)  # the probe, slow: reopens
+    assert moved("half_open") == before["half_open"] + 1
+    assert not _roundtrip(client, vt, "svc9", 1)
+    assert moved("reopen") == before["reopen"] + 1
+    vt.advance(1500)
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        assert _roundtrip(client, vt, "svc9", 2)  # the second probe, fast: closes
+        assert _roundtrip(client, vt, "svc9", 2)
+    finally:
+        obs.disable()
+    assert moved("closed") == before["closed"] + 1 and moved("half_open") == before["half_open"] + 2
+    assert REGISTRY.get("sentinel_breakers_open").value == 0
+    resolves = [s["attrs"] for s in obs.TRACER.snapshot() if s["name"] == "tick.resolve"]
+    assert resolves and all({"items", "degrade_blocked", "cb_opened", "cb_half_opened", "cb_closed",
+                             "cb_reopened", "cb_open_now"} <= set(a) for a in resolves)
+    assert sum(a["cb_half_opened"] for a in resolves) == 1 and sum(a["cb_closed"] for a in resolves) == 1
+    kinds = [e["kind"] for e in obs.FLIGHT.events()]
+    assert "breaker.trip" in kinds and "breaker.close" in kinds

@@ -15,6 +15,9 @@ from sentinel_tpu.obs import slo as S
 from sentinel_tpu.obs.flight import FlightRecorder
 from sentinel_tpu.obs.registry import MetricRegistry
 
+# the token services built here are closed when the module ends (tests/conftest.py)
+pytestmark = pytest.mark.usefixtures("closes_token_services")
+
 #: the exposition-lines grammar the repo pins (tests/test_obs.py)
 _LINE_PAT = re.compile(
     r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^}]*\})? -?[0-9][0-9a-zA-Z+.e-]*$"

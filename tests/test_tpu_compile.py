@@ -139,3 +139,106 @@ def test_a_wide_stores_hand_back_lays_no_plane_out_anew(one_chip):
     assert not _relayouts(text)
     # the kernels' own tables, two planes a depth, and nothing the size of one beside them
     assert compiled.memory_analysis().temp_size_in_bytes < (2 * 2 + 1) * 32768 * 128 * 4
+
+
+# -- the breaker and window planes at 107,008 rows (PR 39) ------------------
+
+_LAID = re.compile(r"(\w+)\[([\d,]+)\]\{([\d,]+):T\(([\d,]+)\)")
+
+
+def _padded_bytes(dtype, dims, minor_to_major, tile, width=4):
+    """Bytes an array takes as the chip lays it out: its tile covers the
+    minor-most dimensions, each rounded up to the tile's."""
+    dims = [int(n) for n in dims.split(",")]
+    order = [int(n) for n in minor_to_major.split(",")]
+    tile = [int(n) for n in tile.split(",")]
+    for axis, t in zip(order, reversed(tile)):
+        dims[axis] = -(-dims[axis] // t) * t
+    return math.prod(dims) * (1 if dtype == "pred" else width)
+
+
+@pytest.fixture(scope="module")
+def breaker_tick(one_chip):
+    """The light tick of perfbench/configs/degrade-100k-slow-ratio.json's
+    engine sizes (106,992 resources and as many breakers, no sketch tier, one
+    breaker bucket) with the degrade stage, as text and memory analysis: about
+    50 s of the chip's compiler, once for the tests below."""
+    import json
+    import os
+
+    from sentinel_tpu.core.config import EngineConfig
+    from sentinel_tpu.ops import engine as E
+    from sentinel_tpu.ops import wire as WIRE
+    from sentinel_tpu.runtime.registry import Registry
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "perfbench", "configs", "degrade-100k-slow-ratio.json")) as f:
+        sizes = json.load(f)["engine"]
+    cfg = EngineConfig(**sizes, use_mxu_tables=True, fused_effects=True, seg_effects=True,
+                       packed_wire=True)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(jnp.shape(x), x.dtype, sharding=one_chip), tree)
+
+    state = described(jax.eval_shape(lambda: E._init_state(cfg)))
+    rules = described(E.compile_ruleset(cfg, Registry(cfg)))
+    b, b2 = WIRE.tick_shapes(cfg)[0]
+    wire = jax.ShapeDtypeStruct((WIRE.input_layout_for(cfg, b, b2).total,), jnp.uint32,
+                                sharding=one_chip)
+    tick = E.make_tick(cfg, features=frozenset({"nodes", "occupy", "flow", "degrade"}), wire_in=True)
+    with mock.patch.object(FU, "interpret_mode", lambda: False):  # the backend here is the CPU
+        compiled = tick.lower(state, rules, wire).compile()
+    return cfg, compiled.as_text(), compiled.memory_analysis()
+
+
+def _entry_planes(text, prefix):
+    """``{name: (dtype, dims, minor_to_major, tile)}`` of the tick's state
+    arguments whose name starts with ``prefix``."""
+    found = {}
+    for line in text.splitlines():
+        m = re.search(rf"%state_({prefix}\w*?)\.\d+ = " + _LAID.pattern + r".* parameter\(", line)
+        if m:
+            found[m.group(1)] = m.groups()[1:]
+    return found
+
+
+def test_the_breaker_planes_padding_stays_within_a_small_multiple_of_their_data(breaker_tick):
+    """``cb_counts`` [rule, bucket, 3] and ``cb_epochs`` [rule, bucket] at
+    100,000 rules: ISSUE 39 reckoned a (bucket, 3) face tiled 8 x 128 a rule,
+    4 KiB each and 400 MB in all.  The chip's compiler does not lay them out
+    so: it puts the rule axis minor-most in every breaker plane it is handed,
+    and the four planes take a few MB.  Held here so that a change of the
+    planes' form that loses that shows."""
+    cfg, text, _memory = breaker_tick
+    rules = cfg.max_degrade_rules + 1
+    planes = _entry_planes(text, "cb_")
+    assert set(planes) == {"cb_state", "cb_retry_ms", "cb_counts", "cb_epochs"}
+    for name, (dtype, dims, order, tile) in planes.items():
+        sizes = [int(n) for n in dims.split(",")]
+        assert sizes[int(order.split(",")[0])] == rules, (name, dims, order)  # rules on the lanes
+        data = math.prod(sizes) * 4
+        assert _padded_bytes(dtype, dims, order, tile) <= 2.5 * data, (name, dims, order, tile)
+
+
+def test_the_window_planes_padding_stays_within_twice_their_data(breaker_tick):
+    """The second and the minute window at 107,008 rows: the compiler keeps
+    the row axis minor-most in every plane it is handed (a minute plane's 60
+    buckets round up to 64), so the state's arguments take at most twice
+    their data.  Whole-plane copies ARE in this program (a window plane that
+    a ``lax.cond`` branch updates is laid out anew at the branch's edge:
+    PERF.md sections 5 and 7, PR 39, price them on the chip); the change that
+    takes them out brings the assertion that none comes back."""
+    cfg, text, memory = breaker_tick
+    planes = _entry_planes(text, "win_")
+    assert {"win_sec_counts", "win_min_counts", "win_min_rt_sum", "win_sec_run"} <= set(planes)
+    data = padded = 0
+    for name, (dtype, dims, order, tile) in planes.items():
+        n = math.prod(int(x) for x in dims.split(","))
+        if n < cfg.node_rows:
+            continue
+        assert order.split(",")[0] == "0", (name, order)  # the row axis on the lanes
+        data += n * 4
+        padded += _padded_bytes(dtype, dims, order, tile)
+    assert data > 150e6 and padded <= 2.0 * data
+    assert memory.argument_size_in_bytes <= 2.0 * data
