@@ -23,7 +23,9 @@ verdict leaf that survives packing re-opens a per-array sync in
 records the live output fields (observed via eval_shape, not re-derived
 from config); this pass flags any field outside the allowance: the wire
 buffer itself, ``wait_ms`` (the sidecar-overflow escape hatch, read only
-on the rare tick whose PASS_WAIT rows overflow the fixed sidecar), and
+on a tick whose PASS_WAIT rows overflow the fixed sidecar: rare without
+pacing rules, every tick under them, and counted by
+``sentinel_wire_wait_overflow_ticks_total`` either way), and
 ``seg_dropped`` (a plain-int trace constant, never read back packed).
 
 Packed-wire upload surface: the program the packed client calls takes

@@ -102,7 +102,7 @@ class EngineState(NamedTuple):
     win_min: W.WindowState  # [node_rows] minute window (60 x 1 s default)
     concurrency: jax.Array  # int32 [node_rows] curThreadNum per node
     # per flow-rule controller state
-    latest_passed_ms: jax.Array  # float32 [F+1] RateLimiterController.latestPassedTime
+    latest_passed_ms: jax.Array  # int32 [F+1] RateLimiterController.latestPassedTime (whole engine-ms, as now_ms)
     warmup_tokens: jax.Array  # float32 [F+1] WarmUpController.storedTokens
     warmup_last_s: jax.Array  # int32 [F+1] lastFilledTime (seconds)
     # per-slot admitted counts of the CURRENT second (exact passQps for the
@@ -429,7 +429,7 @@ def _init_state(cfg: EngineConfig) -> EngineState:
         win_sec=W.init_window(rows, sec_cfg),
         win_min=W.init_window(min_rows, min_cfg),
         concurrency=jnp.zeros((rows,), dtype=jnp.int32),
-        latest_passed_ms=jnp.full((F + 1,), -1.0e9, dtype=jnp.float32),
+        latest_passed_ms=jnp.full((F + 1,), LATEST_IDLE_MS, dtype=jnp.int32),
         warmup_tokens=jnp.zeros((F + 1,), dtype=jnp.float32),
         warmup_last_s=jnp.full((F + 1,), -1, dtype=jnp.int32),
         warm_acc=jnp.zeros((F + 1,), dtype=jnp.float32),
@@ -1977,6 +1977,9 @@ def _check_flow(
     # ONE packed matmul replaces a dozen serialized per-field gathers; the
     # dynamic warm-up token state rides in the same matrix, packed fresh
     # each tick (a [F+1, 12] stack — free)
+    # the count crosses whole, as its three bfloat16 parts (T.bf16_parts):
+    # a pacing cost is round(1000 / count), and 400 for 401 is another ms
+    count_parts = T.bf16_parts(f.count)
     fg = T.small_gather_fields(
         cfg,
         T.pack_fields(
@@ -1987,23 +1990,24 @@ def _check_flow(
                 f.ref_node,  # 3
                 f.ref_ctx,  # 4
                 f.grade,  # 5
-                f.count,  # 6
+                count_parts[0],  # 6
                 f.behavior,  # 7
                 f.max_queue_ms,  # 8
                 f.warning_token,  # 9
                 f.slope,  # 10
                 state.warmup_tokens,  # 11
+                count_parts[1],  # 12
+                count_parts[2],  # 13
             ]
         ),
         slots_f,
     )
-    # latestPassedTime is absolute engine-ms: by multi-day uptime its
-    # magnitude outgrows the matmul's bf16x3 precision (~2^-22 relative),
-    # so it takes the bit-exact integer gather (cost granularity is 1 ms
-    # anyway — RateLimiter costs are rounded to whole ms)
-    latest_g = T.small_gather_int(
-        cfg, jnp.round(state.latest_passed_ms).astype(jnp.int32), slots_f
-    ).astype(jnp.float32)
+    # latestPassedTime is absolute engine-ms in int32, as now_ms is: its
+    # magnitude outgrows the matmul's bf16x3 precision (~2^-22 relative)
+    # and, past 2^24 ms (4.66 h), float32 itself, so it takes the
+    # bit-exact integer gather and is made relative to now BEFORE it
+    # becomes a float (latest_rel_ms)
+    m_rl = latest_rel_ms(T.small_gather_int(cfg, state.latest_passed_ms, slots_f), now_ms)
     enabled = fg[:, 0] > 0
     la = fg[:, 1].astype(jnp.int32)
     origin = _fan(acq.origin_id, K)
@@ -2033,7 +2037,7 @@ def _check_flow(
     node_safe = jnp.where(node_ok, node, cfg.trash_row)
 
     grade = fg[:, 5].astype(jnp.int32)
-    rcount = fg[:, 6]
+    rcount = fg[:, 6] + fg[:, 12] + fg[:, 13]
     behavior = jnp.where(grade == GRADE_QPS, fg[:, 7].astype(jnp.int32), CONTROL_DEFAULT)
     cnt = _fan(acq.count, K).astype(jnp.float32)
 
@@ -2055,14 +2059,7 @@ def _check_flow(
     pace_qps = jnp.where(
         behavior == CONTROL_WARM_UP_RATE_LIMITER, warm_qps, jnp.maximum(rcount, 1e-9)
     )
-    # clamp pacing cost to the fused effects path's 3-digit envelope
-    # (~4.6 h of pacing per item — larger is unreal and would overflow the
-    # int32 segmented ranks); the clamped item still blocks via rl_wait
-    cost = jnp.where(
-        is_rl,
-        jnp.minimum(jnp.floor(1000.0 * cnt / pace_qps + 0.5), float((1 << 24) - 1)),
-        0.0,
-    )
+    cost = jnp.where(is_rl, pace_cost_ms(cnt, pace_qps), 0.0)
 
     # --- within-tick ranks (key: decision node; RL keys by rule slot)
     key = jnp.where(is_rl, jnp.int32(cfg.node_rows) + slots_f, node_safe)
@@ -2119,12 +2116,10 @@ def _check_flow(
     thread_block = conc + rank_thr + cnt > rcount
     basic_block = jnp.where(grade == GRADE_QPS, qps_block, thread_block)
 
-    # RateLimiterController.canPass:50-105 (exact batched leaky bucket)
-    now_f = now_ms.astype(jnp.float32)
-    l0 = latest_g
+    # RateLimiterController.canPass:50-105 (exact batched leaky bucket),
+    # every term relative to now: expected - now
     csum_incl = rank_cost + cost
-    expected = jnp.maximum(l0 + csum_incl, now_f + csum_incl - cost)
-    wait = expected - now_f
+    wait = jnp.maximum(m_rl + csum_incl, csum_incl - cost)
     rl_block = wait > fg[:, 8]
 
     entry_block = jnp.where(is_rl, rl_block, basic_block) & applicable
@@ -2242,9 +2237,47 @@ def _check_flow(
     )
 
 
+def pace_cost_ms(cnt, pace_qps):
+    """``Math.round(1000 * count / qps)`` (RateLimiterController.java:57: a
+    half rounds up), clamped to the fused effects path's 3-digit envelope
+    (~4.6 h of pacing per item — larger is unreal and would overflow the
+    int32 segmented ranks; the clamped item still blocks via its wait).
+
+    A float32 divide that is a last bit short turns a quotient standing on an
+    exact half (1000 / 80 = 12.5) into the millisecond below, and a
+    backend's divide need not be correctly rounded (the TPU's is a
+    reciprocal and refinement steps).  So the rounded quotient ``c`` is held
+    to what defines it, ``(c - 0.5) * qps <= 1000 * count < (c + 0.5) * qps``,
+    by two products, which are exact for whole counts and whole qps."""
+    num = 1000.0 * cnt
+    c = jnp.floor(num / pace_qps + 0.5)
+    c = c + ((c + 0.5) * pace_qps <= num) - ((c - 0.5) * pace_qps > num)
+    return jnp.minimum(c, float((1 << 24) - 1))
+
+
+#: latestPassedTime of a rule that has never admitted anything
+LATEST_IDLE_MS = -(10**9)
+#: how far behind now a latestPassedTime is still told apart: further back
+#: the bucket is idle whatever the rule's cost (costs are capped at 2^24 - 1)
+_LATEST_REL_FLOOR = -(1 << 24)
+
+
+def latest_rel_ms(latest_ms, now_ms):
+    """``latestPassedTime - now`` as float32, exact: the difference is taken
+    in int32 (both are whole engine-ms) and floored at -2^24, which also
+    keeps LATEST_IDLE_MS from wrapping.  A float32 plane beside the int32
+    now_ms held whole milliseconds only up to 2^24 ms = 4.66 h of engine
+    time: past it a 1 ms cost vanished in the rounding (PERF.md, PR 42)."""
+    now_ms = now_ms.astype(jnp.int32)
+    far = latest_ms < now_ms + _LATEST_REL_FLOOR
+    return jnp.where(far, _LATEST_REL_FLOOR, latest_ms - now_ms).astype(jnp.float32)
+
+
 def _apply_latest(latest_passed_ms, T_s, n_s, now_ms):
     """Closed-form latestPassedTime advance from per-slot (cost, count)
-    sums — see the comment block in _check_flow.
+    sums — see the comment block in _check_flow.  The plane is int32
+    engine-ms; the arithmetic runs relative to now in float32 (exact while
+    a slot's summed cost stays under 2^24) and lands as a whole number.
 
     Drift bound vs the reference's per-request CAS
     (RateLimiterController.java:50-105), pinned by
@@ -2257,10 +2290,9 @@ def _apply_latest(latest_passed_ms, T_s, n_s, now_ms):
     running total is conservative (slight under-admission, never a
     sustained burst past the configured rate)."""
     mean_cost = T_s / jnp.maximum(n_s, 1.0)
-    cand = jnp.maximum(
-        latest_passed_ms + T_s, now_ms.astype(jnp.float32) + T_s - mean_cost
-    )
-    return jnp.where(n_s > 0, cand, latest_passed_ms)
+    cand = jnp.maximum(latest_rel_ms(latest_passed_ms, now_ms) + T_s, T_s - mean_cost)
+    landed = now_ms.astype(jnp.int32) + jnp.round(cand).astype(jnp.int32)
+    return jnp.where(n_s > 0, landed, latest_passed_ms)
 
 
 @jax.named_scope("stage.tail_flow")

@@ -318,7 +318,6 @@ def run_checks_seg(
     from sentinel_tpu.ops.rank import grouped_exclusive_cumsum
 
     b = acq.res.shape[0]
-    now_f = now_ms.astype(jnp.float32)
     cnt = acq.count.astype(jnp.float32)
     zero_block = jnp.zeros((b,), bool)
     live = ctx.live
@@ -406,20 +405,23 @@ def run_checks_seg(
             f = rules.flow
             sec_cfg = W.WindowConfig(cfg.second_sample_count, cfg.second_window_ms)
             slot_u = slot_vals["flow"]
+            count_parts = T.bf16_parts(f.count)  # the count crosses whole (engine._check_flow)
             fg = T.small_gather_fields(
                 cfg,
                 T.pack_fields(
                     [
                         f.enabled, f.limit_app, f.strategy, f.ref_node, f.ref_ctx,
-                        f.grade, f.count, f.behavior, f.max_queue_ms,
+                        f.grade, count_parts[0], f.behavior, f.max_queue_ms,
                         f.warning_token, f.slope, state.warmup_tokens,
+                        count_parts[1], count_parts[2],
                     ]
                 ),
                 slot_u,
             )
-            latest_u = T.small_gather_int(
-                cfg, jnp.round(state.latest_passed_ms).astype(jnp.int32), slot_u
-            ).astype(jnp.float32)
+            # relative to now while still int32: exact past 2^24 ms of engine time
+            latest_rel_u = E.latest_rel_ms(
+                T.small_gather_int(cfg, state.latest_passed_ms, slot_u), now_ms
+            )
             enabled = fg[:, 0] > 0
             la = fg[:, 1].astype(jnp.int32)
             named = (la >= 0) & (la == carry.origin_id)
@@ -447,7 +449,7 @@ def run_checks_seg(
             applicable_u = applicable_u & node_ok
             node_safe_u = jnp.where(node_ok & (node < cfg.node_rows), node, cfg.trash_row)
             grade = fg[:, 5].astype(jnp.int32)
-            rcount = fg[:, 6]
+            rcount = fg[:, 6] + fg[:, 12] + fg[:, 13]
             behavior = jnp.where(
                 grade == GRADE_QPS, fg[:, 7].astype(jnp.int32), CONTROL_DEFAULT
             )
@@ -496,7 +498,7 @@ def run_checks_seg(
             i_fslot = exp.add(jnp.where(live, slot_u, cfg.max_flow_rules))
             i_mq = exp.add_f(thr_eff - wp)
             i_mt = exp.add_f(rcount - conc)
-            i_mrl = exp.add_f(latest_u - now_f)
+            i_mrl = exp.add_f(latest_rel_u)
             i_maxq = exp.add_f(fg[:, 8])
             i_pace = exp.add_f(pace_qps)
             i_mo = exp.add_f(rcount - pool)
@@ -647,14 +649,7 @@ def run_checks_seg(
             mq_i = exp.get_f(i_maxq)
             pace_i = exp.get_f(i_pace)
             margin_o = exp.get_f(i_mo)
-            # same 3-digit pacing-cost clamp as _check_flow (int32 rank safety)
-            cost = jnp.where(
-                rl_i,
-                jnp.minimum(
-                    jnp.floor(1000.0 * cnt / pace_i + 0.5), float((1 << 24) - 1)
-                ),
-                0.0,
-            )
+            cost = jnp.where(rl_i, E.pace_cost_ms(cnt, pace_i), 0.0)
             elig_f = eligible & app_i
             rank_key = jnp.where(rl_i, jnp.int32(cfg.node_rows) + slot_i, node_i)
             direct_any = ~jnp.any(

@@ -283,6 +283,26 @@ def pack_fields(fields: Sequence[jax.Array]) -> jax.Array:
     return jnp.stack(cols, axis=1)
 
 
+def bf16_parts(x) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A float32 column as three addends, each exact in bfloat16, whose sum
+    is the column exactly (3 x 8 significant bits).  At PRECISION the chip
+    rounds a table that crosses the MXU to bfloat16: a FlowRule count of 401
+    arrived as 400, and its pacing cost as 3 ms where 1000 / 401 rounds to 2
+    (PERF.md, PR 42).  A value that has to arrive whole crosses as its parts
+    and is summed on the far side.  The parts are cut by masking the low 16
+    bits, not by a convert to bfloat16 and back, which a compiler that may
+    keep excess precision is free to drop."""
+
+    def head(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32) & jnp.uint32(0xFFFF0000)
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    x = jnp.asarray(x).astype(jnp.float32)
+    p0 = head(x)
+    p1 = head(x - p0)
+    return p0, p1, x - p0 - p1
+
+
 #: above this, a flat [N, S] one-hot's memory traffic dominates — switch to
 #: the two-level decomposition (same MACs, B×(n_hi+n_lo) memory)
 _FLAT_ONEHOT_LIMIT = 1024

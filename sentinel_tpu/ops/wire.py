@@ -16,9 +16,11 @@ packed on-device so only packed bytes ever cross the transport::
                       each (verdict codes are 0..6 — core/errors.py)
     [sidecar]         EXC_K row indices then EXC_K wait values (uint32):
                       the top-EXC_K rows of wait_ms.  Covers every
-                      PASS_WAIT row whenever n_wait <= EXC_K; a rarer
-                      overflow tick falls back to reading the full
-                      TickOutput.wait_ms column (the one escape hatch).
+                      PASS_WAIT row whenever n_wait <= EXC_K; a tick
+                      with more falls back to reading the full
+                      TickOutput.wait_ms column (the one escape hatch:
+                      every tick where pacing rules admit most items
+                      with a wait — see EXC_K).
     [stats]           N_STATS words — float32 telemetry row, bitcast
     [timeline]        timeline_k * TL_COLS words — float32, bitcast
     [hot]             hotset_k * 2 words — float32, bitcast
@@ -88,9 +90,15 @@ VERDICTS_PER_WORD = 10
 _VMASK = (1 << VERDICT_BITS) - 1
 #: header words: magic, n_wait, seg_dropped, checksum
 HDR_WORDS = 4
-#: PASS_WAIT sidecar capacity — pacing verdicts are rare by design
-#: (flow rules with RATE_LIMITER behavior); 64 rows = 512 B covers the
-#: normal tick, and an overflow tick reads the full wait column instead
+#: PASS_WAIT sidecar capacity: 64 rows = 512 B.  That covers a tick whose
+#: PASS_WAIT rows are the exception (prioritized occupy, a pacing rule on a
+#: resource or two).  A tick with more reads the full wait column instead, in
+#: a second blocking transfer (4 B a row of the tick's shape): under a
+#: RATE_LIMITER rule on every resource that is EVERY tick (perfbench's
+#: rate-limiter-pacing: nine items in ten are PASS_WAIT).  The client counts
+#: those ticks in sentinel_wire_wait_overflow_ticks_total and, tracer on,
+#: times each read in tick.readback's wait_read_ns / wait_read_bytes beside
+#: the header's n_wait as wait_rows
 EXC_K = 64
 #: seed of the explain section's own checksum — distinct from the main
 #: checksum so a flip in either section is attributed to that section

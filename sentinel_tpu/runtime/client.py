@@ -195,6 +195,12 @@ _G_BREAKERS_OPEN = OBS.gauge(
     "sentinel_breakers_open",
     "circuit breakers OPEN or HALF_OPEN after the last tick",
 )
+#: what tick.resolve carries of the row where a pacing (RATE_LIMITER) rule is loaded
+_RESOLVE_PACED_ATTRS = (
+    ("items", E.STAT_VALID),
+    ("pass_wait", E.STAT_PASS_WAIT),
+    ("flow_blocked", E.STAT_BLOCK_FLOW),
+)
 #: what tick.resolve carries of the row where the degrade stage is compiled
 _RESOLVE_BREAKER_ATTRS = (
     ("items", E.STAT_VALID),
@@ -251,6 +257,11 @@ _C_WIRE = {
 _C_PACKED_DECODE = OBS.counter(
     "sentinel_packed_decode_failures_total",
     "fused wire readbacks rejected by the packed decoder (tick fails CLOSED)",
+)
+_C_WAIT_OVERFLOW = OBS.counter(
+    "sentinel_wire_wait_overflow_ticks_total",
+    "packed ticks with more PASS_WAIT rows than the wire's sidecar holds "
+    "(ops/wire.EXC_K): each read the whole wait column in a second transfer",
 )
 # -- window rotation cadence (r14 running-sum windows, ops/window.py):
 # refresh() is a pure function of the stamped tick timestamp, so the
@@ -652,6 +663,9 @@ class SentinelClient:
         self._auth_host_rules: Dict[str, list] = {}
         self._param_lanes_by_res: Dict[str, list] = {}
         self._param_ruled = np.zeros(1, bool)
+        #: a local FlowRule paces (RATE_LIMITER or WARM_UP_RATE_LIMITER): its
+        #: ticks' spans carry the PASS_WAIT counts (_RESOLVE_PACED_ATTRS, wait_rows)
+        self._paced = False
         # the shared degrade-hysteresis primitive (adaptive/degrade.py):
         # enter-on-failure with cooldown, exit on first healthy probe —
         # same journal kinds / counters / gauge as before the refactor
@@ -1435,6 +1449,10 @@ class SentinelClient:
             if rid is not None and rid <= self.cfg.max_resources:
                 ruled[rid] = True
         self._param_ruled = ruled
+        self._paced = any(
+            r.control_behavior in (R.CONTROL_RATE_LIMITER, R.CONTROL_WARM_UP_RATE_LIMITER)
+            for r in local_flow
+        )
 
         # engine specialization: with the client presorting every batch
         # (see _run_tick), a ruleset of single-lane DIRECT/default-limitApp
@@ -3930,7 +3948,7 @@ class SentinelClient:
         if frame is not None:
             # packed mode: every block below was decoded from the ONE
             # fused transfer — no further device reads on this path
-            # (except the rare wait-sidecar overflow escape hatch)
+            # (except the wait-sidecar overflow escape hatch further down)
             stats = frame.stats
             if stats is not None:
                 self._fold_device_stats(stats)
@@ -3985,13 +4003,26 @@ class SentinelClient:
         # transfer entirely on the common no-pacing tick.  The device
         # telemetry row answers "any PASS_WAIT?" without scanning the
         # verdict array on the host.
+        _rb_attrs = None
         if frame is not None:
             wait = frame.wait
+            if self._paced and _t_rb:
+                _rb_attrs = {"wait_rows": frame.n_wait}
             if wait is None:
-                # > EXC_K pacing rows this tick: the sidecar overflowed —
-                # the ONE escape-hatch read outside the fused transfer
-                wait = np.asarray(out.wait_ms)  # stlint: disable=host-sync — sidecar-overflow escape hatch (rare by design)
+                # > EXC_K waiting rows this tick: the sidecar overflowed — the
+                # ONE escape-hatch read outside the fused transfer, a second
+                # blocking read of the whole wait column (ops/wire.EXC_K says
+                # when that is rare and when it is every tick)
+                _t_w = OT.now_ns()
+                wait = np.asarray(out.wait_ms)  # stlint: disable=host-sync — sidecar-overflow escape hatch (counted, and timed while tracing)
                 _C_WIRE["rx"].inc(wait.nbytes)
+                _C_WAIT_OVERFLOW.inc()
+                if _t_rb:
+                    _rb_attrs = {
+                        "wait_rows": frame.n_wait,
+                        "wait_read_ns": OT.now_ns() - _t_w,
+                        "wait_read_bytes": wait.nbytes,
+                    }
         elif stats is not None and not stats[E.STAT_PASS_WAIT] > 0:
             wait = np.zeros(verdict.shape[0], np.int32)
         elif stats is None and not (verdict == ERR.PASS_WAIT).any():
@@ -4000,7 +4031,7 @@ class SentinelClient:
             wait = np.asarray(out.wait_ms)  # stlint: disable=host-sync — readback point
             _C_WIRE["rx"].inc(wait.nbytes)
         if _t_rb:
-            OT.stage("tick.readback", _t_rb, _H_READBACK, trace=p.tick_id)
+            OT.stage("tick.readback", _t_rb, _H_READBACK, trace=p.tick_id, attrs=_rb_attrs)
         FP.hit(_FP_FANOUT)  # chaos: raise BEFORE any consumer resolves
         if not self._claim_tick(p, "done"):
             return  # the watchdog failed this tick over while we read back
@@ -4079,6 +4110,9 @@ class SentinelClient:
             if stats is not None and "degrade" in self._features:
                 # the tick's breaker blocks over its items, and its STAT_CB_* row
                 attrs.update((k, int(stats[i])) for k, i in _RESOLVE_BREAKER_ATTRS)
+            if stats is not None and self._paced:
+                # of the tick's items, those admitted with a wait and those refused
+                attrs.update((k, int(stats[i])) for k, i in _RESOLVE_PACED_ATTRS)
             OT.stage("tick.resolve", _t_res, _H_RESOLVE, trace=p.tick_id, attrs=attrs)
 
     def _param_rows(self, wb) -> int:
