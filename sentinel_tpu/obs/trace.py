@@ -424,17 +424,19 @@ def event(name: str, trace: int = 0, attrs: Optional[dict] = None) -> None:
 # -- summaries ---------------------------------------------------------------
 
 
-#: attrs that summarize() counts by value: which way a span went, and how
-#: many host-to-device transfers a tick's input took (``tick.assemble``)
-_COUNTED_ATTRS = ("path", "why", "puts")
+#: attrs that summarize() counts by value: which way a span went, how
+#: many host-to-device transfers a tick's input took and how many columns
+#: of each side it wrote as a fill (``tick.assemble``), and how many
+#: completion columns the drain concatenated (``tick.drain``)
+_COUNTED_ATTRS = ("path", "why", "puts", "absent_a", "absent_c", "joined")
 
 
 def summarize(spans: Iterable[dict], prefix: Optional[str] = None) -> Dict[str, dict]:
     """Per-name duration stats over snapshot()/chrome-trace spans:
     ``{name: {count, p50_ms, p99_ms, mean_ms, total_ms}}``; a name whose
     spans carry a ``path`` attr (``tick.presort``), a ``why`` attr
-    (``tick.idle``) or a ``puts`` attr (``tick.assemble``) also gets that
-    key: how many spans took each value."""
+    (``tick.idle``) or one of the other ``_COUNTED_ATTRS`` (``tick.assemble``,
+    ``tick.drain``) also gets that key: how many spans took each value."""
     import numpy as np
 
     by_name: Dict[str, List[float]] = {}

@@ -2402,37 +2402,43 @@ class SentinelClient:
         param_hash: Optional[np.ndarray] = None,
     ) -> None:
         """Bulk exits for block-acquired traffic: column arrays, queued
-        for the next tick (completions are fire-and-forget)."""
+        for the next tick (completions are fire-and-forget).  A column the
+        caller does not pass is queued as ``None``: it stays absent through
+        the drain and the tick's build, which write its default straight
+        into the input buffer (_join_completions, _run_tick)."""
         from sentinel_tpu.native.ring import FLAG_COMPLETION, FLAG_INBOUND
 
         res = np.ascontiguousarray(res, dtype=np.int32)
         n = len(res)
-        trash = self.cfg.trash_row
 
-        def col(x, fill, dt=np.int32):
+        def col(x, dt=np.int32):
             if x is None:
-                return np.full(n, fill, dt)
+                return None
             x = np.ascontiguousarray(x, dtype=dt)
             assert len(x) == n
             return x
 
-        flags = np.full(n, FLAG_COMPLETION, np.int32) | np.where(
-            col(inbound, 0) != 0, FLAG_INBOUND, 0
-        )
+        inbound = col(inbound)
+        flags = None
+        if inbound is not None:
+            flags = np.where(
+                inbound != 0, np.int32(FLAG_COMPLETION | FLAG_INBOUND),
+                np.int32(FLAG_COMPLETION),
+            )
+        aux = [None] * 4
         if param_hash is not None:
             ph = np.ascontiguousarray(param_hash, dtype=np.int32)
-            aux = [ph[:, k] if k < ph.shape[1] else np.zeros(n, np.int32) for k in range(4)]
-        else:
-            aux = [np.zeros(n, np.int32)] * 4
+            assert len(ph) == n
+            aux = [ph[:, k] if k < ph.shape[1] else None for k in range(4)]
         block = (
             res,
-            col(success, 1),
-            col(origin_node, trash),
-            col(ctx_node, trash),
+            col(success),
+            col(origin_node),
+            col(ctx_node),
             flags,
-            col(rt, 0.0, np.float32),
-            col(error, 0),
-            np.zeros(n, np.int32),
+            col(rt, np.float32),
+            col(error),
+            None,  # the tag: nothing reads a completion's
             *aux,
         )
         with self._lock:
@@ -2628,11 +2634,15 @@ class SentinelClient:
                         )
                     )
                     n_comp += len(spill)
-            # bulk completion blocks join after ring + spill
+            # bulk completion blocks join after ring + spill.  Under the
+            # lock, which every producer and every resolver callback wants,
+            # the pieces are only taken off the queue (slices are views);
+            # they are joined once it is released
+            joined = 0
             if n_comp < self.cfg.complete_batch_size and self._comp_blocks:
+                pieces = []
+                room_c = self.cfg.complete_batch_size - n_comp
                 with self._lock:
-                    pieces = []
-                    room_c = self.cfg.complete_batch_size - n_comp
                     while room_c > 0 and self._comp_blocks:
                         cb = self._comp_blocks[0]
                         k = len(cb[0])
@@ -2641,15 +2651,16 @@ class SentinelClient:
                             self._comp_blocks.pop(0)
                             room_c -= k
                         else:
-                            pieces.append(tuple(col[:room_c] for col in cb))
+                            pieces.append(tuple(
+                                None if col is None else col[:room_c] for col in cb
+                            ))
                             self._comp_blocks[0] = tuple(
-                                col[room_c:] for col in cb
+                                None if col is None else col[room_c:] for col in cb
                             )
                             room_c = 0
                 if pieces:
-                    comp = tuple(
-                        np.concatenate([comp[j]] + [p[j] for p in pieces])
-                        for j in range(len(comp))
+                    comp, joined = self._join_completions(
+                        comp if n_comp else None, pieces
                     )
                     n_comp = len(comp[0])
             fronts = []
@@ -2697,6 +2708,8 @@ class SentinelClient:
                         "n_obj": len(acq), "n_blk": sum(t for _b, _o, t in blocks),
                         "n_comp": n_comp, "blocks": len(blocks),
                         "left_blocks": left_blocks, "left_items": left_items,
+                        # completion columns concatenated (_join_completions)
+                        "joined": joined,
                     },
                 )
             _t_lock = 0
@@ -2776,6 +2789,44 @@ class SentinelClient:
                 if not more:
                     return
             now_ms = None  # subsequent drain loops use fresh time
+
+    def _join_completions(self, head, pieces) -> Tuple[tuple, int]:
+        """One tick's completion columns out of what the ring and the spill
+        gave (``head``, None when they gave nothing) and the pieces taken
+        off ``_comp_blocks``, in that order.  A column that no part carries
+        stays ``None`` (the build writes its default into the input buffer,
+        _run_tick); one that some part carries gets the others' default; the
+        tag and the lanes past ``param_dims``, which nothing reads, are
+        dropped.  One piece alone is handed on as it is.  Returns the columns
+        and how many of them were concatenated."""
+        if head is None and len(pieces) == 1:
+            return pieces[0], 0
+        from sentinel_tpu.native.ring import FLAG_COMPLETION
+
+        parts = ([] if head is None else [head]) + pieces
+        total = sum(len(p[0]) for p in parts)
+        trash = self.cfg.trash_row
+        # (res, success, origin_node, ctx_node, flags, rt, error, tag, lanes)
+        fills = (None, 1, trash, trash, FLAG_COMPLETION, None, 0, None, 0, 0, 0, 0)
+        out: list = []
+        joined = 0
+        for j, fill in enumerate(fills):
+            if (
+                j == 7
+                or j >= 8 + self.cfg.param_dims
+                or all(p[j] is None for p in parts)
+            ):
+                out.append(None)
+                continue
+            col = np.empty(total, np.float32 if j == 5 else np.int32)
+            o = 0
+            for p in parts:
+                k = len(p[0])
+                col[o : o + k] = fill if p[j] is None else p[j]
+                o += k
+            out.append(col)
+            joined += 1
+        return tuple(out), joined
 
     def _sweep_expired(self, now_ms: Optional[int]) -> None:
         """Shed already-expired queued work CLOSED before device dispatch
@@ -3370,8 +3421,24 @@ class SentinelClient:
 
         inv_a = None
         _au_cols = None
+        _abs_a = _abs_c = 0  # columns written as a fill a side (span attrs)
         if acq or n_front or n_blk:
             n = len(acq)
+            # A column that no row of this tick carries stays absent: it is
+            # not staged, not a sort key, not gathered and not downcast; its
+            # view of the input buffer is written as the fill below, which
+            # gives the same bytes.  An object request and a front-door item
+            # carry every column; a block piece carries what its caller
+            # passed (most pass res, the origin, inbound and the hot-param
+            # lanes and nothing else: SphU.entry(name)'s count 1, no
+            # priority, the default context, no pre-verdict).
+            every = bool(acq) or bool(n_front)
+
+            def carried(f):
+                return every or any(
+                    getattr(blk, f) is not None for blk, _o, _t in blocks
+                )
+
             def arr(f, fill, dt, front_col=None, blk_default=None):
                 """Column assembly: object requests [0:n], array-block
                 slices [n:n+n_blk] (vectorized), front-door items after.
@@ -3392,10 +3459,6 @@ class SentinelClient:
                 if front_col is not None and n_front:
                     out[n + n_blk : n + n_blk + n_front] = front_col
                 return out
-            f_row = front[0] if n_front else None
-            f_cnt = front[1] if n_front else None
-            f_prio = front[2] if n_front else None
-
             def _ph_cols():
                 ph = self._sbuf("a.ph", (B, M), np.int32)
                 ph.fill(0)
@@ -3415,7 +3478,22 @@ class SentinelClient:
                         ph[n + n_blk : n + n_blk + n_front, 1] = front[5]
                 return ph
 
-            res_np = arr("res", trash, np.int32, f_row)
+            _n_a = n + n_blk + n_front
+            # the carried columns in the order sx_presort gathers them, each
+            # at the fill a padding row holds (the layout's); a block piece
+            # without counts counts 1 a row
+            fills = {
+                c.field: c.fill for c in wb.layout.acq if c.field != "param_hash"
+            }
+            front_col = dict(zip(("res", "count", "prio"), front)) if n_front else {}
+            cols = {
+                f: arr(
+                    f, fill, np.int32, front_col.get(f),
+                    blk_default=1 if f == "count" else None,
+                )
+                for f, fill in fills.items()
+                if carried(f)
+            }
             # the fused digit planes carry counts exactly up to
             # max_batch_count (EngineConfig docs); clamping at the
             # single batch-build choke point makes that envelope real
@@ -3423,28 +3501,22 @@ class SentinelClient:
             # clamp tracks the ACTIVE path (engine._use_fused, incl.
             # the SENTINEL_NO_PALLAS kill switch) — the unfused paths
             # are exact to 65535 and stay unclamped.
-            cnt_np = arr("count", 0, np.int32, f_cnt, blk_default=1)
-            if clamp:
-                np.minimum(cnt_np, cfg.max_batch_count, out=cnt_np)
+            cnt_live = min(1, cfg.max_batch_count) if clamp else 1
+            if clamp and "count" in cols:
+                np.minimum(cols["count"], cfg.max_batch_count, out=cols["count"])
             if self._audit is not None:
                 # shadow-fold input: the CLAMPED columns, pre-presort
                 # (fold order is irrelevant — sums) — exactly the units
                 # the engine lands in the sketch.  The staging buffers
                 # are not reused before observe() runs below this tick.
-                _au_cols = (res_np, cnt_np)
-            # the columns in the order sx_presort gathers them
-            cols = {
-                "res": res_np,
-                "count": cnt_np,
-                "prio": arr("prio", 0, np.int32, f_prio),
-                "origin_id": arr("origin_id", -1, np.int32),
-                "origin_node": arr("origin_node", trash, np.int32),
-                "ctx_node": arr("ctx_node", trash, np.int32),
-                "ctx_name": arr("ctx_name", -1, np.int32),
-                "inbound": arr("inbound", 0, np.int32),
-                "pre_verdict": arr("pre_verdict", 0, np.int32),
-            }
-            ph_np = _ph_cols()
+                # An absent count is its constant (a padding row is on
+                # the trash row, which the fold leaves out).
+                _au_cols = (
+                    cols["res"],
+                    cols["count"] if "count" in cols
+                    else np.broadcast_to(np.int32(cnt_live), (B,)),
+                )
+            ph_np = _ph_cols() if carried("param_hash") else None
             if presort:
                 _tp = OT.t0()
                 # key order matches engine_seg.prepare_acquire's segment
@@ -3456,7 +3528,6 @@ class SentinelClient:
                 # writes the inverse permutation and gathers the columns
                 # straight into the input buffer's views; a narrow column
                 # (the gather moves 4-byte rows) goes through an s.* slot
-                _n_a = n + n_blk + n_front
                 dst = {
                     f: va[f] if va[f].dtype == np.int32
                     else self._sbuf("s." + f, B, np.int32)
@@ -3465,13 +3536,16 @@ class SentinelClient:
                 free = self._inv_free.setdefault(B, [])
                 inv_a = free.pop() if free else np.empty(B, np.int32)
                 _path = RING.presort(
-                    tuple(cols[f] for f in _ACQ_SEG_KEYS), _n_a,
+                    # a constant key orders nothing
+                    tuple(cols[f] for f in _ACQ_SEG_KEYS if f in cols), _n_a,
                     self._sbuf("s.order", B, np.int32), inv_a,
                     self._sbuf("s.scratch", 2 * max(B, B2), np.uint64),
                     tuple(cols.values()), tuple(dst.values()),
-                    ph_np, va["param_hash"],
+                    ph_np, None if ph_np is None else va["param_hash"],
                 )
-                cols, ph_np = dst, va["param_hash"]
+                cols = dst
+                if ph_np is not None:
+                    ph_np = va["param_hash"]
                 if _tp:
                     _tp0 = _tp0 or _tp
                     _ns_presort += OT.now_ns() - _tp
@@ -3481,7 +3555,7 @@ class SentinelClient:
                 if B <= 4096 or (self._seg_sample_ctr & 7) == 0:
                     _segs = self._note_seg_count(
                         self._host_seg_count(
-                            tuple(cols[f] for f in _ACQ_SEG_KEYS)
+                            tuple(cols[f] for f in _ACQ_SEG_KEYS if f in cols)
                         ),
                         B, cfg.batch_size,
                     )
@@ -3492,15 +3566,36 @@ class SentinelClient:
             for f, x in cols.items():
                 if x is not va[f]:
                     np.copyto(va[f], x, casting="unsafe")
-            if ph_np is not va["param_hash"]:
+            if ph_np is None:
+                va["param_hash"].fill(0)
+                _abs_a += 1
+            elif ph_np is not va["param_hash"]:
                 np.copyto(va["param_hash"], ph_np.T)  # lane by lane
+            # the absent columns: a live row's value and a padding row's
+            # are one, except count's (a block's rows count 1, padding 0),
+            # whose padding run lies where the presort spliced it
+            for f, fill in fills.items():
+                if f in cols:
+                    continue
+                _abs_a += 1
+                if f != "count":
+                    va[f].fill(fill)
+                    continue
+                va[f].fill(cnt_live)
+                if _n_a < B:
+                    p0 = _n_a if inv_a is None else int(inv_a[_n_a])
+                    va[f][p0 : p0 + B - _n_a] = fill
         else:
             wb.idle_acquire()  # an idle side is its fill
         if comp is not None:
             from sentinel_tpu.native.ring import FLAG_INBOUND
 
+            # a column is None where no completion of this tick carries it
+            # (submit_completion_block, _join_completions): it stays out of
+            # the presort and its live rows are written as the default
             (res_a, cnt_a, org_a, ctx_a, flags_a, rt_a, err_a, _tag,
              *aux_a) = comp
+            aux_a = list(aux_a[:M])
             n = len(res_a)
             if self._adaptive is not None and n:
                 # BBR minRT input: this tick's completion RT floor
@@ -3515,37 +3610,50 @@ class SentinelClient:
                 # among them) land in their views; the ones narrowed,
                 # clamped or masked on the way (below) go through sc.* slots
                 _n_c = n
-                # (a producer may hand a wider integer column, as
-                # submit_completion_block's flags are: narrowed here)
-                src = tuple(
-                    np.ascontiguousarray(
-                        x, np.float32 if x is rt_a else np.int32
+                slot = lambda i, x: (
+                    None if x is None else self._sbuf(f"sc.{i}", B2, np.int32)[:n]
+                )
+                # (column, where its gathered rows go), as comp orders them
+                sides = [
+                    (res_a, vc["res"][:n]), (cnt_a, slot(1, cnt_a)),
+                    (org_a, vc["origin_node"][:n]), (ctx_a, vc["ctx_node"][:n]),
+                    (flags_a, slot(4, flags_a)), (rt_a, vc["rt"][:n]),
+                    (err_a, slot(6, err_a)),
+                    *((x, vc["param_hash"][k, :n]) for k, x in enumerate(aux_a)),
+                ]
+                # (a producer may hand a strided or a wider integer column,
+                # as submit_completion_block's lanes are: made plain here)
+                src = [
+                    None if x is None else np.ascontiguousarray(
+                        x, np.float32 if j == 5 else np.int32
                     )
-                    for x in (res_a, cnt_a, org_a, ctx_a, flags_a, rt_a,
-                              err_a, *aux_a[:M])
-                )
+                    for j, (x, _d) in enumerate(sides)
+                ]
+                dst = [None if x is None else d for x, d in sides]
                 placed = ("res", "origin_node", "ctx_node", "rt", "param_hash")
-                slot = lambda i: self._sbuf(f"sc.{i}", B2, np.int32)[:n]
-                dst = (
-                    vc["res"][:n], slot(1), vc["origin_node"][:n],
-                    vc["ctx_node"][:n], slot(4), vc["rt"][:n], slot(6),
-                    *(vc["param_hash"][k, :n] for k in range(len(src) - 7)),
-                )
                 _path_c = RING.presort(
-                    (src[0], src[3], src[2]), n,
+                    # a constant key orders nothing
+                    tuple(src[j] for j in (0, 3, 2) if src[j] is not None), n,
                     self._sbuf("sc.order", B2, np.int32)[:n], None,
-                    self._sbuf("s.scratch", 2 * max(B, B2), np.uint64), src, dst,
+                    self._sbuf("s.scratch", 2 * max(B, B2), np.uint64),
+                    tuple(x for x in src if x is not None),
+                    tuple(d for d in dst if d is not None),
                 )
                 _path = _path or _path_c
                 res_a, cnt_a, org_a, ctx_a, flags_a, rt_a, err_a = dst[:7]
-                aux_a = list(dst[7:])
+                aux_a = dst[7:]
                 if _tp:
                     _tp0 = _tp0 or _tp
                     _ns_presort += OT.now_ns() - _tp
                 self._seg_sample_ctr_c += 1
                 if B2 <= 4096 or (self._seg_sample_ctr_c & 7) == 0:
                     _segs_c = self._note_seg_count(
-                        self._host_seg_count((res_a, ctx_a, org_a), pad_to=B2),
+                        self._host_seg_count(
+                            tuple(
+                                x for x in (res_a, ctx_a, org_a) if x is not None
+                            ),
+                            pad_to=B2,
+                        ),
                         B2, cfg.complete_batch_size,
                     )
                     _segs = _segs or _segs_c
@@ -3556,33 +3664,42 @@ class SentinelClient:
             # max_batch_count, the acquire side's envelope)
             live = {f: v[..., :n] for f, v in vc.items()}
 
-            def put(f, x):
-                if f not in placed:
+            def put(f, x, absent=None):
+                nonlocal _abs_c
+                if x is None:
+                    live[f][...] = absent
+                    _abs_c += 1
+                elif f not in placed:
                     np.copyto(live[f], x, casting="unsafe")
 
-            def put_count(f, x):
-                if clamp:
+            def put_count(f, x, absent):
+                if clamp and x is not None:
                     np.minimum(
                         x, cfg.max_batch_count, out=live[f], casting="unsafe"
                     )
                 else:
-                    put(f, x)
+                    put(f, x, min(absent, cfg.max_batch_count) if clamp else absent)
 
             put("res", res_a)
-            put("origin_node", org_a)
-            put("ctx_node", ctx_a)
-            np.bitwise_and(
-                flags_a, FLAG_INBOUND, out=live["inbound"], casting="unsafe"
-            )
+            put("origin_node", org_a, trash)
+            put("ctx_node", ctx_a, trash)
+            if flags_a is None:
+                put("inbound", None, 0)
+            else:
+                np.bitwise_and(
+                    flags_a, FLAG_INBOUND, out=live["inbound"], casting="unsafe"
+                )
             put("rt", rt_a)
-            put_count("success", cnt_a)
-            put_count("error", err_a)
+            put_count("success", cnt_a, 1)
+            put_count("error", err_a, 0)
             lanes = live["param_hash"]  # (M, n): lane by lane
             for k in range(M):
-                if k >= len(aux_a):
+                if k >= len(aux_a) or aux_a[k] is None:
                     lanes[k] = 0
                 elif "param_hash" not in placed:
                     np.copyto(lanes[k], aux_a[k], casting="unsafe")
+            if all(x is None for x in aux_a):
+                _abs_c += 1
             for c in wb.layout.comp:
                 vc[c.field][..., n:] = c.fill
         else:
@@ -3604,9 +3721,12 @@ class SentinelClient:
                 trace=tick_id,
                 # puts: host-to-device transfers made for this tick's
                 # input; put_ns: the time they held this thread
+                # absent_a / absent_c: the columns of each side that no row
+                # carried, written as a fill
                 attrs={
                     "b": B, "b2": B2, "puts": puts, "tx_bytes": tx_bytes,
                     "put_ns": (_t_disp or OT.now_ns()) - _t_put,
+                    "absent_a": _abs_a, "absent_c": _abs_c,
                 },
             )
             if _ns_presort:

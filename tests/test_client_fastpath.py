@@ -493,6 +493,273 @@ def test_run_tick_uploads_the_parents_presorted_columns(vt, fill, monkeypatch):
         assert cb[name].tobytes() == w.tobytes(), name
 
 
+class _FrozenTime(TimeSource):
+    """Engine time stands still, and the client stays threaded (a
+    VirtualTimeSource makes it sync): verdicts do not depend on how long
+    a tick thread took."""
+
+    def now_ms(self):
+        return 2_000
+
+
+# -- absent columns stay absent ------------------------------------------------
+#
+# A column that no row of a tick carries is never materialised (client.py
+# _run_tick, _join_completions): the tests below hold the bytes that cross to
+# what the same rows give with every column passed at its default.
+
+_ABSENT_B = 2048  # rehearsal size
+
+_L, _M, _F = (256, 256), (512, 512), (_ABSENT_B, _ABSENT_B)
+
+#: case -> (acquire pieces' rows, of which carry counts, object requests,
+#: completions through the ring, what a piece carries, the ticks' shapes)
+_ABSENT_CASES = {
+    "light": ((100, 100), (), 0, 0, "generator", [_L]),  # n < B
+    "middle": ((150, 150, 150), (), 0, 0, "generator", [_M]),
+    "full-part": ((500, 500, 500), (), 0, 0, "generator", [_F]),
+    # 2,100 rows a side: a tick with n == B whose third piece is cut, then
+    # a light one of the remainder
+    "full": ((700, 700, 700), (), 0, 0, "generator", [_F, _L]),
+    "counts-beside": ((300, 300), (0,), 0, 0, "generator", [_F]),
+    "objects-beside": ((200, 200), (), 5, 0, "generator", [_M]),
+    "ring-and-blocks": ((120, 120), (), 0, 20, "generator", [_M]),
+    "bare": ((90, 400), (), 0, 0, "nothing", [_M]),  # res alone, (res, rt) alone
+}
+
+
+def _absent_traffic(cfg, rng, case):
+    """The case's rows, seeded: per acquire piece and per completion piece
+    the keyword columns a caller passes (``some``) and the same with every
+    optional column passed at its default (``every``)."""
+    from sentinel_tpu.runtime.client import AcquireRequest, Completion
+
+    pieces, with_counts, n_obj, n_ring, carries, _shapes = _ABSENT_CASES[case]
+    trash, M = cfg.trash_row, cfg.param_dims
+    i32 = lambda lo, hi, n: rng.integers(lo, hi, n).astype(np.int32)
+    acq, comp = [], []
+    for k, n in enumerate(pieces):
+        ids = i32(1, 48, n)
+        ids[rng.random(n) < 0.05] = trash  # a few live rows on the trash row
+        some = {}
+        if carries == "generator":
+            some = dict(
+                origin_node=i32(50, 54, n), origin_id=i32(-1, 3, n),
+                inbound=i32(0, 2, n), param_hash=i32(0, 9, (n, M)),
+            )
+        if k in with_counts:
+            some["counts"] = i32(1, 70000, n)  # over the clamp
+        every = dict(
+            counts=np.ones(n, np.int32), prio=np.zeros(n, np.int32),
+            origin_id=np.full(n, -1, np.int32), origin_node=np.full(n, trash, np.int32),
+            ctx_node=np.full(n, trash, np.int32), ctx_name=np.full(n, -1, np.int32),
+            inbound=np.zeros(n, np.int32), param_hash=np.zeros((n, M), np.int32),
+            pre_verdict=np.zeros(n, np.int32),
+        )
+        acq.append((ids, some, {**every, **some}))
+        c_some = {f: some[f] for f in ("inbound", "param_hash") if f in some}
+        c_every = dict(
+            success=np.ones(n, np.int32), error=np.zeros(n, np.int32),
+            inbound=np.zeros(n, np.int32), origin_node=np.full(n, trash, np.int32),
+            ctx_node=np.full(n, trash, np.int32), param_hash=np.zeros((n, M), np.int32),
+        )
+        comp.append((ids, rng.random(n).astype(np.float32), c_some, {**c_every, **c_some}))
+    objs = [
+        dict(
+            res=int(r), count=int(rng.integers(1, 4)), prio=int(rng.integers(0, 2)),
+            origin_id=int(rng.integers(-1, 3)), origin_node=int(rng.integers(50, 54)),
+            ctx_node=int(rng.integers(54, 58)), ctx_name=int(rng.integers(-1, 2)),
+            inbound=int(rng.integers(0, 2)),
+            param_hash=tuple(int(v) for v in rng.integers(0, 9, M)),
+        )
+        for r in i32(1, 48, n_obj)
+    ]
+    ring = [
+        Completion(
+            res=int(r), origin_node=int(rng.integers(50, 54)),
+            ctx_node=int(rng.integers(54, 58)), inbound=int(rng.integers(0, 2)),
+            rt=float(rng.integers(1, 90)), success=int(rng.integers(1, 70000)),
+            error=int(rng.integers(0, 3)),
+            param_hash=tuple(int(v) for v in rng.integers(0, 9, M)),
+        )
+        for r in i32(1, 48, n_ring)
+    ]
+    return acq, comp, [lambda o=o: AcquireRequest(**o) for o in objs], ring
+
+
+def _absent_client(monkeypatch, cfg_kw=None, **kw):
+    """A threaded client that is never started and whose ticks go nowhere:
+    ``tick_once`` drains and builds, the spy keeps every built tick."""
+    c = SentinelClient(
+        cfg=small_engine_config(
+            **SEG, batch_size=_ABSENT_B, complete_batch_size=_ABSENT_B,
+            **(cfg_kw or {}),
+        ),
+        time_source=_FrozenTime(), mode="threaded", pipeline_depth=0, **kw,
+    )
+    built = []
+    real = c._run_tick
+
+    def run_tick(*a, **kw):
+        built.append(real(*a, **kw))
+        return built[-1]
+
+    monkeypatch.setattr(c, "_run_tick", run_tick)
+    monkeypatch.setattr(c, "_tick", lambda state, rules, wire_in: (state, None))
+    monkeypatch.setattr(c, "_resolve_tick", lambda p: None)
+    # the header carries the load and the CPU share: held still
+    monkeypatch.setattr(c, "_sys", SimpleNamespace(sample=lambda: (0.5, 0.25)))
+    return c, built
+
+
+@pytest.mark.parametrize("lib", ["native", "numpy"])
+@pytest.mark.parametrize("case", list(_ABSENT_CASES))
+def test_a_tick_of_absent_columns_uploads_the_bytes_of_explicit_defaults(
+    monkeypatch, case, lib
+):
+    """Pieces that leave their optional columns out against the same pieces
+    with every column passed at its default: every input buffer is byte-equal
+    (into views poisoned beforehand, so each is written whole) and inv_a too;
+    light, middle and full shape, n < B and n == B, a piece with counts beside
+    one without, object requests beside blocks, completions from the ring and
+    from blocks at once, with the native library and without."""
+    import sentinel_tpu.native.ring as RM
+
+    if lib == "numpy":
+        monkeypatch.setattr(RM, "load_native", lambda: None)
+    elif RM.load_native() is None:
+        pytest.skip("no native library here")
+    c, built = _absent_client(monkeypatch)
+    try:
+        acq, comp, objs, ring = _absent_traffic(c.cfg, np.random.default_rng(43), case)
+        sent = {}
+        for which in ("some", "every"):
+            if which == "some":
+                # the first side builds into views that hold something else
+                for b, b2 in WIRE.tick_shapes(c.cfg):
+                    wb = c._input_buffer(c.cfg, b, b2)
+                    for v in (*wb.acq.values(), *wb.comp.values()):
+                        v.view(np.uint8).fill(0xA5)
+                    c._wire_free[wb.layout].append(wb)
+            for r in ring:
+                c._submit_completion(r)
+            c._acquires.extend(mk() for mk in objs)
+            for (ids, some, every), (_ids, rt, c_some, c_every) in zip(acq, comp):
+                c.submit_block(ids, **(some if which == "some" else every))
+                c.submit_completion_block(
+                    ids, rt, **(c_some if which == "some" else c_every)
+                )
+            del built[:]
+            c.tick_once(now_ms=1_000)
+            sent[which] = [
+                (p.wire_in.layout, p.wire_in.buf.tobytes(), p.inv_a.tobytes())
+                for p in built
+            ]
+        shapes = [(lo.b, lo.b2) for lo, _b, _i in sent["some"]]
+        assert shapes == _ABSENT_CASES[case][-1]
+        assert sent["some"] == sent["every"]
+    finally:
+        c.stop()
+
+
+def test_the_audits_shadow_folds_an_absent_count_as_its_constant(monkeypatch):
+    """The sketch audit's shadow reads the tick's res and clamped count
+    columns: a tick whose pieces carry no counts gives it the constant, and
+    it folds the volume it folds for the same pieces with counts of one."""
+    folded = {}
+    for which in ("some", "every"):
+        c, _built = _absent_client(
+            monkeypatch, cfg_kw=dict(sketch_stats=True, sketch_width=256),
+            sketch_audit_k=4,
+        )
+        try:
+            acq, _comp, _objs, _ring = _absent_traffic(
+                c.cfg, np.random.default_rng(45), "full-part"
+            )
+            for ids, some, every in acq:
+                c.submit_block(ids, **(some if which == "some" else every))
+            c.tick_once(now_ms=1_000)
+            assert ("a.count" in {k[0] for k in c._stage}) is (which == "every")
+            folded[which] = (dict(c._audit._vol), dict(c._audit._tracked))
+        finally:
+            c.stop()
+    live = sum(int((ids != c.cfg.trash_row).sum()) for ids, _s, _e in acq)
+    assert folded["some"] == folded["every"]
+    assert list(folded["some"][0].values()) == [live]
+
+
+def test_block_traffic_as_the_generators_send_it_stages_the_carried_columns_only(
+    monkeypatch,
+):
+    """Counts that hold on the CPU: blocks with res, the origin, inbound and
+    the hot-param lanes and nothing else leave no staging slot for the five
+    columns they do not carry, the presort gathers four acquire columns and
+    five completion columns, a queued completion block holds None where the
+    caller passed nothing, and the spans say so (absent_a, absent_c, joined)."""
+    import sentinel_tpu.native.ring as RM
+    from sentinel_tpu import obs
+
+    c, built = _absent_client(monkeypatch)
+    calls = []
+    real = RM.presort
+
+    def spy(keys, n_live, order, inv, scratch, src=(), dst=(), wide=None, wide_dst=None):
+        calls.append((len(keys), len(src), wide is not None))
+        return real(keys, n_live, order, inv, scratch, src, dst, wide, wide_dst)
+
+    monkeypatch.setattr(RM, "presort", spy)
+    rng = np.random.default_rng(44)
+    M = c.cfg.param_dims
+    i32 = lambda lo, hi, n: rng.integers(lo, hi, n).astype(np.int32)
+
+    def feed(n):
+        ids, ph, inb = i32(1, 48, n), i32(0, 9, (n, M)), i32(0, 2, n)
+        c.submit_block(
+            ids, origin_node=i32(50, 54, n), origin_id=i32(-1, 3, n),
+            param_hash=ph, inbound=inb,
+        )
+        c.submit_completion_block(ids, rng.random(n).astype(np.float32),
+                                  inbound=inb, param_hash=ph)
+
+    obs.TRACER.reset()
+    obs.enable()
+    try:
+        feed(300)
+        (queued,) = c._comp_blocks
+        res, success, origin_node, ctx_node, flags, rt, error, tag, *aux = queued
+        assert [x is None for x in (success, origin_node, ctx_node, error, tag)] == [True] * 5
+        assert [x is None for x in aux] == [False] * M + [True] * (4 - M)
+        assert flags.dtype == np.int32 and rt.dtype == np.float32
+        c.tick_once(now_ms=1_000)  # one piece a side
+        feed(200)
+        feed(200)
+        c.tick_once(now_ms=1_001)  # two pieces a side
+    finally:
+        obs.disable()
+        c.stop()
+    assert len(built) == 2
+    slots = {name for name, _shape, _dt in c._stage}
+    assert slots >= {"a.res", "a.origin_id", "a.origin_node", "a.inbound", "a.ph"}
+    assert not slots & {
+        "a.count", "a.prio", "a.ctx_node", "a.ctx_name", "a.pre_verdict",
+        "s.count", "s.prio", "s.pre_verdict", "sc.1", "sc.6",
+    }
+    # (keys, columns, the wide one): res, origin_node and origin_id order the
+    # acquire side, res alone the completions
+    assert calls == [(3, 4, True), (1, 3 + M, False)] * 2
+    spans = obs.TRACER.snapshot()
+    asm = [s["attrs"] for s in spans if s["name"] == "tick.assemble"]
+    assert [(a["absent_a"], a["absent_c"]) for a in asm] == [(5, 4)] * 2
+    drains = [s["attrs"] for s in spans if s["name"] == "tick.drain"]
+    # res, flags, rt and the M lanes of two pieces; one piece goes on as it is
+    assert [d["joined"] for d in drains] == [0, 3 + M]
+    # counted where the benchmark's span_summary prints it
+    summary = obs.summarize(spans)
+    assert summary["tick.assemble"]["absent_a"] == {5: 2}
+    assert summary["tick.assemble"]["absent_c"] == {4: 2}
+    assert summary["tick.drain"]["joined"] == {0: 1, 3 + M: 1}
+
+
 class _Door:
     """A front door's response ring: keeps what the resolver answers."""
 
@@ -672,15 +939,6 @@ class _Gate:
     def sleep(self, _seconds):
         self.held.release()
         assert self.open.wait(60), "the test never opened the gate"
-
-
-class _FrozenTime(TimeSource):
-    """Engine time stands still, and the client stays threaded (a
-    VirtualTimeSource makes it sync): verdicts do not depend on how long
-    a tick thread took."""
-
-    def now_ms(self):
-        return 2_000
 
 
 def _stalled_client(monkeypatch, depth, **kw):
